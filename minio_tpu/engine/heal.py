@@ -379,11 +379,12 @@ def _reconstruct_rows(es: ErasureSet, fi: FileInfo,
     # batch stacking, no per-block loop (native ec_gf_rows, GFNI when
     # the CPU has it).
     if not es._use_device and k + m <= 64:
+        from native import ecio_native
+        from native._build import BuildError
         try:
-            from native import ecio_native
             return ecio_native.gf_transform_rows(
                 [rows[s] for s in use], list(use), k, m, list(need))
-        except Exception:  # noqa: BLE001 — no toolchain: batch path
+        except BuildError:  # no toolchain: batch path
             pass
     # Split logical shard into full-block matrix + tail.
     n_full = logical // shard_size
@@ -740,7 +741,7 @@ def _heal_part_pipelined(es: ErasureSet, bucket: str, obj: str,
                     rebuilt = np.asarray(rebuilt) if need else None
             else:
                 if co is not None and co.hot(es.device_idx):
-                    h = co.submit(("digest", algo, S),
+                    h = co.submit(("digest", algo, S, 0),
                                   x.reshape(nb * k, S),
                                   coalesce.make_digest_kernel(algo),
                                   weight=nb, device=es.device_idx)

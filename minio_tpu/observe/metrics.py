@@ -1067,7 +1067,7 @@ class MetricsRegistry:
             "mtpu_bpool_in_use_bytes", "Aligned-pool bytes leased out")
         # Device-resident shard plane (ops/devcache.py) + host->device
         # boundary ledger: the instrumented proof that object bytes
-        # cross the tunnel at most once (first touch ~1.0 byte crossed
+        # cross the boundary at most once (first touch ~1.0 byte crossed
         # per byte served, ~0 on cache hits).
         self.devcache_hits = Gauge(
             "mtpu_devcache_hits_total",

@@ -827,29 +827,27 @@ class DispatchCoalescer:
 
 # -- shared kernels ----------------------------------------------------------
 
-def make_digest_kernel(algo: str, pad_rows: int = 0):
+def make_digest_kernel(algo: str, pad_rows: int = 0,
+                       device: int | None = None):
     """Batched bitrot digest over stacked (N, S) rows — the healthy-GET
-    verify and heal-verify workhorse.  `pad_rows`: bound jit shapes on
-    device backends (0 = host kernels, no padding needed)."""
+    verify and heal-verify workhorse.  `pad_rows` > 0 asks for the
+    device digest program, on lane `device`, with rows zero-padded to
+    that multiple so jit shapes stay bounded; 0 (or an algorithm whose
+    host kernel is preferred) hashes with the host kernels.  The
+    submitter's key carries `pad_rows`, like every parameter a kernel
+    closes over."""
     from ..storage import bitrot_io
-
-    def kernel(stacked, spans, ctx):
-        if pad_rows:
-            x, n = pad_batch(stacked, pad_rows)
-            out = bitrot_io._hash_batch(x, algo)[:n]
-        else:
-            out = bitrot_io._hash_batch(stacked, algo)
-        return [out[lo:hi] for lo, hi in spans]
 
     if pad_rows:
         from . import fused
 
         if algo in fused.DEVICE_ALGOS and bitrot_io.device_preferred(algo):
-            # Pipeline form: the lane pre-placed the (padded) rows on
-            # its device — hash them asynchronously and defer the sync
-            # to resolve().  Same algorithm, same digests, as
-            # _hash_batch produces for the serial path.
+            from . import devices
+
             def launch(x, n, spans, ctx):
+                # Pipeline form: the lane pre-placed the (padded) rows
+                # on its device — hash them asynchronously and defer
+                # the sync to resolve().
                 out_dev = fused.hash_rows_async(x, algo)
 
                 def resolve():
@@ -858,8 +856,17 @@ def make_digest_kernel(algo: str, pad_rows: int = 0):
 
                 return resolve
 
+            def kernel(stacked, spans, ctx):
+                x, n = pad_batch(stacked, pad_rows)
+                return launch(devices.put(x, device), n, spans, ctx)()
+
             kernel.launch = launch
             kernel.pad_rows = pad_rows
+            return kernel
+
+    def kernel(stacked, spans, ctx):
+        out = bitrot_io._hash_batch(stacked, algo)
+        return [out[lo:hi] for lo, hi in spans]
 
     return kernel
 

@@ -30,27 +30,70 @@ cached.
 from __future__ import annotations
 
 import os
+import sys
 
-_VISIBLE: tuple[list, str] | None = None
+#: (devices, platform, device_kind, device_count) — the ONE answer to
+#: "what does this deployment compute on".  `devices` holds the jax
+#: Device objects in the process that owns them and is empty in a
+#: pre-fork pool worker, which adopts the owner's answer instead of
+#: asking JAX (a chip belongs to one process).
+_VISIBLE: tuple[list, str, str, int] | None = None
 
 
-def _visible() -> tuple[list, str]:
-    """(devices, backend) — cached; device topology is fixed per
-    process.  Import of jax is deferred to first use so import-light
-    processes (the pre-fork supervisor) never pay for it."""
+def _visible() -> tuple[list, str, str, int]:
+    """Cached; device topology is fixed per process.  Import of jax is
+    deferred to first use so import-light processes (the pre-fork
+    supervisor) never pay for it.  A jax that cannot start raises: it
+    is an error, not a host lane."""
     global _VISIBLE
     if _VISIBLE is None:
-        try:
-            import jax
+        import jax
 
-            _VISIBLE = (list(jax.devices()), jax.default_backend())
-        except Exception:  # noqa: BLE001 — no jax → single host lane
-            _VISIBLE = ([], "none")
+        devs = list(jax.devices())
+        _VISIBLE = (devs, jax.default_backend(), devs[0].device_kind,
+                    len(devs))
     return _VISIBLE
 
 
+def adopt(platform: str, kind: str, count: int) -> None:
+    """Pool worker: take the device owner's answer.  The worker holds
+    no device itself (`jax_device` is None), so anything it decides
+    from the platform routes to the owner."""
+    global _VISIBLE
+    _VISIBLE = ([], platform, kind, int(count))
+
+
+def on_tpu() -> bool:
+    """Whether the deployment's shard math runs on a TPU."""
+    return _visible()[1] == "tpu"
+
+
+def local_tpu() -> bool:
+    """on_tpu() AND this process is the one holding the chip."""
+    devs, plat, _, _ = _visible()
+    return plat == "tpu" and bool(devs)
+
+
+def describe() -> dict:
+    """The four values the boot line and healthinfo's `device` block
+    carry, plus whether THIS process has initialised a jax backend (a
+    pool worker must answer false: the devices are its owner's)."""
+    _, plat, kind, count = _visible()
+    xb = sys.modules.get("jax._src.xla_bridge")
+    return {"platform": plat, "kind": kind, "count": count,
+            "lanes": n_devices(),
+            "in_process": bool(xb and xb.backends_are_initialized())}
+
+
+def boot_line(info: dict | None = None) -> str:
+    """The one line a serving process prints about its devices."""
+    d = info or describe()
+    return (f"minio_tpu: device platform={d['platform']} "
+            f"kind={d['kind']!r} count={d['count']} lanes={d['lanes']}")
+
+
 def visible_count() -> int:
-    return max(1, len(_visible()[0]))
+    return _visible()[3]
 
 
 def n_devices() -> int:
@@ -62,10 +105,8 @@ def n_devices() -> int:
         except ValueError:
             n = 1
         return max(1, min(n, visible_count()))
-    devs, backend = _visible()
-    if backend == "tpu" and len(devs) > 1:
-        return len(devs)
-    return 1
+    _, plat, _, count = _visible()
+    return count if plat == "tpu" else 1
 
 
 def device_for_set(set_index: int) -> int:
@@ -75,10 +116,10 @@ def device_for_set(set_index: int) -> int:
 
 
 def jax_device(idx: int):
-    """The jax Device lane `idx` dispatches on (None when jax is
-    unavailable).  Indices wrap over the visible topology so a lane
-    index is always placeable."""
-    devs, _ = _visible()
+    """The jax Device lane `idx` dispatches on (None in a pool worker,
+    which holds none).  Indices wrap over the visible topology so a
+    lane index is always placeable."""
+    devs = _visible()[0]
     if not devs:
         return None
     return devs[int(idx) % len(devs)]
@@ -108,8 +149,8 @@ def put(x, device_idx: int | None):
 
 
 def _reset_after_fork() -> None:
-    # A forked child may land on a different backend (workers re-import
-    # jax post-fork); drop the cached topology.
+    # The supervisor forks before anything asks; a child asks (owner)
+    # or adopts (worker) for itself.
     global _VISIBLE
     _VISIBLE = None
 

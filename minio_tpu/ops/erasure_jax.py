@@ -128,7 +128,8 @@ class ReedSolomonTPU:
         self.parity_shards = parity_shards
         self.total_shards = data_shards + parity_shards
         if use_pallas is None:
-            use_pallas = jax.default_backend() == "tpu"
+            from . import devices
+            use_pallas = devices.on_tpu()
         self.use_pallas = use_pallas
 
     # -- core primitive -------------------------------------------------------

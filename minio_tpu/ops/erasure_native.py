@@ -2,8 +2,8 @@
 
 Role: SURVEY.md §7 hard-part #5 ("a TPU failure must degrade, not
 corrupt") and the honest host-path e2e numbers: when the process has no
-TPU — or the TPU is only reachable over a slow tunnel — the erasure
-engine runs shard math through native/rs_cpu.cc, the same vpshufb
+TPU the erasure engine runs shard math through native/rs_cpu.cc, the
+same vpshufb
 nibble-table technique as the reference's klauspost/reedsolomon assembly
 (go.mod:41).  Tables come from the repo's own gf256, so bytes on disk
 are identical to the device path's (differentially tested).

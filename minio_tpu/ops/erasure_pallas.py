@@ -35,12 +35,13 @@ DEFAULT_TILE_S = 8192
 FORCE_INTERPRET = False
 
 
-def _choose_tile_s(s: int) -> int | None:
-    """Largest multiple-of-128 tile <= DEFAULT_TILE_S that divides s."""
-    for t in range(min(DEFAULT_TILE_S, s - s % 128), 0, -128):
+def _choose_tile_s(s: int) -> int:
+    """Largest multiple-of-128 tile <= DEFAULT_TILE_S that divides s
+    (s is a positive multiple of 128, so 128 always does)."""
+    for t in range(min(DEFAULT_TILE_S, s), 0, -128):
         if s % t == 0:
             return t
-    return None
+    raise ValueError(f"shard size {s} is not a positive multiple of 128")
 
 
 def _unpack_mm_pack(x, mat_ref, rows: int):
@@ -106,22 +107,29 @@ def gf_matmul_blocks(mat_bits: jax.Array | np.ndarray, x: jax.Array,
     """Fused-kernel GF(2^8) batched matmul; drop-in for the XLA path.
 
     mat_bits: (8R, 8C) plane-major bit matrix; x: (B, C, S) uint8 shards.
-    Falls back to the portable XLA path when the geometry doesn't tile
-    (shard size not a multiple of 128) or when off-TPU outside tests.
+    On a TPU every geometry runs the kernel: a shard size that is not a
+    lane multiple (K = 3, 5, 6, 7, 12 over a 1 MiB block) is zero-padded
+    up to the next multiple of 128 and the output sliced back — GF
+    matmul is bytewise along S, so the pad's output is simply dropped.
+    Off-TPU the portable XLA path (ops/erasure_jax.py) serves, unless a
+    test sets FORCE_INTERPRET to run the kernel in the interpreter.
 
     salt: optional (1,) int32 — xors every input byte inside the kernel
     (benchmark protocol; production passes None and pays nothing).
     """
-    from . import erasure_jax
+    from . import devices, erasure_jax
 
     x = jnp.asarray(x, dtype=jnp.uint8)
     b, c, s = x.shape
     mat = jnp.asarray(mat_bits, dtype=jnp.bfloat16)
-    tile_s = _choose_tile_s(s)
-    on_tpu = jax.default_backend() == "tpu"
-    if (not on_tpu and not FORCE_INTERPRET) or tile_s is None or b == 0:
+    on_tpu = devices.on_tpu()
+    if (not on_tpu and not FORCE_INTERPRET) or b == 0 or s == 0:
         if salt is not None:
             x = x ^ salt[0].astype(jnp.uint8)
         return erasure_jax._gf_matmul_blocks(mat, x, rows)
-    return _pallas_gf_matmul(mat, x, rows, tile_s, interpret=not on_tpu,
-                             salt=salt)
+    pad = -s % 128
+    if pad:
+        x = jnp.pad(x, ((0, 0), (0, 0), (0, pad)))
+    out = _pallas_gf_matmul(mat, x, rows, _choose_tile_s(s + pad),
+                            interpret=not on_tpu, salt=salt)
+    return out[:, :, :s] if pad else out

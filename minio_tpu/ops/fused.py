@@ -67,7 +67,7 @@ def _placed(x, device: int | None):
     Inputs that are ALREADY jax arrays (a coalescer lane's pipelined
     staging upload, a devcache-resident batch) pass straight through —
     they crossed the boundary once when they were placed, and the h2d
-    ledger counted them there; re-placing would both double the tunnel
+    ledger counted them there; re-placing would both double the
     crossing and double the count."""
     if isinstance(x, jax.Array):
         return x
@@ -81,14 +81,6 @@ def _placed(x, device: int | None):
         return jnp.asarray(x, dtype=jnp.uint8)
     devcache.note_h2d(nbytes, device)
     return jax.device_put(jnp.asarray(x, dtype=jnp.uint8), dev)
-
-
-def donate_ok() -> bool:
-    """Input-buffer donation is only a win (and only warning-free) on
-    accelerator backends where XLA actually reuses the device
-    allocation; the host-CPU backend ignores donations with a warning
-    per dispatch, so gate it off there."""
-    return devices_mod._visible()[1] in ("tpu", "gpu")
 
 
 def _digest_rows(x2d: jax.Array, algo: str, key: bytes) -> jax.Array:
@@ -173,8 +165,7 @@ def verify_and_transform(x, k: int, m: int, sources: tuple[int, ...],
 
 
 @functools.lru_cache(maxsize=64)
-def _encode_hash_jit(k: int, m: int, algo: str, key: bytes,
-                     donate: bool = False):
+def _encode_hash_jit(k: int, m: int, algo: str, key: bytes):
     mat = jnp.asarray(erasure_jax._encode_matrix_bits(k, m),
                       dtype=jnp.bfloat16)
 
@@ -187,27 +178,20 @@ def _encode_hash_jit(k: int, m: int, algo: str, key: bytes,
             algo, key).reshape(kk + m, b, 32)
         return parity, digests
 
-    return jax.jit(fn, donate_argnums=(0,) if donate else ())
+    return jax.jit(fn)
 
 
 def encode_and_hash(x, k: int, m: int, algo: str = "highwayhash256S",
                     key: bytes = MAGIC_KEY,
-                    device: int | None = None,
-                    donate: bool = False):
+                    device: int | None = None):
     """((B, K, S) data) -> ((B, M, S) parity, (K+M, B, 32) digests).
 
     The PUT hot path: parity AND per-shard-block bitrot digests in one
     device dispatch; framing on the host is then pure byte interleaving.
     Digest layout is shard-major to match frame_shards_batch's
     (n_shards, n_blocks) order.  `device` places the dispatch on that
-    coalescer lane's device (None = default device).  `donate=True`
-    hands the placed input buffer to XLA for reuse — legal because the
-    encode input is placement-owned (nothing retains it after the
-    dispatch; the devcache only ever retains VERIFY inputs), and only
-    honored on accelerator backends (donate_ok)."""
+    coalescer lane's device (None = default device)."""
     x = _placed(x, device)
     return _traced_dispatch(
-        "device.encode_hash",
-        _encode_hash_jit(k, m, algo, key,
-                         donate=bool(donate) and donate_ok()), x,
+        "device.encode_hash", _encode_hash_jit(k, m, algo, key), x,
         device=device)

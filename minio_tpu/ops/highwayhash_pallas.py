@@ -163,6 +163,7 @@ def supported(n_streams: int, n_packets: int) -> bool:
     in hh256_batch_jax (part of the jit cache key); this checks only
     backend/shape feasibility.
     """
-    return (jax.default_backend() == "tpu"
+    from . import devices
+    return (devices.local_tpu()
             and n_packets >= PB
             and n_streams >= SBLK // 4)

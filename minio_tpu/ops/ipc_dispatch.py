@@ -34,6 +34,10 @@ Fallback ladder (liveness beats packing, always):
     up);
   * any per-item owner error -> the handle raises and the engine's
     existing per-request direct fallback recomputes the span.
+Each rung is counted.  A worker holds no device (a chip belongs to the
+owner), so on a TPU deployment a rung that would compute a DEVICE
+kernel in the worker raises instead — the request fails loudly and the
+respawned owner serves the retry; host kernels fall back as above.
 
 Routing policy (`MTPU_IPC_DISPATCH`):
   * ``auto`` (default) — only kernels that need the accelerator route
@@ -120,12 +124,13 @@ def _owner_codec(tag: str, k: int, m: int):
         from .erasure import ReedSolomonTPU
         c = ReedSolomonTPU(k, m)
     else:
+        from native import rs_comparator
+        from native._build import BuildError
         try:
-            from native import rs_comparator
             rs_comparator.load()
             from .erasure_native import ReedSolomonNative
             c = ReedSolomonNative(k, m)
-        except Exception:  # noqa: BLE001 — no g++/ISA: portable codec
+        except BuildError:  # no toolchain: portable codec
             from .erasure import ReedSolomonTPU
             c = ReedSolomonTPU(k, m)
     with _CODEC_MU:
@@ -173,9 +178,9 @@ def _enc_kernel(tag: str, k: int, m: int, algo: str,
 
         def launch(x, n, spans, ctx):
             # Pipeline form (lane-staged device input, sync deferred to
-            # resolve) — same donation rule as the in-process kernel.
+            # resolve).
             parity_d, digests_d = fused.encode_and_hash(
-                x, k, m, algo=algo, device=device, donate=True)
+                x, k, m, algo=algo, device=device)
 
             def resolve():
                 parity = np.asarray(parity_d)[:n]
@@ -255,8 +260,9 @@ def kernel_from_key(key: tuple, device: int | None = None):
     registry does not know (the worker then keeps them local)."""
     kind = key[0]
     if kind == "digest":
-        _, algo, _shard = key
-        return coalesce.make_digest_kernel(algo)
+        _, algo, _shard, pad_rows = key
+        return coalesce.make_digest_kernel(str(algo), int(pad_rows),
+                                           device=device)
     if kind == "pf":
         _, k, m, shard = key
         return _pf_kernel(int(k), int(m), int(shard))
@@ -471,14 +477,10 @@ class RemoteCoalescer:
 
     @staticmethod
     def _device_backend() -> bool:
-        from ..engine import erasure_set as es
-        if es._USE_DEVICE is None:
-            try:
-                import jax
-                es._USE_DEVICE = jax.default_backend() == "tpu"
-            except Exception:  # noqa: BLE001 — no jax: host only
-                es._USE_DEVICE = False
-        return bool(es._USE_DEVICE)
+        # The worker adopted the owner's platform at boot
+        # (server/workers.py); it never asks JAX itself.
+        from . import devices
+        return devices.on_tpu()
 
     def _submit_remote(self, key: tuple, payload, weight,
                        device: int = 0) -> RemoteHandle:

@@ -111,17 +111,15 @@ def device_lane_self_test() -> None:
     serving (PR 10 device sharding): a device whose compiled kernels or
     HBM produce wrong bytes must refuse to boot, named by index, rather
     than corrupt the slice of erasure sets affine to it.  Single-lane
-    hosts run exactly one pass (the historical default-device check);
-    skips silently when jax is unavailable."""
+    hosts run exactly one pass (the historical default-device check).
+    Runs in the process that holds the devices — in a pre-fork pool
+    that is the device owner, never a worker."""
     import numpy as np
 
     from . import devices as devices_mod
+    from . import fused
     from .erasure_cpu import ReedSolomonCPU
     from .mxhash import mxh256
-
-    if devices_mod.jax_device(0) is None:
-        return
-    from . import fused
 
     k, m, s = 2, 2, 128
     rng = np.random.default_rng(0xD0D)
@@ -200,12 +198,15 @@ def metrics_registry_self_test() -> None:
             + ", ".join(sorted(missing)))
 
 
-def run_startup_self_tests() -> None:
+def run_startup_self_tests(device: bool = True) -> None:
+    """`device=False` in a pool worker: the device owner runs the
+    device-lane test, since a chip belongs to one process."""
     erasure_self_test()
     bitrot_self_test()
     mxhash_self_test()
     digest_self_test()
-    device_lane_self_test()
+    if device:
+        device_lane_self_test()
     metrics_registry_self_test()
     # Fail boot on a misconfigured bitrot write algorithm (clear config
     # error now, not a confusing per-request failure later).

@@ -26,8 +26,8 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from ..ops import erasure_jax
 from ..ops.erasure_jax import _encode_matrix_bits, _transform_matrix_bits
+from ..ops.erasure_pallas import gf_matmul_blocks
 
 
 def make_mesh(n_devices: int | None = None,
@@ -52,6 +52,11 @@ class ShardedCodec:
 
     Single-chip geometry stays identical; the mesh only changes placement —
     by design, so that bytes produced under any mesh match the CPU oracle.
+    Each device's share of the GF matmul goes through the same entry
+    point as the single-device path (the Pallas kernel on a TPU), so the
+    shard_maps run with check_vma=False: the static VMA check cannot see
+    through a pallas_call (nor infer that an all_gather's output is
+    replicated over "lanes").
     """
 
     def __init__(self, data_shards: int, parity_shards: int, mesh: Mesh):
@@ -74,11 +79,11 @@ class ShardedCodec:
         def step(x):
             # Elementwise along lanes + batched over blocks: no collectives;
             # XLA keeps everything local to each device.
-            return erasure_jax._gf_matmul_blocks(mat, x, self.m)
+            return gf_matmul_blocks(mat, x, self.m)
 
         return jax.jit(
             jax.shard_map(step, mesh=mesh, in_specs=(in_spec,),
-                          out_specs=out_spec))
+                          out_specs=out_spec, check_vma=False))
 
     def encode_blocks(self, data: jax.Array | np.ndarray) -> jax.Array:
         """(B, K, S) -> (B, M, S), B sharded over "blocks", S over "lanes"."""
@@ -115,14 +120,12 @@ class ShardedCodec:
         def step(x_local):
             # x_local: (B_local, K/axis, S) — gather full K rows on-device.
             x_full = jax.lax.all_gather(x_local, "lanes", axis=1, tiled=True)
-            return erasure_jax._gf_matmul_blocks(mat, x_full, n_t)
+            return gf_matmul_blocks(mat, x_full, n_t)
 
         return jax.jit(
             jax.shard_map(step, mesh=mesh,
                           in_specs=(P("blocks", "lanes", None),),
                           out_specs=P("blocks", None, None),
-                          # all_gather output is replicated over "lanes"; the
-                          # static VMA check cannot infer that here.
                           check_vma=False))
 
     def reconstruct_blocks(self, shards, sources: tuple[int, ...],
@@ -142,7 +145,7 @@ class ShardedCodec:
                           dtype=jnp.bfloat16)
 
         def step(x, parity):
-            want = erasure_jax._gf_matmul_blocks(mat, x, self.m)
+            want = gf_matmul_blocks(mat, x, self.m)
             local = jnp.sum((want != parity).astype(jnp.int32))
             return jax.lax.psum(jax.lax.psum(local, "blocks"), "lanes")
 
@@ -150,7 +153,7 @@ class ShardedCodec:
             jax.shard_map(step, mesh=mesh,
                           in_specs=(P("blocks", None, "lanes"),
                                     P("blocks", None, "lanes")),
-                          out_specs=P()))
+                          out_specs=P(), check_vma=False))
 
     def verify_blocks(self, data, parity) -> int:
         """Returns the number of mismatching parity bytes (0 == healthy)."""

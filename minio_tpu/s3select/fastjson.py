@@ -15,14 +15,12 @@ from __future__ import annotations
 import ctypes
 import json
 import os
-import subprocess
 
 import numpy as np
 
 _DIR = os.path.join(os.path.dirname(os.path.dirname(
     os.path.dirname(os.path.abspath(__file__)))), "native")
 _SRC = os.path.join(_DIR, "njson.cc")
-_SO = os.path.join(_DIR, "build", "libnjson.so")
 
 _lib = None
 _load_error: Exception | None = None
@@ -34,14 +32,8 @@ def load():
         raise _load_error
     if _lib is None:
         try:
-            os.makedirs(os.path.dirname(_SO), exist_ok=True)
-            if (not os.path.exists(_SO) or os.path.getmtime(_SO)
-                    < os.path.getmtime(_SRC)):
-                subprocess.run(
-                    ["g++", "-O3", "-march=native", "-shared", "-fPIC",
-                     "-o", _SO, _SRC],
-                    check=True, capture_output=True, text=True)
-            lib = ctypes.CDLL(_SO)
+            from native._build import build
+            lib = ctypes.CDLL(build("njson", _SRC))
             lib.ndjson_extract.restype = ctypes.c_long
             lib.ndjson_extract.argtypes = [
                 ctypes.c_void_p, ctypes.c_long, ctypes.c_void_p,

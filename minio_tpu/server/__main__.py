@@ -136,6 +136,19 @@ def main(argv: list[str] | None = None) -> int:
             return 2
         certs = (cert, key)
 
+    # JAX's persistent compile cache: placed from outside where
+    # JAX_COMPILATION_CACHE_DIR is set, else at a FIXED path in the
+    # checkout (the path is part of the cache key, so one that moved
+    # would never hit).  Set before the pre-fork branch and before any
+    # jax import, so every child inherits it.  The served programs
+    # compile in 0.2-2 s each, under jax's default 1 s floor for what
+    # it keeps, hence the floor of 0.
+    os.environ.setdefault(
+        "JAX_COMPILATION_CACHE_DIR",
+        os.path.join(os.path.dirname(os.path.dirname(os.path.dirname(
+            os.path.abspath(__file__)))), ".jax_cache"))
+    os.environ.setdefault("JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS", "0")
+
     # Pre-fork worker pool (server/workers.py): MTPU_WORKERS=N forks N
     # SO_REUSEPORT HTTP workers plus one device-owner process.  The
     # branch sits BEFORE any engine/jax import — forking after XLA
@@ -156,8 +169,12 @@ def main(argv: list[str] | None = None) -> int:
 
     # Startup self-test guards (hard-fail like cmd/erasure-coding.go:158,
     # cmd/bitrot.go:214).
+    from ..ops import devices
     from ..ops.selftest import run_startup_self_tests
     run_startup_self_tests()
+    # This process initialised the JAX backend: say, once, what every
+    # device decision below it was made from.
+    print(devices.boot_line(), flush=True)
 
     from .server import S3Server
 

@@ -2468,10 +2468,15 @@ class S3Server:
                        "dispatches": h2d["h2d_dispatches"],
                        "lanes": {str(k): v
                                  for k, v in h2d["lanes"].items()}}
-        except Exception:  # noqa: BLE001 — device block is best-effort
+        except Exception:  # noqa: BLE001 — h2d ledger is best-effort
             pass
+        from ..ops import devices as _devices
         return {
             "endpoint": f"{self.host}:{self.port}",
+            # What the shard math runs on, as the process that holds
+            # the devices found it (the boot line says the same; a pool
+            # worker reports its owner's answer, in_process false).
+            "device": _devices.describe(),
             "time": round(_time.time(), 3),
             "draining": bool(self.draining),
             "inflight": int(self._inflight),

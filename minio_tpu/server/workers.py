@@ -7,13 +7,22 @@ erasure backend (cmd/server-main.go:441); the Python-shaped equivalent
 is the classic pre-fork design:
 
   supervisor (this module, light: no jax, no engine imports)
-    |- device owner   owns JAX/native kernel state, runs the REAL
-    |                 DispatchCoalescer; serves the shared-memory
-    |                 dispatch plane (ops/ipc_dispatch.py)
+    |- device owner   the ONE process that initialises a JAX backend
+    |                 (a chip belongs to one process): runs the
+    |                 device-lane self-test, publishes platform /
+    |                 device_kind / count in the control block,
+    |                 runs the REAL DispatchCoalescer and serves the
+    |                 shared-memory dispatch plane (ops/ipc_dispatch.py)
     |- worker 0       full S3 vertical; also the recovery owner:
-    |                 startup self-tests, boot recovery sweep, MRF
+    |                 host self-tests, boot recovery sweep, MRF
     |                 orphan-journal adoption, the data scanner
     |- worker 1..N-1  full S3 vertical
+
+Workers adopt the owner's device answer (ops/devices.adopt) and never
+ask JAX: device-bound kernels ship to the owner, and a worker's
+JAX_PLATFORMS names no backend, so a stray JAX call in a worker raises
+instead of racing the owner for the chip or computing on a quiet CPU
+backend.
 
 Every worker binds the SAME (host, port) with SO_REUSEPORT — the
 kernel load-balances accepted connections across processes, so there
@@ -64,7 +73,9 @@ _GHDR = 16                       # global slots
 _WSLOTS = 10                     # per-worker slab stride
 # global: 0 owner_gen, 1 owner_pid, 2 owner_beat_ns, 3 supervisor_pid,
 #         4 nworkers, 5 owner_co_dispatches, 6 owner_co_items,
-#         7 owner_co_pending, 8 owner_co_weight, 9 topology_gen
+#         7 owner_co_pending, 8 owner_co_weight, 9 topology_gen,
+#         10 device_count (0 = owner has not answered yet),
+#         12 platform (8 ascii bytes), 13-15 device_kind (24 ascii bytes)
 # worker: 0 pid, 1 beat_ns, 2 ready, 3 draining, 4 respawns,
 #         5 requests_total, 6 inflight, 7 audit_dropped,
 #         8 hotcache_hits, 9 hotcache_misses
@@ -145,6 +156,30 @@ class SharedState:
         if not self._a[1]:
             return False
         return (_now_ns() - int(self._a[2])) < int(stale_s * 1e9)
+
+    # device -----------------------------------------------------------------
+
+    def publish_device(self, info: dict) -> None:
+        """Owner: what ops/devices found.  The count is written last —
+        it is the field readers poll."""
+        self._mm[12 * 8:13 * 8] = info["platform"].encode(
+            "ascii", "replace")[:8].ljust(8, b"\0")
+        self._mm[13 * 8:16 * 8] = info["kind"].encode(
+            "ascii", "replace")[:24].ljust(24, b"\0")
+        self._a[10] = int(info["count"])
+
+    def device_info(self) -> dict | None:
+        """The owner's answer, or None before it gave one."""
+        count = int(self._a[10])
+        if not count:
+            return None
+        return {
+            "platform": bytes(self._mm[12 * 8:13 * 8]).rstrip(
+                b"\0").decode("ascii"),
+            "kind": bytes(self._mm[13 * 8:16 * 8]).rstrip(
+                b"\0").decode("ascii"),
+            "count": count,
+        }
 
     # topology ---------------------------------------------------------------
 
@@ -417,7 +452,17 @@ def _owner_main(plane: WorkerPlane) -> int:
     if _early_stop["hit"]:       # TERM landed during import/boot
         stop.set()
 
-    from ..ops import coalesce, ipc_dispatch
+    # The chip's one process: ask JAX (a backend that cannot start is
+    # an error and takes the pool down with it), prove every lane, then
+    # tell the workers what they are serving from.
+    from ..ops import coalesce, devices, ipc_dispatch
+    from ..ops.selftest import device_lane_self_test
+    device_lane_self_test()
+    info = devices.describe()
+    plane.state.publish_device(info)
+    if plane.state.owner_gen() == 1:
+        print(devices.boot_line(info) + f" (device owner, pid "
+              f"{os.getpid()})", flush=True)
     co = coalesce.get()
     ipc_dispatch.serve_owner(plane, stop, co)
     # Heartbeat on the main thread: workers route remote only while
@@ -434,6 +479,12 @@ def _worker_main(plane: WorkerPlane, idx: int, cfg: dict) -> int:
     os.environ["MTPU_WORKER_ID"] = str(idx)
     os.environ["MTPU_WORKERS_TOTAL"] = str(plane.nworkers)
     os.environ["MTPU_WORKER_ROLE"] = "worker"
+    # No backend of that name exists, so JAX raises if anything in this
+    # process reaches for one: the devices are the owner's.
+    os.environ["JAX_PLATFORMS"] = "the_device_owner_holds_the_devices"
+    from ..ops import devices
+    info = plane.state.device_info()     # run_pool waited for it
+    devices.adopt(info["platform"], info["kind"], info["count"])
     if idx != 0:
         # Exactly one scanner / recovery owner per deployment.
         os.environ["MTPU_SCANNER"] = "0"
@@ -453,7 +504,7 @@ def _worker_main(plane: WorkerPlane, idx: int, cfg: dict) -> int:
 
     if idx == 0:
         from ..ops.selftest import run_startup_self_tests
-        run_startup_self_tests()
+        run_startup_self_tests(device=False)
 
     from ..background.mrf import attach_mrf
     from ..engine.pools import ServerPools
@@ -644,27 +695,44 @@ def run_pool(nworkers: int, pool_paths: list[list[str]], creds,
     children: dict[int, tuple[str, int]] = {}   # pid -> (role, idx)
 
     plane.state.bump_owner_gen()
-    children[_fork(_owner_main, plane)] = ("owner", -1)
+    owner = _fork(_owner_main, plane)
+    children[owner] = ("owner", -1)
+    deadline = time.monotonic() + float(
+        os.environ.get("MTPU_BOOT_TIMEOUT", "120") or 120)
 
-    # Worker 0 boots ALONE first: it creates/adopts format.json, runs
+    def boot_wait(done, pid_watched: int, who: str) -> int:
+        """0 once done(); else the exit code to leave with (the pool is
+        already killed)."""
+        while not done():
+            pid, st = os.waitpid(-1, os.WNOHANG)
+            if pid == pid_watched:
+                rc = os.waitstatus_to_exitcode(st)
+                print(f"minio_tpu: {who} died during boot (rc={rc})",
+                      file=sys.stderr, flush=True)
+                _killall(children, signal.SIGKILL)
+                return rc if rc > 0 else 1
+            if stopping["flag"] or time.monotonic() > deadline:
+                _killall(children, signal.SIGKILL)
+                return 1
+            time.sleep(0.05)
+        return 0
+
+    # The owner answers first: no worker exists before the pool knows
+    # what it computes on, and an owner whose JAX cannot start (no chip
+    # under JAX_PLATFORMS=tpu) ends the pool here.
+    rc = boot_wait(lambda: plane.state.device_info() is not None,
+                   owner, "device owner")
+    if rc:
+        return rc
+
+    # Worker 0 boots ALONE next: it creates/adopts format.json, runs
     # the recovery sweep and MRF adoption — the writes every other
     # worker must observe, not race.
     w0 = _fork(_worker_main, plane, 0, cfg)
     children[w0] = ("worker", 0)
-    deadline = time.monotonic() + float(
-        os.environ.get("MTPU_BOOT_TIMEOUT", "120") or 120)
-    while not plane.state.is_ready(0):
-        pid, st = os.waitpid(-1, os.WNOHANG)
-        if pid == w0:
-            rc = os.waitstatus_to_exitcode(st)
-            print(f"minio_tpu: worker 0 died during boot (rc={rc})",
-                  file=sys.stderr, flush=True)
-            _killall(children, signal.SIGKILL)
-            return rc if rc > 0 else 1
-        if stopping["flag"] or time.monotonic() > deadline:
-            _killall(children, signal.SIGKILL)
-            return 1
-        time.sleep(0.05)
+    rc = boot_wait(lambda: plane.state.is_ready(0), w0, "worker 0")
+    if rc:
+        return rc
 
     for i in range(1, nworkers):
         children[_fork(_worker_main, plane, i, cfg)] = ("worker", i)

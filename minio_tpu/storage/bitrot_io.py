@@ -37,13 +37,15 @@ _HH_NATIVE = None        # None = untried; False = unavailable
 
 def _hh_native():
     """The AVX2/AVX-512 HighwayHash kernel (native/highwayhash.cc), or
-    False when the toolchain is unavailable."""
+    False on a host with no toolchain to build it."""
     global _HH_NATIVE
     if _HH_NATIVE is None:
+        from native import hh_native
+        from native._build import BuildError
         try:
-            from native.hh_native import hh256_rows_native
-            _HH_NATIVE = hh256_rows_native
-        except Exception:  # noqa: BLE001 — no g++: spec paths
+            hh_native.load()
+            _HH_NATIVE = hh_native.hh256_rows_native
+        except BuildError:  # no g++: spec paths
             _HH_NATIVE = False
     return _HH_NATIVE
 
@@ -81,30 +83,21 @@ _MXH_NATIVE = None       # None = untried; False = unavailable
 
 
 def _mxh_host(blocks: np.ndarray) -> np.ndarray:
-    """Host mxh256: native AVX-VNNI kernel (native/mxh256.cc) when the
-    toolchain/ISA allows, else the numpy spec path."""
+    """Host mxh256: native AVX-VNNI kernel (native/mxh256.cc), or the
+    numpy spec path on a host with no toolchain to build it."""
     global _MXH_NATIVE
     if _MXH_NATIVE is None:
+        from native import mxh_native
+        from native._build import BuildError
         try:
-            from native.mxh_native import mxh256_rows_native
-            _MXH_NATIVE = mxh256_rows_native
-        except Exception:  # noqa: BLE001 — no g++/ISA: spec path
+            mxh_native.load()
+            _MXH_NATIVE = mxh_native.mxh256_rows_native
+        except BuildError:  # no g++: spec path
             _MXH_NATIVE = False
     if _MXH_NATIVE:
         return _MXH_NATIVE(blocks)
     from ..ops.mxhash import mxh256_batch
     return mxh256_batch(blocks)
-
-
-def _mxh_batch(blocks: np.ndarray) -> np.ndarray:
-    # Device dispatch only where there IS a device — on CPU backends the
-    # native host kernel beats the XLA emulation ~50x.
-    if blocks.size >= _DEVICE_HASH_THRESHOLD:
-        import jax
-        if jax.default_backend() == "tpu":
-            from ..ops.mxhash_jax import mxh256_batch_jax
-            return np.asarray(mxh256_batch_jax(blocks))
-    return _mxh_host(blocks)
 
 
 def _hashlib_batch(name: str, digest_size: int):
@@ -120,7 +113,12 @@ def _hashlib_batch(name: str, digest_size: int):
 
 
 ALGORITHMS: dict[str, tuple[int, object]] = {
-    "mxh256": (32, _mxh_batch),             # TPU-native (ops/mxhash.py)
+    # mxh256 (ops/mxhash.py) is TPU-native where its shapes are bounded
+    # and its uploads counted: in the fused programs and the coalescer
+    # lanes' digest kernel.  This generic hasher sees any row length
+    # (tails, inline objects, heal reads) — one device compile per new
+    # length — so it is the host kernel, like HighwayHash's.
+    "mxh256": (32, _mxh_host),
     "highwayhash256S": (32, _hh_batch),
     "highwayhash256": (32, _hh_batch),      # whole-file legacy variant
     "sha256": (32, _hashlib_batch("sha256", 32)),
@@ -318,18 +316,18 @@ def unframe_shard(data: bytes, shard_size: int, verify: bool = True,
             # Fused native pass (heal/scanner hot path): hash-verify and
             # gather the frames in one sweep instead of
             # contiguous-copy -> hash -> concatenate-copy.
+            from native import ecio_native
+            from native._build import BuildError
             try:
-                from native import ecio_native
                 y, _, nbad = ecio_native.get_verify(
                     [frames], [0], n_full, shard_size, 1, 1, [])
+            except BuildError:  # no toolchain: numpy path
+                pass
+            else:
                 if nbad:
                     raise ErrFileCorrupt("bitrot hash mismatch")
                 pieces.append(y.reshape(-1))
                 frames = None
-            except ErrFileCorrupt:
-                raise
-            except Exception:  # noqa: BLE001 — no toolchain: numpy path
-                pass
         if frames is not None:
             hashes = frames[:, :hs]
             blocks = frames[:, hs:]

@@ -50,12 +50,13 @@ def native_available() -> bool:
     if _native_state is None:
         with _probe_mu:
             if _native_state is None:
+                from native import digest_native
+                from native._build import BuildError
                 try:
-                    from native import digest_native
                     digest_native.load()
                     _native_mod = digest_native
                     _native_state = True
-                except Exception:
+                except BuildError:  # no toolchain: hashlib serves
                     _native_state = False
     return _native_state
 

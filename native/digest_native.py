@@ -13,13 +13,13 @@ from __future__ import annotations
 
 import ctypes
 import os
-import subprocess
 
 import numpy as np
 
+from ._build import build
+
 _DIR = os.path.dirname(os.path.abspath(__file__))
 _SRC = os.path.join(_DIR, "digest.cc")
-_SO = os.path.join(_DIR, "build", "libmtpudigest.so")
 
 _lib = None
 
@@ -32,29 +32,11 @@ MD5_ISA_NAMES = {MD5_SCALAR: "scalar", MD5_SSE2: "sse2", MD5_AVX2: "avx2"}
 SHA_ISA_NAMES = {SHA_SCALAR: "scalar", SHA_NI: "shani"}
 
 
-def _build() -> str:
-    os.makedirs(os.path.dirname(_SO), exist_ok=True)
-    if (not os.path.exists(_SO)
-            or os.path.getmtime(_SO) < os.path.getmtime(_SRC)):
-        # No -march=native on purpose: runtime dispatch is the contract.
-        # Compile to a private temp path and os.replace() into place so
-        # a concurrent booter never CDLLs a half-written .so.
-        tmp = f"{_SO}.{os.getpid()}.tmp"
-        try:
-            subprocess.run(
-                ["g++", "-O3", "-shared", "-fPIC", "-o", tmp, _SRC],
-                check=True, capture_output=True, text=True)
-            os.replace(tmp, _SO)
-        finally:
-            if os.path.exists(tmp):
-                os.unlink(tmp)
-    return _SO
-
-
 def load():
     global _lib
     if _lib is None:
-        lib = ctypes.CDLL(_build())
+        # No -march=native on purpose: runtime dispatch is the contract.
+        lib = ctypes.CDLL(build("mtpudigest", _SRC, march_native=False))
         lib.mtpu_digest_isa.restype = ctypes.c_char_p
         lib.mtpu_digest_supported.argtypes = [ctypes.c_int, ctypes.c_int]
         lib.mtpu_digest_supported.restype = ctypes.c_int

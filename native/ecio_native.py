@@ -2,11 +2,10 @@
 
 The host data path's hot core: one C pass per batch doing
 encode+hash+frame (PUT) or verify+gather+reconstruct (GET), reading and
-writing mmap'd shard files so Python never copies object bytes.  Same
-build pattern as rs_comparator/mxh_native: compiled on first use with
--O3 -march=native; callers catch load failures and keep the separate-
-pass numpy path (a missing toolchain slows the data path, never breaks
-it).
+writing mmap'd shard files so Python never copies object bytes.
+Compiled on first use with -O3 -march=native by the shared build rule
+(native/_build.py); a host without a toolchain raises BuildError and
+callers keep the separate-pass numpy path.
 """
 
 from __future__ import annotations
@@ -14,9 +13,10 @@ from __future__ import annotations
 import ctypes
 import functools
 import os
-import subprocess
 
 import numpy as np
+
+from ._build import build
 
 try:
     from minio_tpu.observe.span import span as _span
@@ -28,9 +28,7 @@ except Exception:  # standalone shim use: tracing becomes a no-op
 
 _DIR = os.path.dirname(os.path.abspath(__file__))
 _SRC = os.path.join(_DIR, "ecio.cc")
-_DEPS = (_SRC, os.path.join(_DIR, "mxh256.cc"),
-         os.path.join(_DIR, "rs_cpu.cc"))
-_SO = os.path.join(_DIR, "build", "libecio.so")
+_DEPS = (os.path.join(_DIR, "mxh256.cc"), os.path.join(_DIR, "rs_cpu.cc"))
 
 _lib = None
 _load_error: Exception | None = None
@@ -38,18 +36,6 @@ _load_error: Exception | None = None
 ALGO = "mxh256"          # the one algorithm these kernels speak
 HASH_SIZE = 32
 MAX_ROWS = 64            # C kernels use fixed srcs[64] stack arrays
-
-
-def _build() -> str:
-    os.makedirs(os.path.dirname(_SO), exist_ok=True)
-    if (not os.path.exists(_SO)
-            or any(os.path.getmtime(_SO) < os.path.getmtime(d)
-                   for d in _DEPS)):
-        subprocess.run(
-            ["g++", "-O3", "-march=native", "-shared", "-fPIC",
-             "-o", _SO, _SRC],
-            check=True, capture_output=True, text=True)
-    return _SO
 
 
 def load():
@@ -69,7 +55,7 @@ def load():
 
 
 def _load_inner():
-    lib = ctypes.CDLL(_build())
+    lib = ctypes.CDLL(build("ecio", _SRC, _DEPS))
     lib.ec_isa.restype = ctypes.c_char_p
     lib.ec_put_frame.argtypes = [
         ctypes.c_void_p, ctypes.c_int, ctypes.c_int, ctypes.c_int,

@@ -1,41 +1,31 @@
 """ctypes loader for the native HighwayHash-256 kernel
 (native/highwayhash.cc).
 
-Same build pattern as mxh_native: compiled on first use with
--O3 -march=native; callers catch ImportError/OSError and fall back to
-the numpy/JAX spec paths. ctypes releases the GIL for the whole batch.
+Compiled on first use with -O3 -march=native by the shared build rule
+(native/_build.py); a host without a toolchain raises BuildError and
+callers use the numpy/JAX spec paths. ctypes releases the GIL for the
+whole batch.
 """
 
 from __future__ import annotations
 
 import ctypes
 import os
-import subprocess
 
 import numpy as np
 
+from ._build import build
+
 _DIR = os.path.dirname(os.path.abspath(__file__))
 _SRC = os.path.join(_DIR, "highwayhash.cc")
-_SO = os.path.join(_DIR, "build", "libhighwayhash.so")
 
 _lib = None
-
-
-def _build() -> str:
-    os.makedirs(os.path.dirname(_SO), exist_ok=True)
-    if (not os.path.exists(_SO)
-            or os.path.getmtime(_SO) < os.path.getmtime(_SRC)):
-        subprocess.run(
-            ["g++", "-O3", "-march=native", "-shared", "-fPIC",
-             "-o", _SO, _SRC],
-            check=True, capture_output=True, text=True)
-    return _SO
 
 
 def load():
     global _lib
     if _lib is None:
-        lib = ctypes.CDLL(_build())
+        lib = ctypes.CDLL(build("highwayhash", _SRC))
         lib.hh_isa.restype = ctypes.c_char_p
         lib.hh256_rows.argtypes = [
             ctypes.c_void_p, ctypes.c_size_t, ctypes.c_size_t,

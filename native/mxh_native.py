@@ -1,9 +1,8 @@
 """ctypes loader for the native mxh256 kernel (native/mxh256.cc).
 
-Same build pattern as rs_comparator: compiled on first use with
--O3 -march=native, falling back loudly to the numpy spec path if the
-toolchain or ISA is unavailable (mxh256_rows_native raises; callers
-catch and use ops/mxhash.mxh256_batch).
+Compiled on first use with -O3 -march=native by the shared build rule
+(native/_build.py); a host without a toolchain raises BuildError and
+callers use ops/mxhash.mxh256_batch.
 """
 
 from __future__ import annotations
@@ -11,32 +10,21 @@ from __future__ import annotations
 import ctypes
 import functools
 import os
-import subprocess
 
 import numpy as np
 
+from ._build import build
+
 _DIR = os.path.dirname(os.path.abspath(__file__))
 _SRC = os.path.join(_DIR, "mxh256.cc")
-_SO = os.path.join(_DIR, "build", "libmxh256.so")
 
 _lib = None
-
-
-def _build() -> str:
-    os.makedirs(os.path.dirname(_SO), exist_ok=True)
-    if (not os.path.exists(_SO)
-            or os.path.getmtime(_SO) < os.path.getmtime(_SRC)):
-        subprocess.run(
-            ["g++", "-O3", "-march=native", "-shared", "-fPIC",
-             "-o", _SO, _SRC],
-            check=True, capture_output=True, text=True)
-    return _SO
 
 
 def load():
     global _lib
     if _lib is None:
-        lib = ctypes.CDLL(_build())
+        lib = ctypes.CDLL(build("mxh256", _SRC))
         lib.mxh_isa.restype = ctypes.c_char_p
         lib.mxh256_rows.argtypes = [
             ctypes.c_void_p, ctypes.c_size_t, ctypes.c_size_t,

@@ -1,6 +1,6 @@
 """ctypes loader + measured-baseline driver for the native RS comparator.
 
-Builds native/rs_cpu.cc on first use (g++ -O3 -march=native), loads it,
+Builds native/rs_cpu.cc on first use (native/_build.py), loads it,
 and offers:
   - encode(): native encode for differential testing vs the gf256 oracle,
   - measure_encode_gbps(): the measured CPU baseline bench.py uses in
@@ -15,32 +15,21 @@ from __future__ import annotations
 
 import ctypes
 import os
-import subprocess
 
 import numpy as np
 
+from ._build import build
+
 _DIR = os.path.dirname(os.path.abspath(__file__))
 _SRC = os.path.join(_DIR, "rs_cpu.cc")
-_SO = os.path.join(_DIR, "build", "librs_cpu.so")
 
 _lib = None
-
-
-def _build() -> str:
-    os.makedirs(os.path.dirname(_SO), exist_ok=True)
-    if (not os.path.exists(_SO)
-            or os.path.getmtime(_SO) < os.path.getmtime(_SRC)):
-        subprocess.run(
-            ["g++", "-O3", "-march=native", "-shared", "-fPIC",
-             "-o", _SO, _SRC],
-            check=True, capture_output=True, text=True)
-    return _SO
 
 
 def load():
     global _lib
     if _lib is None:
-        lib = ctypes.CDLL(_build())
+        lib = ctypes.CDLL(build("rs_cpu", _SRC))
         lib.rs_isa.restype = ctypes.c_char_p
         lib.rs_bench_encode.restype = ctypes.c_double
         lib.rs_bench_encode.argtypes = [

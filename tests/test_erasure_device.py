@@ -77,11 +77,31 @@ def test_pallas_interpret_matches_oracle():
         assert np.array_equal(parity[b], want), f"block {b}"
 
 
-def test_pallas_fallback_on_untileable_shape():
-    # Shard size 100 is not a multiple of 128 -> falls back to XLA path.
+@pytest.mark.parametrize("k,m,s", [(6, 6, 174763), (12, 4, 87382)])
+def test_pallas_interpret_pads_awkward_shard_sizes(k, m, s):
+    # S = ceil(1 MiB / K) for K = 6 (the server's default on 12 drives)
+    # and K = 12 (16-drive EC:4) is no multiple of 128: the kernel runs
+    # on S padded up to one and the pad's output is sliced off.
+    blocks = _random_blocks(2, k, s, seed=7)
+    cpu = ReedSolomonCPU(k, m)
+    erasure_pallas.FORCE_INTERPRET = True
+    try:
+        parity = np.asarray(
+            ReedSolomonTPU(k, m, use_pallas=True).encode_blocks(blocks))
+    finally:
+        erasure_pallas.FORCE_INTERPRET = False
+    assert parity.shape == (2, m, s)
+    for b in range(2):
+        want = np.stack(cpu.encode(list(blocks[b]))[k:])
+        assert np.array_equal(parity[b], want), f"block {b}"
+
+
+def test_xla_path_serves_any_shard_size_off_tpu():
+    # Off-TPU (and outside FORCE_INTERPRET) the portable XLA path is the
+    # codec, whatever the shard size.
     k, m = 4, 2
     blocks = _random_blocks(2, k, 100, seed=5)
-    dev = ReedSolomonTPU(k, m, use_pallas=True)  # fallback inside
+    dev = ReedSolomonTPU(k, m, use_pallas=True)
     parity = np.asarray(dev.encode_blocks(blocks))
     cpu = ReedSolomonCPU(k, m)
     want = np.stack(cpu.encode(list(blocks[0]))[k:])

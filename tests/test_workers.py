@@ -266,7 +266,7 @@ class TestRemoteProtocol:
     def test_digest_roundtrip_matches_local_oracle(self, ipc_plane):
         rng = np.random.default_rng(7)
         payload = rng.integers(0, 256, size=(8, 4096), dtype=np.uint8)
-        key = ("digest", "mxh256", 4096)
+        key = ("digest", "mxh256", 4096, 0)
         fn = coalesce.make_digest_kernel("mxh256")
 
         local = coalesce.DispatchCoalescer()
@@ -297,7 +297,7 @@ class TestRemoteProtocol:
         try:
             for _ in range(5):
                 ipc_plane.state.owner_beat()
-                h = rc.submit(("digest", "mxh256", 1024), payload, fn)
+                h = rc.submit(("digest", "mxh256", 1024, 0), payload, fn)
                 h.result(timeout=60.0)
             deadline = time.monotonic() + 10
             while (ipc_plane.arena.stats()["in_use_bytes"]
@@ -327,7 +327,7 @@ class TestRemoteProtocol:
         plane.state.owner_beat()
         rc = ipc.RemoteCoalescer(plane, 0)
         try:
-            h = rc.submit(("digest", "mxh256", 64),
+            h = rc.submit(("digest", "mxh256", 64, 0),
                           np.zeros((1, 64), np.uint8),
                           coalesce.make_digest_kernel("mxh256"))
             np.asarray(h.result(timeout=60.0))
@@ -348,7 +348,7 @@ class TestRemoteProtocol:
         plane.state.owner_beat()
         rc = ipc.RemoteCoalescer(plane, 0)
         try:
-            h = rc.submit(("digest", "mxh256", 64),
+            h = rc.submit(("digest", "mxh256", 64, 0),
                           np.zeros((1, 64), np.uint8),
                           coalesce.make_digest_kernel("mxh256"))
             assert rc.stats()["remote_submits"] == 1
@@ -509,6 +509,24 @@ class TestPoolSmoke:
         cli.put_object("poolsmoke", "ranged", big)
         assert cli.get_object("poolsmoke", "ranged",
                               range_=(1000, 999999)) == big[1000:1000000]
+
+    def test_no_worker_initialises_a_jax_backend(self, pool_server):
+        """A chip belongs to one process: the device owner asks JAX,
+        the workers adopt its answer.  After a PUT through the codec,
+        every worker that answers healthinfo (fresh connections land on
+        both) reports the owner's platform and no backend of its own."""
+        cli = _cli(pool_server)
+        cli.make_bucket("nojax")
+        body = np.random.default_rng(5).integers(
+            0, 256, size=2 * _MB + 3, dtype=np.uint8).tobytes()
+        cli.put_object("nojax", "o", body)
+        assert cli.get_object("nojax", "o") == body
+        for _ in range(24):
+            _, _, data = cli.request("GET", "/minio/admin/v3/healthinfo")
+            (doc,) = json.loads(data)["nodes"].values()
+            assert doc["device"] == {
+                "platform": "cpu", "kind": "cpu", "count": 8, "lanes": 1,
+                "in_process": False}
 
     def test_requests_spread_across_workers(self, pool_server):
         cli = _cli(pool_server)

@@ -372,7 +372,7 @@ def run_load(es, *, clients: int = 4, object_size: int = 1 << 20,
 
     snap0 = DATA_PATH.snapshot()
     # H2D boundary ledger + device-shard-cache deltas (ISSUE 17): how
-    # many bytes crossed the host->device tunnel per byte this run
+    # many bytes crossed the host->device boundary per byte this run
     # moved, and how often verified shard batches were already
     # device-resident.  Import is lazy: the ledger lives next to the
     # cache and neither pulls in jax at import time.
