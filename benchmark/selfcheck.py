@@ -1,0 +1,110 @@
+#!/usr/bin/env python3
+"""Checks of the yardstick's own arithmetic.  Run by hand (needs no chip, no
+server, no JAX):
+
+    python3 benchmark/selfcheck.py
+"""
+
+from __future__ import annotations
+
+import json
+import os
+import sys
+
+HERE = os.path.dirname(os.path.abspath(__file__))
+sys.path.insert(0, HERE)
+
+import numpy as np  # noqa: E402
+
+import reference  # noqa: E402
+import run  # noqa: E402
+import trace_reduce  # noqa: E402
+import traffic  # noqa: E402
+import work  # noqa: E402
+
+V5E = "TPU v5 lite"
+
+
+def close(a: float, b: float, rel: float = 5e-3) -> bool:
+    return abs(a - b) <= rel * abs(b)
+
+
+def main() -> int:
+    # The trace reduction: union of overlapping, nested and disjoint
+    # intervals, and the idle share it gives.
+    spans = [(0, 10), (5, 12), (20, 30), (22, 25), (30, 31), (40, 40)]
+    assert trace_reduce.union_ns(spans) == 12 + 11, "union"
+    assert trace_reduce.union_ns([]) == 0.0
+    assert trace_reduce.idle_pct(23e-9, 100e-9) == 77.0, "idle share"
+
+    # work.py against ROADMAP Speed 7's two figures for EC:8+4.
+    assert close(work.bf16_parity_gbps(4, V5E), 385.0), "bf16-bound GB/s"
+    assert close(work.hbm_gbps(8, 4, V5E), 546.0), "HBM-bound GB/s"
+    least = work.least_seconds(1e9, 8, 4, V5E)
+    assert least["bound"] == "hbm" and close(least["seconds"], 1.5 / 819,
+                                             1e-3), least
+    # EC:2+2 moves 2 bytes per data byte and needs half the parity rows.
+    assert close(work.hbm_gbps(2, 2, V5E), 409.5)
+    try:
+        work.peaks("TPU v9")
+    except KeyError:
+        pass
+    else:
+        raise AssertionError("an unknown device kind must be an error")
+
+    # The percentile on a known list.
+    assert run.percentile([1, 2, 3, 4, 5], 50) == 3
+    assert run.percentile(list(range(1, 101)), 95) == 95.05
+    assert run.percentile([7.0], 95) == 7.0
+    assert run.percentile([1, 2, float("inf")], 95) == float("inf")
+
+    # The loader on every cell of BENCHMARK.json, and the traffic rules.
+    with open(os.path.join(os.path.dirname(HERE), "BENCHMARK.json")) as f:
+        bench = json.load(f)
+    for cell in bench["workloads"]:
+        for tdir in (None, os.path.join("tests", "traffic")):
+            wl, cfg = traffic.load_cell(bench, cell["name"], tdir)
+            assert cfg["drives"] == cfg["data_shards"] + cfg["parity_shards"]
+            block = sum(wl["mix"].values())
+            ops = traffic.op_blocks(3, 0, wl["mix"])
+            first = [next(ops) for _ in range(block)]
+            assert {o: first.count(o) for o in wl["mix"]} == wl["mix"]
+        for m in run.cell_metrics(bench, "per_layer", cell["name"]):
+            how = traffic.load_metric(m["name"])
+            assert m["name"].startswith(how["name"]) and how["kind"] in (
+                "ratio", "trace_idle", "trace_roofline"), how
+    # Two seeds send the same operations in another order.
+    a = traffic.op_blocks(1, 0, {"GET": 3, "PUT": 1})
+    b = traffic.op_blocks(2 ** 31 + 5, 0, {"GET": 3, "PUT": 1})
+    sa, sb = [next(a) for _ in range(40)], [next(b) for _ in range(40)]
+    assert sorted(sa) == sorted(sb) and sa != sb
+
+    # A body is a function of (seed, client, key, part, generation).
+    wl = {"object_bytes": 4096, "part_bytes": 0, "pool_buffers": 2}
+    b0, b1 = traffic.Bodies(9, 0, wl), traffic.Bodies(9, 0, wl)
+    body = b"".join(b0.chunks("k", 0, 0))
+    assert len(body) == 4096 and body == b"".join(b1.chunks("k", 0, 0))
+    assert body != b"".join(b0.chunks("k", 0, 1))
+    assert b0.matches(body, "k", 0, wl) and not b0.matches(body, "k", 1, wl)
+
+    # The reference: the code is systematic, and a bit flipped on disk in
+    # the bytes or in the digest, or a shard not there, is seen.
+    block = np.random.default_rng(4).bytes(5000)
+    rows = reference.encode_block(block, 2, 2)
+    assert rows.shape == (4, 2500)
+    assert bytes(rows[:2].reshape(-1)[:5000]) == block
+    files = reference.shard_files(block, 2, 2)
+    assert reference.compare_part(block, 2, 2, files)["frames"] == 4
+    for at, what in ((40, "bad_bytes"), (5, "bad_digest")):
+        bad = bytearray(files[3])
+        bad[at] ^= 1
+        res = reference.compare_part(block, 2, 2, files[:3] + [bytes(bad)])
+        assert res[what] == 1, res
+    assert reference.compare_part(block, 2, 2, files[:3])["shards_missing"] \
+        == 1
+    print("selfcheck: ok")
+    return 0
+
+
+if __name__ == "__main__":
+    sys.exit(main())
