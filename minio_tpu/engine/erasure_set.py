@@ -627,7 +627,8 @@ class ErasureSet:
             for chunk, is_last in streams.batched_chunks(
                     data, stream, BATCH_BLOCKS * BLOCK_SIZE):
                 if stream is not None:
-                    md5.update(chunk)    # bytes path already has its etag
+                    with ospan.span("engine.etag"):
+                        md5.update(chunk)  # bytes path already has its etag
                 total += len(chunk)
                 yield chunk, is_last
 
@@ -676,7 +677,7 @@ class ErasureSet:
             # not leave committed versions on the survivors (the
             # reference likewise aborts before RenameData,
             # cmd/erasure-object.go:1200).
-            with ospan.span("engine.stage"):
+            with ospan.span("engine.write"):
                 res = self._map_drives_positions(stage)
             stage_errs = [e for _, e in res]
             err = Q.reduce_write_quorum_errs(stage_errs, write_quorum)
@@ -719,8 +720,9 @@ class ErasureSet:
                     self._encode_chunks(counted_chunks(), k, parity, algo),
                     "engine.encode"):
                 # batch_shards: n framed byte strings in SHARD order.
-                per_drive = Q.unshuffle_to_drives(batch_shards,
-                                                  distribution)
+                with ospan.span("engine.shuffle"):
+                    per_drive = Q.unshuffle_to_drives(batch_shards,
+                                                      distribution)
 
                 def write_one(pos):
                     d = self.drives[pos]
@@ -1218,8 +1220,10 @@ class ErasureSet:
             # transpose + tobytes chain copied the batch three times).
             if digests is not None:
                 digests = np.asarray(digests)
-            return bitrot_io.frame_shard_views(
-                blocks, np.asarray(parity), digests, algo)
+            parity = np.asarray(parity)
+            with ospan.span("engine.frame"):
+                return bitrot_io.frame_shard_views(
+                    blocks, parity, digests, algo)
 
         # Cross-request coalescing (MTPU_COALESCE, ops/coalesce.py):
         # instead of dispatching this request's batch directly, submit
@@ -1285,9 +1289,12 @@ class ErasureSet:
                     # Non-power-of-two K: each block zero-pads to
                     # K*shard_size (split padding rule,
                     # cf. erasure-coding.go:81).
-                    blocks = np.zeros((nb, k * shard_size), dtype=np.uint8)
-                    blocks[:, :BLOCK_SIZE] = batch.reshape(nb, BLOCK_SIZE)
-                    blocks = blocks.reshape(nb, k, shard_size)
+                    with ospan.span("engine.stage"):
+                        blocks = np.zeros((nb, k * shard_size),
+                                          dtype=np.uint8)
+                        blocks[:, :BLOCK_SIZE] = batch.reshape(
+                            nb, BLOCK_SIZE)
+                        blocks = blocks.reshape(nb, k, shard_size)
                 if fused_host is not None:
                     if co is not None:
                         h = co.submit(
@@ -2263,6 +2270,7 @@ class ErasureSet:
                 for s in range(k):
                     y[:, s, :] = rows[s][1]
                 asm_s += time.monotonic() - tg
+                ospan.record("engine.assemble", asm_s)
                 if use_co:
                     # Coalesced digest over the already-gathered rows
                     # (the gather IS the assembly, so this adds no
@@ -2330,9 +2338,13 @@ class ErasureSet:
             DATA_PATH.record_healthy_read(
                 length, read_s=t_read - t0, verify_s=t_verify - t_read,
                 assemble_s=asm_s + (done - ta))
-            ospan.record("engine.read", t_read - t0)
-            ospan.record("engine.verify", t_verify - t_read)
-            ospan.record("engine.assemble", asm_s + (done - ta))
+            # The same clock reads place the stages on the request's
+            # timeline; the gather recorded above nests under the
+            # verify it is part of, as do the drive reads and the
+            # coalescer wait under theirs.
+            ospan.bracket("engine.read", t0, t_read)
+            ospan.bracket("engine.verify", t_read, t_verify)
+            ospan.bracket("engine.assemble", ta, done)
             if report is not None:
                 # Hot-tier evidence: this segment was served purely by
                 # the full-k verify (dict ops are GIL-atomic enough for

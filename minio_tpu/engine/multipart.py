@@ -162,17 +162,21 @@ def put_object_part(es: ErasureSet, bucket: str, obj: str, upload_id: str,
         # (append, rename, meta) per drive.
         t0 = time.perf_counter()
         total = len(data)
-        # ETag digest overlaps the encode dispatch (same bytes, same
-        # order: byte-identical to hashlib.md5(data)).
-        etag_md5 = streams.PipelinedMD5()
-        etag_md5.feed(data)
-        try:
-            per_drive = Q.unshuffle_to_drives(
-                es._encode_full(bytes(data), k, m, algo), ec.distribution)
-        finally:
-            etag_md5.close()
-        etag = etag_md5.hexdigest()
-        part_meta = _part_meta_blob(part_number, etag, total, algo)
+        with ospan.span("mp.encode"):
+            # ETag digest overlaps the encode dispatch (same bytes,
+            # same order: byte-identical to hashlib.md5(data)).
+            etag_md5 = streams.PipelinedMD5()
+            with ospan.span("mp.md5"):
+                etag_md5.feed(data)
+            try:
+                per_drive = Q.unshuffle_to_drives(
+                    es._encode_full(bytes(data), k, m, algo),
+                    ec.distribution)
+            finally:
+                etag_md5.close()
+            with ospan.span("mp.md5"):
+                etag = etag_md5.hexdigest()
+            part_meta = _part_meta_blob(part_number, etag, total, algo)
         t1 = time.perf_counter()
 
         def put_one(pos):
@@ -185,19 +189,18 @@ def put_object_part(es: ErasureSet, bucket: str, obj: str, upload_id: str,
             d.write_all(SYS_VOL, f"{path}/part.{part_number}.meta",
                         part_meta)
 
-        try:
-            res = es._map_drives_positions(put_one)
-            err = Q.reduce_write_quorum_errs([e for _, e in res],
-                                             write_quorum)
-            if err is not None:
-                raise err
-            crash_point("mp.part.post_publish")
-        finally:
-            _cleanup_stage(es, stage)
+        with ospan.span("mp.write"):
+            try:
+                res = es._map_drives_positions(put_one)
+                err = Q.reduce_write_quorum_errs([e for _, e in res],
+                                                 write_quorum)
+                if err is not None:
+                    raise err
+                crash_point("mp.part.post_publish")
+            finally:
+                _cleanup_stage(es, stage)
         t2 = time.perf_counter()
         DATA_PATH.record_mp_batch(total, t1 - t0, t2 - t1)
-        ospan.record("mp.encode", t1 - t0)
-        ospan.record("mp.write", t2 - t1)
         return ObjectPartInfo(number=part_number, size=total,
                               actual_size=total, etag=etag)
 
@@ -209,12 +212,14 @@ def put_object_part(es: ErasureSet, bucket: str, obj: str, upload_id: str,
         nonlocal total
         for chunk, is_last in streams.batched_chunks(
                 data, stream, BATCH_BLOCKS * BLOCK_SIZE):
-            md5.update(chunk)
+            with ospan.span("mp.md5"):
+                md5.update(chunk)
             total += len(chunk)
             yield chunk, is_last
 
     def shuffle(batch_shards):
-        return Q.unshuffle_to_drives(batch_shards, ec.distribution)
+        with ospan.span("engine.shuffle"):
+            return Q.unshuffle_to_drives(batch_shards, ec.distribution)
 
     def write_batch(per_drive):
         def write_one(pos):
@@ -236,10 +241,6 @@ def put_object_part(es: ErasureSet, bucket: str, obj: str, upload_id: str,
     def record(read_s, compute_s, write_s):
         nbytes, seen[0] = total - seen[0], total
         DATA_PATH.record_mp_batch(nbytes, read_s + compute_s, write_s)
-        # on_batch runs in the caller (traced) thread: bridge the
-        # pipeline's measured stage times into the span tree.
-        ospan.record("mp.encode", read_s + compute_s)
-        ospan.record("mp.write", write_s)
 
     try:
         # Encode of batch i+1 (the `reads` pull) overlaps the shard
@@ -249,9 +250,11 @@ def put_object_part(es: ErasureSet, bucket: str, obj: str, upload_id: str,
         pl.StagePipeline(es._iter_pool).run(
             es._encode_chunks(counted_chunks(), k, m, algo,
                               double_buffer=True),
-            shuffle, write_batch, on_batch=record)
+            shuffle, write_batch, on_batch=record,
+            stages=("mp.encode", "mp.write"))
 
-        etag = md5.hexdigest()
+        with ospan.span("mp.md5"):
+            etag = md5.hexdigest()
         part_meta = _part_meta_blob(part_number, etag, total, algo)
 
         def publish(pos):

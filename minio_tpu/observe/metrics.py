@@ -219,6 +219,10 @@ class DataPathStats:
             # stay the cross-lane totals; this map is what the
             # mtpu_device_lane_* gauge families render from.
             self.lanes = {}
+            # XLA compiles in this process (ops/devices.py listener):
+            # persistent-cache hits are not counted.
+            self.jit_compiles = 0
+            self.jit_compile_s = 0.0
             # Cross-process dispatch (ops/ipc_dispatch.py, worker pool):
             # items shipped to the device owner, results received,
             # fallbacks (arena/ring full -> computed locally), and
@@ -378,6 +382,11 @@ class DataPathStats:
             row["items"] += items
             row["weight"] += weight
             row["wait_s"] += wait_s
+
+    def record_jit_compile(self, seconds: float) -> None:
+        with self._mu:
+            self.jit_compiles += 1
+            self.jit_compile_s += seconds
 
     def record_co_fault(self, members: int) -> None:
         """A coalesced dispatch raised; `members` spans were retried
@@ -594,6 +603,8 @@ class DataPathStats:
                 "co_fallbacks": self.co_fallbacks,
                 "lanes": {d: dict(row)
                           for d, row in sorted(self.lanes.items())},
+                "jit_compiles": self.jit_compiles,
+                "jit_compile_s": self.jit_compile_s,
                 "ipc_submits": self.ipc_submits,
                 "ipc_rows": self.ipc_rows,
                 "ipc_results": self.ipc_results,
@@ -778,6 +789,18 @@ class MetricsRegistry:
             "mtpu_device_lane_queue_wait_seconds_total",
             "Summed per-item queue wait before dispatch on this "
             "device lane", ("device",))
+        self.device_lane_state_seconds = Gauge(
+            "mtpu_device_lane_state_seconds_total",
+            "Wall time of a device lane since it was made, by state "
+            "(no_work, linger, pack, h2d, launch, device_wait; they "
+            "sum to the lane's age)", ("lane", "state"))
+        self.jit_compiles = Gauge(
+            "mtpu_jit_compiles_total",
+            "XLA compilations in this process (persistent-cache hits "
+            "are not counted)")
+        self.jit_compile_seconds = Gauge(
+            "mtpu_jit_compile_seconds_total",
+            "Seconds spent in XLA compilations in this process")
         # Cross-process dispatch families (worker pool, PR 9).
         self.ipc_submits = Gauge(
             "mtpu_ipc_dispatch_submits_total",
@@ -883,6 +906,12 @@ class MetricsRegistry:
         self.trace_stage_ms = Gauge(
             "mtpu_trace_stage_ms_total",
             "Summed span time by API and stage in ms", ("api", "stage"))
+        self.trace_stage_self_ms = Gauge(
+            "mtpu_trace_stage_self_ms_total",
+            "Summed span self time (duration minus the union of its "
+            "children) by API, stage and layer in ms; a request "
+            "root's own is stage http.other",
+            ("api", "stage", "layer"))
         self.trace_stage_count = Gauge(
             "mtpu_trace_stage_spans_total",
             "Span count by API and stage", ("api", "stage"))
@@ -1560,6 +1589,8 @@ class MetricsRegistry:
                 if row["dispatches"] else 0.0, device=str(dev))
             self.device_lane_queue_wait.set(row["wait_s"],
                                             device=str(dev))
+        self.jit_compiles.set(snap["jit_compiles"])
+        self.jit_compile_seconds.set(snap["jit_compile_s"])
         self.ipc_submits.set(snap["ipc_submits"])
         self.ipc_results.set(snap["ipc_results"])
         self.ipc_fallbacks.set(snap["ipc_fallbacks"])
@@ -1652,23 +1683,41 @@ class MetricsRegistry:
             self.h2d_lane_dispatches.set(row["h2d_dispatches"],
                                          device=str(dev))
         from ..ops import coalesce as _coalesce
+        from ..ops import devices as _devices
         co = _coalesce._CO
+        lanes = {}
         if co is not None:
             cst = co.stats()
+            lanes = cst["lanes"]
             self.h2d_pipeline_dispatches.set(cst["pipeline_dispatches"])
             self.h2d_overlap_seconds.set(cst["overlap_s"])
             self.h2d_pack_seconds.set(cst["pack_s"])
             self.h2d_upload_seconds.set(cst["h2d_s"])
             self.h2d_resolve_seconds.set(cst["resolve_s"])
+        # Every state of every lane from boot, at 0 until the lane is
+        # made: a window in which nothing was counted reads 0, not
+        # missing.  The lane count is asked for only where the process
+        # already knows its devices (a scrape never starts JAX).
+        nlanes = _devices.n_devices() if _devices._VISIBLE else 1
+        for d in range(max(nlanes, len(lanes))):
+            state_s = lanes.get(d, {}).get("state_s", {})
+            for state in _coalesce.DispatchLane.STATES:
+                self.device_lane_state_seconds.set(
+                    state_s.get(state, 0.0), lane=str(d), state=state)
 
     def _sync_spans(self) -> None:
         # Imported lazily: span.py is the one observe module allowed to
         # stay import-light (it sits on every request's hot path).
-        from .span import BUCKETS_MS, TRACER
+        from .span import BUCKETS_MS, ROOT_SELF_STAGE, TRACER, layer_of
         snap = TRACER.snapshot()
         for api, a in snap["apis"].items():
             self.trace_api_count.set(a["count"], api=api)
             self.trace_api_errors.set(a["errors"], api=api)
+            # A request root's own self time is the front door's; a
+            # lane dispatch's root is its own stage.
+            own = api if layer_of(api) == "lane" else ROOT_SELF_STAGE
+            self.trace_stage_self_ms.set(a["self_ms"], api=api, stage=own,
+                                         layer=layer_of(own))
             for q in ("p50", "p90", "p99"):
                 self.trace_api_latency.set(a[f"{q}_ms"], api=api,
                                            quantile=q)
@@ -1677,6 +1726,9 @@ class MetricsRegistry:
                                            stage=stage)
                 self.trace_stage_ms.set(st["total_ms"], api=api,
                                         stage=stage)
+                self.trace_stage_self_ms.set(
+                    st["self_ms"], api=api, stage=stage,
+                    layer=layer_of(stage))
                 cum = 0
                 for i, bound in enumerate(BUCKETS_MS):
                     cum += st["buckets"][i]

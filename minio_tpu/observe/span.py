@@ -5,11 +5,26 @@ internal/pubsub usage in the reference).
 A request opens ONE root span (``TRACER.root("api.PutObject", ...)``);
 code anywhere below it on the same logical call chain opens nested
 stage spans with the module-level ``span("engine.encode")`` helper, or
-attaches pre-measured timings with ``record(name, seconds)`` (the
-StagePipeline ``on_batch`` bridge).  Span placement rides contextvars,
+attaches pre-measured timings with ``record(name, seconds)`` /
+``bracket(name, start, end)``.  Span placement rides contextvars,
 so the tree needs no plumbing through call signatures; fan-out code
 that jumps threads wraps the worker callable in ``wrap_ctx`` to carry
-the current span across.
+the current span across.  A coalescer lane thread serves many requests
+at once, so it opens a root of its own per dispatch (``lane.dispatch``)
+that names the requests it serves (``members``).
+
+One clock: every span starts and ends on ``time.monotonic()``, the
+clock the lane timers (ops/coalesce.py) and the benchmark's traced
+window use, so a record places each span on one timeline: ``start_ms``
+is the offset from the root's start, ``self_ms`` the span's duration
+minus the union of its children's intervals (children that ran side by
+side in pool threads are not subtracted twice).  ``layer_of`` maps a
+stage name to the layer of the system it belongs to; the exporter sums
+self time per layer, which is what says where a request's time went.
+While a span is real and jax is loaded it is also a
+``jax.profiler.TraceAnnotation``: with a profiler running, every span
+lands in the device trace on its host thread's line, stamped by the
+profiler's own clock.
 
 Cost model (the whole point):
 
@@ -17,8 +32,9 @@ Cost model (the whole point):
   bool check returning the shared ``NOOP`` singleton, and ``span()`` /
   ``record()`` are a single contextvar read — no Span object is ever
   allocated (``SPAN_ALLOCS`` is the test sentinel for that).
-- Tracing ON: spans cost one object + two perf_counter reads each, paid
-  only by requests actually being traced (``MTPU_TRACE_SAMPLE``
+- Tracing ON: spans cost one object, two clock reads and (with jax
+  loaded) one TraceAnnotation each, paid only by requests actually
+  being traced (``MTPU_TRACE_SAMPLE``
   down-samples root creation; untraced requests fall back to NOOP).
 
 Completed root spans become plain-dict trace records that fan out to:
@@ -31,6 +47,7 @@ by ``GET /minio/admin/v3/top/apis`` and the Prometheus exporter).
 from __future__ import annotations
 
 import os
+import sys
 import threading
 import time
 from collections import deque
@@ -58,6 +75,66 @@ def carry_var(var: ContextVar) -> None:
 #: the disabled path never materialises span objects.
 SPAN_ALLOCS = 0
 
+#: Counts every jax.profiler.TraceAnnotation a span constructs: zero
+#: while tracing is off, whether or not jax is loaded.
+ANNOTATION_ALLOCS = 0
+
+#: Stage-name prefix -> layer of the system (PERF.md section 3), first
+#: match wins.  One table: the exporter's `layer` label, the per-layer
+#: self-time metrics and the tests all read it.
+LAYERS = (
+    ("http.", "front_door"),
+    ("engine.", "engine"), ("mp.", "engine"),
+    ("storage.", "storage"), ("host.hash_batch", "storage"),
+    ("coalesce.", "dispatch"), ("ipc.", "dispatch"),
+    ("metalane.", "dispatch"),
+    ("lane.", "lane"),
+    ("device.", "device"),
+)
+
+#: Stage under which a request root's own self time (what no child span
+#: covers) is aggregated.
+ROOT_SELF_STAGE = "http.other"
+
+
+def layer_of(stage: str) -> str:
+    for prefix, layer in LAYERS:
+        if stage.startswith(prefix):
+            return layer
+    return "other"
+
+
+def _annotation(name: str):
+    """A jax.profiler.TraceAnnotation for a real span, or None while
+    jax is not (fully) imported: span.py never imports it itself."""
+    prof = getattr(sys.modules.get("jax"), "profiler", None)
+    cls = getattr(prof, "TraceAnnotation", None)
+    if cls is None:
+        return None
+    global ANNOTATION_ALLOCS
+    ANNOTATION_ALLOCS += 1
+    return cls(name)
+
+
+def _uncovered_s(lo: float, hi: float, children) -> float:
+    """Length of [lo, hi] minus the union of the children's intervals
+    clipped to it."""
+    covered, cur_s, cur_e = 0.0, None, None
+    for s, e in sorted((max(c.t0, lo), min(c.t0 + c.dur_s, hi))
+                       for c in children):
+        if e <= s:
+            continue
+        if cur_e is None or s > cur_e:
+            if cur_e is not None:
+                covered += cur_e - cur_s
+            cur_s, cur_e = s, e
+        elif e > cur_e:
+            cur_e = e
+    if cur_e is not None:
+        covered += cur_e - cur_s
+    return max(0.0, (hi - lo) - covered)
+
+
 #: Bound on children held per span: a pathological stream can emit
 #: unbounded per-batch spans; beyond this the tree drops the extras
 #: (durations still aggregate via record()'s parent check failing last).
@@ -79,13 +156,22 @@ class _NoopSpan:
     def tag(self, **kw):
         return self
 
+    def discard(self):
+        return self
+
+    def suspend(self):
+        return self
+
+    def resume(self):
+        return self
+
 
 NOOP = _NoopSpan()
 
 
 class Span:
     __slots__ = ("name", "tags", "t0", "dur_s", "children",
-                 "_parent", "_token", "_tracer")
+                 "_parent", "_token", "_tracer", "_ann", "_dropped")
 
     def __init__(self, tracer, name: str, tags: dict | None = None):
         global SPAN_ALLOCS
@@ -98,25 +184,55 @@ class Span:
         self.children: list[Span] = []
         self._parent = None
         self._token = None
+        self._ann = None
+        self._dropped = False
 
     def tag(self, **kw):
         self.tags.update(kw)
         return self
 
+    def discard(self):
+        """Leave this span out of the tree when it exits (a pull that
+        found its iterator exhausted is no stage)."""
+        self._dropped = True
+        return self
+
     def __enter__(self):
         self._parent = _current.get()
         self._token = _current.set(self)
-        self.t0 = time.perf_counter()
+        self._ann = _annotation(self.name)
+        if self._ann is not None:
+            self._ann.__enter__()
+        self.t0 = time.monotonic()
         return self
 
-    def __exit__(self, et, ev, tb):
-        self.dur_s = time.perf_counter() - self.t0
+    def _leave(self) -> None:
         try:
             _current.reset(self._token)
         except ValueError:
             # Entered in one context, exited in another (thread hop):
             # restore the parent by value instead.
             _current.set(self._parent)
+
+    def suspend(self):
+        """Step out of an open span without ending it, and back in with
+        resume(): a pipelined lane dispatch stays open from its pack to
+        its resolve while the lane packs the next one."""
+        self._leave()
+        return self
+
+    def resume(self):
+        self._token = _current.set(self)
+        return self
+
+    def __exit__(self, et, ev, tb):
+        self.dur_s = time.monotonic() - self.t0
+        if self._ann is not None:
+            self._ann.__exit__(et, ev, tb)
+            self._ann = None
+        self._leave()
+        if self._dropped:
+            return False
         p = self._parent
         if p is not None:
             if len(p.children) < MAX_CHILDREN:
@@ -125,12 +241,28 @@ class Span:
             self._tracer._finish_root(self, et is not None)
         return False
 
-    def to_dict(self) -> dict:
-        d = {"name": self.name, "dur_ms": round(self.dur_s * 1e3, 4)}
+    def self_s(self) -> float:
+        if not self.children:
+            return self.dur_s
+        return _uncovered_s(self.t0, self.t0 + self.dur_s, self.children)
+
+    def root_tag(self, key: str):
+        sp = self
+        while sp._parent is not None:
+            sp = sp._parent
+        return sp.tags.get(key)
+
+    def to_dict(self, t_root: float | None = None) -> dict:
+        if t_root is None:
+            t_root = self.t0
+        d = {"name": self.name,
+             "start_ms": round((self.t0 - t_root) * 1e3, 4),
+             "dur_ms": round(self.dur_s * 1e3, 4),
+             "self_ms": round(self.self_s() * 1e3, 4)}
         if self.tags:
             d["tags"] = dict(self.tags)
         if self.children:
-            d["spans"] = [c.to_dict() for c in self.children]
+            d["spans"] = [c.to_dict(t_root) for c in self.children]
         return d
 
 
@@ -181,14 +313,16 @@ _PCTL_WINDOW = 512     # per-API root durations kept for percentiles
 
 
 class _ApiAgg:
-    __slots__ = ("count", "errors", "total_ms", "durs_ms", "stages")
+    __slots__ = ("count", "errors", "total_ms", "self_ms", "durs_ms",
+                 "stages")
 
     def __init__(self):
         self.count = 0
         self.errors = 0
         self.total_ms = 0.0
+        self.self_ms = 0.0          # the roots' own self time
         self.durs_ms: deque = deque(maxlen=_PCTL_WINDOW)
-        # stage name -> [count, total_ms, per-bucket counts]
+        # stage name -> [count, total_ms, per-bucket counts, self_ms]
         self.stages: dict[str, list] = {}
 
 
@@ -275,41 +409,42 @@ class SpanTracer:
         rec["time"] = time.time()
         rec["error"] = err
         with self._mu:
-            self._aggregate_locked(root, err)
+            self._aggregate_locked(rec, err)
             if self._ring is not None:
                 self._ring.append(rec)
         self.pubsub.publish(rec)
 
-    def _aggregate_locked(self, root: Span, err: bool) -> None:
-        api = root.name
+    def _aggregate_locked(self, rec: dict, err: bool) -> None:
+        """Fold one finished root's record into its API's aggregates."""
+        api = rec["name"]
         agg = self._agg.get(api)
         if agg is None:
             if len(self._agg) >= _MAX_APIS:
                 return
             agg = self._agg[api] = _ApiAgg()
-        dur_ms = root.dur_s * 1e3
         agg.count += 1
         agg.errors += err
-        agg.total_ms += dur_ms
-        agg.durs_ms.append(dur_ms)
-        stack = list(root.children)
+        agg.total_ms += rec["dur_ms"]
+        agg.self_ms += rec["self_ms"]
+        agg.durs_ms.append(rec["dur_ms"])
+        stack = list(rec.get("spans", ()))
         while stack:
             sp = stack.pop()
-            st = agg.stages.get(sp.name)
+            stack.extend(sp.get("spans", ()))
+            st = agg.stages.get(sp["name"])
             if st is None:
                 if len(agg.stages) >= _MAX_STAGES:
-                    stack.extend(sp.children)
                     continue
-                st = agg.stages[sp.name] = [0, 0.0,
-                                            [0] * len(BUCKETS_MS)]
-            ms = sp.dur_s * 1e3
+                st = agg.stages[sp["name"]] = [
+                    0, 0.0, [0] * len(BUCKETS_MS), 0.0]
+            ms = sp["dur_ms"]
             st[0] += 1
             st[1] += ms
+            st[3] += sp["self_ms"]
             for i, b in enumerate(BUCKETS_MS):
                 if ms <= b:
                     st[2][i] += 1
                     break
-            stack.extend(sp.children)
 
     # -- read-side -----------------------------------------------------------
 
@@ -330,6 +465,8 @@ class SpanTracer:
                 apis[api] = {
                     "count": a.count,
                     "errors": a.errors,
+                    "total_ms": round(a.total_ms, 4),
+                    "self_ms": round(a.self_ms, 4),
                     "avg_ms": round(a.total_ms / a.count, 4)
                     if a.count else 0.0,
                     "p50_ms": round(_pctl(durs, 0.50), 4),
@@ -338,6 +475,7 @@ class SpanTracer:
                     "stages": {
                         name: {"count": st[0],
                                "total_ms": round(st[1], 4),
+                               "self_ms": round(st[3], 4),
                                "buckets": list(st[2])}
                         for name, st in sorted(a.stages.items())},
                 }
@@ -371,14 +509,55 @@ def root_span(name: str, **tags):
     return TRACER.root(name, **tags)
 
 
-def record(name: str, seconds: float, **tags) -> None:
-    """Attach a pre-measured child span (StagePipeline on_batch timings,
-    device sync times, per-drive I/O) to the current span, if any."""
-    parent = _current.get()
-    if parent is not None and len(parent.children) < MAX_CHILDREN:
-        sp = Span(TRACER, name, tags or None)
-        sp.dur_s = seconds
+def span_or_root(name: str, **tags):
+    """A nested span where the caller is inside a traced request, else
+    a root of its own (a lane thread's dispatch)."""
+    if _current.get() is not None:
+        return Span(TRACER, name, tags)
+    return TRACER.root(name, **tags)
+
+
+def _attach(parent: Span, name: str, start: float, seconds: float,
+            tags: dict | None) -> Span:
+    sp = Span(TRACER, name, tags)
+    sp.t0 = start
+    sp.dur_s = seconds
+    if len(parent.children) < MAX_CHILDREN:
         parent.children.append(sp)
+    return sp
+
+
+def record(name: str, seconds: float, **tags) -> None:
+    """Attach a pre-measured child span that ends now (a queue wait, a
+    compile, per-drive I/O) to the current span, if any."""
+    parent = _current.get()
+    if parent is not None:
+        _attach(parent, name, time.monotonic() - seconds, seconds,
+                tags or None)
+
+
+def bracket(name: str, start: float, end: float) -> None:
+    """Attach a stage measured by the caller's own clock reads
+    (time.monotonic()) as a child of the current span, and move under
+    it the children the current span gained inside [start, end]: the
+    waits and drive calls the stage made nest where they belong, so
+    their time is not counted as the stage's own too."""
+    parent = _current.get()
+    if parent is None:
+        return
+    kids = parent.children
+    inside = [c for c in kids
+              if c.t0 >= start - 1e-6 and c.t0 + c.dur_s <= end + 1e-6]
+    for c in inside:
+        kids.remove(c)
+    _attach(parent, name, start, end - start, None).children = inside
+
+
+def request_id():
+    """The handler's request id of the traced request the caller runs
+    under (None when untraced)."""
+    cur = _current.get()
+    return None if cur is None else cur.root_tag("request_id")
 
 
 def current():
@@ -419,22 +598,39 @@ def wrap_ctx(fn):
 
 def timed_iter(gen, name: str):
     """Wrap a batch generator so the time blocked producing each item
-    is recorded as a child span of the consumer's current span.
-    Returns the generator unchanged when untraced."""
+    is a child span of the consumer's current span (spans the producer
+    opens nest under it).  Returns the generator unchanged when
+    untraced."""
     if _current.get() is None:
         return gen
 
     def timed():
         it = iter(gen)
         while True:
-            t0 = time.perf_counter()
-            try:
-                item = next(it)
-            except StopIteration:
-                return
-            record(name, time.perf_counter() - t0)
+            with span(name) as sp:
+                try:
+                    item = next(it)
+                except StopIteration:
+                    sp.discard()
+                    return
             yield item
     return timed()
+
+
+def flat(rec: dict) -> dict | None:
+    """The per-request line `GET /minio/admin/v3/trace` serves, from a
+    request root's record; None for a root that is no request (a lane
+    dispatch)."""
+    tags = rec.get("tags", {})
+    if "method" not in tags:
+        return None
+    return {"time": rec["time"], "api": rec["name"],
+            "method": tags["method"], "path": tags.get("path", ""),
+            "statusCode": tags.get("status", 0),
+            "durationMs": round(rec["dur_ms"], 3),
+            "requestSize": tags.get("request_size", 0),
+            "responseSize": tags.get("response_size", 0),
+            "sourceIp": tags.get("source_ip", "")}
 
 
 # -- analysis helpers (bench attribution, tests) ----------------------------

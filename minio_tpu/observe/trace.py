@@ -1,15 +1,13 @@
-"""HTTP trace pubsub: zero-cost when nobody subscribes.
-
-The cmd/http-tracer.go:117 + internal/pubsub equivalent: every request
-builds a TraceInfo (timings, sizes, status) and publishes it; `admin
-trace`-style subscribers attach/detach dynamically. Publish is a no-op
-when there are no subscribers, matching the reference's design goal.
-"""
+"""The internal/pubsub equivalent: subscribers attach and detach
+dynamically, publish fans an item out to whoever is attached.  The one
+trace plane that publishes through it is observe/span.py (a request's
+root span carries what cmd/http-tracer.go's TraceInfo did: status,
+sizes, source address); bucket/notify.py streams events through its
+own instance."""
 
 from __future__ import annotations
 
 import threading
-import time
 from collections import deque
 
 
@@ -41,29 +39,3 @@ class PubSub:
     def num_subscribers(self) -> int:
         with self._mu:
             return len(self._subs)
-
-
-class HTTPTracer:
-    def __init__(self):
-        self.pubsub = PubSub()
-
-    def active(self) -> bool:
-        return self.pubsub.num_subscribers > 0
-
-    def trace(self, *, method: str, path: str, status: int,
-              duration_ms: float, request_size: int = 0,
-              response_size: int = 0, api_name: str = "",
-              source_ip: str = "") -> None:
-        if not self.active():
-            return
-        self.pubsub.publish({
-            "time": time.time(),
-            "api": api_name or method,
-            "method": method,
-            "path": path,
-            "statusCode": status,
-            "durationMs": round(duration_ms, 3),
-            "requestSize": request_size,
-            "responseSize": response_size,
-            "sourceIp": source_ip,
-        })

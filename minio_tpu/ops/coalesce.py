@@ -158,15 +158,17 @@ class DispatchCtx:
 
 class Handle:
     """Future for one submitted work item.  `result()` blocks until the
-    dispatcher resolved the item (and bridges the measured queue wait
-    into the caller's span tree as the `coalesce.wait` stage);
-    `release()` tells the buffer pool the caller is done with any
-    pooled views this result aliases."""
+    dispatcher resolved the item — the caller's block is the
+    `coalesce.wait` stage of its span tree, tagged with the queue wait
+    (enqueue to dispatch start) and the lane's device; `release()`
+    tells the buffer pool the caller is done with any pooled views this
+    result aliases.  `rid` is the submitter's request id while it is
+    traced: what a lane dispatch lists as its `members`."""
 
     __slots__ = ("_ev", "_res", "_exc", "_t_enq", "_t_disp", "_ctx",
-                 "weight", "nrows")
+                 "weight", "nrows", "device", "rid")
 
-    def __init__(self, weight: int, nrows: int):
+    def __init__(self, weight: int, nrows: int, device: int = 0):
         self._ev = threading.Event()
         self._res = None
         self._exc: BaseException | None = None
@@ -175,14 +177,17 @@ class Handle:
         self._ctx: DispatchCtx | None = None
         self.weight = weight
         self.nrows = nrows
+        self.device = device
+        self.rid = ospan.request_id()
 
     def result(self, timeout: float | None = 120.0):
-        if not self._ev.wait(timeout):
-            raise TimeoutError("coalesced dispatch did not complete")
-        if self._t_disp is not None:
-            ospan.record("coalesce.wait",
-                         max(0.0, self._t_disp - self._t_enq))
-            self._t_disp = None
+        with ospan.span("coalesce.wait") as sp:
+            if not self._ev.wait(timeout):
+                raise TimeoutError("coalesced dispatch did not complete")
+            if self._t_disp is not None:
+                sp.tag(device=self.device, queue_ms=round(
+                    max(0.0, self._t_disp - self._t_enq) * 1e3, 4))
+                self._t_disp = None
         if self._exc is not None:
             raise self._exc
         return self._res
@@ -204,8 +209,29 @@ class DispatchLane:
     #: this, submit() blocks (backpressure) instead of buffering.
     QUEUE_FACTOR = 4
 
+    #: The lane's wall time since it was made, partitioned: `no_work`
+    #: (parked with empty queues and nothing in flight), `linger` (the
+    #: adaptive window, the in-flight window and the scheduler's own
+    #: bookkeeping), then the four phases of a dispatch — `pack`
+    #: (concatenate / copy into staging, pad), `h2d` (device_put),
+    #: `launch` (the kernel call; a kernel without a launch/resolve
+    #: split — host kernels, verify+transform — runs whole under it),
+    #: `device_wait` (the resolve: blocked until the device's result is
+    #: on the host).  The lane thread's state wins while it is awake; a
+    #: dispatch run inline on a request thread is charged while the
+    #: thread is parked.  One set of timers: the `lane.*` spans open at
+    #: the same edges, and `pack_s` / `h2d_s` / `resolve_s` in stats()
+    #: are these.
+    STATES = ("no_work", "linger", "pack", "h2d", "launch",
+              "device_wait")
+
     def __init__(self, device: int = 0):
         self.device = int(device)
+        self._clk_mu = threading.Lock()
+        self._state = "no_work"
+        self._state_t = self.t_created = time.monotonic()
+        self._state_s = dict.fromkeys(self.STATES, 0.0)
+        self._awake = False
         self._mu = threading.Lock()
         self._work = threading.Condition(self._mu)
         self._space = threading.Condition(self._mu)
@@ -249,10 +275,49 @@ class DispatchLane:
         self.h2d_bytes = 0
         self.h2d_dispatches = 0
         self.pipeline_dispatches = 0
-        self.pack_s = 0.0
-        self.h2d_s = 0.0
-        self.resolve_s = 0.0
         self.overlap_s = 0.0
+
+    # -- the lane's clock ----------------------------------------------------
+
+    def _clock(self, state: str, inline: bool = False) -> None:
+        """Enter `state`: the time since the last transition goes to
+        the state that ends here."""
+        now = time.monotonic()
+        with self._clk_mu:
+            if inline:
+                if self._awake:
+                    return
+            else:
+                self._awake = state != "no_work"
+            self._state_s[self._state] += now - self._state_t
+            self._state, self._state_t = state, now
+
+    def _stage(self, state: str, inline: bool = False):
+        """One edge for both clocks: the lane's state and, inside a
+        traced dispatch, the `lane.<state>` span."""
+        self._clock(state, inline)
+        return ospan.span(_LANE_SPAN[state])
+
+    def state_seconds(self) -> dict[str, float]:
+        """Seconds per state up to now; they sum to the lane's age."""
+        now = time.monotonic()
+        with self._clk_mu:
+            out = dict(self._state_s)
+            out[self._state] += now - self._state_t
+        return out
+
+    def _dispatch_span(self, key: tuple, items: list[tuple], rows: int,
+                       padded: int):
+        """The `lane.dispatch` span of one batch: nested under the
+        request when the dispatch runs inline on its thread, else a
+        root of the lane thread's own, naming the requests it serves."""
+        if not ospan.TRACER.enabled:
+            return ospan.NOOP
+        return ospan.span_or_root(
+            "lane.dispatch", device=self.device,
+            program="/".join(str(p) for p in key), items=len(items),
+            rows=rows, padded_rows=padded,
+            members=sorted({h.rid for _, h in items if h.rid}))
 
     # -- submission ----------------------------------------------------------
 
@@ -266,7 +331,8 @@ class DispatchLane:
         the key encodes every parameter the kernel closes over."""
         payload = np.asarray(payload)
         nrows = int(payload.shape[0]) if payload.ndim else 1
-        h = Handle(int(weight) if weight is not None else nrows, nrows)
+        h = Handle(int(weight) if weight is not None else nrows, nrows,
+                   self.device)
         cap = self.QUEUE_FACTOR * max_batch()
         with self._mu:
             if self._stopped:
@@ -292,10 +358,13 @@ class DispatchLane:
                     self._thread.start()
                 # Backpressure: an item never waits on its OWN weight
                 # (a single oversized item must always be admissible).
-                while self._pending_weight and \
+                if self._pending_weight and \
                         self._pending_weight + h.weight > cap:
-                    self._space.wait(0.05)
-                    cap = self.QUEUE_FACTOR * max_batch()
+                    with ospan.span("coalesce.backpressure"):
+                        while self._pending_weight and \
+                                self._pending_weight + h.weight > cap:
+                            self._space.wait(0.05)
+                            cap = self.QUEUE_FACTOR * max_batch()
                 q = self._queues.get(key)
                 if q is None:
                     q = self._queues[key] = deque()
@@ -306,8 +375,10 @@ class DispatchLane:
                 self._work.notify()
         if inline:
             try:
-                self._dispatch([(payload, h)], h.weight, fn)
+                self._dispatch([(payload, h)], h.weight, fn, key,
+                               inline=True)
             finally:
+                self._clock("no_work", inline=True)
                 with self._mu:
                     self._inline -= 1
         return h
@@ -342,6 +413,7 @@ class DispatchLane:
         return oldest_key
 
     def _loop(self) -> None:
+        self._clock("linger")
         try:
             while True:
                 do_drain = False
@@ -363,7 +435,9 @@ class DispatchLane:
                             break
                         if self._stopped:
                             return
+                        self._clock("no_work")
                         self._work.wait()
+                        self._clock("linger")
                         key = self._pick_key()
                     if not do_drain:
                         q = self._queues[key]
@@ -397,7 +471,8 @@ class DispatchLane:
                 if do_drain:
                     self._drain_pipeline()
                 else:
-                    self._dispatch(items, w, fn, pipelined=True)
+                    self._dispatch(items, w, fn, key, pipelined=True)
+                self._clock("linger")
                 with self._mu:
                     # Stay "dispatching" while a launch is unresolved so
                     # the inline fast path cannot race a pending batch.
@@ -408,6 +483,8 @@ class DispatchLane:
             # queued rather than leaving submitters parked on handles
             # no thread will ever resolve.
             self._abort(e)
+        finally:
+            self._clock("no_work")
 
     def _abort(self, exc: BaseException) -> None:
         """Dispatcher death: error every queued handle, route all future
@@ -419,6 +496,8 @@ class DispatchLane:
             pending, self._pending = self._pending, None
             if pending is not None:
                 victims.extend(h for _, h in pending[1])
+                pending[6].resume().tag(error=True).__exit__(
+                    None, None, None)
             for q in self._queues.values():
                 victims.extend(h for _, h in q)
                 q.clear()
@@ -434,30 +513,57 @@ class DispatchLane:
             h._exc = err
             h._ev.set()
 
-    def _dispatch(self, items: list[tuple], w: int, fn,
-                  pipelined: bool = False) -> None:
+    def _dispatch(self, items: list[tuple], w: int, fn, key: tuple,
+                  pipelined: bool = False, inline: bool = False) -> None:
         if pipelined:
             launch = getattr(fn, "launch", None)
             if launch is not None and devcache.h2d_pipeline_enabled():
-                if self._dispatch_pipelined(items, w, fn, launch):
+                if self._dispatch_pipelined(items, w, fn, key, launch):
                     return
             # Serial dispatch from the lane thread must not outrun a
             # still-pending launch (per-key FIFO): resolve it first.
             if self._pending is not None:
                 self._drain_pipeline()
         t_disp = time.monotonic()
+        n = sum(h.nrows for _, h in items)
+        mult = int(getattr(fn, "pad_rows", 1) or 1)
+        with self._dispatch_span(key, items, n, n + (-n) % mult):
+            self._dispatch_serial(items, w, fn, t_disp, inline)
+
+    def _dispatch_serial(self, items: list[tuple], w: int, fn,
+                         t_disp: float, inline: bool) -> None:
+        """One batch through its kernel on the calling thread.  A
+        kernel with a launch/resolve split runs as the four phases the
+        pipelined path has (the same calls its own body makes); any
+        other runs whole under `launch`."""
         ctx = DispatchCtx(self._bufs, len(items))
+        launch = getattr(fn, "launch", None)
         try:
-            if len(items) == 1:
-                stacked = items[0][0]
+            with self._stage("pack", inline):
+                if len(items) == 1:
+                    stacked = items[0][0]
+                else:
+                    stacked = np.concatenate([p for p, _ in items],
+                                             axis=0)
+                spans = []
+                lo = 0
+                for _, h in items:
+                    spans.append((lo, lo + h.nrows))
+                    lo += h.nrows
+                if launch is not None:
+                    x, n = pad_batch(stacked, int(fn.pad_rows))
+            if launch is None:
+                with self._stage("launch", inline):
+                    results = fn(stacked, spans, ctx)
             else:
-                stacked = np.concatenate([p for p, _ in items], axis=0)
-            spans = []
-            lo = 0
-            for _, h in items:
-                spans.append((lo, lo + h.nrows))
-                lo += h.nrows
-            results = fn(stacked, spans, ctx)
+                from . import devices as devices_mod
+
+                with self._stage("h2d", inline):
+                    x = devices_mod.put(x, self.device)
+                with self._stage("launch", inline):
+                    resolve = launch(x, n, spans, ctx)
+                with self._stage("device_wait", inline):
+                    results = resolve()
         except BaseException as e:  # noqa: BLE001 — contain the fault
             if ctx.buf is not None:
                 self._bufs.give(ctx.buf)
@@ -528,7 +634,7 @@ class DispatchLane:
         return lease.view[:nbytes]
 
     def _dispatch_pipelined(self, items: list[tuple], w: int, fn,
-                            launch) -> bool:
+                            key: tuple, launch) -> bool:
         """Pack the batch into the spare staging buffer, ship it with an
         async device_put, launch the kernel, and resolve the PREVIOUS
         launch afterwards — so this batch's host work (pack + upload
@@ -555,22 +661,26 @@ class DispatchLane:
         mult = int(getattr(fn, "pad_rows", 1) or 1)
         padded = n + (-n) % mult
         need = padded * row_bytes
-        slot = self._staging_flip
-        self._staging_flip ^= 1
-        view = self._staging_view(slot, need).reshape(
-            (padded,) + row_shape)
-        lo = 0
-        for p, h in items:
-            view[lo:lo + h.nrows] = p
-            lo += h.nrows
-        if padded > n:
-            view[n:] = 0
+        # Open from this pack to this batch's resolve, one dispatch
+        # later: suspended in between, while the lane packs the next.
+        root = self._dispatch_span(key, items, n, padded).__enter__()
+        with self._stage("pack"):
+            slot = self._staging_flip
+            self._staging_flip ^= 1
+            view = self._staging_view(slot, need).reshape(
+                (padded,) + row_shape)
+            lo = 0
+            for p, h in items:
+                view[lo:lo + h.nrows] = p
+                lo += h.nrows
+            if padded > n:
+                view[n:] = 0
         t_pack = time.monotonic()
         import jax
 
-        x = jax.device_put(view, dev)     # async H2D from pinned staging
-        devcache.note_h2d(need, self.device)
-        t_h2d = time.monotonic()
+        with self._stage("h2d"):
+            x = jax.device_put(view, dev)  # async H2D from pinned staging
+            devcache.note_h2d(need, self.device)
         spans = []
         lo = 0
         for _, h in items:
@@ -578,7 +688,8 @@ class DispatchLane:
             lo += h.nrows
         ctx = DispatchCtx(self._bufs, len(items))
         try:
-            resolve = launch(x, n, spans, ctx)
+            with self._stage("launch"):
+                resolve = launch(x, n, spans, ctx)
         except BaseException:  # noqa: BLE001 — fall back to serial
             # Launch is the cheap half (placement + trace); a fault here
             # re-runs the batch on the serial path, whose containment
@@ -586,16 +697,16 @@ class DispatchLane:
             if ctx.buf is not None:
                 self._bufs.give(ctx.buf)
                 ctx.buf = None
+            root.tag(error=True).__exit__(None, None, None)
             return False
+        root.suspend()
         prev, self._pending = self._pending, (
-            resolve, items, w, fn, ctx, t_pack)
+            resolve, items, w, fn, ctx, t_pack, root)
         host_s = time.monotonic() - t0
         with self._mu:
             self.h2d_bytes += need
             self.h2d_dispatches += 1
             self.pipeline_dispatches += 1
-            self.pack_s += t_pack - t0
-            self.h2d_s += t_h2d - t_pack
             if prev is not None:
                 # Everything this batch just did on the host ran while
                 # `prev`'s kernel executed on-device.
@@ -612,10 +723,18 @@ class DispatchLane:
     def _resolve(self, pending: tuple) -> None:
         """Sync one launched batch and scatter its results — the second
         phase of `_dispatch`, deferred one dispatch behind the launch."""
-        resolve, items, w, fn, ctx, t_disp = pending
-        t0 = time.monotonic()
+        resolve, items, w, fn, ctx, t_disp, root = pending
+        root.resume()
         try:
-            results = resolve()
+            self._resolve_open(resolve, items, w, fn, ctx, t_disp)
+        finally:
+            root.__exit__(None, None, None)
+
+    def _resolve_open(self, resolve, items: list[tuple], w: int, fn, ctx,
+                      t_disp: float) -> None:
+        try:
+            with self._stage("device_wait"):
+                results = resolve()
         except BaseException:  # noqa: BLE001 — contain the fault
             if ctx.buf is not None:
                 self._bufs.give(ctx.buf)
@@ -642,8 +761,6 @@ class DispatchLane:
                     self.member_retries += 1
                 h._t_disp = t_disp
                 h._ev.set()
-            with self._mu:
-                self.resolve_s += time.monotonic() - t0
             return
         wait_sum = 0.0
         for (_, h), res in zip(items, results):
@@ -659,7 +776,6 @@ class DispatchLane:
             self.wait_s += wait_sum
             self.max_items = max(self.max_items, len(items))
             self._ema = 0.75 * self._ema + 0.25 * len(items)
-            self.resolve_s += time.monotonic() - t0
         DATA_PATH.record_coalesce_dispatch(len(items), w, wait_sum)
         DATA_PATH.record_lane_dispatch(self.device, len(items), w,
                                        wait_sum)
@@ -687,6 +803,7 @@ class DispatchLane:
             h._ev.set()
 
     def stats(self) -> dict:
+        state_s = self.state_seconds()
         with self._mu:
             return {
                 "device": self.device,
@@ -704,10 +821,11 @@ class DispatchLane:
                 "h2d_bytes": self.h2d_bytes,
                 "h2d_dispatches": self.h2d_dispatches,
                 "pipeline_dispatches": self.pipeline_dispatches,
-                "pack_s": self.pack_s,
-                "h2d_s": self.h2d_s,
-                "resolve_s": self.resolve_s,
+                "pack_s": state_s["pack"],
+                "h2d_s": state_s["h2d"],
+                "resolve_s": state_s["device_wait"],
                 "overlap_s": self.overlap_s,
+                "state_s": state_s,
                 "broken": self._broken is not None,
             }
 
@@ -875,6 +993,8 @@ def make_digest_kernel(algo: str, pad_rows: int = 0,
 
 _CO: DispatchCoalescer | None = None
 _CO_MU = threading.Lock()
+
+_LANE_SPAN = {st: "lane." + st for st in DispatchLane.STATES}
 
 #: Remote-submit front end (ops/ipc_dispatch.RemoteCoalescer), attached
 #: by server/workers.py inside a forked HTTP worker.  When set, every

@@ -31,6 +31,7 @@ from __future__ import annotations
 
 import os
 import sys
+import threading
 
 #: (devices, platform, device_kind, device_count) — the ONE answer to
 #: "what does this deployment compute on".  `devices` holds the jax
@@ -49,10 +50,49 @@ def _visible() -> tuple[list, str, str, int]:
     if _VISIBLE is None:
         import jax
 
+        _count_compiles(jax)
         devs = list(jax.devices())
         _VISIBLE = (devs, jax.default_backend(), devs[0].device_kind,
                     len(devs))
     return _VISIBLE
+
+
+_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+_CACHE_HIT_EVENT = "/jax/compilation_cache/cache_retrieval_time_sec"
+_compile_tl = threading.local()
+_compile_listener = False
+
+
+def _count_compiles(jax) -> None:
+    """Count what XLA compiles in this process (once, where the device
+    owner initialises JAX): `mtpu_jit_compiles_total` and
+    `mtpu_jit_compile_seconds_total`.  JAX reports the duration of
+    "compile or load from the persistent cache" on the compiling
+    thread, and just before it, on a cache hit, the retrieval time: a
+    hit is no compile and is not counted.  The listener runs on the
+    thread that stalled, so the compile also lands as `device.compile`
+    on whatever span is current there: the request that met a
+    first-sight shape, or the lane's dispatch."""
+    global _compile_listener
+    if _compile_listener:
+        return
+    _compile_listener = True
+    from ..observe import span as ospan
+    from ..observe.metrics import DATA_PATH
+
+    def on_duration(event: str, secs: float, **_kw) -> None:
+        if event == _CACHE_HIT_EVENT:
+            _compile_tl.hit = True
+        elif event == _COMPILE_EVENT:
+            if getattr(_compile_tl, "hit", False):
+                _compile_tl.hit = False
+                return
+            DATA_PATH.record_jit_compile(secs)
+            ospan.record("device.compile", secs)
+
+    from jax import monitoring
+
+    monitoring.register_event_duration_secs_listener(on_duration)
 
 
 def adopt(platform: str, kind: str, count: int) -> None:

@@ -119,17 +119,18 @@ def gf_matmul_blocks(mat_bits: jax.Array | np.ndarray, x: jax.Array,
     """
     from . import devices, erasure_jax
 
-    x = jnp.asarray(x, dtype=jnp.uint8)
-    b, c, s = x.shape
-    mat = jnp.asarray(mat_bits, dtype=jnp.bfloat16)
-    on_tpu = devices.on_tpu()
-    if (not on_tpu and not FORCE_INTERPRET) or b == 0 or s == 0:
-        if salt is not None:
-            x = x ^ salt[0].astype(jnp.uint8)
-        return erasure_jax._gf_matmul_blocks(mat, x, rows)
-    pad = -s % 128
-    if pad:
-        x = jnp.pad(x, ((0, 0), (0, 0), (0, pad)))
-    out = _pallas_gf_matmul(mat, x, rows, _choose_tile_s(s + pad),
-                            interpret=not on_tpu, salt=salt)
-    return out[:, :, :s] if pad else out
+    with jax.named_scope("gf_matmul"):
+        x = jnp.asarray(x, dtype=jnp.uint8)
+        b, c, s = x.shape
+        mat = jnp.asarray(mat_bits, dtype=jnp.bfloat16)
+        on_tpu = devices.on_tpu()
+        if (not on_tpu and not FORCE_INTERPRET) or b == 0 or s == 0:
+            if salt is not None:
+                x = x ^ salt[0].astype(jnp.uint8)
+            return erasure_jax._gf_matmul_blocks(mat, x, rows)
+        pad = -s % 128
+        if pad:
+            x = jnp.pad(x, ((0, 0), (0, 0), (0, pad)))
+        out = _pallas_gf_matmul(mat, x, rows, _choose_tile_s(s + pad),
+                                interpret=not on_tpu, salt=salt)
+        return out[:, :, :s] if pad else out

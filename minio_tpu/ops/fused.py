@@ -30,7 +30,6 @@ import functools
 import jax
 import jax.numpy as jnp
 
-from ..observe import span as ospan
 from . import devcache
 from . import devices as devices_mod
 from . import erasure_jax, erasure_pallas
@@ -42,20 +41,12 @@ from .mxhash_jax import mxh256_rows
 DEVICE_ALGOS = ("mxh256", "highwayhash256S", "highwayhash256")
 
 
-def _traced_dispatch(name: str, fn, x, device: int | None = None):
-    """Run a jitted kernel call; inside a traced request the span covers
-    dispatch AND device completion (block_until_ready), so the trace
-    attributes real device time (tagged with the lane's device index
-    when the dispatch is placed). Untraced calls stay fully async —
-    callers sync via np.asarray exactly as before."""
-    if not ospan.active():
-        return fn(x)
-    with ospan.span(name) as sp:
-        if device is not None:
-            sp.tag(device=int(device))
-        out = fn(x)
-        jax.block_until_ready(out)
-        return out
+def _named_jit(name: str, fn):
+    """jit `fn` under a name that says what the program is: the
+    profiler's `XLA Modules` line and the compile log then tell an
+    encode from a GET-verify from a hash-only program (`jit_<name>`)."""
+    fn.__name__ = fn.__qualname__ = name
+    return jax.jit(fn)
 
 
 def _placed(x, device: int | None):
@@ -94,10 +85,9 @@ def _digest_rows(x2d: jax.Array, algo: str, key: bytes) -> jax.Array:
 
 @functools.lru_cache(maxsize=16)
 def _hash_rows2d_jit(algo: str, key: bytes):
-    @jax.jit
     def fn(x):  # (N, S) uint8
         return _digest_rows(x, algo, key)
-    return fn
+    return _named_jit(f"hash_rows_{algo}", fn)
 
 
 def hash_rows_async(x, algo: str, key: bytes = MAGIC_KEY):
@@ -113,12 +103,11 @@ def hash_rows_async(x, algo: str, key: bytes = MAGIC_KEY):
 
 @functools.lru_cache(maxsize=16)
 def _hash_rows_jit(algo: str, key: bytes):
-    @jax.jit
     def fn(x):  # (B, K, S) uint8
         b, kk, s = x.shape
         return _digest_rows(x.reshape(b * kk, s), algo, key).reshape(
             b, kk, 32)
-    return fn
+    return _named_jit(f"verify_{algo}", fn)
 
 
 @functools.lru_cache(maxsize=512)
@@ -129,7 +118,6 @@ def _verify_transform_jit(k: int, m: int, sources: tuple[int, ...],
         dtype=jnp.bfloat16)
     rows = len(targets)
 
-    @jax.jit
     def fn(x):  # x: (B, K, S) uint8 — rows in `sources` order
         b, kk, s = x.shape
         digests = _digest_rows(x.reshape(b * kk, s), algo, key).reshape(
@@ -137,7 +125,9 @@ def _verify_transform_jit(k: int, m: int, sources: tuple[int, ...],
         out = erasure_pallas.gf_matmul_blocks(mat, x, rows)
         return digests, out
 
-    return fn
+    return _named_jit(
+        f"verify_transform_k{k}m{m}_s{'_'.join(map(str, sources))}"
+        f"_t{'_'.join(map(str, targets))}_{algo}", fn)
 
 
 def verify_and_transform(x, k: int, m: int, sources: tuple[int, ...],
@@ -155,13 +145,9 @@ def verify_and_transform(x, k: int, m: int, sources: tuple[int, ...],
     """
     x = _placed(x, device)
     if not targets:
-        return _traced_dispatch("device.verify",
-                                _hash_rows_jit(algo, key), x,
-                                device=device), None
-    fn = _verify_transform_jit(k, m, tuple(sources), tuple(targets),
-                               algo, key)
-    return _traced_dispatch("device.verify_transform", fn, x,
-                            device=device)
+        return _hash_rows_jit(algo, key)(x), None
+    return _verify_transform_jit(k, m, tuple(sources), tuple(targets),
+                                 algo, key)(x)
 
 
 @functools.lru_cache(maxsize=64)
@@ -172,13 +158,13 @@ def _encode_hash_jit(k: int, m: int, algo: str, key: bytes):
     def fn(x):  # x: (B, K, S) uint8 data shards
         b, kk, s = x.shape
         parity = erasure_pallas.gf_matmul_blocks(mat, x, m)
-        full = jnp.concatenate([x, parity], axis=1)       # (B, K+M, S)
-        digests = _digest_rows(
-            full.transpose(1, 0, 2).reshape((kk + m) * b, s),
-            algo, key).reshape(kk + m, b, 32)
+        with jax.named_scope("stack_for_hash"):
+            full = jnp.concatenate([x, parity], axis=1)   # (B, K+M, S)
+            rows = full.transpose(1, 0, 2).reshape((kk + m) * b, s)
+        digests = _digest_rows(rows, algo, key).reshape(kk + m, b, 32)
         return parity, digests
 
-    return jax.jit(fn)
+    return _named_jit(f"encode_hash_k{k}m{m}_{algo}", fn)
 
 
 def encode_and_hash(x, k: int, m: int, algo: str = "highwayhash256S",
@@ -191,7 +177,4 @@ def encode_and_hash(x, k: int, m: int, algo: str = "highwayhash256S",
     Digest layout is shard-major to match frame_shards_batch's
     (n_shards, n_blocks) order.  `device` places the dispatch on that
     coalescer lane's device (None = default device)."""
-    x = _placed(x, device)
-    return _traced_dispatch(
-        "device.encode_hash", _encode_hash_jit(k, m, algo, key), x,
-        device=device)
+    return _encode_hash_jit(k, m, algo, key)(_placed(x, device))

@@ -361,12 +361,13 @@ class RemoteHandle:
         self.nrows = nrows
 
     def result(self, timeout: float | None = 120.0):
-        if not self._ev.wait(timeout):
-            raise TimeoutError("remote dispatch did not complete")
-        if self._t_done is not None:
-            ospan.record("ipc.wait",
-                         max(0.0, self._t_done - self._t_enq))
-            self._t_done = None
+        with ospan.span("ipc.wait") as sp:
+            if not self._ev.wait(timeout):
+                raise TimeoutError("remote dispatch did not complete")
+            if self._t_done is not None:
+                sp.tag(queue_ms=round(
+                    max(0.0, self._t_done - self._t_enq) * 1e3, 4))
+                self._t_done = None
         if self._exc is not None:
             raise self._exc
         return self._res

@@ -93,12 +93,13 @@ class MetaHandle:
         self._t_disp: float | None = None
 
     def result(self, timeout: float | None = 120.0):
-        if not self._ev.wait(timeout):
-            raise TimeoutError("batched metadata op did not complete")
-        if self._t_disp is not None:
-            ospan.record("metalane.wait",
-                         max(0.0, self._t_disp - self._t_enq))
-            self._t_disp = None
+        with ospan.span("metalane.wait") as sp:
+            if not self._ev.wait(timeout):
+                raise TimeoutError("batched metadata op did not complete")
+            if self._t_disp is not None:
+                sp.tag(queue_ms=round(
+                    max(0.0, self._t_disp - self._t_enq) * 1e3, 4))
+                self._t_disp = None
         if self._exc is not None:
             raise self._exc
         return self._res

@@ -13,8 +13,6 @@ config #5): the shard bytes cross HBM once for verify + reconstruct.
 
 from __future__ import annotations
 
-import functools
-
 import jax
 import jax.numpy as jnp
 
@@ -48,19 +46,20 @@ def mxh256_rows(x: jax.Array) -> jax.Array:
     """Traceable core: (n, L) uint8 -> (n, 32) uint8 digests."""
     n, ln = x.shape
     cur = x
-    while True:
-        cur = _level(cur)
-        if cur.shape[1] == mxhash.DIGEST_SIZE:
-            break
-    tag = jnp.asarray(mxhash.length_tag(ln))   # trace-time constant
-    return cur ^ tag[None, :]
+    with jax.named_scope("mxh256"):
+        while True:
+            cur = _level(cur)
+            if cur.shape[1] == mxhash.DIGEST_SIZE:
+                break
+        tag = jnp.asarray(mxhash.length_tag(ln))  # trace-time constant
+        return cur ^ tag[None, :]
 
 
-@functools.partial(jax.jit)
-def _mxh256_batch_jit(x):
+@jax.jit
+def mxh256_batch(x):
     return mxh256_rows(x)
 
 
 def mxh256_batch_jax(blocks) -> jax.Array:
     """Jitted batch digest: (n, L) uint8 -> (n, 32) uint8."""
-    return _mxh256_batch_jit(jnp.asarray(blocks, dtype=jnp.uint8))
+    return mxh256_batch(jnp.asarray(blocks, dtype=jnp.uint8))

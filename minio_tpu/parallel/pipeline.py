@@ -79,60 +79,69 @@ class StagePipeline:
 
     ``on_batch(read_s, compute_s, write_s)``, when given, is invoked
     once per batch with wall-clock seconds spent pulling the item from
-    `reads`, in `compute`, and in `write` — the per-stage attribution
-    the bench and /metrics surface for the multipart PUT pipeline.
+    `reads`, in `compute`, and in `write` — the always-on per-stage
+    attribution /metrics surfaces for the multipart PUT pipeline.
     With a pool the write time reported alongside a batch is the
     previous batch's (they overlap by design); only the aggregate sums
-    are meaningful."""
+    are meaningful.
+
+    ``stages=(name, write_name)``, when given, names the spans a traced
+    request gets per batch: `name` around read+compute on the caller's
+    thread, `write_name` around the write where it runs (the pool
+    thread), so what each stage calls nests under it."""
 
     def __init__(self, pool: Executor | None):
         self.pool = pool
 
-    def run(self, reads, compute, write, on_batch=None) -> int:
+    def run(self, reads, compute, write, on_batch=None,
+            stages: tuple[str, str] | None = None) -> int:
         n = 0
         clock = time.perf_counter
         it = iter(reads)
-        if self.pool is None:
-            while True:
+        front, back = stages or (None, None)
+
+        def pull():
+            """One batch through read + compute: (result, read_s,
+            compute_s), or None when `reads` is exhausted."""
+            with (ospan.span(front) if front else ospan.NOOP) as sp:
                 t0 = clock()
                 try:
                     item = next(it)
                 except StopIteration:
-                    break
+                    sp.discard()
+                    return None
                 t1 = clock()
                 res = compute(item)
-                t2 = clock()
+                return res, t1 - t0, clock() - t1
+
+        def timed_write(res):
+            with ospan.span(back) if back else ospan.NOOP:
+                t0 = clock()
                 write(res)
+                return clock() - t0
+
+        if self.pool is None:
+            while (got := pull()) is not None:
+                res, read_s, compute_s = got
+                write_s = timed_write(res)
                 if on_batch is not None:
-                    on_batch(t1 - t0, t2 - t1, clock() - t2)
+                    on_batch(read_s, compute_s, write_s)
                 n += 1
             return n
         wfut = None
         pend_rs = pend_cs = 0.0
-
-        @ospan.wrap_ctx
-        def timed_write(res):
-            t0 = clock()
-            write(res)
-            return clock() - t0
+        pooled_write = ospan.wrap_ctx(timed_write)
 
         try:
-            while True:
-                t0 = clock()
-                try:
-                    item = next(it)
-                except StopIteration:
-                    break
-                t1 = clock()
-                res = compute(item)
-                t2 = clock()
+            while (got := pull()) is not None:
+                res, read_s, compute_s = got
                 if wfut is not None:
                     w_s = wfut.result()
                     wfut = None
                     if on_batch is not None:
                         on_batch(pend_rs, pend_cs, w_s)
-                pend_rs, pend_cs = t1 - t0, t2 - t1
-                wfut = self.pool.submit(timed_write, res)
+                pend_rs, pend_cs = read_s, compute_s
+                wfut = self.pool.submit(pooled_write, res)
                 n += 1
             if wfut is not None:
                 w_s = wfut.result()

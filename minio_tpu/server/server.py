@@ -152,9 +152,7 @@ class S3Server:
         self.trace_sink = trace_sink
         from ..observe.logger import Logger, RingTarget
         from ..observe.metrics import MetricsRegistry
-        from ..observe.trace import HTTPTracer
         self.metrics = MetricsRegistry()
-        self.tracer = HTTPTracer()
         self.log = Logger()
         self.log_ring = RingTarget()
         self.log.add_target(self.log_ring)
@@ -478,7 +476,8 @@ class S3Server:
                 api_name = _api_name(self.command, path, query,
                                      self.headers)
                 rspan = ospan.TRACER.root(
-                    api_name, method=self.command, path=path)
+                    api_name, method=self.command, path=path,
+                    request_id=self.request_id)
                 rspan.__enter__()
                 # Audit identity/routing facts for THIS request.  Reset
                 # here because handler instances persist across
@@ -592,27 +591,28 @@ class S3Server:
                         outer.qos.charge_bucket_bw(
                             req_bucket,
                             outer._qos_bucket_rate(req_bucket), nbytes)
-                outer.tracer.trace(
-                    method=self.command, path=path, status=resp.status,
-                    duration_ms=dur * 1e3,
-                    request_size=int(self.headers.get("Content-Length",
-                                                      0) or 0),
-                    response_size=resp_size,
-                    source_ip=self.client_address[0])
                 if outer.slo_enabled:
                     outer.metrics.observe_api(api_name, dur,
                                               error=resp.status >= 400,
                                               nbytes=resp_size)
                 sb = ("" if path.startswith("/minio/")
                       else path.lstrip("/"))
-                rspan.tag(status=resp.status, bytes=resp_size,
-                          bucket=sb.split("/", 1)[0],
-                          object=(sb.split("/", 1)[1]
-                                  if "/" in sb else ""),
-                          error=resp.status >= 400)
+                if rspan is not ospan.NOOP:
+                    # What the admin trace stream's flat line carries
+                    # (ospan.flat), beside the tree's own tags.
+                    rspan.tag(status=resp.status,
+                              bucket=sb.split("/", 1)[0],
+                              object=(sb.split("/", 1)[1]
+                                      if "/" in sb else ""),
+                              error=resp.status >= 400,
+                              request_size=int(self.headers.get(
+                                  "Content-Length", 0) or 0),
+                              response_size=resp_size,
+                              source_ip=self.client_address[0])
                 try:
                     if resp.status != 499:
-                        self._respond(resp)
+                        with ospan.span("http.respond"):
+                            self._respond(resp)
                 except (BrokenPipeError, ConnectionResetError,
                         TimeoutError):
                     self.close_connection = True
@@ -764,6 +764,11 @@ class S3Server:
         # healing for the life of the process.
         self._httpd.shutdown()
         self._httpd.server_close()
+        # The polling trace subscription is this server's: the span
+        # tracer is the process's and would stay on without it.
+        ring = self.__dict__.pop("_trace_ring", None)
+        if ring is not None:
+            ospan.TRACER.unsubscribe(ring)
         # Flush + stop the audit drain threads (file targets flush
         # their tail; queued entries drain before the sentinel).
         for t in self.audit_targets:
@@ -865,7 +870,8 @@ class S3Server:
         if length > MAX_HEADER_BODY:
             raise S3Error("EntityTooLarge")
         if length:
-            return req.rfile.read(length)
+            with ospan.span("http.read_body"):
+                return req.rfile.read(length)
         if req.headers.get("Transfer-Encoding", "").lower() == "chunked":
             # HTTP chunked framing (not aws-chunked).
             out = bytearray()
@@ -1550,11 +1556,15 @@ class S3Server:
                 return j(seq.status())
             return j({"sequences": self.heal_state.statuses()})
         if sub == "trace" and method == "GET":
+            # Polling form of the one trace plane: the first call
+            # subscribes (which turns span tracing on); each call
+            # drains what completed since, one flat line per request.
             if not hasattr(self, "_trace_ring"):
-                self._trace_ring = self.tracer.pubsub.subscribe(2000)
-            items = list(self._trace_ring)
-            self._trace_ring.clear()
-            return j({"trace": items})
+                self._trace_ring = ospan.TRACER.subscribe(2000)
+            q = self._trace_ring
+            recs = [q.popleft() for _ in range(len(q))]
+            return j({"trace": [f for f in map(ospan.flat, recs)
+                                if f is not None]})
         if sub == "trace" and method == "POST":
             # Live span-trace stream (cf. TraceHandler,
             # cmd/admin-handlers.go): chunked NDJSON of completed
@@ -2569,11 +2579,12 @@ class S3Server:
         return results, node_up
 
     def _dispatch(self, req, path: str, query: dict) -> Response:
-        if self._stream_eligible(req.command, path, query):
-            body, access_key = self._authenticate_streaming(req, path,
-                                                            query)
-        else:
-            body, access_key = self._authenticate(req, path, query)
+        with ospan.span("http.auth"):
+            if self._stream_eligible(req.command, path, query):
+                body, access_key = self._authenticate_streaming(
+                    req, path, query)
+            else:
+                body, access_key = self._authenticate(req, path, query)
         # Auth succeeded and routing begins: stamp the audit identity.
         # A request that raised before this point audits with a null
         # object and an empty accessKey (rejected pre-dispatch).

@@ -28,6 +28,7 @@ import uuid
 import zlib
 
 from . import bitrot_io, diskio, oscounters
+from ..observe import span as ospan
 from ..utils import msgpackx
 from ..utils.crashpoints import crash_point
 from .errors import (ErrDiskNotFound, ErrFileAccessDenied, ErrFileCorrupt,
@@ -181,7 +182,7 @@ class LocalDrive:
     def write_all(self, vol: str, path: str, data: bytes) -> None:
         """Atomic small-file write (tmp + rename + fsync)."""
         self._check_vol(vol)
-        with self._osc.timed("write"):
+        with self._osc.timed("write", "meta_write"):
             return self._write_all(vol, path, data)
 
     def _write_all(self, vol: str, path: str, data: bytes) -> None:
@@ -236,7 +237,7 @@ class LocalDrive:
     # -- shard-file ops ------------------------------------------------------
 
     def create_file(self, vol: str, path: str, data: bytes) -> None:
-        with self._osc.timed('write'):
+        with self._osc.timed('write', 'create'):
             return self._create_file_impl(vol, path, data)
 
     def _create_file_impl(self, vol: str, path: str, data: bytes) -> None:
@@ -259,7 +260,7 @@ class LocalDrive:
         crash_point("shard.create.post_fsync")
 
     def append_file(self, vol: str, path: str, data: bytes) -> None:
-        with self._osc.timed('write'):
+        with self._osc.timed('write', 'append'):
             return self._append_file_impl(vol, path, data)
 
     def _ensure_parent_in_vol(self, vol: str, p: str) -> None:
@@ -306,7 +307,7 @@ class LocalDrive:
         the write goes O_DIRECT; EINVAL (tmpfs, odd fs) falls back to
         the buffered fd transparently.  Byte-identical to the
         append_file loop — pinned by the zerocopy matrix tests."""
-        with self._osc.timed('write'):
+        with self._osc.timed('write', 'append'):
             return self._write_file_batches_impl(vol, path, batches)
 
     def _write_file_batches_impl(self, vol: str, path: str,
@@ -427,10 +428,10 @@ class LocalDrive:
         """Atomic same-drive file move (parents auto-created)."""
         src = self._file_path(src_vol, src_path)
         dst = self._file_path(dst_vol, dst_path)
-        if not os.path.isfile(src):
-            raise ErrFileNotFound(f"{src_vol}/{src_path}")
-        self._ensure_parent_in_vol(dst_vol, dst)
         with self._osc.timed("rename"):
+            if not os.path.isfile(src):
+                raise ErrFileNotFound(f"{src_vol}/{src_path}")
+            self._ensure_parent_in_vol(dst_vol, dst)
             os.replace(src, dst)
 
     def list_raw(self, vol: str, path: str = "") -> list[str]:
@@ -484,7 +485,8 @@ class LocalDrive:
             # which quorum + heal already handle.
             p = self._file_path(vol, os.path.join(obj, XL_META_FILE))
             self._ensure_parent_in_vol(vol, p)
-            with self._osc.timed("write"), open(p, "wb") as f:
+            with self._osc.timed("write", "meta_write"), \
+                    open(p, "wb") as f:
                 f.write(meta.to_bytes())
             return
         self.write_all(vol, os.path.join(obj, XL_META_FILE), meta.to_bytes())
@@ -523,7 +525,7 @@ class LocalDrive:
         it with the quorum-elected metadata instead of failing forever.
         """
         self._check_vol(vol)
-        with self._meta_lock:
+        with ospan.span("storage.write_metadata"), self._meta_lock:
             try:
                 meta = self._read_xlmeta(vol, obj)
             except (ErrFileNotFound, ErrFileCorrupt):
@@ -708,6 +710,11 @@ class LocalDrive:
         src_dir is the staging dir whose *contents* are the part files;
         they are moved to <dst_obj>/<fi.data_dir>/.
         """
+        with ospan.span("storage.rename_data"):
+            self._rename_data(src_vol, src_dir, fi, dst_vol, dst_obj)
+
+    def _rename_data(self, src_vol: str, src_dir: str, fi: FileInfo,
+                     dst_vol: str, dst_obj: str) -> None:
         self._check_vol(dst_vol)
         with self._meta_lock:
             fresh = False

@@ -12,7 +12,7 @@ import time
 from collections import defaultdict
 from contextlib import contextmanager
 
-from ..observe.span import record as _span_record
+from ..observe.span import span as _span
 
 class Counters:
     """One instance per drive, so per-drive numbers actually attribute
@@ -20,9 +20,9 @@ class Counters:
     aggregates under every drive and overcount N x when summed).
 
     `drive` labels the owning drive; inside a traced request every
-    timed op doubles as a per-drive I/O span ("drive.read" etc.) —
-    the dt is already measured here, so the span costs one contextvar
-    read when tracing is off."""
+    timed op is also a per-drive I/O span, "storage.<stage>" (the
+    drive call: append, create, meta_write) or "storage.<op>" where no
+    stage is named — one contextvar read when tracing is off."""
 
     def __init__(self, drive: str = ""):
         self._mu = threading.Lock()
@@ -31,16 +31,17 @@ class Counters:
         self._drive = drive
 
     @contextmanager
-    def timed(self, op: str):
-        t0 = time.perf_counter()
-        try:
-            yield
-        finally:
-            dt = time.perf_counter() - t0
-            with self._mu:
-                self._counts[op] += 1
-                self._seconds[op] += dt
-            _span_record("drive." + op, dt, drive=self._drive)
+    def timed(self, op: str, stage: str | None = None):
+        with _span("storage." + (stage or op)) as sp:
+            sp.tag(drive=self._drive)
+            t0 = time.perf_counter()
+            try:
+                yield
+            finally:
+                dt = time.perf_counter() - t0
+                with self._mu:
+                    self._counts[op] += 1
+                    self._seconds[op] += dt
 
     def snapshot(self) -> dict:
         with self._mu:

@@ -13,6 +13,8 @@ from __future__ import annotations
 import hashlib
 import queue as _queue
 
+from ..observe import span as ospan
+
 #: Dedicated digest workers for PipelinedMD5.  They must NOT share an
 #: engine pool: an md5 worker occupies its slot for a whole PUT, and a
 #: worker that only ever drains its own queue can never deadlock — the
@@ -423,7 +425,10 @@ def _pooled_chunks(head: bytes, stream, chunk_len: int):
                 carry = carry[pre:]
             filled = pre
             if filled < chunk_len:
-                filled += _fill_from(stream, view[pre:])
+                # One span per chunk pulled (a pipeline batch), not one
+                # per recv: the time blocked on the client's socket.
+                with ospan.span("http.read_body"):
+                    filled += _fill_from(stream, view[pre:])
             if filled < chunk_len:
                 yield view[:filled], True    # final chunk (may be empty)
                 return
@@ -456,12 +461,13 @@ def batched_chunks(head: bytes, stream, chunk_len: int):
     buf = bytearray(head)
     eof = False
     while True:
-        while not eof and len(buf) < chunk_len:
-            piece = stream.read(chunk_len - len(buf))
-            if not piece:
-                eof = True
-            else:
-                buf += piece
+        with ospan.span("http.read_body"):
+            while not eof and len(buf) < chunk_len:
+                piece = stream.read(chunk_len - len(buf))
+                if not piece:
+                    eof = True
+                else:
+                    buf += piece
         if eof and len(buf) <= chunk_len:
             yield bytes(buf), True       # final chunk (may be empty)
             return

@@ -470,17 +470,22 @@ class TestLaneObservability:
             in text
 
     def test_dispatch_span_tagged_with_device(self, ndev):
+        """The device index rides the lane's spans: `lane.dispatch`
+        (here run inline, so nested under the request) and the
+        request's `coalesce.wait`."""
         from minio_tpu.observe import span as ospan
-        from minio_tpu.ops import fused
         ndev(8)
         ospan.TRACER.configure(ring=8)
         try:
-            x = np.zeros((1, 2, 128), dtype=np.uint8)
+            x = np.ones((3, 4), dtype=np.uint8)
             with ospan.root_span("get") as root:
-                fused.encode_and_hash(x, 2, 2, algo="mxh256", device=5)
-            kids = [s for s in root.children
-                    if s.name == "device.encode_hash"]
-            assert kids and kids[0].tags.get("device") == 5
+                h = coalesce.get().submit(("sum",), x, sum_kernel(),
+                                          device=5)
+                assert h.result() == 12
+            by = {s.name: s for s in root.children}
+            assert by["lane.dispatch"].tags["device"] == 5
+            assert by["lane.dispatch"].tags["program"] == "sum"
+            assert by["coalesce.wait"].tags["device"] == 5
         finally:
             ospan.TRACER.configure(ring=0)
 

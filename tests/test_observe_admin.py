@@ -12,7 +12,7 @@ from minio_tpu.engine.sets import ErasureSets
 from minio_tpu.iam.iam import IAMSys
 from minio_tpu.observe.logger import Logger, RingTarget, audit_entry
 from minio_tpu.observe.metrics import MetricsRegistry
-from minio_tpu.observe.trace import HTTPTracer
+from minio_tpu.observe import span as ospan
 from minio_tpu.server.client import S3Client, S3ClientError
 from minio_tpu.server.server import S3Server
 from minio_tpu.server.sigv4 import Credentials
@@ -54,14 +54,27 @@ class TestUnits:
         assert "mtpu_s3_ttfb_seconds_count 2" in text
 
     def test_tracer_zero_cost_without_subscribers(self):
-        tr = HTTPTracer()
-        assert not tr.active()
-        tr.trace(method="GET", path="/x", status=200, duration_ms=1)
-        q = tr.pubsub.subscribe()
-        tr.trace(method="PUT", path="/y", status=200, duration_ms=2)
-        assert len(q) == 1 and q[0]["method"] == "PUT"
-        tr.pubsub.unsubscribe(q)
-        tr.trace(method="GET", path="/z", status=200, duration_ms=1)
+        """The one trace plane: no subscriber, no record (and no Span);
+        a subscriber gets each finished request root, whose flat form
+        is the line the polling admin route serves."""
+        tr = ospan.SpanTracer()
+        tr.configure(ring=0, sample=1.0)
+        assert not tr.enabled
+        assert tr.root("api.GetObject", method="GET", path="/x") \
+            is ospan.NOOP
+        q = tr.subscribe()
+        with tr.root("api.PutObject", method="PUT", path="/y") as sp:
+            sp.tag(status=200, request_size=7, response_size=0,
+                   source_ip="1.2.3.4")
+        assert len(q) == 1
+        line = ospan.flat(q[0])
+        assert line["method"] == "PUT" and line["path"] == "/y"
+        assert line["api"] == "api.PutObject"
+        assert line["statusCode"] == 200 and line["requestSize"] == 7
+        assert line["sourceIp"] == "1.2.3.4" and line["durationMs"] >= 0
+        tr.unsubscribe(q)
+        assert tr.root("api.GetObject", method="GET", path="/z") \
+            is ospan.NOOP
         assert len(q) == 1
 
     def test_logger_ring_and_once(self):
