@@ -116,6 +116,14 @@ class ConfigSys:
                 return self._stored[subsys][key]
             return self._defaults.get(subsys, {}).get(key, "")
 
+    def is_set(self, subsys: str, key: str) -> bool:
+        """Whether an operator set the key (environment or stored), as
+        against its registered default."""
+        with self._mu:
+            return (f"{ENV_PREFIX}_{subsys.upper()}_{key.upper()}"
+                    in self._env
+                    or key in self._stored.get(subsys, {}))
+
     def get_subsys(self, subsys: str) -> dict[str, str]:
         with self._mu:
             out = dict(self._defaults.get(subsys, {}))

@@ -1070,62 +1070,20 @@ class ErasureSet:
 
     def _enc_kernel(self, k: int, m: int, algo: str, fused_dev: bool,
                     device: int | None = None):
-        """Device/native encode over the stacked blocks; device shapes
-        are padded to BATCH_BLOCKS buckets so coalesced batch sizes
-        don't multiply jit compiles.  Returns (parity, digests) per
-        span — the same pair the direct dispatch produces, so the
-        framing path downstream is shared.  `device` is the lane the
-        batch is placed on (the submitting set's affinity)."""
-
-        def kernel(stacked, spans, ctx):
-            if fused_dev:
-                x, n = coalesce.pad_batch(stacked, BATCH_BLOCKS)
-                parity, digests = fused.encode_and_hash(x, k, m,
-                                                        algo=algo,
-                                                        device=device)
-                parity = np.asarray(parity)[:n]
-                digests = np.asarray(digests)[:, :n]
-                return [(parity[lo:hi], digests[:, lo:hi])
-                        for lo, hi in spans]
-            if self._use_device:
-                x, n = coalesce.pad_batch(stacked, BATCH_BLOCKS)
-                parity = np.asarray(
-                    self._codec(k, m).encode_blocks(
-                        devices_mod.put(x, device)))[:n]
-            else:
-                parity = np.asarray(
-                    self._native(k, m).encode_blocks(stacked))
-            return [(parity[lo:hi], None) for lo, hi in spans]
-
-        if fused_dev or self._use_device:
-            def launch(x, n, spans, ctx):
-                # Pipeline form: `x` arrives staged on the lane's
-                # device, padded to BATCH_BLOCKS.
-                if fused_dev:
-                    parity_d, digests_d = fused.encode_and_hash(
-                        x, k, m, algo=algo, device=device)
-
-                    def resolve():
-                        parity = np.asarray(parity_d)[:n]
-                        digests = np.asarray(digests_d)[:, :n]
-                        return [(parity[lo:hi], digests[:, lo:hi])
-                                for lo, hi in spans]
-
-                    return resolve
-                if not self._use_device:
-                    raise RuntimeError("device codec unavailable")
-                parity_d = self._codec(k, m).encode_blocks(
-                    devices_mod.put(x, device))
-
-                def resolve():
-                    parity = np.asarray(parity_d)[:n]
-                    return [(parity[lo:hi], None) for lo, hi in spans]
-
-                return resolve
-
-            kernel.launch = launch
-            kernel.pad_rows = BATCH_BLOCKS
-        return kernel
+        """Device/native encode over the stacked blocks (ops/coalesce
+        .make_encode_kernel): a device batch is sized by the ladder of
+        BATCH_BLOCKS, so its shape follows the blocks it carries.
+        Returns (parity, digests) per span — the same pair the direct
+        dispatch produces, so the framing path downstream is shared.
+        `device` is the lane the batch is placed on (the submitting
+        set's affinity)."""
+        codec = None
+        if not fused_dev:
+            codec = (self._codec(k, m) if self._use_device
+                     else self._native(k, m))
+        return coalesce.make_encode_kernel(
+            k, m, algo, BATCH_BLOCKS, device, codec,
+            on_device=fused_dev or self._use_device)
 
     def _direct_encode(self, blocks, k: int, m: int, algo: str):
         """The no-coalescer encode for one (nb, K, S) batch — the same
@@ -1145,41 +1103,25 @@ class ErasureSet:
     def _vt_kernel(self, k: int, m: int, sources: tuple, targets: tuple,
                    algo: str, device: int | None = None):
         """Fused device verify(+reconstruct) over stacked (B, K, S)
-        gathers — the healthy-verify / degraded-decode / heal work
-        item.  Digest layout is (B, K, hs): axis 0 is the concat axis
-        for both outputs.  `device` places the dispatch on the
-        submitting set's affine lane."""
+        gathers (ops/coalesce.make_verify_kernel).  `device` places the
+        dispatch on the submitting set's affine lane."""
+        return coalesce.make_verify_kernel(k, m, sources, targets, algo,
+                                           BATCH_BLOCKS, device)
 
-        def kernel(stacked, spans, ctx):
-            x, n = coalesce.pad_batch(stacked, BATCH_BLOCKS)
-            digests, out = fused.verify_and_transform(
-                x, k, m, sources, targets, algo=algo, device=device)
-            digests = np.asarray(digests)[:n]
-            out = np.asarray(out)[:n] if targets else None
-            return [(digests[lo:hi],
-                     out[lo:hi] if out is not None else None)
-                    for lo, hi in spans]
-
-        def launch(x, n, spans, ctx):
-            # Pipeline form (ops/coalesce.py): `x` is the lane's staged
-            # device array, already padded to BATCH_BLOCKS and counted
-            # at its upload — the sync moves to resolve(), one dispatch
-            # behind.
-            digests_d, out_d = fused.verify_and_transform(
-                x, k, m, sources, targets, algo=algo, device=device)
-
-            def resolve():
-                digests = np.asarray(digests_d)[:n]
-                out = np.asarray(out_d)[:n] if targets else None
-                return [(digests[lo:hi],
-                         out[lo:hi] if out is not None else None)
-                        for lo, hi in spans]
-
-            return resolve
-
-        kernel.launch = launch
-        kernel.pad_rows = BATCH_BLOCKS
-        return kernel
+    def build_ladder(self, parity: int | None = None) -> None:
+        """Ask for the shape ladder of the device programs this set's
+        PUTs and GETs run at `parity` (None: the set's default): the
+        fused encode and the GET digest, for the write algorithm on
+        the set's lane.  Built off the calling
+        thread (ops/coalesce.build_ladder); nothing to do where the
+        shard math runs on the host."""
+        if not self._use_device:
+            return
+        m = self.clamp_parity(parity)
+        k = self.n - m
+        coalesce.build_geometry_ladder(
+            k, m, -(-BLOCK_SIZE // k), bitrot_io.write_algo(),
+            BATCH_BLOCKS, self.device_idx)
 
     def _encode_chunks(self, chunks, k: int, m: int,
                        algo: str | None = None,
@@ -2275,7 +2217,8 @@ class ErasureSet:
                     # Coalesced digest over the already-gathered rows
                     # (the gather IS the assembly, so this adds no
                     # copy): stacked with other requests' verify/encode
-                    # digest work into one batched hash kernel.
+                    # digest work into one batched hash kernel, sized
+                    # by the ladder of BATCH_BLOCKS * k rows.
                     pad_rows = BATCH_BLOCKS * k if self._use_device else 0
                     h = co.submit(
                         ("digest", algo, shard_size, pad_rows),

@@ -117,7 +117,17 @@ class ServerPools:
         if tier is not None:
             from .hotcache import attach_sets
             attach_sets(es, tier)
+        self.build_ladders(pools=[es])
         return len(self.pools) - 1
+
+    def build_ladders(self, parity: int | None = None,
+                      pools: list[ErasureSets] | None = None) -> None:
+        """Ask every set (of `pools`, default all) for the shape ladder
+        of its device programs at `parity` (None: each set's default);
+        see ErasureSet.build_ladder."""
+        for p in self.pools if pools is None else pools:
+            for es in p.sets:
+                es.build_ladder(parity)
 
     # -- bucket ops ----------------------------------------------------------
 

@@ -370,18 +370,22 @@ class DataPathStats:
             self.co_wait_s += wait_s
 
     def record_lane_dispatch(self, device: int, items: int, weight: int,
-                             wait_s: float) -> None:
-        """One coalesced launch on device lane `device`."""
+                             wait_s: float, rows: int = 0,
+                             padded_rows: int = 0) -> None:
+        """One coalesced launch on device lane `device`: `rows` of
+        work in a batch padded to `padded_rows`."""
         with self._mu:
             row = self.lanes.get(device)
             if row is None:
                 row = self.lanes[device] = {
                     "dispatches": 0, "items": 0, "weight": 0,
-                    "wait_s": 0.0}
+                    "wait_s": 0.0, "rows": 0, "padded_rows": 0}
             row["dispatches"] += 1
             row["items"] += items
             row["weight"] += weight
             row["wait_s"] += wait_s
+            row["rows"] += rows
+            row["padded_rows"] += padded_rows
 
     def record_jit_compile(self, seconds: float) -> None:
         with self._mu:
@@ -789,6 +793,16 @@ class MetricsRegistry:
             "mtpu_device_lane_queue_wait_seconds_total",
             "Summed per-item queue wait before dispatch on this "
             "device lane", ("device",))
+        self.device_lane_rows = Gauge(
+            "mtpu_device_lane_rows_total",
+            "Rows of work the lane's dispatches carried (axis 0 of "
+            "the batch: blocks of an encode, shard rows of a digest)",
+            ("lane",))
+        self.device_lane_padded_rows = Gauge(
+            "mtpu_device_lane_padded_rows_total",
+            "Rows the lane's dispatches ran at, their step of the "
+            "shape ladder; pad share = 1 - rows / padded rows",
+            ("lane",))
         self.device_lane_state_seconds = Gauge(
             "mtpu_device_lane_state_seconds_total",
             "Wall time of a device lane since it was made, by state "
@@ -1134,6 +1148,10 @@ class MetricsRegistry:
         self.h2d_bytes = Gauge(
             "mtpu_h2d_bytes_total",
             "Bytes that crossed the host->device boundary")
+        self.d2h_bytes = Gauge(
+            "mtpu_d2h_bytes_total",
+            "Bytes of results the dispatch kernels brought back from "
+            "the device, pad rows included")
         self.h2d_dispatches = Gauge(
             "mtpu_h2d_dispatches_total",
             "Host->device upload crossings (device_put calls)")
@@ -1589,6 +1607,9 @@ class MetricsRegistry:
                 if row["dispatches"] else 0.0, device=str(dev))
             self.device_lane_queue_wait.set(row["wait_s"],
                                             device=str(dev))
+            self.device_lane_rows.set(row["rows"], lane=str(dev))
+            self.device_lane_padded_rows.set(row["padded_rows"],
+                                             lane=str(dev))
         self.jit_compiles.set(snap["jit_compiles"])
         self.jit_compile_seconds.set(snap["jit_compile_s"])
         self.ipc_submits.set(snap["ipc_submits"])
@@ -1677,6 +1698,7 @@ class MetricsRegistry:
             self.devcache_capacity.set(dsnap["capacity_bytes"])
         hsnap = _devcache.h2d_stats()
         self.h2d_bytes.set(hsnap["h2d_bytes"])
+        self.d2h_bytes.set(hsnap["d2h_bytes"])
         self.h2d_dispatches.set(hsnap["h2d_dispatches"])
         for dev, row in hsnap["lanes"].items():
             self.h2d_lane_bytes.set(row["h2d_bytes"], device=str(dev))

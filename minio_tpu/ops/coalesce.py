@@ -41,7 +41,11 @@ Scheduling contract (per lane):
   in-flight kernel call land in the next batch for free;
 - bounded-queue backpressure: submit() blocks while the total queued
   weight exceeds a small multiple of the batch budget, so a flood of
-  writers cannot buffer unbounded shard batches in memory.
+  writers cannot buffer unbounded shard batches in memory;
+- the shape of a device dispatch follows the rows it holds: a batch is
+  zero-padded to the smallest built step of its kernel's shape ladder
+  (`step_rows` below), not to a fixed 32 blocks, so staging copy,
+  upload, program and the fetch of the result shrink with it.
 
 Env (read per call so tests flip them without re-importing):
 
@@ -56,7 +60,9 @@ Env (read per call so tests flip them without re-importing):
 
 from __future__ import annotations
 
+import functools
 import os
+import sys
 import threading
 import time
 from collections import deque
@@ -88,15 +94,119 @@ def max_batch() -> int:
 
 
 def pad_batch(x: np.ndarray, multiple: int) -> tuple[np.ndarray, int]:
-    """Zero-pad axis 0 up to the next multiple so jit'd device kernels
-    see a bounded set of shapes (32, 64, ...) instead of one compile per
-    coalesced batch size.  Returns (padded, original_n)."""
+    """Zero-pad axis 0 up to the next multiple.  Returns (padded,
+    original_n).  The dispatch path pads to a step of the shape ladder
+    instead (`step_rows`)."""
     n = x.shape[0]
-    pad = (-n) % multiple
+    return _pad_rows(x, n + (-n) % multiple), n
+
+
+def _pad_rows(x: np.ndarray, rows: int) -> np.ndarray:
+    pad = rows - x.shape[0]
     if not pad:
-        return x, n
+        return x
     return np.concatenate(
-        [x, np.zeros((pad,) + x.shape[1:], dtype=x.dtype)]), n
+        [x, np.zeros((pad,) + x.shape[1:], dtype=x.dtype)])
+
+
+# -- the shape ladder ---------------------------------------------------------
+#
+# A device program is compiled per input shape, so a batch is zero-
+# padded to one of a bounded set of row counts.  A kernel that declares
+# `pad_rows = P` and names its `program` gets the ladder P/32 x LADDER,
+# and multiples of P above it: a batch of n rows runs at the smallest
+# step that holds it, so the staging copy, the upload, the program and
+# the fetch of the result all carry at most 2n rows, not P.  A step
+# serves only once its program is built (`build_ladder`, off the serving
+# threads, top step first), so the lane never compiles one: until a step
+# is ready the next larger one serves, and the top step is the shape
+# every batch had before there was a ladder.  Where even the top step
+# is not built (a geometry nobody announced, a program met for the first
+# time) the submitter builds it on its own thread before it queues the
+# item (`DispatchLane.submit`): that one request waits for the compile,
+# the lane and everyone on it do not.  A kernel whose `ladder` is off
+# (one program per variant, as verify+transform with targets) keeps the
+# single shape P.
+
+LADDER = (1, 2, 4, 8, 16, 32)
+
+
+def step_rows(n: int, pad_rows: int, built=None) -> int:
+    """Rows a batch of `n` is padded to under `pad_rows`: the smallest
+    ladder step >= n that `built(rows)` admits, else the next multiple
+    of `pad_rows`.  THE padding rule of every dispatch kernel."""
+    if built is not None and n <= pad_rows and pad_rows % LADDER[-1] == 0:
+        unit = pad_rows // LADDER[-1]
+        for f in LADDER[:-1]:
+            if unit * f >= n and built(unit * f):
+                return unit * f
+    return n + (-n) % pad_rows
+
+
+def kernel_rows(fn, n: int, row_shape: tuple) -> int:
+    """`step_rows` for kernel `fn` and a batch of `n` rows of
+    `row_shape`: what the lanes and the kernels' own bodies pad to."""
+    built = None
+    if getattr(fn, "ladder", False):
+        prog = fn.program()
+
+        def built(rows):
+            return prog.built((rows,) + tuple(row_shape), fn.device)
+    return step_rows(n, int(getattr(fn, "pad_rows", 1) or 1), built)
+
+
+_BUILD_MU = threading.Lock()
+_BUILD_Q: deque = deque()
+_BUILD_THREAD: threading.Thread | None = None
+
+
+def build_ladder(fn, row_shape: tuple) -> None:
+    """Ask for kernel `fn`'s program at every step of its ladder for
+    rows of `row_shape`, top step first.  One daemon thread builds what
+    is asked, in order, and ends when nothing is left: compiles (or
+    loads from the persistent cache) never run on a serving thread.
+    Nothing in a process that holds no device (a pool worker)."""
+    global _BUILD_THREAD
+    from . import devices
+
+    if not getattr(fn, "ladder", False) \
+            or devices.jax_device(fn.device) is None:
+        return
+    unit = fn.pad_rows // LADDER[-1]
+    with _BUILD_MU:
+        for f in reversed(LADDER):
+            _BUILD_Q.append((fn.program(), (unit * f,) + tuple(row_shape),
+                             fn.device))
+        if _BUILD_THREAD is None:
+            _BUILD_THREAD = threading.Thread(
+                target=_build_loop, name="mtpu-ladder-build", daemon=True)
+            _BUILD_THREAD.start()
+
+
+def _build_loop() -> None:
+    global _BUILD_THREAD
+    while True:
+        with _BUILD_MU:
+            if not _BUILD_Q:
+                _BUILD_THREAD = None
+                return
+            prog, shape, device = _BUILD_Q.popleft()
+        try:
+            prog.build(shape, device)
+        except Exception as e:  # noqa: BLE001 — the next step up serves
+            print(f"minio_tpu: ladder step {shape} on lane {device} "
+                  f"not built: {e!r}", file=sys.stderr, flush=True)
+
+
+def ladder_idle() -> bool:
+    """Whether every build asked for so far is done."""
+    return _BUILD_THREAD is None
+
+
+def ladder_wait() -> None:
+    """Block until every build asked for so far is done (tests)."""
+    while (t := _BUILD_THREAD) is not None:
+        t.join()
 
 
 class _BufPool:
@@ -333,6 +443,12 @@ class DispatchLane:
         nrows = int(payload.shape[0]) if payload.ndim else 1
         h = Handle(int(weight) if weight is not None else nrows, nrows,
                    self.device)
+        prog = getattr(fn, "program", None)
+        if prog is not None and nrows <= fn.pad_rows:
+            # The lane never compiles: the shape every batch of up to
+            # pad_rows rows can fall back to is built here, on the
+            # caller's thread, if nobody built it yet (once a program).
+            prog().build((fn.pad_rows,) + payload.shape[1:], fn.device)
         cap = self.QUEUE_FACTOR * max_batch()
         with self._mu:
             if self._stopped:
@@ -526,16 +642,18 @@ class DispatchLane:
                 self._drain_pipeline()
         t_disp = time.monotonic()
         n = sum(h.nrows for _, h in items)
-        mult = int(getattr(fn, "pad_rows", 1) or 1)
-        with self._dispatch_span(key, items, n, n + (-n) % mult):
-            self._dispatch_serial(items, w, fn, t_disp, inline)
+        padded = kernel_rows(fn, n, items[0][0].shape[1:])
+        with self._dispatch_span(key, items, n, padded):
+            self._dispatch_serial(items, w, fn, t_disp, inline, padded)
 
     def _dispatch_serial(self, items: list[tuple], w: int, fn,
-                         t_disp: float, inline: bool) -> None:
+                         t_disp: float, inline: bool,
+                         padded: int) -> None:
         """One batch through its kernel on the calling thread.  A
         kernel with a launch/resolve split runs as the four phases the
-        pipelined path has (the same calls its own body makes); any
-        other runs whole under `launch`."""
+        pipelined path has (the same calls its own body makes), padded
+        to `padded` rows, its step of the ladder; any other runs whole
+        under `launch`."""
         ctx = DispatchCtx(self._bufs, len(items))
         launch = getattr(fn, "launch", None)
         try:
@@ -550,8 +668,9 @@ class DispatchLane:
                 for _, h in items:
                     spans.append((lo, lo + h.nrows))
                     lo += h.nrows
+                n = lo
                 if launch is not None:
-                    x, n = pad_batch(stacked, int(fn.pad_rows))
+                    x = _pad_rows(stacked, padded)
             if launch is None:
                 with self._stage("launch", inline):
                     results = fn(stacked, spans, ctx)
@@ -614,7 +733,8 @@ class DispatchLane:
             self.max_items = max(self.max_items, len(items))
             self._ema = 0.75 * self._ema + 0.25 * len(items)
         DATA_PATH.record_coalesce_dispatch(len(items), w, wait_sum)
-        DATA_PATH.record_lane_dispatch(self.device, len(items), w, wait_sum)
+        DATA_PATH.record_lane_dispatch(self.device, len(items), w,
+                                       wait_sum, n, padded)
 
     # -- pinned-staging H2D pipeline (ISSUE 17 tentpole) ---------------------
 
@@ -658,8 +778,7 @@ class DispatchLane:
                 return False
         t0 = time.monotonic()
         n = sum(h.nrows for _, h in items)
-        mult = int(getattr(fn, "pad_rows", 1) or 1)
-        padded = n + (-n) % mult
+        padded = kernel_rows(fn, n, row_shape)
         need = padded * row_bytes
         # Open from this pack to this batch's resolve, one dispatch
         # later: suspended in between, while the lane packs the next.
@@ -701,7 +820,7 @@ class DispatchLane:
             return False
         root.suspend()
         prev, self._pending = self._pending, (
-            resolve, items, w, fn, ctx, t_pack, root)
+            resolve, items, w, fn, ctx, t_pack, root, n, padded)
         host_s = time.monotonic() - t0
         with self._mu:
             self.h2d_bytes += need
@@ -723,15 +842,16 @@ class DispatchLane:
     def _resolve(self, pending: tuple) -> None:
         """Sync one launched batch and scatter its results — the second
         phase of `_dispatch`, deferred one dispatch behind the launch."""
-        resolve, items, w, fn, ctx, t_disp, root = pending
+        resolve, items, w, fn, ctx, t_disp, root, n, padded = pending
         root.resume()
         try:
-            self._resolve_open(resolve, items, w, fn, ctx, t_disp)
+            self._resolve_open(resolve, items, w, fn, ctx, t_disp, n,
+                               padded)
         finally:
             root.__exit__(None, None, None)
 
     def _resolve_open(self, resolve, items: list[tuple], w: int, fn, ctx,
-                      t_disp: float) -> None:
+                      t_disp: float, n: int, padded: int) -> None:
         try:
             with self._stage("device_wait"):
                 results = resolve()
@@ -778,7 +898,7 @@ class DispatchLane:
             self._ema = 0.75 * self._ema + 0.25 * len(items)
         DATA_PATH.record_coalesce_dispatch(len(items), w, wait_sum)
         DATA_PATH.record_lane_dispatch(self.device, len(items), w,
-                                       wait_sum)
+                                       wait_sum, n, padded)
 
     # -- lifecycle / introspection ------------------------------------------
 
@@ -944,49 +1064,168 @@ class DispatchCoalescer:
 
 
 # -- shared kernels ----------------------------------------------------------
+#
+# The device kernels of the data plane, built from the parameters a
+# coalescer key carries: the engine (engine/erasure_set.py) and the
+# pool's device owner (ops/ipc_dispatch.kernel_from_key) build the same
+# kernel from the same key.
+
+def _device_kernel(launch, pad_rows: int, device: int | None,
+                   program=None, ladder: bool = False):
+    """The dispatch kernel around a `launch(x, n, spans, ctx) ->
+    resolve` pair.  The lanes drive the pair themselves (pack, upload,
+    launch, then resolve one dispatch later); called whole (a solo
+    retry, a direct call) the kernel pads to its step, uploads, and
+    resolves at once.  `program()` gives the ops/fused.Program the
+    launch runs, where it is one: its `pad_rows` shape is then built
+    before a lane sees a batch.  It is asked for only where a batch is
+    dispatched: a pool worker builds kernels (their keys travel to the
+    owner) and must not reach for JAX.  With `ladder` the batch runs
+    at the smallest built step of the shape ladder, else at a multiple
+    of `pad_rows`."""
+    from . import devices
+
+    def kernel(stacked, spans, ctx):
+        n = stacked.shape[0]
+        x = _pad_rows(stacked, kernel_rows(kernel, n, stacked.shape[1:]))
+        return kernel.launch(devices.put(x, device), n, spans, ctx)()
+
+    kernel.launch = launch
+    kernel.pad_rows = pad_rows
+    kernel.device = device
+    kernel.program = program
+    kernel.ladder = ladder
+    return kernel
+
+
+def make_encode_kernel(k: int, m: int, algo: str, pad_rows: int,
+                       device: int | None = None, codec=None,
+                       on_device: bool = True):
+    """Encode over stacked (B, K, S) blocks -> (parity, digests) per
+    span, the pair the direct dispatch produces.  With no `codec` it is
+    the fused device program (parity AND bitrot digests in one launch,
+    ops/fused.py), sized by the ladder of `pad_rows`; with a device
+    codec (`on_device`, host-hashed algorithms) parity only, at
+    multiples of `pad_rows`; with a host codec parity only, unpadded."""
+    if codec is not None and not on_device:
+        def kernel(stacked, spans, ctx):
+            parity = np.asarray(codec.encode_blocks(stacked))
+            return [(parity[lo:hi], None) for lo, hi in spans]
+
+        return kernel
+    from . import devices, fused
+
+    def launch(x, n, spans, ctx):
+        if codec is None:
+            parity_d, digests_d = fused.encode_and_hash(
+                x, k, m, algo=algo, device=device)
+        else:
+            parity_d = codec.encode_blocks(devices.put(x, device))
+            digests_d = None
+
+        def resolve():
+            parity = devcache.fetch(parity_d)[:n]
+            if digests_d is None:
+                return [(parity[lo:hi], None) for lo, hi in spans]
+            digests = devcache.fetch(digests_d)[:, :n]
+            return [(parity[lo:hi], digests[:, lo:hi])
+                    for lo, hi in spans]
+
+        return resolve
+
+    if codec is not None:
+        return _device_kernel(launch, pad_rows, device)
+    return _device_kernel(
+        launch, pad_rows, device,
+        functools.partial(fused.encode_hash_program, k, m, algo),
+        ladder=True)
+
+
+def make_verify_kernel(k: int, m: int, sources: tuple, targets: tuple,
+                       algo: str, pad_rows: int,
+                       device: int | None = None):
+    """Fused device verify(+reconstruct) over stacked (B, K, S) gathers
+    — the healthy-verify / degraded-decode / heal work item.  Digest
+    layout is (B, K, hs): axis 0 is the concat axis of both outputs.
+    With no targets it is one hash program per algorithm and takes the
+    ladder; with targets there is one program per (sources, targets),
+    met on first sight, and it keeps the single shape `pad_rows`."""
+    from . import fused
+
+    def launch(x, n, spans, ctx):
+        digests_d, out_d = fused.verify_and_transform(
+            x, k, m, sources, targets, algo=algo, device=device)
+
+        def resolve():
+            digests = devcache.fetch(digests_d)[:n]
+            out = devcache.fetch(out_d)[:n] if targets else None
+            return [(digests[lo:hi],
+                     out[lo:hi] if out is not None else None)
+                    for lo, hi in spans]
+
+        return resolve
+
+    return _device_kernel(
+        launch, pad_rows, device,
+        functools.partial(fused.verify_transform_program, k, m, sources,
+                          targets, algo),
+        ladder=not targets)
+
 
 def make_digest_kernel(algo: str, pad_rows: int = 0,
                        device: int | None = None):
     """Batched bitrot digest over stacked (N, S) rows — the healthy-GET
     verify and heal-verify workhorse.  `pad_rows` > 0 asks for the
-    device digest program, on lane `device`, with rows zero-padded to
-    that multiple so jit shapes stay bounded; 0 (or an algorithm whose
-    host kernel is preferred) hashes with the host kernels.  The
-    submitter's key carries `pad_rows`, like every parameter a kernel
-    closes over."""
+    device digest program, on lane `device`, sized by the ladder of
+    `pad_rows`; 0 (or an algorithm whose host kernel is preferred)
+    hashes with the host kernels.  The submitter's key carries
+    `pad_rows`, like every parameter a kernel closes over."""
     from ..storage import bitrot_io
 
     if pad_rows:
         from . import fused
 
         if algo in fused.DEVICE_ALGOS and bitrot_io.device_preferred(algo):
-            from . import devices
-
             def launch(x, n, spans, ctx):
-                # Pipeline form: the lane pre-placed the (padded) rows
-                # on its device — hash them asynchronously and defer
-                # the sync to resolve().
-                out_dev = fused.hash_rows_async(x, algo)
+                out_dev = fused.hash_rows_async(x, algo, device=device)
 
                 def resolve():
-                    out = np.asarray(out_dev)[:n]
+                    out = devcache.fetch(out_dev)[:n]
                     return [out[lo:hi] for lo, hi in spans]
 
                 return resolve
 
-            def kernel(stacked, spans, ctx):
-                x, n = pad_batch(stacked, pad_rows)
-                return launch(devices.put(x, device), n, spans, ctx)()
-
-            kernel.launch = launch
-            kernel.pad_rows = pad_rows
-            return kernel
+            return _device_kernel(
+                launch, pad_rows, device,
+                functools.partial(fused.hash_rows_program, algo),
+                ladder=True)
 
     def kernel(stacked, spans, ctx):
         out = bitrot_io._hash_batch(stacked, algo)
         return [out[lo:hi] for lo, hi in spans]
 
     return kernel
+
+
+def build_geometry_ladder(k: int, m: int, shard_size: int, algo: str,
+                          pad_blocks: int, device: int) -> None:
+    """Ask for the ladders of the two programs every PUT and healthy
+    GET of a set of geometry (k, m, shard_size) writing `algo` runs on
+    lane `device`: its fused encode and its GET digest, each built from
+    the parameters the engine's keys carry.  (The verify-only hash of
+    the slow read path can take a ladder too, but nothing asks for it
+    here: each program costs seconds to compile once.)  Nothing where
+    the algorithm hashes on the host."""
+    from ..storage import bitrot_io
+    from . import fused
+
+    if not (m and algo in fused.DEVICE_ALGOS
+            and bitrot_io.device_preferred(algo)):
+        return
+    build_ladder(make_encode_kernel(k, m, algo, pad_blocks, device),
+                 (k, shard_size))
+    build_ladder(make_digest_kernel(algo, pad_blocks * k, device),
+                 (shard_size,))
 
 
 # -- process singleton -------------------------------------------------------
@@ -1046,10 +1285,15 @@ def _reset_after_fork() -> None:
     # A forked child inherits the parent's singleton OBJECT but not its
     # dispatcher threads — submits would queue forever.  Drop both the
     # scheduler and any remote front end (its listener thread is gone
-    # too); the child lazily builds fresh ones.
-    global _CO, _REMOTE
+    # too); the child lazily builds fresh ones.  The ladder's build
+    # thread is the parent's as well: what it had queued is not the
+    # child's to build.
+    global _CO, _REMOTE, _BUILD_MU, _BUILD_THREAD
     _CO = None
     _REMOTE = None
+    _BUILD_MU = threading.Lock()
+    _BUILD_THREAD = None
+    _BUILD_Q.clear()
 
 
 os.register_at_fork(after_in_child=_reset_after_fork)

@@ -66,6 +66,7 @@ _H2D_MU = threading.Lock()
 _H2D_BYTES = 0
 _H2D_DISPATCHES = 0
 _H2D_LANES: dict[int, dict] = {}
+_D2H_BYTES = 0
 
 
 def note_h2d(nbytes: int, device: int | None = None) -> None:
@@ -83,20 +84,33 @@ def note_h2d(nbytes: int, device: int | None = None) -> None:
             lane["h2d_dispatches"] += 1
 
 
+def fetch(arr) -> np.ndarray:
+    """A dispatch kernel's result on the host, the crossing counted:
+    the whole array comes back, pad rows and all, so the ledger shows
+    what a dispatch's shape costs on the way back too."""
+    global _D2H_BYTES
+    out = np.asarray(arr)
+    with _H2D_MU:
+        _D2H_BYTES += out.nbytes
+    return out
+
+
 def h2d_stats() -> dict:
     with _H2D_MU:
         return {
             "h2d_bytes": _H2D_BYTES,
             "h2d_dispatches": _H2D_DISPATCHES,
+            "d2h_bytes": _D2H_BYTES,
             "lanes": {d: dict(v) for d, v in sorted(_H2D_LANES.items())},
         }
 
 
 def reset_h2d() -> None:
-    global _H2D_BYTES, _H2D_DISPATCHES
+    global _H2D_BYTES, _H2D_DISPATCHES, _D2H_BYTES
     with _H2D_MU:
         _H2D_BYTES = 0
         _H2D_DISPATCHES = 0
+        _D2H_BYTES = 0
         _H2D_LANES.clear()
 
 

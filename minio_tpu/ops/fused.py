@@ -26,6 +26,7 @@ cmd/bitrot-streaming.go:35).
 from __future__ import annotations
 
 import functools
+import threading
 
 import jax
 import jax.numpy as jnp
@@ -41,12 +42,45 @@ from .mxhash_jax import mxh256_rows
 DEVICE_ALGOS = ("mxh256", "highwayhash256S", "highwayhash256")
 
 
-def _named_jit(name: str, fn):
-    """jit `fn` under a name that says what the program is: the
+class Program:
+    """`fn` jitted under a name that says what the program is (the
     profiler's `XLA Modules` line and the compile log then tell an
-    encode from a GET-verify from a hash-only program (`jit_<name>`)."""
-    fn.__name__ = fn.__qualname__ = name
-    return jax.jit(fn)
+    encode from a GET-verify from a hash-only program: `jit_<name>`),
+    plus the executables built ahead of time for it, one per (input
+    shape, lane device).  Calling a shape that is built compiles
+    nothing, whatever thread calls; any other shape goes through the
+    jit and compiles on first sight.  The dispatch lanes size a batch
+    by what is built here (ops/coalesce.py: the shape ladder)."""
+
+    def __init__(self, name: str, fn):
+        fn.__name__ = fn.__qualname__ = name
+        self.jit = jax.jit(fn)
+        self._built: dict[tuple, object] = {}
+        self._build_mu = threading.Lock()
+
+    def __call__(self, x, device: int | None = None):
+        exe = self._built.get((x.shape, device))
+        return self.jit(x) if exe is None else exe(x)
+
+    def built(self, shape: tuple, device: int | None) -> bool:
+        return (tuple(shape), device) in self._built
+
+    def build(self, shape: tuple, device: int | None) -> None:
+        """Compile (or load from the persistent cache) the executable
+        for a uint8 input of `shape` committed to lane `device`, as the
+        lanes' staged uploads are.  Nothing to do in a process that
+        holds no device."""
+        dev = None if device is None else devices_mod.jax_device(device)
+        if dev is None:
+            return
+        with self._build_mu:        # two who ask at once: one compiles
+            if self.built(shape, device):
+                return
+            spec = jax.ShapeDtypeStruct(
+                tuple(shape), jnp.uint8,
+                sharding=jax.sharding.SingleDeviceSharding(dev))
+            self._built[tuple(shape), device] = \
+                self.jit.lower(spec).compile()
 
 
 def _placed(x, device: int | None):
@@ -87,18 +121,23 @@ def _digest_rows(x2d: jax.Array, algo: str, key: bytes) -> jax.Array:
 def _hash_rows2d_jit(algo: str, key: bytes):
     def fn(x):  # (N, S) uint8
         return _digest_rows(x, algo, key)
-    return _named_jit(f"hash_rows_{algo}", fn)
+    return Program(f"hash_rows_{algo}", fn)
 
 
-def hash_rows_async(x, algo: str, key: bytes = MAGIC_KEY):
+def hash_rows_program(algo: str, key: bytes = MAGIC_KEY) -> Program:
+    return _hash_rows2d_jit(algo, key)
+
+
+def hash_rows_async(x, algo: str, key: bytes = MAGIC_KEY,
+                    device: int | None = None):
     """(N, S) rows -> (N, 32) digests as an UNSYNCED jax array — the
     coalescer lanes' pipelined digest form (the caller resolves via
     np.asarray one dispatch later).  `x` may already be device-resident
-    (counted at its placement site)."""
+    (counted at its placement site), on lane `device`."""
     if not isinstance(x, jax.Array):
         devcache.note_h2d(int(getattr(x, "nbytes", 0) or 0))
         x = jnp.asarray(x, dtype=jnp.uint8)
-    return _hash_rows2d_jit(algo, key)(x)
+    return _hash_rows2d_jit(algo, key)(x, device)
 
 
 @functools.lru_cache(maxsize=16)
@@ -107,7 +146,9 @@ def _hash_rows_jit(algo: str, key: bytes):
         b, kk, s = x.shape
         return _digest_rows(x.reshape(b * kk, s), algo, key).reshape(
             b, kk, 32)
-    return _named_jit(f"verify_{algo}", fn)
+    return Program(f"verify_{algo}", fn)
+
+
 
 
 @functools.lru_cache(maxsize=512)
@@ -125,7 +166,7 @@ def _verify_transform_jit(k: int, m: int, sources: tuple[int, ...],
         out = erasure_pallas.gf_matmul_blocks(mat, x, rows)
         return digests, out
 
-    return _named_jit(
+    return Program(
         f"verify_transform_k{k}m{m}_s{'_'.join(map(str, sources))}"
         f"_t{'_'.join(map(str, targets))}_{algo}", fn)
 
@@ -143,11 +184,20 @@ def verify_and_transform(x, k: int, m: int, sources: tuple[int, ...],
     the coalescer-lane index the dispatch is placed on (None = default
     device, the pre-sharding behavior).
     """
-    x = _placed(x, device)
+    out = verify_transform_program(k, m, sources, targets, algo, key)(
+        _placed(x, device), device)
+    return out if targets else (out, None)
+
+
+def verify_transform_program(k: int, m: int, sources: tuple[int, ...],
+                             targets: tuple[int, ...], algo: str,
+                             key: bytes = MAGIC_KEY) -> Program:
+    """The program `verify_and_transform` runs: the hash alone where
+    nothing is to be rebuilt, else one per (sources, targets)."""
     if not targets:
-        return _hash_rows_jit(algo, key)(x), None
+        return _hash_rows_jit(algo, key)
     return _verify_transform_jit(k, m, tuple(sources), tuple(targets),
-                                 algo, key)(x)
+                                 algo, key)
 
 
 @functools.lru_cache(maxsize=64)
@@ -164,7 +214,12 @@ def _encode_hash_jit(k: int, m: int, algo: str, key: bytes):
         digests = _digest_rows(rows, algo, key).reshape(kk + m, b, 32)
         return parity, digests
 
-    return _named_jit(f"encode_hash_k{k}m{m}_{algo}", fn)
+    return Program(f"encode_hash_k{k}m{m}_{algo}", fn)
+
+
+def encode_hash_program(k: int, m: int, algo: str,
+                        key: bytes = MAGIC_KEY) -> Program:
+    return _encode_hash_jit(k, m, algo, key)
 
 
 def encode_and_hash(x, k: int, m: int, algo: str = "highwayhash256S",
@@ -177,4 +232,4 @@ def encode_and_hash(x, k: int, m: int, algo: str = "highwayhash256S",
     Digest layout is shard-major to match frame_shards_batch's
     (n_shards, n_blocks) order.  `device` places the dispatch on that
     coalescer lane's device (None = default device)."""
-    return _encode_hash_jit(k, m, algo, key)(_placed(x, device))
+    return _encode_hash_jit(k, m, algo, key)(_placed(x, device), device)

@@ -157,107 +157,12 @@ def _pf_kernel(k: int, m: int, shard_size: int):
     return kernel
 
 
-def _enc_kernel(tag: str, k: int, m: int, algo: str,
-                device: int | None = None):
-    """Owner-side mirror of ErasureSet._enc_kernel; the tag picks the
-    backend the submitting worker would have used, `device` the lane
-    the dispatch is placed on."""
-    from ..engine.erasure_set import BATCH_BLOCKS
-    from . import devices as devices_mod
-    from . import fused
-
-    if tag == "fd":
-        def kernel(stacked, spans, ctx):
-            x, n = coalesce.pad_batch(stacked, BATCH_BLOCKS)
-            parity, digests = fused.encode_and_hash(x, k, m, algo=algo,
-                                                    device=device)
-            parity = np.asarray(parity)[:n]
-            digests = np.asarray(digests)[:, :n]
-            return [(parity[lo:hi], digests[:, lo:hi])
-                    for lo, hi in spans]
-
-        def launch(x, n, spans, ctx):
-            # Pipeline form (lane-staged device input, sync deferred to
-            # resolve).
-            parity_d, digests_d = fused.encode_and_hash(
-                x, k, m, algo=algo, device=device)
-
-            def resolve():
-                parity = np.asarray(parity_d)[:n]
-                digests = np.asarray(digests_d)[:, :n]
-                return [(parity[lo:hi], digests[:, lo:hi])
-                        for lo, hi in spans]
-
-            return resolve
-
-        kernel.launch = launch
-        kernel.pad_rows = BATCH_BLOCKS
-        return kernel
-
-    codec = _owner_codec(tag, k, m)
-    if tag == "dev":
-        def kernel(stacked, spans, ctx):
-            x, n = coalesce.pad_batch(stacked, BATCH_BLOCKS)
-            parity = np.asarray(codec.encode_blocks(
-                devices_mod.put(x, device)))[:n]
-            return [(parity[lo:hi], None) for lo, hi in spans]
-
-        def launch(x, n, spans, ctx):
-            parity_d = codec.encode_blocks(devices_mod.put(x, device))
-
-            def resolve():
-                parity = np.asarray(parity_d)[:n]
-                return [(parity[lo:hi], None) for lo, hi in spans]
-
-            return resolve
-
-        kernel.launch = launch
-        kernel.pad_rows = BATCH_BLOCKS
-    else:
-        def kernel(stacked, spans, ctx):
-            parity = np.asarray(codec.encode_blocks(stacked))
-            return [(parity[lo:hi], None) for lo, hi in spans]
-    return kernel
-
-
-def _vt_kernel(k: int, m: int, sources: tuple, targets: tuple, algo: str,
-               device: int | None = None):
-    """Owner-side mirror of ErasureSet._vt_kernel (fused verify/
-    reconstruct)."""
-    from ..engine.erasure_set import BATCH_BLOCKS
-    from . import fused
-
-    def kernel(stacked, spans, ctx):
-        x, n = coalesce.pad_batch(stacked, BATCH_BLOCKS)
-        digests, out = fused.verify_and_transform(
-            x, k, m, sources, targets, algo=algo, device=device)
-        digests = np.asarray(digests)[:n]
-        out = np.asarray(out)[:n] if targets else None
-        return [(digests[lo:hi], out[lo:hi] if out is not None else None)
-                for lo, hi in spans]
-
-    def launch(x, n, spans, ctx):
-        digests_d, out_d = fused.verify_and_transform(
-            x, k, m, sources, targets, algo=algo, device=device)
-
-        def resolve():
-            digests = np.asarray(digests_d)[:n]
-            out = np.asarray(out_d)[:n] if targets else None
-            return [(digests[lo:hi],
-                     out[lo:hi] if out is not None else None)
-                    for lo, hi in spans]
-
-        return resolve
-
-    kernel.launch = launch
-    kernel.pad_rows = BATCH_BLOCKS
-    return kernel
-
-
 def kernel_from_key(key: tuple, device: int | None = None):
     """Rebuild the dispatch kernel for a coalescer key (placed on lane
     `device` for device-backed kinds).  Raises KeyError for kinds this
-    registry does not know (the worker then keeps them local)."""
+    registry does not know (the worker then keeps them local).  The
+    device kinds come from the engine's own builders (ops/coalesce.py),
+    so owner and engine pad, launch and resolve by one rule."""
     kind = key[0]
     if kind == "digest":
         _, algo, _shard, pad_rows = key
@@ -266,14 +171,20 @@ def kernel_from_key(key: tuple, device: int | None = None):
     if kind == "pf":
         _, k, m, shard = key
         return _pf_kernel(int(k), int(m), int(shard))
+    if kind in ("enc", "vt"):
+        from ..engine.erasure_set import BATCH_BLOCKS
     if kind == "enc":
+        # The tag is the backend the submitting worker would have used.
         _, tag, k, m, algo, _shard = key
-        return _enc_kernel(str(tag), int(k), int(m), str(algo),
-                           device=device)
+        return coalesce.make_encode_kernel(
+            int(k), int(m), str(algo), BATCH_BLOCKS, device,
+            None if tag == "fd" else _owner_codec(str(tag), int(k), int(m)),
+            on_device=tag != "nat")
     if kind == "vt":
         _, k, m, sources, targets, algo, _shard = key
-        return _vt_kernel(int(k), int(m), tuple(sources), tuple(targets),
-                          str(algo), device=device)
+        return coalesce.make_verify_kernel(
+            int(k), int(m), tuple(sources), tuple(targets), str(algo),
+            BATCH_BLOCKS, device)
     raise KeyError(f"no remote kernel for key kind {kind!r}")
 
 

@@ -232,6 +232,7 @@ def main(argv: list[str] | None = None) -> int:
                 print(f"minio_tpu: cluster boot failed: {e}",
                       file=sys.stderr, flush=True)
                 return 1
+            srv0.build_ladders(hold_ready=True)
             print(f"minio_tpu cluster node ready on {srv0.endpoint} "
                   f"(deployment ok)", flush=True)
             try:
@@ -375,6 +376,11 @@ def main(argv: list[str] | None = None) -> int:
                        bucket_dns=bucket_dns_from_env(args.host,
                                                       port)).start()
         port = srv.port                  # keep the port across restarts
+        # The lanes' shape ladder for the geometry served, built off the
+        # serving threads: until a step is built the next larger one
+        # serves, so requests are answered meanwhile; only the
+        # readiness probe waits for it.
+        srv.build_ladders(hold_ready=True)
         if srv.bucket_dns is not None:
             # SRV records must advertise the BOUND port (--port 0
             # binds an ephemeral one)

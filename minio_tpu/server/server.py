@@ -179,6 +179,10 @@ class S3Server:
         # that gauge closes before the response body is written, and a
         # drain must wait for the LAST BYTE of every streamed GET.
         self.draining = False
+        # Boot asked for the lanes' shape ladder and it is still being
+        # built: requests are served (at the next larger step), the
+        # readiness probe waits (see build_ladders).
+        self.warming = False
         self._inflight = 0
         self._drain_cv = threading.Condition()
         # Overload plane (server/qos.py): the process-tree singleton —
@@ -756,6 +760,22 @@ class S3Server:
                                         daemon=True)
         self._thread.start()
         return self
+
+    def build_ladders(self, hold_ready: bool = False) -> None:
+        """Ask for the device programs' shape ladders (ops/coalesce.py)
+        at every parity this deployment writes with: the sets' default
+        and each storage class an operator has set.  At boot, and again
+        when a storage class is set; what is built already is skipped.
+        `hold_ready` (boot) keeps /minio/health/ready at 503 until what
+        was asked for is built, so whoever waits for readiness before
+        sending load meets no compile and no oversized step; a request
+        that comes earlier is served all the same."""
+        self.warming = self.warming or hold_ready
+        cfg = self.handlers.config_sys
+        for parity in {None} | {
+                cfg.parity_for_class(sc) for sc in ("standard", "rrs")
+                if cfg.is_set("storage_class", sc)}:
+            self.pools.build_ladders(parity)
 
     def shutdown(self) -> None:
         # The scanner's lifecycle belongs to the process (__main__) —
@@ -1739,6 +1759,8 @@ class S3Server:
                                     req_obj["value"])
                 except KeyError as e:
                     raise S3Error("InvalidArgument", str(e)) from None
+                if req_obj["subsys"] == "storage_class":
+                    self.build_ladders()
                 return j({"ok": True})
         if sub == "config-help" and method == "GET":
             if not hasattr(self, "config") or self.config is None:
@@ -2299,9 +2321,13 @@ class S3Server:
         if path == "/minio/health/live":
             return Response(200)
         if path == "/minio/health/ready":
-            # ready = object layer bound (cluster boot done) AND not
-            # draining — load balancers stop routing here first.
-            if self.draining:
+            # ready = object layer bound (cluster boot done), the boot
+            # ladder built, AND not draining — load balancers stop
+            # routing here first.
+            if self.warming:
+                from ..ops import coalesce
+                self.warming = not coalesce.ladder_idle()
+            if self.draining or self.warming:
                 return Response(503, headers={"Retry-After": "1"})
             return Response(200 if self.pools is not None else 503)
         if self.pools is None:

@@ -436,7 +436,28 @@ def _child_entry(fn, *a) -> None:
     os._exit(rc & 0xFF)
 
 
-def _owner_main(plane: WorkerPlane) -> int:
+def _owner_ladders(cfg: dict) -> None:
+    """The shape ladders of the pool's boot geometry, in the one
+    process that holds the devices: each pool's sets at their default
+    parity, set i on lane i % lanes (what the workers' ErasureSets
+    submit under).  A storage class set later in a worker is not seen
+    here: its batches run at the top step."""
+    from ..engine.erasure_set import BATCH_BLOCKS, BLOCK_SIZE
+    from ..ops import coalesce, devices
+    from ..storage.bitrot_io import write_algo
+    if not devices.on_tpu():
+        return
+    for paths in cfg["pool_paths"]:
+        n = cfg["set_drive_count"] or len(paths)
+        k = n - n // 2
+        for lane in {i % devices.n_devices()
+                     for i in range(len(paths) // n)}:
+            coalesce.build_geometry_ladder(
+                k, n // 2, -(-BLOCK_SIZE // k), write_algo(),
+                BATCH_BLOCKS, lane)
+
+
+def _owner_main(plane: WorkerPlane, cfg: dict) -> int:
     _set_pdeathsig()
     os.environ["MTPU_WORKER_ROLE"] = "owner"
     # The owner IS the remote end — it must never try to remote-submit.
@@ -465,6 +486,7 @@ def _owner_main(plane: WorkerPlane) -> int:
               f"{os.getpid()})", flush=True)
     co = coalesce.get()
     ipc_dispatch.serve_owner(plane, stop, co)
+    _owner_ladders(cfg)
     # Heartbeat on the main thread: workers route remote only while
     # this stays fresh, so a wedged owner quietly degrades the pool to
     # local dispatch instead of hanging it.
@@ -695,7 +717,7 @@ def run_pool(nworkers: int, pool_paths: list[list[str]], creds,
     children: dict[int, tuple[str, int]] = {}   # pid -> (role, idx)
 
     plane.state.bump_owner_gen()
-    owner = _fork(_owner_main, plane)
+    owner = _fork(_owner_main, plane, cfg)
     children[owner] = ("owner", -1)
     deadline = time.monotonic() + float(
         os.environ.get("MTPU_BOOT_TIMEOUT", "120") or 120)
@@ -790,7 +812,7 @@ def run_pool(nworkers: int, pool_paths: list[list[str]], creds,
             print(f"minio_tpu: device owner died (rc={rc}); "
                   f"respawning", file=sys.stderr, flush=True)
             plane.state.bump_owner_gen()
-            children[_fork(_owner_main, plane)] = ("owner", -1)
+            children[_fork(_owner_main, plane, cfg)] = ("owner", -1)
         elif role == "worker":
             n = plane.state.bump_respawn(idx)
             print(f"minio_tpu: worker {idx} died (rc={rc}); "

@@ -112,7 +112,26 @@ def test_fused_mxh256_programs(one_chip, program, kernels):
     }[program]()
     x = jax.ShapeDtypeStruct((BATCH_BLOCKS, k, 131072), jnp.uint8,
                              sharding=one_chip)
-    _check(fn.lower(x).compile(), kernels)
+    _check(fn.jit.lower(x).compile(), kernels)
+
+
+# The shape ladder (ops/coalesce.py) runs the same programs at 1, 2, 4, 8
+# and 16 blocks: the smallest and a middle step of both cells' geometry.
+@pytest.mark.parametrize("k,m,s,blocks", [
+    (2, 2, 524288, 1), (2, 2, 524288, 4), (8, 4, 131072, 1),
+    (8, 4, 131072, 16)])
+def test_ladder_steps_of_encode_and_get_digest(one_chip, k, m, s, blocks):
+    enc = fused.encode_hash_program(k, m, "mxh256").jit.lower(
+        jax.ShapeDtypeStruct((blocks, k, s), jnp.uint8,
+                             sharding=one_chip)).compile()
+    _check(enc, kernels=1)
+    assert enc.out_info[0].shape == (blocks, m, s)
+    assert enc.out_info[1].shape == (k + m, blocks, 32)
+    dig = fused.hash_rows_program("mxh256").jit.lower(
+        jax.ShapeDtypeStruct((blocks * k, s), jnp.uint8,
+                             sharding=one_chip)).compile()
+    _check(dig, kernels=0)
+    assert dig.out_info.shape == (blocks * k, 32)
 
 
 @pytest.mark.parametrize("program", ["encode", "gather_reconstruct"])

@@ -428,3 +428,306 @@ class TestEngineEquivalence:
             assert worst < 5.0, f"small op starved: {worst:.2f}s"
         finally:
             coalesce.reset()
+
+
+# -- the shape ladder ----------------------------------------------------------
+#
+# CPU backend, device kernels: jax host devices stand in for the chip,
+# so a batch really crosses to a device and its shape is what the
+# program sees.  Geometry EC:2+2 over 256-byte shard rows.
+
+K, M, S, ALGO = 2, 2, 256, "mxh256"
+PAD = 32                                   # BATCH_BLOCKS
+
+
+def _programs():
+    from minio_tpu.ops import fused
+    return (fused.encode_hash_program(K, M, ALGO),
+            fused.hash_rows_program(ALGO),
+            fused.verify_transform_program(K, M, (0, 1), (), ALGO))
+
+
+@pytest.fixture
+def ladder():
+    """A cold coalescer, and no step of the test geometry left built
+    for whatever test this worker runs next."""
+    coalesce.reset()
+    yield
+    coalesce.ladder_wait()
+    coalesce.reset()
+    for prog in _programs():
+        prog._built.clear()
+
+
+def _built_ladder(fn, row_shape):
+    coalesce.build_ladder(fn, row_shape)
+    coalesce.ladder_wait()
+    return fn
+
+
+def _enc():
+    return coalesce.make_encode_kernel(K, M, ALGO, PAD, 0)
+
+
+def _dig():
+    return coalesce.make_digest_kernel(ALGO, PAD * K, 0)
+
+
+def _blocks(n, seed=1):
+    return np.random.default_rng(seed).integers(
+        0, 256, (n, K, S), dtype=np.uint8)
+
+
+def _spy(fn):
+    """Record the shape of every array handed to fn's launch."""
+    seen, launch = [], fn.launch
+
+    def spy(x, n, spans, ctx):
+        seen.append(tuple(x.shape))
+        return launch(x, n, spans, ctx)
+
+    fn.launch = spy
+    return seen
+
+
+_FIXED: dict = {}
+
+
+def _fixed_pad_reference():
+    """What the fixed pad gave: a whole multiple of 32 blocks through
+    the plain jit, no ladder; row i of it is block i's answer."""
+    if not _FIXED:
+        from minio_tpu.ops import fused
+        x = _blocks(64)
+        parity, digests = fused.encode_and_hash(x, K, M, algo=ALGO)
+        rows = np.asarray(fused.hash_rows_async(
+            x.reshape(64 * K, S), ALGO))
+        _FIXED.update(x=x, parity=np.asarray(parity),
+                      digests=np.asarray(digests), rows=rows)
+    return _FIXED
+
+
+class TestShapeLadder:
+    @pytest.mark.parametrize("n,pad_rows,built,want", [
+        (1, 32, "all", 1), (2, 32, "all", 2), (3, 32, "all", 4),
+        (9, 32, "all", 16), (17, 32, "all", 32), (32, 32, "all", 32),
+        (33, 32, "all", 64), (65, 32, "all", 96),
+        (1, 32, (), 32), (1, 32, (4, 16), 4), (5, 32, (4, 16), 16),
+        (17, 32, (4, 16), 32), (3, 32, None, 32), (33, 32, None, 64),
+        (2, 64, "all", 2), (3, 64, "all", 4), (5, 64, "all", 8),
+        (64, 64, "all", 64), (65, 64, "all", 128),
+        (7, 1, "all", 7), (7, 48, "all", 48),
+    ])
+    def test_step_rows(self, n, pad_rows, built, want):
+        pred = None if built is None else (
+            (lambda rows: True) if built == "all"
+            else (lambda rows: rows in built))
+        assert coalesce.step_rows(n, pad_rows, pred) == want
+
+    @pytest.mark.parametrize("n", range(1, 34))
+    def test_encode_identical_to_fixed_pad(self, ladder, n):
+        ref = _fixed_pad_reference()
+        fn = _built_ladder(_enc(), (K, S))
+        seen = _spy(fn)
+        parity, digests = coalesce.get().submit(
+            ("enc", "fd", K, M, ALGO, S), ref["x"][:n], fn).result(30)
+        assert seen == [(coalesce.step_rows(n, PAD, lambda r: True), K, S)]
+        assert np.array_equal(parity, ref["parity"][:n])
+        assert np.array_equal(digests, ref["digests"][:, :n])
+
+    @pytest.mark.parametrize("n", range(1, 34))
+    def test_get_digest_identical_to_fixed_pad(self, ladder, n):
+        ref = _fixed_pad_reference()
+        fn = _built_ladder(_dig(), (S,))
+        seen = _spy(fn)
+        rows = coalesce.get().submit(
+            ("digest", ALGO, S, PAD * K),
+            ref["x"][:n].reshape(n * K, S), fn).result(30)
+        assert seen == [(coalesce.step_rows(n * K, PAD * K,
+                                            lambda r: True), S)]
+        assert np.array_equal(rows, ref["rows"][:n * K])
+
+    @pytest.mark.parametrize("path", ["inline", "serial", "pipelined"])
+    @pytest.mark.parametrize("n,want", [(1, 1), (3, 4), (17, 32),
+                                        (33, 64)])
+    def test_launch_sees_the_step(self, ladder, monkeypatch, path, n,
+                                  want):
+        monkeypatch.setenv("MTPU_H2D_PIPELINE",
+                           "1" if path == "pipelined" else "0")
+        fn = _built_ladder(_enc(), (K, S))
+        seen = _spy(fn)
+        co = coalesce.get()
+        if path != "inline":
+            co._ema = 2.0                 # traffic: queue, do not inline
+        before = DATA_PATH.snapshot()["lanes"].get(0, {})
+        x = _blocks(n, seed=n)
+        parity, _ = co.submit(("enc", "fd", K, M, ALGO, S), x,
+                              fn).result(30)
+        assert seen == [(want, K, S)] and parity.shape == (n, M, S)
+        st = co.lane_stats()[0]
+        assert st["pipeline_dispatches"] == (path == "pipelined")
+        assert (co._thread is None) == (path == "inline")
+        row = DATA_PATH.snapshot()["lanes"][0]
+        assert row["rows"] - before.get("rows", 0) == n
+        assert row["padded_rows"] - before.get("padded_rows", 0) == want
+
+    def test_only_a_built_step_serves_and_the_lane_never_compiles(
+            self, ladder):
+        """Top step built: a 1-row batch runs at 32.  Step 1 built: it
+        runs at 1.  Neither dispatch traces or compiles anything: the
+        jit behind the program is never entered."""
+        prog = _programs()[0]
+        jit_entries = prog.jit._cache_size()
+        fn = _enc()
+        prog.build((PAD, K, S), 0)
+        seen = _spy(fn)
+        co = coalesce.get()
+        co._ema = 2.0                     # on the lane thread
+        compiles = DATA_PATH.snapshot()["jit_compiles"]
+        x = _blocks(1)
+        p32, d32 = co.submit(("enc", "fd", K, M, ALGO, S), x,
+                             fn).result(30)
+        assert seen == [(PAD, K, S)]
+        prog.build((1, K, S), 0)          # off the lane thread
+        compiles_built = DATA_PATH.snapshot()["jit_compiles"]
+        p1, d1 = co.submit(("enc", "fd", K, M, ALGO, S), x,
+                           fn).result(30)
+        assert seen == [(PAD, K, S), (1, K, S)]
+        assert np.array_equal(p1, p32) and np.array_equal(d1, d32)
+        assert prog.jit._cache_size() == jit_entries
+        snap = DATA_PATH.snapshot()["jit_compiles"]
+        assert compiles_built - compiles <= 1 and snap == compiles_built
+
+    def test_ladder_is_built_top_step_first(self, ladder, monkeypatch):
+        from minio_tpu.ops import fused
+        order = []
+        build = fused.Program.build
+
+        def spy(self, shape, device):
+            order.append((self.jit.__name__, shape[0]))
+            return build(self, shape, device)
+
+        monkeypatch.setattr(fused.Program, "build", spy)
+        coalesce.build_geometry_ladder(K, M, S, ALGO, PAD, 0)
+        coalesce.ladder_wait()
+        assert order == (
+            [(f"encode_hash_k{K}m{M}_{ALGO}", r)
+             for r in (32, 16, 8, 4, 2, 1)]
+            + [(f"hash_rows_{ALGO}", r * K) for r in (32, 16, 8, 4, 2, 1)])
+        enc, dig, verify = _programs()
+        assert all(enc.built((r, K, S), 0) and dig.built((r * K, S), 0)
+                   for r in coalesce.LADDER)
+        assert not verify._built
+
+    def test_a_step_that_fails_to_build_leaves_the_next_one_up(
+            self, ladder, monkeypatch, capsys):
+        from minio_tpu.ops import fused
+        build = fused.Program.build
+
+        def flaky(self, shape, device):
+            if shape[0] == 1:
+                raise RuntimeError("no such tile")
+            return build(self, shape, device)
+
+        monkeypatch.setattr(fused.Program, "build", flaky)
+        fn = _built_ladder(_enc(), (K, S))
+        assert "not built" in capsys.readouterr().err
+        assert coalesce.kernel_rows(fn, 1, (K, S)) == 2
+
+    @pytest.mark.parametrize("targets,want", [((), 1), ((0,), PAD)])
+    def test_verify_kernel_keeps_one_shape_when_it_has_targets(
+            self, ladder, tmp_path, targets, want):
+        es = make_set(tmp_path, name="vt")
+        sources = (1, 2) if targets else (0, 1)
+        fn = es._vt_kernel(K, M, sources, targets, ALGO, device=0)
+        assert fn.ladder == (not targets) and fn.program is not None
+        _built_ladder(fn, (K, S))
+        seen = _spy(fn)
+        x = _blocks(1)
+        digests, out = coalesce.get().submit(
+            ("vt", K, M, sources, targets, ALGO, S), x, fn).result(60)
+        assert seen == [(want, K, S)]
+        assert digests.shape == (1, K, 32)
+        assert (out is None) == (not targets)
+
+    @pytest.mark.parametrize("targets", [(), (0,)])
+    def test_an_unbuilt_program_is_built_by_its_submitter(
+            self, ladder, tmp_path, monkeypatch, targets):
+        """Nothing built, not even the top step (a geometry nobody
+        announced, a verify+transform variant met for the first time):
+        the submitting thread builds the 32-row shape before it queues
+        the item, so the lane thread runs a built executable and never
+        enters the jit."""
+        from minio_tpu.ops import fused
+        sources = (1, 2) if targets else (0, 1)
+        fn = make_set(tmp_path, name="cold")._vt_kernel(
+            K, M, sources, targets, ALGO, device=0)
+        prog = fn.program()
+        prog._built.clear()
+        builds, build = [], fused.Program.build
+
+        def spy(self, shape, device):
+            if not self.built(shape, device):
+                builds.append((threading.current_thread().name, shape))
+            return build(self, shape, device)
+
+        monkeypatch.setattr(fused.Program, "build", spy)
+        jit_entries = prog.jit._cache_size()
+        seen = _spy(fn)
+        co = coalesce.get()
+        co._ema = 2.0                     # through the lane thread
+        for _ in range(2):
+            digests, _ = co.submit(
+                ("vt", K, M, sources, targets, ALGO, S), _blocks(1),
+                fn).result(60)
+        assert digests.shape == (1, K, 32)
+        assert builds == [(threading.current_thread().name, (PAD, K, S))]
+        assert seen == [(PAD, K, S)] * 2 and co._thread is not None
+        assert prog.jit._cache_size() == jit_entries
+        prog._built.clear()
+
+    def test_building_a_kernel_reaches_for_no_program(self, monkeypatch):
+        """A pool worker builds these kernels to submit their keys to
+        the device owner: building one must not touch JAX (the worker
+        has no backend), so the program is looked up at dispatch."""
+        from minio_tpu.ops import fused
+
+        def no_jax(*a, **kw):
+            raise AssertionError("a kernel builder asked for a program")
+
+        for name in ("encode_hash_program", "hash_rows_program",
+                     "verify_transform_program"):
+            monkeypatch.setattr(fused, name, no_jax)
+        for fn in (_enc(), _dig(),
+                   coalesce.make_verify_kernel(K, M, (0, 1), (), ALGO,
+                                               PAD, 0),
+                   coalesce.make_verify_kernel(K, M, (1, 2), (0,), ALGO,
+                                               PAD, 0)):
+            assert fn.pad_rows and callable(fn.program)
+
+    @pytest.mark.parametrize("key", [
+        ("enc", "fd", K, M, ALGO, S), ("digest", ALGO, S, PAD * K),
+        ("vt", K, M, (0, 1), (), ALGO, S)])
+    def test_ipc_kernels_pad_by_the_same_rule(self, ladder, monkeypatch,
+                                              key):
+        """The owner-side kernels are the engine's builders: same
+        program, and their shapes come out of coalesce.step_rows."""
+        from minio_tpu.ops import ipc_dispatch as ipc
+        fn = ipc.kernel_from_key(key, device=0)
+        prog = dict(zip(("enc", "digest", "vt"), _programs()))[key[0]]
+        assert fn.program() is prog and fn.pad_rows in (PAD, PAD * K)
+        asked = []
+
+        def rule(n, pad_rows, built=None):
+            asked.append((n, pad_rows))
+            return 8
+
+        monkeypatch.setattr(coalesce, "step_rows", rule)
+        seen = _spy(fn)
+        x = _blocks(3)
+        if key[0] == "digest":
+            x = x.reshape(3 * K, S)
+        fn(x, [(0, x.shape[0])], None)              # the kernel's own pad
+        coalesce.get().submit(key, x, fn).result(30)    # the lane's
+        assert asked == [(x.shape[0], fn.pad_rows)] * 2
+        assert seen == [(8,) + x.shape[1:]] * 2

@@ -56,12 +56,16 @@ def drive_files(drive, bucket):
 
 
 @pytest.fixture
-def forced_device():
+def forced_device(monkeypatch):
     """Pin the device kernel paths on the CPU test mesh (jax host
     devices stand in for TPU cores) so GET verify and PUT encode
     actually cross the H2D boundary — the paths the staging pipeline
     and the ledger instrument.  Coalescer retired on both edges so
-    lanes with pipelined kernels never straddle the flip."""
+    lanes with pipelined kernels never straddle the flip.  Hedged
+    reads are off: on a loaded test host a drive read can be slow
+    enough that a parity shard wins, and a degraded read neither
+    fills the cache nor crosses the boundary once."""
+    monkeypatch.setenv("MTPU_HEDGE", "0")
     old = es_mod._USE_DEVICE
     coalesce.reset()
     es_mod._USE_DEVICE = True

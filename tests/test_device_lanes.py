@@ -469,6 +469,47 @@ class TestLaneObservability:
         assert 'mtpu_device_lane_queue_wait_seconds_total{device="6"}' \
             in text
 
+    def test_rows_padded_rows_and_d2h_grow_as_said(self, ndev):
+        """One 1-row and one 32-row encode on a built ladder: 33 rows
+        carried, 33 rows run, and the parity and digests of 33 rows
+        fetched back; all three in the registry the boot self test
+        walks."""
+        from minio_tpu.ops import devcache, fused
+        from minio_tpu.ops.selftest import metrics_registry_self_test
+        ndev(1)
+        k, m, s = 2, 2, 256
+        fn = coalesce.make_encode_kernel(k, m, "mxh256", 32, 0)
+        coalesce.build_ladder(fn, (k, s))
+        coalesce.ladder_wait()
+        try:
+            before = dict(DATA_PATH.snapshot()["lanes"].get(0, {}))
+            devcache.reset_h2d()
+            co = coalesce.get()
+            for n in (1, 32):
+                co.submit(("enc", "fd", k, m, "mxh256", s),
+                          np.ones((n, k, s), dtype=np.uint8),
+                          fn).result(30.0)
+            row = DATA_PATH.snapshot()["lanes"][0]
+            assert row["rows"] - before.get("rows", 0) == 33
+            assert row["padded_rows"] - before.get("padded_rows", 0) == 33
+            assert devcache.h2d_stats()["d2h_bytes"] == \
+                33 * m * s + (k + m) * 33 * 32
+            assert devcache.h2d_stats()["h2d_bytes"] == 33 * k * s
+        finally:
+            fused.encode_hash_program(k, m, "mxh256")._built.clear()
+        text = MetricsRegistry().render()
+        assert f'mtpu_device_lane_rows_total{{lane="0"}} {row["rows"]}' \
+            in text
+        assert ('mtpu_device_lane_padded_rows_total{lane="0"} '
+                f'{row["padded_rows"]}') in text
+        assert f"mtpu_d2h_bytes_total {33 * m * s + (k + m) * 33 * 32}" \
+            in text
+        names = {f.name for f in MetricsRegistry().families()}
+        assert {"mtpu_device_lane_rows_total",
+                "mtpu_device_lane_padded_rows_total",
+                "mtpu_d2h_bytes_total"} <= names
+        metrics_registry_self_test()
+
     def test_dispatch_span_tagged_with_device(self, ndev):
         """The device index rides the lane's spans: `lane.dispatch`
         (here run inline, so nested under the request) and the
@@ -488,6 +529,112 @@ class TestLaneObservability:
             assert by["coalesce.wait"].tags["device"] == 5
         finally:
             ospan.TRACER.configure(ring=0)
+
+
+# -- the ladder a deployment asks for at boot ---------------------------------
+
+class TestBootLadder:
+    @pytest.fixture
+    def asked(self, monkeypatch):
+        calls = []
+        monkeypatch.setattr(coalesce, "build_geometry_ladder",
+                            lambda *a: calls.append(a))
+        return calls
+
+    @pytest.mark.parametrize("use_device,parity,want", [
+        (True, None, [(3, 1, 349526, "mxh256", 32)]),
+        (True, 2, [(2, 2, 524288, "mxh256", 32)]),
+        (False, None, []),
+    ])
+    def test_each_set_asks_for_its_geometry_on_its_lane(
+            self, tmp_path, ndev, monkeypatch, asked, use_device, parity,
+            want):
+        from minio_tpu.engine import erasure_set as esmod
+        from minio_tpu.engine.pools import ServerPools
+        ndev(8)
+        monkeypatch.setattr(esmod, "_USE_DEVICE", use_device)
+        pools = ServerPools([make_ring(tmp_path, nsets=3)])
+        pools.build_ladders(parity)
+        assert asked == [w + (lane,) for lane in range(3) for w in want]
+
+    def test_server_asks_at_every_configured_parity(self, tmp_path,
+                                                    asked, monkeypatch):
+        """Boot and an admin `config set storage_class` both end in
+        build_ladders(): the sets' default parity and the classes'."""
+        from minio_tpu.engine import erasure_set as esmod
+        from minio_tpu.engine.pools import ServerPools
+        from minio_tpu.server.server import S3Server
+        from minio_tpu.server.sigv4 import Credentials
+        monkeypatch.setattr(esmod, "_USE_DEVICE", True)
+        pools = ServerPools([make_ring(tmp_path, nsets=1, parity=2)])
+        srv = S3Server(pools, Credentials("minioadmin", "minioadmin"),
+                       port=0)
+        try:
+            srv.build_ladders()
+            assert [a[:2] for a in asked] == [(2, 2)]
+            del asked[:]
+            srv.handlers.config_sys.set("storage_class", "rrs", "EC:1")
+            srv.build_ladders()
+            assert sorted(a[:2] for a in asked) == [(2, 2), (3, 1)]
+        finally:
+            srv._httpd.server_close()
+
+    def test_readiness_waits_for_the_boot_ladder_only(self, tmp_path,
+                                                      monkeypatch):
+        from minio_tpu.engine.pools import ServerPools
+        from minio_tpu.server.server import S3Server
+        from minio_tpu.server.sigv4 import Credentials
+        srv = S3Server(ServerPools([make_ring(tmp_path, nsets=1)]),
+                       Credentials("minioadmin", "minioadmin"), port=0)
+        idle = {"v": False}
+        monkeypatch.setattr(coalesce, "ladder_idle", lambda: idle["v"])
+
+        def ready():
+            return srv._dispatch_internal(None, "/minio/health/ready",
+                                          {}).status
+        try:
+            assert ready() == 200          # nothing was asked for
+            srv.build_ladders()            # a storage class set live
+            assert ready() == 200
+            srv.build_ladders(hold_ready=True)             # boot
+            assert ready() == 503
+            srv.build_ladders()            # ... does not end the wait
+            assert ready() == 503
+            idle["v"] = True
+            assert ready() == 200
+            idle["v"] = False              # a later build: not waited for
+            assert ready() == 200
+        finally:
+            srv._httpd.server_close()
+
+    def test_a_pool_worker_builds_nothing(self, tmp_path, monkeypatch):
+        """A worker has adopted the owner's platform and holds no
+        device: asking for a ladder there (an admin `config set` lands
+        in a worker) queues nothing and looks no program up."""
+        from minio_tpu.engine.pools import ServerPools
+        from minio_tpu.ops import fused
+        monkeypatch.setattr(devices, "_VISIBLE",
+                            ([], "tpu", "TPU v5 lite", 1))
+        monkeypatch.setattr(
+            fused, "encode_hash_program",
+            lambda *a: pytest.fail("a worker reached for a program"))
+        pools = ServerPools([make_ring(tmp_path, nsets=1, parity=2)])
+        assert pools.pools[0].sets[0]._use_device
+        pools.build_ladders()
+        assert coalesce.ladder_idle() and not coalesce._BUILD_Q
+
+    def test_pool_owner_asks_for_the_boot_geometry(self, asked,
+                                                   monkeypatch):
+        from minio_tpu.server import workers
+        cfg = {"pool_paths": [[f"/d{i}" for i in range(8)]],
+               "set_drive_count": 4}
+        workers._owner_ladders(cfg)
+        assert asked == []                  # shard math on the host
+        monkeypatch.setattr(devices, "on_tpu", lambda: True)
+        monkeypatch.setenv("MTPU_DEVICES", "2")
+        workers._owner_ladders(cfg)
+        assert asked == [(2, 2, 524288, "mxh256", 32, lane)
+                         for lane in (0, 1)]
 
 
 # -- keyspace placement (tools/loadgen) --------------------------------------
