@@ -17,10 +17,12 @@ admin healthinfo.  Any failed phase ends it non-zero.  Readings it prints are
 client-side smoke readings of one run, not benchmark results.
 
     python chip_smoke.py                 one chip (what the driver runs)
-    python chip_smoke.py --chips 4       four-chip host: four EC:2+2 sets on
-                                         four lanes + the mesh, then the same
-                                         requests under MTPU_DEVICES=1
-                                         MTPU_MESH=0, compared shard by shard
+    python chip_smoke.py --chips 4       four-chip host: four EC:2+2 sets, a
+                                         set a lane (what the server chooses
+                                         itself), then the same requests with
+                                         the mesh forced (MTPU_MESH=1) and on
+                                         one lane (MTPU_DEVICES=1 MTPU_MESH=0),
+                                         compared shard by shard
     JAX_PLATFORMS=cpu python chip_smoke.py --small
                                          rehearsal: every phase at a small
                                          size, then exit 1 (no chip)
@@ -447,6 +449,11 @@ def check_counters(run: Run, m0: dict, m1: dict, lanes: int, log) -> dict:
             str(d): counter(
                 m1, f'mtpu_device_lane_dispatches_total{{device="{d}"}}')
             for d in range(lanes)},
+        "encode_blocks": {
+            plane: counter(
+                m1, f'mtpu_encode_blocks_total{{plane="{plane}"}}')
+            - counter(m0, f'mtpu_encode_blocks_total{{plane="{plane}"}}')
+            for plane in ("lane", "mesh", "host")},
         "coalesce_items": counter(m1, "mtpu_coalesce_items_total"),
         "coalesce_dispatches": counter(m1, "mtpu_coalesce_dispatches_total"),
         **{n: counter(m1, n) for n in FALLBACK_COUNTERS},
@@ -551,9 +558,11 @@ def one_chip(args, root: str, log) -> dict:
         srv.kill()
 
 
-def four_chip_requests(args, srv: Server, log, lanes: int) -> dict:
-    """The requests both four-chip servers get.  Returns what must be
-    equal between them: ETags and per-shard file digests."""
+def four_chip_requests(args, srv: Server, log, lanes: int,
+                       plane: str) -> dict:
+    """The requests every four-chip server gets; `plane` is where the
+    PUTs' parity must have been computed.  Returns what must be equal
+    between the servers: ETags and per-shard file digests."""
     small = args.small
     dev = srv.device()
     run = Run(srv, args.seed, {}, log)
@@ -569,7 +578,18 @@ def four_chip_requests(args, srv: Server, log, lanes: int) -> dict:
     victim = sorted(run.objects)[0]
     run.get_all(skip=(victim,))
     run.degraded_then_heal(victim)
-    counters = check_counters(run, m0, srv.metrics(), lanes, log)
+    on_chip = dev["platform"] == "tpu"
+    # (The rehearsal's host codec verifies a healthy GET off the lanes
+    # when the mesh is forced, and computes a lane's parity itself.)
+    counters = check_counters(run, m0, srv.metrics(),
+                              lanes if on_chip or plane != "mesh" else 0,
+                              log)
+    if not on_chip and plane == "lane":
+        plane = "host"
+    want = {p: run.put_bytes // MIB if p == plane else 0
+            for p in ("lane", "mesh", "host")}
+    need(counters["encode_blocks"] == want,
+         f"PUT parity by plane {counters['encode_blocks']}, want {want}")
     key = sorted(run.objects)[1]
     t0 = time.monotonic()
     frames = check_against_reference(
@@ -585,15 +605,21 @@ def four_chip_requests(args, srv: Server, log, lanes: int) -> dict:
 
 
 def four_chips(args, root: str, log) -> dict:
-    """Four EC:2+2 sets over 16 drives: set i rides lane i % 4, a degraded
-    GET reconstructs through the mesh (an all-gather across chips).  Then
-    — after the first server has exited, since the chips belong to one
-    process at a time — the same requests on one lane with the mesh off,
-    and every ETag and shard file must agree."""
+    """Four EC:2+2 sets over 16 drives.  Left to itself the server gives
+    every chip the set it owns (`mesh_rule`: sets >= chips): set i's PUT
+    encode, GET digests and degraded decode ride lane i % 4.  Then, one
+    after the other, since the chips belong to one process at a time,
+    the same requests with the mesh forced (PUT parity SPMD over all
+    four chips, the degraded GET through its all-gather) and on one
+    lane with the mesh off, and every ETag and shard file must agree
+    between the three."""
+    servers = (
+        ("four_sets_four_lanes", {}, 4, "lane"),
+        ("four_sets_mesh_forced", {"MTPU_MESH": "1"}, 4, "mesh"),
+        ("one_lane_no_mesh", {"MTPU_DEVICES": "1", "MTPU_MESH": "0"}, 1,
+         "lane"))
     results = {}
-    for name, env, lanes in (
-            ("four_lanes_and_mesh", {}, 4),
-            ("one_lane_no_mesh", {"MTPU_DEVICES": "1", "MTPU_MESH": "0"}, 1)):
+    for name, env, lanes, plane in servers:
         srv = Server(os.path.join(root, name), 16,
                      extra_args=("--set-drive-count", "4"), extra_env=env)
         try:
@@ -604,21 +630,25 @@ def four_chips(args, root: str, log) -> dict:
                  f"four devices asked for, the server sees {dev['count']}")
             need(dev["lanes"] == lanes, f"lanes {dev['lanes']}, want {lanes}")
             results[name] = four_chip_requests(
-                args, srv, lambda s, n=name: log(f"[{n}] {s}"), lanes)
+                args, srv, lambda s, n=name: log(f"[{n}] {s}"), lanes, plane)
         finally:
             srv.kill()
-    a, b = results["four_lanes_and_mesh"], results["one_lane_no_mesh"]
-    need(a["etags"] == b["etags"], "ETags differ between the two servers")
-    need(a["shards"] == b["shards"],
-         "on-disk shard files differ between four lanes + mesh and the "
-         "one-lane oracle: "
-         f"{[k for k in a['shards'] if a['shards'][k] != b['shards'].get(k)]}")
-    log(f"oracle: {len(a['etags'])} ETags and "
-        f"{sum(len(v) for v in a['shards'].values())} shard files equal "
-        f"between four lanes + mesh and MTPU_DEVICES=1 MTPU_MESH=0")
+    oracle = results["one_lane_no_mesh"]
+    for name in ("four_sets_four_lanes", "four_sets_mesh_forced"):
+        r = results[name]
+        need(r["etags"] == oracle["etags"],
+             f"ETags differ between {name} and the one-lane oracle")
+        differ = [k for k, v in r["shards"].items()
+                  if v != oracle["shards"].get(k)]
+        need(r["shards"] == oracle["shards"],
+             f"on-disk shard files differ between {name} and the one-lane "
+             f"oracle: {differ}")
+    log(f"oracle: {len(oracle['etags'])} ETags and "
+        f"{sum(len(v) for v in oracle['shards'].values())} shard files equal "
+        f"between four lanes, the forced mesh and MTPU_DEVICES=1 MTPU_MESH=0")
     for r in results.values():
         del r["shards"], r["etags"]
-    return {"device": a["device"], **results}
+    return {"device": results["four_sets_four_lanes"]["device"], **results}
 
 
 def main() -> int:

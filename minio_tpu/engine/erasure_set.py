@@ -22,8 +22,10 @@ from __future__ import annotations
 import hashlib
 import os
 import queue as _queuemod
+import threading
 import time
 import uuid
+import weakref
 from concurrent.futures import ThreadPoolExecutor
 
 import numpy as np
@@ -91,7 +93,7 @@ _MESH = None
 # would cost more in faults than the overlap saves).  One pipelined
 # encode per thread at a time, and StagePipeline joins its in-flight
 # write before returning, so reuse across calls is safe.
-_DB_ARENAS = __import__("threading").local()
+_DB_ARENAS = threading.local()
 
 
 def _db_arenas(nbytes: int) -> list:
@@ -102,22 +104,52 @@ def _db_arenas(nbytes: int) -> list:
     return pair
 
 
-def _mesh_mode() -> bool:
-    """Whether the engine places codec work on a multi-device mesh.
+# The erasure sets this process serves (weak: a set that is dropped
+# stops counting), for the mesh rule below.
+_LOCAL_SETS: "weakref.WeakSet[ErasureSet]" = weakref.WeakSet()
+_LOCAL_SETS_MU = threading.Lock()
 
-    Auto (MTPU_MESH unset): on when this process holds >1 TPU chip —
-    the WithAutoGoroutines role (cmd/erasure-coding.go:63), scaling
-    the shard math across chips without configuration (a pool worker
-    holds none: its work rides the owner's lanes).  MTPU_MESH=1/0
-    forces (tests use 1 to exercise the SPMD path on the virtual CPU
-    mesh, where auto would stay off for speed)."""
-    import os
-    v = os.environ.get("MTPU_MESH", "")
-    if v == "1":
+
+def _chips_with_a_set() -> int:
+    """How many of the process's lanes own an erasure set it serves
+    (`device_idx` is set index % lanes, so four sets own four lanes)."""
+    with _LOCAL_SETS_MU:
+        live = list(_LOCAL_SETS)
+    return len({es.device_idx for es in live})
+
+
+def mesh_rule(local_tpu: bool, chips: int, sets: int,
+              forced: str = "") -> bool:
+    """Whether codec work is spread over a multi-device mesh, from what
+    the process can observe: the platform, the chips it holds and the
+    chips that already own an erasure set it serves (`sets`).
+
+    Where every chip owns a set, a set's encode, decode and digests
+    ride its own lane: the fused, coalesced, laddered, named, pre-built
+    dispatch a one-chip host runs (ops/coalesce.py), four of them side
+    by side.  The mesh (parallel/sharded.py) is for the other case, one
+    set's shard math spread over chips that would otherwise sit by:
+    chips outnumber the sets that own one.  A pool worker holds no chip
+    (its work rides the owner's lanes), and a host backend has no mesh
+    worth its collectives.  `forced` is MTPU_MESH: "1"/"0" override
+    (tests use 1 to exercise the SPMD path on the virtual CPU mesh)."""
+    if forced == "1":
         return True
-    if v == "0":
+    if forced == "0":
         return False
-    return devices_mod.local_tpu() and devices_mod.visible_count() > 1
+    return local_tpu and chips > 1 and sets < chips
+
+
+def _mesh_mode() -> bool:
+    """`mesh_rule` of this process, now (the WithAutoGoroutines role,
+    cmd/erasure-coding.go:63: scaling without configuration).  Read per
+    call: tests flip MTPU_MESH and MTPU_DEVICES at runtime."""
+    forced = os.environ.get("MTPU_MESH", "")
+    if forced in ("0", "1") or not devices_mod.local_tpu():
+        # Decided without counting (forced: without asking JAX either).
+        return mesh_rule(False, 0, 0, forced)
+    return mesh_rule(True, devices_mod.visible_count(),
+                     _chips_with_a_set(), forced)
 
 
 def _get_fastpath() -> bool:
@@ -186,6 +218,8 @@ class ErasureSet:
         self.default_parity = (self.n // 2 if default_parity is None
                                else default_parity)
         self.set_index = set_index
+        with _LOCAL_SETS_MU:
+            _LOCAL_SETS.add(self)
         # Pool-nesting invariant: work running ON self.pool must never
         # block on another self.pool future.  Two mechanisms enforce it:
         # (1) layered executors — prefetch tasks (get_object_iter
@@ -1238,6 +1272,7 @@ class ErasureSet:
                             nb, BLOCK_SIZE)
                         blocks = blocks.reshape(nb, k, shard_size)
                 if fused_host is not None:
+                    DATA_PATH.record_encode_blocks("host", nb)
                     if co is not None:
                         h = co.submit(
                             ("pf", k, m, shard_size), blocks,
@@ -1263,11 +1298,14 @@ class ErasureSet:
                 # is then pure byte interleaving on the host.
                 parity = digests = None
                 if _mesh_mode():
-                    # Multi-device: place the shard matmul on the mesh
-                    # (blocks x lanes SPMD); digests hash on host.
-                    # Mesh placement stays direct — SPMD shapes don't
-                    # stack across requests.
+                    # Chips outnumber sets (mesh_rule): place the shard
+                    # matmul on the mesh (blocks x lanes SPMD); digests
+                    # hash on host.  Mesh placement stays direct — SPMD
+                    # shapes don't stack across requests.
                     parity = self._mesh_encode(k, m, blocks)
+                DATA_PATH.record_encode_blocks(
+                    "mesh" if parity is not None
+                    else "lane" if self._use_device else "host", nb)
                 if parity is not None:
                     if pending is not None:
                         yield flush(pending)

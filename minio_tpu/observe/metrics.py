@@ -223,6 +223,9 @@ class DataPathStats:
             # persistent-cache hits are not counted.
             self.jit_compiles = 0
             self.jit_compile_s = 0.0
+            # 1 MiB blocks whose PUT parity was computed on each plane:
+            # the owning set's device lane, the SPMD mesh, the host.
+            self.encode_blocks = {"lane": 0, "mesh": 0, "host": 0}
             # Cross-process dispatch (ops/ipc_dispatch.py, worker pool):
             # items shipped to the device owner, results received,
             # fallbacks (arena/ring full -> computed locally), and
@@ -391,6 +394,12 @@ class DataPathStats:
         with self._mu:
             self.jit_compiles += 1
             self.jit_compile_s += seconds
+
+    def record_encode_blocks(self, plane: str, blocks: int) -> None:
+        """`blocks` full 1 MiB blocks of a PUT went to `plane` ("lane",
+        "mesh" or "host") for their parity (engine/_encode_chunks)."""
+        with self._mu:
+            self.encode_blocks[plane] += blocks
 
     def record_co_fault(self, members: int) -> None:
         """A coalesced dispatch raised; `members` spans were retried
@@ -609,6 +618,7 @@ class DataPathStats:
                           for d, row in sorted(self.lanes.items())},
                 "jit_compiles": self.jit_compiles,
                 "jit_compile_s": self.jit_compile_s,
+                "encode_blocks": dict(self.encode_blocks),
                 "ipc_submits": self.ipc_submits,
                 "ipc_rows": self.ipc_rows,
                 "ipc_results": self.ipc_results,
@@ -815,6 +825,11 @@ class MetricsRegistry:
         self.jit_compile_seconds = Gauge(
             "mtpu_jit_compile_seconds_total",
             "Seconds spent in XLA compilations in this process")
+        self.encode_blocks = Gauge(
+            "mtpu_encode_blocks_total",
+            "1 MiB blocks of PUT bodies whose parity was computed on "
+            "this plane: lane (the owning set's device), mesh (SPMD "
+            "over all chips), host", ("plane",))
         # Cross-process dispatch families (worker pool, PR 9).
         self.ipc_submits = Gauge(
             "mtpu_ipc_dispatch_submits_total",
@@ -1612,6 +1627,8 @@ class MetricsRegistry:
                                              lane=str(dev))
         self.jit_compiles.set(snap["jit_compiles"])
         self.jit_compile_seconds.set(snap["jit_compile_s"])
+        for plane, blocks in snap["encode_blocks"].items():
+            self.encode_blocks.set(blocks, plane=plane)
         self.ipc_submits.set(snap["ipc_submits"])
         self.ipc_results.set(snap["ipc_results"])
         self.ipc_fallbacks.set(snap["ipc_fallbacks"])
