@@ -735,7 +735,8 @@ def _smallobj_leg(root: str, flag: str, *, clients: int = 12,
     """One engine leg of smallobj_bench under MTPU_METABATCH=`flag`:
     a PUT storm (4-64 KiB Zipf bodies — amortized fsyncs/object and
     group-commit occupancy), a HEAD storm (HEAD always stats, so it is
-    the pure metadata-read surface the per-drive coalescing must win),
+    the pure metadata-read surface; since PR 30 a request reads its own
+    xl.meta whatever the flag, so both flags run the same read code),
     and a single-client idle probe (the unloaded p50 the 3% gate
     protects — batching must not tax a server with nothing to batch).
 
@@ -744,7 +745,6 @@ def _smallobj_leg(root: str, flag: str, *, clients: int = 12,
     import os
     import threading
 
-    from minio_tpu.observe.metrics import DATA_PATH
     from minio_tpu.ops import metalanes
     from tools.loadgen import (_quantile, _zipf_pick, make_set,
                                run_load, zipf_cdf)
@@ -777,9 +777,7 @@ def _smallobj_leg(root: str, flag: str, *, clients: int = 12,
         }
 
         # HEAD storm: GETs are absorbed by the FileInfo cache, but
-        # HEAD always elects xl.meta across the drives — sustained
-        # concurrent HEADs are where read fan-outs/request must drop
-        # below 1 (shared per-drive rounds beat per-request fan-outs).
+        # HEAD always elects xl.meta across the drives.
         bkt = "sohead"
         if not es.bucket_exists(bkt):
             es.make_bucket(bkt)
@@ -806,7 +804,6 @@ def _smallobj_leg(root: str, flag: str, *, clients: int = 12,
                 errors.append(e)
                 stop.set()
 
-        snap0 = DATA_PATH.snapshot()
         threads = [threading.Thread(target=head_client, args=(ci,),
                                     daemon=True)
                    for ci in range(clients)]
@@ -818,18 +815,12 @@ def _smallobj_leg(root: str, flag: str, *, clients: int = 12,
         for t in threads:
             t.join(60.0)
         wall = time.monotonic() - t_start
-        snap1 = DATA_PATH.snapshot()
         if errors:
             raise errors[0]
         heads = [x for per in lats for x in per]
-        d_rq = (snap1["meta_read_requests"]
-                - snap0["meta_read_requests"])
-        d_rr = snap1["meta_read_rounds"] - snap0["meta_read_rounds"]
         leg["head_ops_per_s"] = round(len(heads) / wall, 1)
         leg["head_p50_ms"] = round(_quantile(heads, 0.50) * 1e3, 3)
         leg["head_p99_ms"] = round(_quantile(heads, 0.99) * 1e3, 3)
-        leg["get_fanouts_per_request"] = (round(d_rr / d_rq, 4)
-                                          if d_rq else 0.0)
 
         # Idle probe: strictly serial small PUT/GET pairs — no
         # concurrency, so the lane inline fast path must route every
@@ -860,9 +851,9 @@ def _smallobj_leg(root: str, flag: str, *, clients: int = 12,
 
 def smallobj_bench(duration_s: float = 3.0, clients: int = 16,
                    idle_ops: int = 400, warmup_s: float = 2.0) -> dict:
-    """Small-object suite (ISSUE 19): ops/s, amortized fsyncs/object,
-    and metadata read fan-outs/request, MTPU_METABATCH=1 vs the =0
-    single-op oracle, per leg.
+    """Small-object suite (ISSUE 19): ops/s and amortized
+    fsyncs/object, MTPU_METABATCH=1 vs the =0 single-op oracle, per
+    leg.
 
     Drives live on a REAL (non-tmpfs) filesystem when one exists: the
     group-commit claim is about fsync amortization, and tmpfs fsync is
@@ -909,8 +900,6 @@ def smallobj_bench(duration_s: float = 3.0, clients: int = 16,
     o_fs = out["so_oracle_fsyncs_per_object"]
     out["so_fsyncs_ratio"] = (round(
         out["so_batch_fsyncs_per_object"] / o_fs, 4) if o_fs else 0.0)
-    out["so_get_fanouts_per_request"] = \
-        out["so_batch_get_fanouts_per_request"]
     o_ip = out["so_oracle_idle_put_p50_ms"]
     out["so_idle_put_p50_ratio"] = (round(
         out["so_batch_idle_put_p50_ms"] / o_ip, 4) if o_ip else 0.0)
@@ -2656,9 +2645,10 @@ def _smallobj_main() -> None:
     suite alone, JSON to stdout and SMALLOBJ_r19.json for the record.
     Gates (ISSUE 19): 4-64 KiB Zipf PUT ops/s >= 1.3x and amortized
     fsyncs/object <= 0.5x vs the MTPU_METABATCH=0 oracle under >= 8
-    concurrent clients, metadata read fan-outs/request < 1 on the
-    coalesced HEAD leg, and the idle-server small PUT/GET p50 within
-    3% of the oracle (batching must not tax the unloaded path)."""
+    concurrent clients, and the idle-server small PUT/GET p50 within
+    3% of the oracle (batching must not tax the unloaded path).  The
+    read-coalescing gate of SMALLOBJ_r19 (fan-outs/request < 1) went
+    with the read lanes in PR 30."""
     import os
     doc = {"rc": 0, "ok": False}
     try:
@@ -2668,8 +2658,6 @@ def _smallobj_main() -> None:
             and extras.get("so_clients", 0) >= 8
             and extras.get("so_put_ops_ratio", 0.0) >= 1.3
             and 0.0 < extras.get("so_fsyncs_ratio", 1.0) <= 0.5
-            and 0.0 < extras.get("so_get_fanouts_per_request", 9.9)
-            < 1.0
             and extras.get("so_idle_put_p50_ratio", 9.9) <= 1.03
             and extras.get("so_idle_get_p50_ratio", 9.9) <= 1.03)
         doc["extras"] = extras
@@ -2682,8 +2670,7 @@ def _smallobj_main() -> None:
             f"({extras.get('so_batch_fsyncs_per_object')} vs "
             f"{extras.get('so_oracle_fsyncs_per_object')}) at batch "
             f"occupancy {extras.get('so_batch_batch_occupancy')}, "
-            f"HEAD fan-outs/request "
-            f"{extras.get('so_get_fanouts_per_request')}, idle p50 "
+            f"idle p50 "
             f"x{extras.get('so_idle_put_p50_ratio')} PUT / "
             f"x{extras.get('so_idle_get_p50_ratio')} GET vs oracle "
             f"on {extras.get('so_fs_type', 'tmpfs')}")

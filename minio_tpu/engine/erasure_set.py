@@ -418,8 +418,10 @@ class ErasureSet:
 
     # -- drive fan-out helpers ----------------------------------------------
 
-    def _map_drives(self, fn, drives=None) -> list:
+    def _map_drives(self, fn, drives=None, inline: bool = False) -> list:
         """Run fn(drive) on every drive in parallel; exceptions captured.
+        `inline` runs the calls one after another on the calling thread
+        instead (the xl.meta read fan-out over in-process drives).
 
         Returns list of (result, error) per drive position.
         """
@@ -433,7 +435,7 @@ class ErasureSet:
             except Exception as e:  # noqa: BLE001 — quorum layer classifies
                 return None, e
 
-        if self._serial_local(drives) or self._on_drive_pool():
+        if inline or self._serial_local(drives) or self._on_drive_pool():
             return [call(d) for d in drives]
         # wrap_ctx: per-drive spans born in pool threads still attach
         # to the traced request (no-op when untraced).
@@ -914,12 +916,17 @@ class ErasureSet:
     #: out — network round-trips overlap even with one core.
     _SERIAL_FANOUT = (os.cpu_count() or 2) == 1
 
+    def _in_process(self, drives=None) -> bool:
+        """Every drive is a LocalDrive of this process (or a hole);
+        isinstance sees through HealthWrappedDrive."""
+        return all(
+            isinstance(d, (LocalDrive, type(None)))
+            for d in (self.drives if drives is None else drives))
+
     def _serial_local(self, drives=None) -> bool:
         """One policy, three dispatch sites: serial per-drive calls
         only on a 1-core host whose drives are all in-process."""
-        return self._SERIAL_FANOUT and all(
-            isinstance(d, (LocalDrive, type(None)))
-            for d in (self.drives if drives is None else drives))
+        return self._SERIAL_FANOUT and self._in_process(drives)
 
     def _on_drive_pool(self) -> bool:
         """True when the calling thread IS one of this set's drive-pool
@@ -1866,18 +1873,27 @@ class ErasureSet:
         return bytes(out)
 
     def _read_metadata(self, bucket, obj, version_id=""):
+        """Read xl.meta from all N drives, once each, and elect.
+
+        How the N reads run follows from what the drives are: drives
+        of this process are read on the calling thread, one after
+        another; remote drives on the pool, where network round trips
+        overlap.  A page-cache read is ~0.05 ms of work; what it costs
+        under concurrent clients is every point where its thread gives
+        the GIL away (~0.7 ms each with 8 clients on the chip host,
+        PERF.md §6, PR 30), and a hand-off to a lane or pool thread
+        only adds such points.  A local drive that turns slow is the
+        breaker's business (health_wrap times every call), as at every
+        other serial-local site."""
         version_id = normalize_version_id(version_id)
-        DATA_PATH.record_meta_read_request()
-        mb = metalanes.get() if metalanes.enabled() else None
-        if mb is not None:
-            mb.note_read(1)
-        try:
-            with ospan.span("engine.quorum"):
-                res = self._read_version_fanout(
-                    bucket, obj, version_id, mb)
-        finally:
-            if mb is not None:
-                mb.note_read(-1)
+        inline = self._in_process()
+        path = "inline" if inline else "pool"
+        DATA_PATH.record_meta_read_request(path)
+        with ospan.span("engine.quorum") as sp:
+            sp.tag(path=path)
+            res = self._map_drives(
+                lambda d: d.read_version(bucket, obj, version_id),
+                inline=inline)
         metas = [fi for fi, _ in res]
         errs = [e for _, e in res]
         n_found = sum(1 for f in metas if f is not None)
@@ -1894,108 +1910,6 @@ class ErasureSet:
             metas, self.n, self.default_parity)
         fi = Q.find_file_info_in_quorum(metas, read_quorum)
         return fi, metas, errs
-
-    def _read_positions(self, bucket, obj, version_id,
-                        positions, mb) -> list:
-        """read_version over a subset of drive positions, returning
-        one (FileInfo|None, error|None) per position in order.  Routes
-        through the per-drive read lanes when concurrent metadata
-        traffic is in flight (distinct keys' fan-outs then merge into
-        one read_version_many round per drive); otherwise the exact
-        oracle per-drive dispatch."""
-        if mb is not None and mb.read_hot():
-            handles = []
-            for pos in positions:
-                d = self.drives[pos]
-                if d is None:
-                    handles.append(None)
-                    continue
-                try:
-                    handles.append(
-                        mb.submit_read(d, bucket, obj, version_id))
-                except Exception as e:  # noqa: BLE001 — quorum classifies
-                    handles.append(e)
-            out = []
-            for h in handles:
-                if h is None:
-                    out.append((None, ErrDiskNotFound("offline")))
-                elif isinstance(h, Exception):
-                    out.append((None, h))
-                else:
-                    try:
-                        out.append((h.result(), None))
-                    except Exception as e:  # noqa: BLE001
-                        out.append((None, e))
-            return out
-        res = self._map_drives(
-            lambda d: d.read_version(bucket, obj, version_id),
-            drives=[self.drives[p] for p in positions])
-        DATA_PATH.record_meta_read_round(len(positions), len(positions))
-        return res
-
-    def _read_version_fanout(self, bucket, obj, version_id, mb) -> list:
-        """The metadata read fan-out with the K+1 trim: read K+1
-        drives first; accept only a unanimous, quorate, inline-object
-        answer (streaming objects must see all N metas — the healthy
-        read fast path keys off `any(m is None)`); otherwise read the
-        REMAINING drives and merge, so every drive is still read
-        exactly once and quorum/error classification matches the all-N
-        oracle.  Unread positions are padded (None, None) — a shape no
-        real drive outcome produces (failures always carry an error).
-
-        Trim trades Python acceptance checks for one skipped drive
-        read — a win only when the read plane is hot (rounds are
-        shared and queued across requests).  On an idle server the
-        serial page-cached read is cheaper than the checks, and idle
-        single-request latency must match the oracle, so a cold plane
-        takes the full fan-out."""
-        k1 = (self.n - self.default_parity) + 1
-        if (not metalanes.trim_enabled() or k1 >= self.n
-                or mb is None or not mb.read_hot()):
-            return self._read_positions(bucket, obj, version_id,
-                                        list(range(self.n)), mb)
-        first = list(range(k1))
-        res1 = self._read_positions(bucket, obj, version_id, first, mb)
-        if self._trim_acceptable(res1):
-            DATA_PATH.record_meta_trim(True)
-            full: list = [(None, None)] * self.n
-            for pos, r in zip(first, res1):
-                full[pos] = r
-            return full
-        DATA_PATH.record_meta_trim(False)
-        rest = list(range(k1, self.n))
-        res2 = self._read_positions(bucket, obj, version_id, rest, mb)
-        full = [None] * self.n
-        for pos, r in zip(first, res1):
-            full[pos] = r
-        for pos, r in zip(rest, res2):
-            full[pos] = r
-        return full
-
-    def _trim_acceptable(self, res) -> bool:
-        """A trimmed first round stands only when nothing about it
-        could change with more drives: every read succeeded, all agree
-        on one version (unanimity — a single dissenter might be the
-        majority among the unread), the agreeing count already meets
-        the object's own read quorum (guards per-object parity lower
-        than the set default), and the elected version never touches
-        shard files (inline/deleted) so no downstream path needs the
-        full per-drive meta picture."""
-        metas = [fi for fi, _ in res]
-        if any(e is not None for _, e in res):
-            return False
-        if any(m is None for m in metas):
-            return False
-        keys = {Q._fi_key(m) for m in metas}
-        if len(keys) != 1:
-            return False
-        read_quorum, _ = Q.object_quorum_from_meta(
-            metas, self.n, self.default_parity)
-        if len(metas) < read_quorum:
-            return False
-        fi = metas[0]
-        return (fi.deleted or fi.inline_data is not None
-                or bool(fi.parts and not fi.data_dir))
 
     def _fi_cache_store(self, bucket, obj, version_id, entry) -> None:
         # Bounded LRU: evict oldest-touched entries one at a time

@@ -497,22 +497,15 @@ class HotObjectCache:
 
 # -- attachment ---------------------------------------------------------------
 
-def _all_local(es) -> bool:
-    """A tier can only trust its generation table when every mutation
-    in the deployment runs through THIS process tree's _mark_dirty —
-    i.e. every drive is local (HealthWrappedDrive is isinstance-
-    transparent).  Offline slots (None) are fine."""
-    from ..storage.drive import LocalDrive
-    return all(d is None or isinstance(d, LocalDrive)
-               for d in es.drives)
-
-
 def attach_sets(sets, tier: HotObjectCache) -> int:
     """Attach `tier` to every all-local ErasureSet of one ErasureSets
-    stack; returns how many sets attached."""
+    stack; returns how many sets attached.  A tier can only trust its
+    generation table when every mutation in the deployment runs
+    through THIS process tree's _mark_dirty, i.e. every drive is
+    in-process (offline slots are fine)."""
     n = 0
     for es in getattr(sets, "sets", [sets]):
-        if _all_local(es):
+        if es._in_process():
             es.hot_tier = tier
             n += 1
     return n

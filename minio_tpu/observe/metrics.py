@@ -296,20 +296,17 @@ class DataPathStats:
             # xl.meta publishes and the fsyncs paying for them (solo
             # write_metadata: 1 fsync per publish; group commit: 1
             # journal fsync amortized over the whole batch), journal
-            # replays at boot, engine metadata-read requests vs the
-            # per-drive dispatch rounds serving them (oracle: N rounds
-            # per request; coalesced: rounds/requests can drop below
-            # 1), K+1 read-trim outcomes, and lane scheduling stats.
+            # replays at boot, engine metadata-read requests by how
+            # their all-N fan-out ran (inline: on the request's thread,
+            # every drive in-process; pool: remote drives), and the
+            # write lanes' scheduling stats.
             self.meta_publishes = 0
             self.meta_fsyncs = 0
             self.meta_group_commits = 0
             self.meta_group_items = 0
             self.meta_journal_replays = 0
             self.meta_read_requests = 0
-            self.meta_read_rounds = 0
-            self.meta_read_keys = 0
-            self.meta_trim_hits = 0
-            self.meta_trim_fallbacks = 0
+            self.meta_read_fanouts = {"inline": 0, "pool": 0}
             self.meta_lane_dispatches = 0
             self.meta_lane_items = 0
             self.meta_lane_wait_s = 0.0
@@ -544,27 +541,13 @@ class DataPathStats:
         with self._mu:
             self.meta_journal_replays += n
 
-    def record_meta_read_request(self) -> None:
-        """One engine-level metadata read (_read_metadata call)."""
+    def record_meta_read_request(self, path: str) -> None:
+        """One engine-level metadata read (_read_metadata call) whose
+        all-N fan-out ran on `path` ("inline": the request's own
+        thread; "pool": the drive pool)."""
         with self._mu:
             self.meta_read_requests += 1
-
-    def record_meta_read_round(self, rounds: int, keys: int) -> None:
-        """Per-drive metadata read dispatches: `rounds` drive calls
-        served `keys` (vol, obj, version) lookups."""
-        with self._mu:
-            self.meta_read_rounds += rounds
-            self.meta_read_keys += keys
-
-    def record_meta_trim(self, hit: bool) -> None:
-        """K+1 read fan-out trim outcome: hit = first trimmed round
-        was quorate and accepted; fallback = the remaining drives had
-        to be read too."""
-        with self._mu:
-            if hit:
-                self.meta_trim_hits += 1
-            else:
-                self.meta_trim_fallbacks += 1
+            self.meta_read_fanouts[path] += 1
 
     def record_meta_lane_dispatch(self, items: int,
                                   wait_s: float) -> None:
@@ -671,13 +654,7 @@ class DataPathStats:
                     if self.meta_publishes else 0.0),
                 "meta_journal_replays": self.meta_journal_replays,
                 "meta_read_requests": self.meta_read_requests,
-                "meta_read_rounds": self.meta_read_rounds,
-                "meta_read_keys": self.meta_read_keys,
-                "meta_read_fanouts_per_request": (
-                    self.meta_read_rounds / self.meta_read_requests
-                    if self.meta_read_requests else 0.0),
-                "meta_trim_hits": self.meta_trim_hits,
-                "meta_trim_fallbacks": self.meta_trim_fallbacks,
+                "meta_read_fanouts": dict(self.meta_read_fanouts),
                 "meta_lane_dispatches": self.meta_lane_dispatches,
                 "meta_lane_items": self.meta_lane_items,
                 "meta_lane_wait_s": self.meta_lane_wait_s,
@@ -1087,19 +1064,12 @@ class MetricsRegistry:
         self.meta_read_requests = Gauge(
             "mtpu_meta_read_requests_total",
             "Engine metadata reads (quorum _read_metadata calls)")
-        self.meta_read_rounds = Gauge(
-            "mtpu_meta_read_rounds_total",
-            "Per-drive metadata read dispatches serving those requests")
         self.meta_read_fanouts = Gauge(
-            "mtpu_meta_read_fanouts_per_request",
-            "Drive dispatches per metadata read (oracle: N drives; "
-            "coalescing drives it below 1)")
-        self.meta_trim_hits = Gauge(
-            "mtpu_meta_trim_hits_total",
-            "K+1-trimmed read fan-outs accepted at quorum")
-        self.meta_trim_fallbacks = Gauge(
-            "mtpu_meta_trim_fallbacks_total",
-            "Trimmed fan-outs that widened to the remaining drives")
+            "mtpu_meta_read_fanouts_total",
+            "Metadata read fan-outs by how they ran: inline (on the "
+            "request's thread, every drive in-process) or pool "
+            "(remote drives); sums to mtpu_meta_read_requests_total",
+            ("path",))
         self.meta_lane_dispatches = Gauge(
             "mtpu_meta_lane_dispatches_total",
             "Metadata lane dispatcher rounds")
@@ -1679,11 +1649,8 @@ class MetricsRegistry:
             round(snap["meta_batch_occupancy"], 6))
         self.meta_journal_replays.set(snap["meta_journal_replays"])
         self.meta_read_requests.set(snap["meta_read_requests"])
-        self.meta_read_rounds.set(snap["meta_read_rounds"])
-        self.meta_read_fanouts.set(
-            round(snap["meta_read_fanouts_per_request"], 6))
-        self.meta_trim_hits.set(snap["meta_trim_hits"])
-        self.meta_trim_fallbacks.set(snap["meta_trim_fallbacks"])
+        for path, n in snap["meta_read_fanouts"].items():
+            self.meta_read_fanouts.set(n, path=path)
         self.meta_lane_dispatches.set(snap["meta_lane_dispatches"])
         self.meta_inline_ops.set(snap["meta_inline_ops"])
         # Aligned-buffer pool: scrape-only, never forces the shared

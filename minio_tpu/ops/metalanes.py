@@ -1,27 +1,27 @@
-"""Per-drive metadata lanes: group-commit writes + coalesced reads.
+"""Per-drive metadata write lanes: group-commit xl.meta publishes.
 
 The shard plane batches (ops/coalesce.py), but until PR 19 the METADATA
 plane did not: a 4 KiB inline PUT paid one fsynced ``write_metadata``
-per drive through a per-request fan-out, and every HEAD/GET metadata
-miss paid an all-N ``read_version`` fan-out — N threads x M requests of
-tiny, unbatchable drive calls.  This module applies the DispatchLane
-discipline to that traffic (ROADMAP open item 2; the reference's
-format-v2 small-object war, cmd/xl-storage-format-v2.go:25):
+per drive through a per-request fan-out.  This module applies the
+DispatchLane discipline to that traffic (ROADMAP open item 2; the
+reference's format-v2 small-object war, cmd/xl-storage-format-v2.go:25).
+It serves writes only: a lane amortises an fsync, and a read has none.
+The xl.meta READ fan-out had lanes too until PR 30; on the chip a wait
+on one cost 5.9 ms for 0.05 ms of page-cache read, so a request now
+reads its own xl.meta (erasure_set._read_metadata).
 
-- one ``MetaLane`` per (drive, kind) owns a FIFO queue and a lazy
-  daemon dispatcher.  Write lanes drain concurrent ``_put_inline``
-  publishes landing on the same drive into ONE
-  ``drive.write_metadata_many`` call — every xl.meta blob in the batch
-  shares a single journal fsync before any caller is acked
-  (group commit; durability ordering unchanged: ack strictly after
-  fsync).  Read lanes drain distinct keys' metadata reads into one
-  ``drive.read_version_many`` round per drive.
+- one ``MetaLane`` per drive owns a FIFO queue and a lazy daemon
+  dispatcher.  It drains concurrent ``_put_inline`` publishes landing
+  on the same drive into ONE ``drive.write_metadata_many`` call — every
+  xl.meta blob in the batch shares a single journal fsync before any
+  caller is acked (group commit; durability ordering unchanged: ack
+  strictly after fsync).
 - the same adaptive-window EMA + inline-degradation discipline as the
   shard coalescer: an idle lane executes the item on the caller's
-  thread through the EXACT single-op drive path (``write_metadata`` /
-  ``read_version``), so a lone request keeps oracle latency and oracle
-  bytes; packing only engages once the engine's in-flight counters (or
-  a busy lane) prove concurrency.
+  thread through the EXACT single-op drive path (``write_metadata``),
+  so a lone request keeps oracle latency and oracle bytes; packing
+  only engages once the engine's in-flight counter (or a busy lane)
+  proves concurrency.
 - fault containment: a failed batch retries its members solo, so one
   poisoned item cannot fail or block an unrelated acked caller; a dead
   dispatcher fails queued handles and degrades every later submit to
@@ -29,17 +29,14 @@ format-v2 small-object war, cmd/xl-storage-format-v2.go:25):
 
 Env (read per call so tests flip them without re-importing):
 
-- MTPU_METABATCH=0 disables the whole plane — the byte-identical
-  oracle (single-op fan-outs, one fsync per xl.meta publish);
+- MTPU_METABATCH=0 disables the plane — the byte-identical oracle
+  (single-op fan-out, one fsync per xl.meta publish);
 - MTPU_METABATCH_WINDOW_US: max time the oldest queued item waits for
   company once the window engages (default 250);
 - MTPU_METABATCH_DEPTH: max items per batched drive call (default 64);
 - MTPU_METABATCH_SOLO=1 forces even a lone PUT through the journaled
   batch path (batch of one) — the kill-9 matrix uses this to land the
-  ``meta.{stage,fsync,publish}`` crash points deterministically;
-- MTPU_META_TRIM gates the engine-side K+1 read fan-out trim (see
-  erasure_set._read_version_fanout) — it rides this module's flags so
-  MTPU_METABATCH=0 restores the full all-N oracle.
+  ``meta.{stage,fsync,publish}`` crash points deterministically.
 """
 
 from __future__ import annotations
@@ -55,10 +52,6 @@ from ..observe.metrics import DATA_PATH
 
 def enabled() -> bool:
     return os.environ.get("MTPU_METABATCH", "1") != "0"
-
-
-def trim_enabled() -> bool:
-    return enabled() and os.environ.get("MTPU_META_TRIM", "1") != "0"
 
 
 def solo_forced() -> bool:
@@ -113,14 +106,13 @@ class MetaHandle:
 
 
 class MetaLane:
-    """One drive's scheduler for one op kind ("write" or "read").
+    """One drive's scheduler for its xl.meta publishes.
 
     `solo_fn(item)` is the exact oracle single-op path; `batch_fn`
-    (feature-detected `write_metadata_many` / `read_version_many`, or
-    None for drives without one) takes a list of items and returns one
-    `(result, exc)` pair per item.  Without a batch op the lane still
-    packs items into one dispatcher round of solo calls — no fsync
-    amortization, but the N-threads-x-M-requests fan-out collapses.
+    (feature-detected `write_metadata_many`, or None for drives
+    without one) takes a list of items and returns one `(result, exc)`
+    pair per item.  Without a batch op the lane still packs items into
+    one dispatcher round of solo calls — no fsync amortization.
     """
 
     #: queued-item cap as a multiple of the batch depth — beyond this,
@@ -324,39 +316,27 @@ class MetaLane:
 
 
 class MetaBatcher:
-    """Facade owning one write lane + one read lane per drive, plus
-    the request-level concurrency counters that ignite packing (the
-    note_read role of the shard coalescer: queue depth alone cannot
-    prove concurrency when every idle submit runs inline)."""
+    """Facade owning one write lane per drive, plus the request-level
+    concurrency counter that ignites packing (the note_read role of
+    the shard coalescer: queue depth alone cannot prove concurrency
+    when every idle submit runs inline)."""
 
     def __init__(self):
         self._mu = threading.Lock()
-        # (id(drive), kind) -> (drive ref, lane).  The drive ref keeps
-        # the id stable for the lane's lifetime.
-        self._lanes: dict[tuple, tuple] = {}
+        # id(drive) -> (drive ref, lane).  The drive ref keeps the id
+        # stable for the lane's lifetime.
+        self._lanes: dict[int, tuple] = {}
         self._closed = False
         self._inflight_puts = 0
-        self._inflight_reads = 0
 
     # -- lane plumbing -------------------------------------------------------
 
-    def _lane(self, drive, kind: str, solo_fn, batch_fn) -> MetaLane:
-        key = (id(drive), kind)
+    def write_lane(self, drive) -> MetaLane:
+        key = id(drive)
         got = self._lanes.get(key)
         if got is not None:
             return got[1]
-        with self._mu:
-            got = self._lanes.get(key)
-            if got is None:
-                name = f"{getattr(drive, 'endpoint', '?')}-{kind}"
-                lane = MetaLane(os.path.basename(str(name)) or name,
-                                solo_fn, batch_fn)
-                if self._closed:
-                    lane._stopped = True
-                got = self._lanes[key] = (drive, lane)
-        return got[1]
 
-    def write_lane(self, drive) -> MetaLane:
         def solo(item):
             vol, obj, fi = item
             drive.write_metadata(vol, obj, fi)
@@ -366,44 +346,27 @@ class MetaBatcher:
         def batch(items):
             return [(None, e) for e in wmm(items)]
 
-        return self._lane(drive, "write", solo,
-                          batch if wmm is not None else None)
-
-    def read_lane(self, drive) -> MetaLane:
-        def solo(item):
-            vol, obj, vid = item
-            fi = drive.read_version(vol, obj, vid)
-            DATA_PATH.record_meta_read_round(1, 1)
-            return fi
-
-        rvm = getattr(drive, "read_version_many", None)
-
-        def batch(items):
-            out = rvm(items)
-            DATA_PATH.record_meta_read_round(1, len(items))
-            return out
-
-        return self._lane(drive, "read", solo,
-                          batch if rvm is not None else None)
+        with self._mu:
+            got = self._lanes.get(key)
+            if got is None:
+                name = f"{getattr(drive, 'endpoint', '?')}-write"
+                lane = MetaLane(os.path.basename(str(name)) or name,
+                                solo, batch if wmm is not None else None)
+                if self._closed:
+                    lane._stopped = True
+                got = self._lanes[key] = (drive, lane)
+        return got[1]
 
     # -- submission ----------------------------------------------------------
 
     def submit_write(self, drive, vol: str, obj: str, fi) -> MetaHandle:
         return self.write_lane(drive).submit((vol, obj, fi))
 
-    def submit_read(self, drive, vol: str, obj: str,
-                    version_id: str) -> MetaHandle:
-        return self.read_lane(drive).submit((vol, obj, version_id))
-
-    # -- ignition signals ----------------------------------------------------
+    # -- ignition signal -----------------------------------------------------
 
     def note_put(self, delta: int) -> None:
         with self._mu:
             self._inflight_puts += delta
-
-    def note_read(self, delta: int) -> None:
-        with self._mu:
-            self._inflight_reads += delta
 
     def put_hot(self) -> bool:
         """Whether routing a small-PUT publish fan-out through the
@@ -411,14 +374,7 @@ class MetaBatcher:
         request with a scheduler handoff)."""
         return (self._inflight_puts > 1
                 or any(lane.busy()
-                       for (_, kind), (_, lane) in list(self._lanes.items())
-                       if kind == "write"))
-
-    def read_hot(self) -> bool:
-        return (self._inflight_reads > 1
-                or any(lane.busy()
-                       for (_, kind), (_, lane) in list(self._lanes.items())
-                       if kind == "read"))
+                       for _, lane in list(self._lanes.values())))
 
     # -- lifecycle / introspection ------------------------------------------
 

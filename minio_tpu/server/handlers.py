@@ -786,15 +786,6 @@ class S3Handlers:
                      "Content-Type": "application/octet-stream",
                      "Accept-Ranges": "none"}
                 return Response(200, b"" if head else data, h)
-        # Request-level ignition note for the metadata lanes: the
-        # in-flight counter is what lets concurrent HEAD/GET metadata
-        # fan-outs on distinct keys coalesce into per-drive
-        # read_version_many rounds (a lone request stays on the exact
-        # single-op oracle path).
-        from ..ops import metalanes
-        _mb = metalanes.get() if metalanes.enabled() else None
-        if _mb is not None:
-            _mb.note_read(1)
         try:
             fi = self.pools.head_object(bucket, key, version_id)
         except ErrObjectNotFound as e:
@@ -805,9 +796,6 @@ class S3Handlers:
             return resp
         except StorageError as e:
             raise from_storage_error(e) from None
-        finally:
-            if _mb is not None:
-                _mb.note_read(-1)
         cond = self._check_conditions(headers, fi)
         if cond is not None:
             return cond

@@ -69,6 +69,10 @@ def _ensure_parent(p: str) -> None:
         os.makedirs(d, exist_ok=True)
 
 
+#: read_all's read size; an xl.meta with its inline shard stays under it.
+_READ_ALL_CHUNK = 256 << 10
+
+
 class LocalDrive:
     """One local drive rooted at `root`."""
 
@@ -206,8 +210,20 @@ class LocalDrive:
     def _read_all_impl(self, vol: str, path: str) -> bytes:
         p = self._file_path(vol, path)
         try:
-            with open(p, "rb") as f:
-                return f.read()
+            # os.open, os.read until empty, os.close: four system
+            # calls for a file under the chunk, where open().read()
+            # makes about twice as many (fstat, isatty's ioctl, lseek,
+            # a second fstat).  Each is a place where a request thread
+            # gives the GIL away and queues for it again: ~0.7 ms each
+            # with 8 clients on the chip host (PERF.md §6, PR 30).
+            fd = os.open(p, os.O_RDONLY)
+            try:
+                chunks = []
+                while buf := os.read(fd, _READ_ALL_CHUNK):
+                    chunks.append(buf)
+            finally:
+                os.close(fd)
+            return b"".join(chunks)     # one chunk: that object itself
         except FileNotFoundError:
             raise ErrFileNotFound(f"{vol}/{path}") from None
         except IsADirectoryError:
@@ -679,21 +695,6 @@ class LocalDrive:
                 except OSError:
                     pass
         return replayed
-
-    def read_version_many(self, items: list) -> list:
-        """Batched ReadVersion: one drive call resolves a list of
-        ``(vol, obj, version_id)`` lookups, returning one
-        ``(FileInfo | None, exception | None)`` pair per item.  The
-        read itself stays per-key (xl.meta files are independent); the
-        win is engine-side — M concurrent requests share ONE dispatch
-        into this drive instead of M pool fan-outs."""
-        out = []
-        for vol, obj, vid in items:
-            try:
-                out.append((self.read_version(vol, obj, vid), None))
-            except Exception as e:  # noqa: BLE001 — per-item verdict
-                out.append((None, e))
-        return out
 
     def update_metadata(self, vol: str, obj: str, fi: FileInfo) -> None:
         with self._meta_lock:

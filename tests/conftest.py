@@ -89,9 +89,9 @@ def coalesce_mode(request, monkeypatch):
 @pytest.fixture(params=["1", "0"], ids=["metabatch", "metasolo"])
 def metabatch_mode(request, monkeypatch):
     """Oracle guard for the batched metadata plane: tests using this
-    fixture run once through the per-drive MetaLanes
-    (MTPU_METABATCH=1, the default — group-commit publishes, coalesced
-    read fan-outs, K+1 trim) and once on the single-op oracle (=0).
+    fixture run once through the per-drive write MetaLanes
+    (MTPU_METABATCH=1, the default — group-commit publishes) and once
+    on the single-op oracle (=0).
     The singleton is retired on both edges so each run starts from
     cold lanes."""
     from minio_tpu.ops import metalanes

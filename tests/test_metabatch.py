@@ -1,9 +1,9 @@
-"""Small-object metadata plane (PR 19): group-commit publishes,
-coalesced read fan-outs, K+1 trim, journal replay, and the FileInfo
-cache LRU — each proven against the MTPU_METABATCH=0 single-op oracle.
+"""Small-object metadata plane (PR 19): group-commit publishes, journal
+replay and the FileInfo cache LRU, each proven against the
+MTPU_METABATCH=0 single-op oracle; and the xl.meta read fan-out
+(PR 30): on the request's own thread where every drive is in-process.
 """
 
-import contextlib
 import os
 import threading
 import zlib
@@ -12,12 +12,17 @@ import numpy as np
 import pytest
 
 from minio_tpu.engine.erasure_set import ErasureSet
+from minio_tpu.observe import span as ospan
 from minio_tpu.observe.metrics import DATA_PATH
 from minio_tpu.ops import metalanes
 from minio_tpu.storage.drive import (META_JOURNAL_DIR, SYS_VOL,
                                      LocalDrive)
-from minio_tpu.storage.errors import (ErrObjectNotFound,
+from minio_tpu.storage.errors import (ErrDiskNotFound,
+                                      ErrErasureReadQuorum,
+                                      ErrObjectNotFound,
+                                      ErrVersionNotFound,
                                       ErrVolumeNotFound)
+from minio_tpu.storage.health_wrap import HealthWrappedDrive
 from minio_tpu.storage.xlmeta import FileInfo
 from minio_tpu.utils import msgpackx
 
@@ -39,7 +44,7 @@ def fi_for(vol, obj, data, vid="", mod=1):
 
 
 # ---------------------------------------------------------------------------
-# drive layer: write_metadata_many / read_version_many / journal replay
+# drive layer: write_metadata_many / journal replay
 # ---------------------------------------------------------------------------
 
 class TestDriveGroupCommit:
@@ -134,16 +139,6 @@ class TestDriveGroupCommit:
         with pytest.raises(Exception):
             d.read_version("v", "lost")
 
-    def test_read_version_many_mixed(self, tmp_path):
-        d = LocalDrive(str(tmp_path / "d"))
-        d.make_volume("v")
-        d.write_metadata("v", "have", fi_for("v", "have", b"yes"))
-        out = d.read_version_many([("v", "have", ""),
-                                   ("v", "missing", "")])
-        assert out[0][1] is None
-        assert out[0][0].inline_data == b"yes"
-        assert out[1][0] is None and out[1][1] is not None
-
 
 # ---------------------------------------------------------------------------
 # lane scheduler: fault containment, degradation, solo forcing
@@ -218,7 +213,7 @@ class TestMetaLane:
 
 
 # ---------------------------------------------------------------------------
-# engine: oracle byte-identity, trim differential, LRU cache
+# engine: oracle byte-identity, read fan-out, LRU cache
 # ---------------------------------------------------------------------------
 
 class TestEngineOracleIdentity:
@@ -302,90 +297,247 @@ class TestEngineOracleIdentity:
             metalanes.reset()
 
 
-class TestReadTrim:
+class RemoteStub:
+    """A drive that is not a LocalDrive of this process (what an RPC
+    client is to the engine): delegates every call to one, and notes
+    the thread each read_version ran on."""
+
+    def __init__(self, root):
+        self._local = LocalDrive(root)
+        self.read_threads = []
+
+    def __getattr__(self, name):
+        return getattr(self._local, name)
+
+    def read_version(self, vol, obj, version_id="", read_data=False):
+        self.read_threads.append(threading.current_thread().name)
+        return self._local.read_version(vol, obj, version_id, read_data)
+
+
+class DeadDrive(LocalDrive):
+    """An in-process drive whose xl.meta reads fail (a pulled disk)."""
+
+    def read_version(self, vol, obj, version_id="", read_data=False):
+        raise ErrDiskNotFound(self.root)
+
+
+def _tripped(drive):
+    """`drive` behind a breaker whose circuit is open."""
+    wd = HealthWrappedDrive(drive)
+    object.__setattr__(wd, "_state", "offline")
+    object.__setattr__(wd, "_last_fault", "test")
+    return wd
+
+
+def _read_lane_threads():
+    return [t.name for t in threading.enumerate()
+            if t.name.startswith("mtpu-metalane-")
+            and t.name.endswith("-read")]
+
+
+def _fanouts(snap0, snap1):
+    return {p: snap1["meta_read_fanouts"][p] - snap0["meta_read_fanouts"][p]
+            for p in ("inline", "pool")}
+
+
+SMALL = payload(4096, 5)                  # inline in xl.meta
+BIG = payload(3 * (1 << 20), 6)           # streaming: shard files
+
+
+class TestReadFanout:
+    """PR 30: a request reads its own xl.meta.  All drives in-process
+    -> all N on the calling thread ("inline"); any remote drive -> the
+    pool fan-out ("pool").  No lane, no second round, no K+1 trim."""
+
     def _prime(self, tmp_path, **kw):
         es = make_set(tmp_path, **kw)
         es.make_bucket("b")
-        self.small = payload(4096, 5)
-        self.big = payload(3 * (1 << 20), 6)
-        es.put_object("b", "small", self.small)
-        es.put_object("b", "big", self.big)
+        es.put_object("b", "small", SMALL)
+        es.put_object("b", "big", BIG)
         return es
 
-    @contextlib.contextmanager
-    def _hot_reads(self):
-        """Simulate concurrent readers in flight: trim only engages on
-        a hot read plane (an idle server takes the untaxed full
-        fan-out), so the trim tests pin inflight > 1 for the call."""
-        mb = metalanes.get()
-        mb.note_read(2)
-        try:
-            yield mb
-        finally:
-            mb.note_read(-2)
-
-    def test_differential_vs_all_n_oracle(self, tmp_path, monkeypatch):
-        """Same election, same bytes, same errors with the trim on and
-        off — and the trimmed read must touch fewer drives for inline
-        objects."""
+    @pytest.mark.parametrize("readers", [1, 8])
+    def test_local_set_reads_inline(self, tmp_path, monkeypatch,
+                                    readers):
+        """All-local set: every drive read on the caller's own thread,
+        all N metas back, and no read lane thread ever started — under
+        8 concurrent readers too (the case that lit the lanes)."""
+        monkeypatch.setattr(ErasureSet, "_SERIAL_FANOUT", False)
         es = self._prime(tmp_path)
-        for flag in ("1", "0"):
-            monkeypatch.setenv("MTPU_META_TRIM", flag)
-            es._fi_cache.clear()
-            with self._hot_reads():
-                fi, metas, errs = es._read_metadata("b", "small")
-            assert es.get_object("b", "small")[1] == self.small
-            if flag == "1":
-                # K+1 of N read; the rest padded (None, None).
-                assert sum(1 for m in metas if m is not None) == \
-                    es.n - es.default_parity + 1
-                assert all(e is None for e in errs)
-            else:
-                assert all(m is not None for m in metas)
-            with pytest.raises(ErrObjectNotFound):
-                es._read_metadata("b", "missing")
+        seen: dict[str, set] = {}
+        real = LocalDrive.read_version
 
-    def test_idle_plane_takes_full_fanout(self, tmp_path, monkeypatch):
-        """No concurrent readers -> no trim: the idle path must be the
-        exact oracle fan-out (all N metas) even with the flag on, so
-        an unloaded server pays zero acceptance-check tax."""
-        es = self._prime(tmp_path)
-        monkeypatch.setenv("MTPU_META_TRIM", "1")
-        es._fi_cache.clear()
-        fi, metas, errs = es._read_metadata("b", "small")
-        assert all(m is not None for m in metas)
-        assert es.get_object("b", "small")[1] == self.small
+        def spy(self, vol, obj, version_id="", read_data=False):
+            seen.setdefault(threading.current_thread().name,
+                            set()).add(self.root)
+            return real(self, vol, obj, version_id, read_data)
 
-    def test_streaming_object_gets_full_metas(self, tmp_path,
-                                              monkeypatch):
-        """A non-inline object must always see all N metas — the
-        healthy-read fast path keys off `any(m is None)` — so the trim
-        widens to the remaining drives and merges."""
-        es = self._prime(tmp_path)
-        monkeypatch.setenv("MTPU_META_TRIM", "1")
-        es._fi_cache.clear()
+        monkeypatch.setattr(LocalDrive, "read_version", spy)
         snap0 = DATA_PATH.snapshot()
-        with self._hot_reads():
-            fi, metas, errs = es._read_metadata("b", "big")
-        assert all(m is not None for m in metas)
-        assert es.get_object("b", "big")[1] == self.big
-        snap1 = DATA_PATH.snapshot()
-        assert (snap1["meta_trim_fallbacks"]
-                > snap0["meta_trim_fallbacks"])
+        out, errors = [], []
 
-    def test_trim_fallback_on_drive_failure(self, tmp_path,
-                                            monkeypatch):
-        """An error inside the trimmed round falls back to all-N and
-        classifies exactly like the oracle (one dead drive at n=4,
-        parity=2 still reads fine)."""
+        def reader(i):
+            try:
+                for j in range(20):
+                    out.append(es._read_metadata(
+                        "b", ("small", "big")[(i + j) % 2]))
+            except Exception as e:  # noqa: BLE001
+                errors.append(e)
+
+        ts = [threading.Thread(target=reader, args=(i,),
+                               name=f"reader-{i}")
+              for i in range(readers)]
+        for t in ts:
+            t.start()
+        for t in ts:
+            t.join()
+        assert not errors
+        assert len(out) == 20 * readers
+        for fi, metas, errs in out:
+            assert all(m is not None for m in metas)
+            assert errs == [None] * es.n
+        # Each reader thread itself touched all N drives; nobody else.
+        assert set(seen) == {f"reader-{i}" for i in range(readers)}
+        assert all(len(roots) == es.n for roots in seen.values())
+        assert _read_lane_threads() == []
+        assert _fanouts(snap0, DATA_PATH.snapshot()) == {
+            "inline": 20 * readers, "pool": 0}
+
+    def test_remote_drive_takes_pool(self, tmp_path, monkeypatch):
+        """One drive that is no LocalDrive: the whole fan-out goes to
+        the drive pool, where round trips overlap; same election."""
+        monkeypatch.setattr(ErasureSet, "_SERIAL_FANOUT", False)
         es = self._prime(tmp_path)
-        monkeypatch.setenv("MTPU_META_TRIM", "1")
-        es.drives[0] = None
-        es._fi_cache.clear()
-        with self._hot_reads():
-            fi, metas, errs = es._read_metadata("b", "small")
-        assert fi is not None
-        assert es.get_object("b", "small")[1] == self.small
+        want = es._read_metadata("b", "big")
+        stub = RemoteStub(es.drives[2].root)
+        es.drives[2] = stub
+        snap0 = DATA_PATH.snapshot()
+        fi, metas, errs = es._read_metadata("b", "big")
+        assert _fanouts(snap0, DATA_PATH.snapshot()) == {
+            "inline": 0, "pool": 1}
+        assert stub.read_threads
+        assert threading.current_thread().name not in stub.read_threads
+        assert fi == want[0] and metas == want[1]
+        assert errs == [None] * es.n
+        assert es.get_object("b", "big")[1] == BIG
+        assert _read_lane_threads() == []
+
+    @pytest.mark.parametrize("key", ["small", "big"])
+    def test_inline_and_streaming_get_all_n_metas(self, tmp_path, key):
+        """No trim: an inline object's read returns all N metas just as
+        a streaming one's does (the healthy-read fast path keys off
+        `any(m is None)`), in one pass over the drives."""
+        es = self._prime(tmp_path)
+        calls = []
+        for pos, d in enumerate(es.drives):
+            real = d.read_version
+            d.read_version = (lambda *a, _r=real, _p=pos, **k:
+                              (calls.append(_p), _r(*a, **k))[1])
+        fi, metas, errs = es._read_metadata("b", key)
+        assert calls == list(range(es.n))      # once each, in order
+        assert all(m is not None for m in metas)
+        assert (fi.inline_data is not None) == (key == "small")
+        assert es.get_object("b", key)[1] == (SMALL if key == "small"
+                                              else BIG)
+
+    @pytest.mark.parametrize("fault", ["none", "failing", "tripped"])
+    @pytest.mark.parametrize("lost", [1, 3])
+    @pytest.mark.parametrize("key", ["small", "big", "missing",
+                                     "small@badversion"])
+    def test_classified_as_map_drives_oracle(self, tmp_path,
+                                             monkeypatch, fault, lost,
+                                             key):
+        """A hole, a failing drive and a breaker-tripped drive give the
+        inline pass exactly what the pool fan-out (`_map_drives`, the
+        MTPU_METABATCH=0 oracle before PR 30) gives: same fi, same
+        metas, same error types position by position, and the same
+        exception for a missing object, a missing version and a lost
+        read quorum."""
+        monkeypatch.setattr(ErasureSet, "_SERIAL_FANOUT", False)
+        es = self._prime(tmp_path)
+        for pos in range(lost):
+            d = es.drives[pos]
+            es.drives[pos] = {"none": lambda d: None,
+                              "failing": lambda d: DeadDrive(d.root),
+                              "tripped": _tripped}[fault](d)
+        obj, _, vid = key.partition("@")
+        vid = vid and "0" * 8 + "-0000-4000-8000-" + "0" * 12
+
+        def run():
+            try:
+                return es._read_metadata("b", obj, vid)
+            except Exception as e:  # noqa: BLE001 — the verdict compared
+                return e
+
+        snap0 = DATA_PATH.snapshot()
+        got = run()
+        assert _fanouts(snap0, DATA_PATH.snapshot())["inline"] == 1
+        monkeypatch.setattr(ErasureSet, "_in_process",
+                            lambda self, drives=None: False)
+        snap0 = DATA_PATH.snapshot()
+        want = run()
+        assert _fanouts(snap0, DATA_PATH.snapshot())["pool"] == 1
+        named = {("missing", 1): ErrObjectNotFound,
+                 ("small@badversion", 1): ErrVersionNotFound,
+                 ("small", 3): ErrErasureReadQuorum,
+                 ("big", 3): ErrErasureReadQuorum}.get((key, lost))
+        if isinstance(want, Exception):
+            assert type(got) is type(want)
+            assert str(got) == str(want)
+            assert named is None or type(got) is named
+        else:
+            assert named is None and lost == 1
+            assert got[0] == want[0]
+            assert got[1] == want[1]
+            assert [type(e) for e in got[2]] == \
+                [type(e) for e in want[2]]
+            assert got[1][0] is None and got[2][0] is not None
+
+    def test_fanout_counter_adds_up_to_requests(self, tmp_path,
+                                                monkeypatch):
+        """mtpu_meta_read_fanouts_total{path} sums to
+        mtpu_meta_read_requests_total over local and remote sets, GET,
+        HEAD, DELETE and misses alike, and the engine.quorum span
+        carries the same `path` as a tag."""
+        monkeypatch.setattr(ErasureSet, "_SERIAL_FANOUT", False)
+        local = self._prime(tmp_path, name="local")
+        remote = self._prime(tmp_path, name="remote")
+        remote.drives[0] = RemoteStub(remote.drives[0].root)
+        snap0 = DATA_PATH.snapshot()
+        ospan.TRACER.configure(ring=8, sample=1.0)
+        try:
+            for es, api in ((local, "api.Local"), (remote, "api.Remote")):
+                with ospan.TRACER.root(api):
+                    es.head_object("b", "small")
+                    es.get_object("b", "big")
+                    with pytest.raises(ErrObjectNotFound):
+                        es.head_object("b", "missing")
+                    es.delete_object("b", "small")
+            recs = {r["name"]: r for r in ospan.TRACER.traces()}
+        finally:
+            ospan.TRACER.configure(ring=0, sample=1.0)
+            ospan.TRACER.reset()
+        snap1 = DATA_PATH.snapshot()
+        d = _fanouts(snap0, snap1)
+        assert d["inline"] > 0 and d["inline"] == d["pool"]
+        assert d["inline"] + d["pool"] == (snap1["meta_read_requests"]
+                                           - snap0["meta_read_requests"])
+
+        def quorum_paths(rec):
+            out = [rec.get("tags", {}).get("path")] \
+                if rec["name"] == "engine.quorum" else []
+            for c in rec.get("spans", []):
+                out += quorum_paths(c)
+            return out
+
+        assert set(quorum_paths(recs["api.Local"])) == {"inline"}
+        assert set(quorum_paths(recs["api.Remote"])) == {"pool"}
+        assert len(quorum_paths(recs["api.Local"])) == d["inline"]
+        from minio_tpu.observe.metrics import MetricsRegistry
+        text = MetricsRegistry().render()
+        assert 'mtpu_meta_read_fanouts_total{path="inline"}' in text
+        assert 'mtpu_meta_read_fanouts_total{path="pool"}' in text
+        assert "mtpu_meta_trim" not in text
 
 
 class TestSmallobjBenchSmoke:
@@ -400,7 +552,7 @@ class TestSmallobjBenchSmoke:
                                   warmup_s=0.2)
         for k in ("put_ops_per_s", "put_p50_ms", "fsyncs_per_object",
                   "batch_occupancy", "head_ops_per_s",
-                  "get_fanouts_per_request", "idle_put_p50_ms",
+                  "head_p50_ms", "idle_put_p50_ms",
                   "idle_get_p50_ms"):
             assert k in leg
         assert leg["put_ops_per_s"] > 0

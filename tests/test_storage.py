@@ -197,6 +197,37 @@ class TestLocalDrive:
         with pytest.raises(ErrFileNotFound):
             drive.read_all("b", "cfg/missing")
 
+    @pytest.mark.parametrize("extra", [-1, 0, 1])
+    @pytest.mark.parametrize("chunks", [0, 1, 3])
+    def test_read_all_returns_every_byte(self, drive, monkeypatch,
+                                         chunks, extra):
+        """read_all reads with os.read in chunks until the end: files
+        empty, under, exactly at and over a chunk boundary come back
+        whole, equal to what open().read() gives."""
+        from minio_tpu.storage import drive as drive_mod
+        monkeypatch.setattr(drive_mod, "_READ_ALL_CHUNK", 4096)
+        size = max(0, chunks * 4096 + extra)
+        body = np.random.default_rng(size).integers(
+            0, 256, size, dtype=np.uint8).tobytes()
+        drive.make_volume("b")
+        drive.write_all("b", "cfg/blob", body)
+        with open(os.path.join(drive.root, "b", "cfg", "blob"), "rb") as f:
+            assert drive.read_all("b", "cfg/blob") == f.read() == body
+
+    def test_read_all_errors_and_no_fd_leak(self, drive):
+        """Same errors as before: a directory and a missing file read
+        as ErrFileNotFound; no descriptor stays open either way."""
+        drive.make_volume("b")
+        drive.write_all("b", "dir/x", b"d")
+        fds = len(os.listdir("/proc/self/fd"))
+        for _ in range(8):
+            with pytest.raises(ErrFileNotFound):
+                drive.read_all("b", "dir")
+            with pytest.raises(ErrFileNotFound):
+                drive.read_all("b", "dir/missing")
+            assert drive.read_all("b", "dir/x") == b"d"
+        assert len(os.listdir("/proc/self/fd")) == fds
+
     def test_rename_data_publish_and_read_version(self, drive):
         drive.make_volume("b")
         # Stage shard file in tmp, then publish.

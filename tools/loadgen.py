@@ -489,8 +489,7 @@ def run_load(es, *, clients: int = 4, object_size: int = 1 << 20,
         # Small-object rows (ISSUE 19): the mix is metadata-bound, so
         # ops/s (not GB/s) is the headline, and the server-side meta_*
         # deltas show what the group-commit plane amortized — fsyncs
-        # per published object, journal batch occupancy, and metadata
-        # read fan-outs per GET/HEAD request.
+        # per published object and journal batch occupancy.
         out["small_lo"] = small[0]
         out["small_hi"] = small[1]
         out["ops_per_s"] = round(len(alls) / wall, 1) if wall else 0.0
@@ -503,17 +502,10 @@ def run_load(es, *, clients: int = 4, object_size: int = 1 << 20,
         d_gc = (snap1["meta_group_commits"]
                 - snap0["meta_group_commits"])
         d_gi = snap1["meta_group_items"] - snap0["meta_group_items"]
-        d_rq = (snap1["meta_read_requests"]
-                - snap0["meta_read_requests"])
-        d_rr = snap1["meta_read_rounds"] - snap0["meta_read_rounds"]
         out["meta_fsyncs_per_object"] = (round(d_fs / d_pub, 4)
                                          if d_pub else 0.0)
         out["meta_batch_occupancy"] = (round(d_gi / d_gc, 3)
                                        if d_gc else 0.0)
-        out["meta_read_fanouts_per_request"] = (round(d_rr / d_rq, 4)
-                                                if d_rq else 0.0)
-        out["meta_trim_hits"] = (snap1["meta_trim_hits"]
-                                 - snap0["meta_trim_hits"])
     if zipf:
         out["zipf_s"] = zipf
         out.update(hot_cold_rows(
