@@ -1551,11 +1551,12 @@ def devcache_bench(batches_per_lane: int = 3) -> dict:
     from tools.loadgen import make_set
 
     out = {"dc_visible_devices": len(jax.devices())}
-    saved_use = es_mod._USE_DEVICE
+    from minio_tpu.engine import shardmath
+    saved_use = shardmath.platform
     saved = {k: os.environ.get(k)
              for k in ("MTPU_DEVICES", "MTPU_DEVCACHE",
                        "MTPU_H2D_PIPELINE")}
-    es_mod._USE_DEVICE = True
+    shardmath.platform = lambda: (True, False)
     os.environ["MTPU_DEVICES"] = "8"
     os.environ["MTPU_DEVCACHE"] = "1"
 
@@ -1637,7 +1638,7 @@ def devcache_bench(batches_per_lane: int = 3) -> dict:
             os.environ["MTPU_H2D_PIPELINE"] = flag
             reset_planes()
             co = coalesce.get()
-            kerns = {d: es._enc_kernel(2, 1, "mxh256", True, device=d)
+            kerns = {d: es.math.enc_kernel(2, 1, "mxh256", True, device=d)
                      for d in range(ndev)}
             # Pin every lane hot so submits take the queued (device)
             # path, then absorb this flag's per-device jit compile with
@@ -1693,7 +1694,7 @@ def devcache_bench(batches_per_lane: int = 3) -> dict:
         out["dc_overlap_frac"] = round(overlap_s / host_s, 3) \
             if host_s else 0.0
     finally:
-        es_mod._USE_DEVICE = saved_use
+        shardmath.platform = saved_use
         for k, v in saved.items():
             if v is None:
                 os.environ.pop(k, None)

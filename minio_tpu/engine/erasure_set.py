@@ -4,15 +4,15 @@ The erasureObjects equivalent (/root/reference/cmd/erasure-object.go:748) with
 the streaming encode/decode drivers (/root/reference/cmd/erasure-encode.go:36,
 cmd/erasure-decode.go:101) redesigned TPU-first:
 
-- data is staged in batches of 1 MiB blocks and erasure-coded as ONE batched
-  device dispatch per batch — (B, K, S) uint8 through the bit-plane MXU
-  matmul — instead of the reference's per-block synchronous SIMD calls
-  (SURVEY.md §5: blocks are the natural batch dimension);
+- data is staged in batches of 1 MiB blocks and erasure-coded one batch
+  ((B, K, S) uint8) a dispatch instead of the reference's per-block
+  synchronous SIMD calls (SURVEY.md §5); which plane computes a batch,
+  coalesced or direct, is `self.math`'s to say (engine/shardmath.py);
 - shard fan-out to drives runs on a thread pool with write-quorum reduce
   (the parallelWriter analogue);
 - reads fetch exactly K shards, verify bitrot frames, trigger spare reads
   on failure (the parallelReader analogue), and reconstruct missing rows
-  with the same device matmul;
+  through the same seam;
 - small objects (<= 128 KiB) inline their framed shards into xl.meta and
   bypass the device (SURVEY.md §7 hard-part #2).
 """
@@ -25,7 +25,6 @@ import queue as _queuemod
 import threading
 import time
 import uuid
-import weakref
 from concurrent.futures import ThreadPoolExecutor
 
 import numpy as np
@@ -33,12 +32,9 @@ import numpy as np
 from ..cluster.dynamic_timeout import DynamicTimeout
 from ..observe import span as ospan
 from ..observe.metrics import DATA_PATH
-from ..ops import coalesce, fused, metalanes
 from ..ops import devcache as devcache_mod
-from ..ops import devices as devices_mod
+from ..ops import metalanes
 from ..ops import zerocopy as zc
-from ..ops.erasure_cpu import ReedSolomonCPU
-from ..ops.erasure_jax import ReedSolomonTPU
 from ..parallel import pipeline as pl
 from ..storage import bitrot_io
 from ..storage.drive import (SMALL_FILE_THRESHOLD, SYS_VOL, TMP_DIR,
@@ -56,101 +52,10 @@ from ..storage.xlmeta import (ErasureInfo, FileInfo, ObjectPartInfo, XLMeta,
 from ..utils import streams
 from ..utils.crashpoints import crash_point
 from . import quorum as Q
-
-BLOCK_SIZE = 1 << 20          # blockSizeV2, cmd/object-api-common.go:40
-BATCH_BLOCKS = 32             # 1 MiB blocks per device dispatch (32 MiB data)
-
-# Test override of ErasureSet._use_device (None = ask ops/devices, the
-# one place that knows the platform).
-_USE_DEVICE: bool | None = None
-
-# Whether the native host codec built + loaded (None = untried).
-_NATIVE_OK: bool | None = None
-
-# Fused host erasure-IO kernel (native/ecio.cc): encode+hash+frame /
-# verify+gather+reconstruct in one C pass (None = untried, False = n/a).
-_ECIO = None
-
-
-def _ecio_mod():
-    global _ECIO
-    if _ECIO is None:
-        from native import ecio_native
-        from native._build import BuildError
-        try:
-            ecio_native.load()
-            _ECIO = ecio_native
-        except BuildError:  # no toolchain: numpy paths serve
-            _ECIO = False
-    return _ECIO or None
-
-# Process-wide mesh for multi-device codec placement (built lazily).
-_MESH = None
-
-# Per-thread pair of alternating fused-encode output buffers for the
-# double-buffered pipeline (same page-fault economics as ecio_native's
-# single _arena_buf: a fresh 2x ~50 MB allocation per multipart part
-# would cost more in faults than the overlap saves).  One pipelined
-# encode per thread at a time, and StagePipeline joins its in-flight
-# write before returning, so reuse across calls is safe.
-_DB_ARENAS = threading.local()
-
-
-def _db_arenas(nbytes: int) -> list:
-    pair = getattr(_DB_ARENAS, "pair", None)
-    if pair is None or pair[0].size < nbytes:
-        pair = [np.empty(nbytes, dtype=np.uint8) for _ in range(2)]
-        _DB_ARENAS.pair = pair
-    return pair
-
-
-# The erasure sets this process serves (weak: a set that is dropped
-# stops counting), for the mesh rule below.
-_LOCAL_SETS: "weakref.WeakSet[ErasureSet]" = weakref.WeakSet()
-_LOCAL_SETS_MU = threading.Lock()
-
-
-def _chips_with_a_set() -> int:
-    """How many of the process's lanes own an erasure set it serves
-    (`device_idx` is set index % lanes, so four sets own four lanes)."""
-    with _LOCAL_SETS_MU:
-        live = list(_LOCAL_SETS)
-    return len({es.device_idx for es in live})
-
-
-def mesh_rule(local_tpu: bool, chips: int, sets: int,
-              forced: str = "") -> bool:
-    """Whether codec work is spread over a multi-device mesh, from what
-    the process can observe: the platform, the chips it holds and the
-    chips that already own an erasure set it serves (`sets`).
-
-    Where every chip owns a set, a set's encode, decode and digests
-    ride its own lane: the fused, coalesced, laddered, named, pre-built
-    dispatch a one-chip host runs (ops/coalesce.py), four of them side
-    by side.  The mesh (parallel/sharded.py) is for the other case, one
-    set's shard math spread over chips that would otherwise sit by:
-    chips outnumber the sets that own one.  A pool worker holds no chip
-    (its work rides the owner's lanes), and a host backend has no mesh
-    worth its collectives.  `forced` is MTPU_MESH: "1"/"0" override
-    (tests use 1 to exercise the SPMD path on the virtual CPU mesh)."""
-    if forced == "1":
-        return True
-    if forced == "0":
-        return False
-    return local_tpu and chips > 1 and sets < chips
-
-
-def _mesh_mode() -> bool:
-    """`mesh_rule` of this process, now (the WithAutoGoroutines role,
-    cmd/erasure-coding.go:63: scaling without configuration).  Read per
-    call: tests flip MTPU_MESH and MTPU_DEVICES at runtime."""
-    forced = os.environ.get("MTPU_MESH", "")
-    if forced in ("0", "1") or not devices_mod.local_tpu():
-        # Decided without counting (forced: without asking JAX either).
-        return mesh_rule(False, 0, 0, forced)
-    return mesh_rule(True, devices_mod.visible_count(),
-                     _chips_with_a_set(), forced)
-
+from .shardmath import BATCH_BLOCKS, BLOCK_SIZE, ShardMath
+# ops/ipc_dispatch.py (the pool owner's "pf" kernel) imports the fused host
+# kernel's loader from here under this name (ROADMAP Design 1: ops asking up).
+from .shardmath import ecio_mod as _ecio_mod  # noqa: F401
 
 def _get_fastpath() -> bool:
     """Healthy-read verify-only fast path gate (MTPU_GET_FASTPATH).
@@ -162,10 +67,6 @@ def _get_fastpath() -> bool:
     oracle the equivalence tests diff against (read per call so tests
     can flip it without re-importing)."""
     return os.environ.get("MTPU_GET_FASTPATH", "1") != "0"
-
-
-def _etag(data: bytes) -> str:
-    return hashlib.md5(data).hexdigest()
 
 
 #: Drive-pool thread tag (see ErasureSet.__init__): lets fan-out helpers
@@ -193,7 +94,7 @@ def _hedge_fixed_ms() -> float | None:
         return None
 
 
-_POOL_LOCAL = __import__("threading").local()
+_POOL_LOCAL = threading.local()
 
 
 def _tag_pool_thread(tag: str) -> None:
@@ -218,8 +119,8 @@ class ErasureSet:
         self.default_parity = (self.n // 2 if default_parity is None
                                else default_parity)
         self.set_index = set_index
-        with _LOCAL_SETS_MU:
-            _LOCAL_SETS.add(self)
+        # The set's shard math, and where it runs (engine/shardmath.py).
+        self.math = ShardMath(set_index)
         # Pool-nesting invariant: work running ON self.pool must never
         # block on another self.pool future.  Two mechanisms enforce it:
         # (1) layered executors — prefetch tasks (get_object_iter
@@ -235,9 +136,6 @@ class ErasureSet:
                                        initializer=_tag_pool_thread,
                                        initargs=(self._pool_tag,))
         self._iter_pool = ThreadPoolExecutor(max_workers=8)
-        self._codec_cache: dict[tuple[int, int], ReedSolomonTPU] = {}
-        self._cpu_cache: dict[tuple[int, int], ReedSolomonCPU] = {}
-        self._native_cache: dict[tuple[int, int], object] = {}
         # Namespace locks guard object mutations (cf. NSLock use at
         # cmd/erasure-object.go:930). Standalone default: in-process RW
         # locks; a distributed deployment injects an NSLockMap over the
@@ -306,115 +204,8 @@ class ErasureSet:
     @property
     def device_idx(self) -> int:
         """The coalescer-lane device this set's kernel traffic rides
-        (PR 10): `set_index % n_devices` — the same deterministic index
-        as the set's sipHashMod placement, one layer down, so affinity
-        is stable across boots and identical in every process.
-        Resolved per call: tests flip MTPU_DEVICES at runtime."""
-        return devices_mod.device_for_set(self.set_index)
-
-    @property
-    def _use_device(self) -> bool:
-        """Device codec on a real TPU; native AVX codec otherwise.
-
-        Off-TPU (tests, FS-like hosts, device loss) the XLA-CPU
-        bit-plane path would be the bottleneck; the native nibble-table
-        codec (ops/erasure_native.py) is the same code the reference's
-        assembly computes.  The platform is decided once per process,
-        by ops/devices (a pool worker holds the device owner's answer).
-        """
-        if _USE_DEVICE is not None:
-            return _USE_DEVICE
-        return devices_mod.on_tpu()
-
-    def _native(self, k: int, m: int):
-        """Host codec: the native AVX kernel, or the portable XLA path
-        on a host with no toolchain to build it.  A kernel that built
-        and does not load is an error, not a reason to degrade."""
-        global _NATIVE_OK
-        key = (k, m)
-        if key in self._native_cache:
-            return self._native_cache[key]
-        if _NATIVE_OK is None:
-            from native import rs_comparator
-            from native._build import BuildError
-            try:
-                rs_comparator.load()
-                _NATIVE_OK = True
-            except BuildError:  # no toolchain
-                _NATIVE_OK = False
-        if _NATIVE_OK:
-            from ..ops.erasure_native import ReedSolomonNative
-            codec = ReedSolomonNative(k, m)
-        else:
-            codec = self._codec(k, m)
-        self._native_cache[key] = codec
-        return codec
-
-    def _sharded(self, k: int, m: int):
-        """Mesh codec (parallel/sharded.py) cached per geometry over the
-        process-wide device mesh."""
-        global _MESH
-        key = ("sharded", k, m)
-        if key not in self._native_cache:
-            from ..parallel.sharded import ShardedCodec, make_mesh
-            if _MESH is None:
-                _MESH = make_mesh()
-            self._native_cache[key] = ShardedCodec(k, m, _MESH)
-        return self._native_cache[key]
-
-    def _mesh_encode(self, k: int, m: int, blocks) -> np.ndarray | None:
-        """Mesh-placed encode, or None when the geometry doesn't tile
-        (caller falls back to the single-device path)."""
-        sc = self._sharded(k, m)
-        baxis = sc.mesh.shape["blocks"]
-        lanes = sc.mesh.shape["lanes"]
-        blocks = np.asarray(blocks)
-        nb, kk, s = blocks.shape
-        if s % lanes:
-            return None
-        pad = (-nb) % baxis
-        if pad:
-            blocks = np.concatenate(
-                [blocks, np.zeros((pad, kk, s), np.uint8)])
-        return np.asarray(sc.encode_blocks(blocks))[:nb]
-
-    def _mesh_transform(self, k: int, m: int, x, sources,
-                        targets) -> np.ndarray | None:
-        sc = self._sharded(k, m)
-        baxis = sc.mesh.shape["blocks"]
-        lanes = sc.mesh.shape["lanes"]
-        x = np.asarray(x)
-        nb, rows, s = x.shape
-        if rows % lanes:
-            return None                     # drive rows don't tile
-        pad = (-nb) % baxis
-        if pad:
-            x = np.concatenate([x, np.zeros((pad, rows, s), np.uint8)])
-        out = np.asarray(sc.reconstruct_blocks(x, tuple(sources),
-                                               tuple(targets)))
-        return out[:nb]
-
-    def _transform(self, k: int, m: int, x, sources, targets) -> np.ndarray:
-        """Backend-picking transform: (B, K, S) -> (B, T, S) numpy."""
-        if _mesh_mode():
-            out = self._mesh_transform(k, m, x, sources, targets)
-            if out is not None:
-                return out
-        if self._use_device:
-            return np.asarray(self._codec(k, m).transform_blocks(
-                x, tuple(sources), tuple(targets)))
-        return np.asarray(self._native(k, m).transform_blocks(
-            np.asarray(x), tuple(sources), tuple(targets)))
-
-    def _codec(self, k: int, m: int) -> ReedSolomonTPU:
-        if (k, m) not in self._codec_cache:
-            self._codec_cache[k, m] = ReedSolomonTPU(k, m)
-        return self._codec_cache[k, m]
-
-    def _cpu(self, k: int, m: int) -> ReedSolomonCPU:
-        if (k, m) not in self._cpu_cache:
-            self._cpu_cache[k, m] = ReedSolomonCPU(k, m)
-        return self._cpu_cache[k, m]
+        (`ShardMath.device_idx`: `set_index % n_devices`, per call)."""
+        return self.math.device_idx
 
     # -- drive fan-out helpers ----------------------------------------------
 
@@ -1079,90 +870,12 @@ class ErasureSet:
                                         BATCH_BLOCKS * BLOCK_SIZE)
         yield from self._encode_chunks(chunks, k, m, algo)
 
-    # -- coalesced-dispatch kernels (ops/coalesce.py) ------------------------
-    #
-    # Each factory returns an fn(stacked, spans, ctx) closure computing
-    # one coalesced batch; the coalescer key carries every parameter the
-    # closure captures, so items from different requests (and different
-    # ErasureSet instances of the same geometry — the kernels are pure
-    # functions of (k, m, algo, S)) stack along the block axis.
-
-    def _pf_kernel(self, k: int, m: int, shard_size: int):
-        """Fused host encode (ecio put_frame): parity + digests + frame
-        layout in one C pass over the stacked blocks.  Output goes into
-        a pooled per-dispatch buffer (fresh mmap-sized allocations per
-        dispatch would pay ~0.5 ms/MiB in page faults — the reason the
-        direct path uses a per-thread arena, which a cross-request
-        result cannot safely alias); shard i's frames are contiguous,
-        so item j's framed views are plain slices."""
-        fused_host = _ecio_mod()
-        frame_len = bitrot_io.digest_size("mxh256") + shard_size
-
-        def kernel(stacked, spans, ctx):
-            nb = stacked.shape[0]
-            per = nb * frame_len
-            buf = ctx.rent((k + m) * per)
-            outs = [buf[i * per:(i + 1) * per] for i in range(k + m)]
-            fused_host.put_frame(stacked, k, m, outs=outs)
-            return [[o[lo * frame_len:hi * frame_len] for o in outs]
-                    for lo, hi in spans]
-
-        return kernel
-
-    def _enc_kernel(self, k: int, m: int, algo: str, fused_dev: bool,
-                    device: int | None = None):
-        """Device/native encode over the stacked blocks (ops/coalesce
-        .make_encode_kernel): a device batch is sized by the ladder of
-        BATCH_BLOCKS, so its shape follows the blocks it carries.
-        Returns (parity, digests) per span — the same pair the direct
-        dispatch produces, so the framing path downstream is shared.
-        `device` is the lane the batch is placed on (the submitting
-        set's affinity)."""
-        codec = None
-        if not fused_dev:
-            codec = (self._codec(k, m) if self._use_device
-                     else self._native(k, m))
-        return coalesce.make_encode_kernel(
-            k, m, algo, BATCH_BLOCKS, device, codec,
-            on_device=fused_dev or self._use_device)
-
-    def _direct_encode(self, blocks, k: int, m: int, algo: str):
-        """The no-coalescer encode for one (nb, K, S) batch — the same
-        (parity, digests) pair `_enc_kernel` produces.  Used as the
-        per-request fallback when a coalesced handle fails (poisoned
-        batch neighbor / dead dispatcher)."""
-        fused_dev = (algo in fused.DEVICE_ALGOS and self._use_device
-                     and bitrot_io.device_preferred(algo))
-        if fused_dev:
-            return fused.encode_and_hash(blocks, k, m, algo=algo,
-                                         device=self.device_idx)
-        if self._use_device:
-            return self._codec(k, m).encode_blocks(
-                devices_mod.put(blocks, self.device_idx)), None
-        return self._native(k, m).encode_blocks(blocks), None
-
-    def _vt_kernel(self, k: int, m: int, sources: tuple, targets: tuple,
-                   algo: str, device: int | None = None):
-        """Fused device verify(+reconstruct) over stacked (B, K, S)
-        gathers (ops/coalesce.make_verify_kernel).  `device` places the
-        dispatch on the submitting set's affine lane."""
-        return coalesce.make_verify_kernel(k, m, sources, targets, algo,
-                                           BATCH_BLOCKS, device)
-
     def build_ladder(self, parity: int | None = None) -> None:
         """Ask for the shape ladder of the device programs this set's
-        PUTs and GETs run at `parity` (None: the set's default): the
-        fused encode and the GET digest, for the write algorithm on
-        the set's lane.  Built off the calling
-        thread (ops/coalesce.build_ladder); nothing to do where the
-        shard math runs on the host."""
-        if not self._use_device:
-            return
+        PUTs and GETs run at `parity` (None: the set's default), see
+        `ShardMath.build_ladder`."""
         m = self.clamp_parity(parity)
-        k = self.n - m
-        coalesce.build_geometry_ladder(
-            k, m, -(-BLOCK_SIZE // k), bitrot_io.write_algo(),
-            BATCH_BLOCKS, self.device_idx)
+        self.math.build_ladder(self.n - m, m)
 
     def _encode_chunks(self, chunks, k: int, m: int,
                        algo: str | None = None,
@@ -1171,95 +884,22 @@ class ErasureSet:
         multiple of BLOCK_SIZE except the final one — yielding lists of
         n framed shard-chunks.  Memory is O(chunk), never O(object).
 
-        Full 1 MiB blocks are encoded as one batched device dispatch
-        ((B, K, S) uint8); the partial tail block goes through the CPU
-        oracle codec (tiny, not worth a dispatch).
+        Full 1 MiB blocks are encoded as one batch ((B, K, S) uint8) on
+        the plane the set's shard math runs on, through a one-deep
+        `pending` pipeline (`shardmath.Encoder`); the partial tail
+        block goes through the CPU oracle codec (tiny, not worth a
+        dispatch).
 
         ``double_buffer=True`` makes every yielded batch safe to consume
-        asynchronously while the NEXT batch encodes: the fused host
-        kernel normally writes into one reused per-thread arena (valid
-        only until the next put_frame on that thread), so a pipelined
-        caller that overlaps shard writes of batch *i* with the encode
-        of batch *i+1* must get alternating buffers.  The device/mesh/
-        numpy paths allocate fresh frames per batch and need no copy.
+        asynchronously while the NEXT batch encodes (a pipelined caller
+        that overlaps shard writes of batch *i* with the encode of batch
+        *i+1*).
         """
         if algo is None:
             algo = bitrot_io.write_algo()
         shard_size = -(-BLOCK_SIZE // k)
-        # Host fast path: ONE native pass per batch does parity + bitrot
-        # digests + frame layout (native/ecio.cc) — no device, so there
-        # is no dispatch to pipeline behind. Width-gated: the C kernels
-        # hold at most 64 row pointers on the stack.
-        fused_host = None
-        if (not self._use_device and algo == "mxh256"
-                and not _mesh_mode() and k + m <= 64):
-            fused_host = _ecio_mod()
-
-        def frame(blocks, parity, digests):
-            # np.asarray here is the device sync point; by the time we
-            # take it, the NEXT batch's dispatch is already in flight.
-            # frame_shard_views fills the framed layout in one pass and
-            # returns zero-copy per-shard views (the previous concat +
-            # transpose + tobytes chain copied the batch three times).
-            if digests is not None:
-                digests = np.asarray(digests)
-            parity = np.asarray(parity)
-            with ospan.span("engine.frame"):
-                return bitrot_io.frame_shard_views(
-                    blocks, parity, digests, algo)
-
-        # Cross-request coalescing (MTPU_COALESCE, ops/coalesce.py):
-        # instead of dispatching this request's batch directly, submit
-        # it to the shared coalescer — concurrent requests' compatible
-        # batches stack into ONE kernel launch and each request gets
-        # its slice back through a future.  The future slots into the
-        # same one-deep `pending` pipeline the direct device path uses,
-        # so in-request overlap is preserved while cross-request
-        # batching happens underneath.
-        co = coalesce.get() if coalesce.enabled() else None
-
-        # Double-buffered pipeline: dispatch batch i, then frame/yield
-        # batch i-1 while the device works — hides dispatch+transfer
-        # latency (the host↔device boundary, not yet measured on this
-        # machine) behind host framing
-        # and the caller's disk writes, the role of the reference's
-        # in-flight parallelWriter (cmd/erasure-encode.go:36).
+        enc = self.math.encoder(k, m, algo, double_buffer)
         pending = None
-        arenas = None       # two alternating fused-output buffers
-        flip = 0
-        # Retired coalesced put_frame handles: their results alias a
-        # POOLED dispatch buffer, and a pipelined consumer may still be
-        # writing batch i when batch i+1 is pulled — so a buffer is
-        # only recycled two yields after its batch was handed out.
-        retired: list = []
-
-        def flush(p):
-            # Coalesced handles can FAIL (a poisoned batch neighbor, a
-            # dead dispatcher): each tag recomputes its span through the
-            # direct reference path — this request's bytes, this
-            # request's kernels, nobody else's fault surface.
-            tag = p[0]
-            if tag == "pf":
-                try:
-                    framed = p[1].result()
-                except Exception:  # noqa: BLE001 — direct fallback
-                    DATA_PATH.record_co_fallback()
-                    return fused_host.put_frame(p[2], k, m)
-                retired.append(p[1])
-                if len(retired) > 2:
-                    retired.pop(0).release()
-                return framed
-            if tag == "co":
-                try:
-                    parity, digests = p[2].result()
-                    p[2].release()   # fresh arrays — nothing pooled
-                except Exception:  # noqa: BLE001 — direct fallback
-                    DATA_PATH.record_co_fallback()
-                    parity, digests = self._direct_encode(p[1], k, m, algo)
-                return frame(p[1], parity, digests)
-            return frame(p[1], p[2], p[3])
-
-        frame_len = bitrot_io.digest_size("mxh256") + shard_size
         for chunk, is_last in chunks:
             buf = np.frombuffer(chunk, dtype=np.uint8)
             n_full = buf.size // BLOCK_SIZE
@@ -1278,86 +918,21 @@ class ErasureSet:
                         blocks[:, :BLOCK_SIZE] = batch.reshape(
                             nb, BLOCK_SIZE)
                         blocks = blocks.reshape(nb, k, shard_size)
-                if fused_host is not None:
-                    DATA_PATH.record_encode_blocks("host", nb)
-                    if co is not None:
-                        h = co.submit(
-                            ("pf", k, m, shard_size), blocks,
-                            self._pf_kernel(k, m, shard_size), weight=nb,
-                            device=self.device_idx)
-                        if pending is not None:
-                            yield flush(pending)
-                        pending = ("pf", h, blocks)
-                    elif double_buffer:
-                        per = BATCH_BLOCKS * frame_len
-                        if arenas is None:
-                            arenas = _db_arenas((k + m) * per)
-                        a = arenas[flip]
-                        flip ^= 1
-                        outs = [a[i * per:i * per + nb * frame_len]
-                                for i in range(k + m)]
-                        yield fused_host.put_frame(blocks, k, m, outs=outs)
-                    else:
-                        yield fused_host.put_frame(blocks, k, m)
-                    continue
-                # Parity AND bitrot digests in ONE device dispatch
-                # (north-star config #5 PUT side, ops/fused.py); framing
-                # is then pure byte interleaving on the host.
-                parity = digests = None
-                if _mesh_mode():
-                    # Chips outnumber sets (mesh_rule): place the shard
-                    # matmul on the mesh (blocks x lanes SPMD); digests
-                    # hash on host.  Mesh placement stays direct — SPMD
-                    # shapes don't stack across requests.
-                    parity = self._mesh_encode(k, m, blocks)
-                DATA_PATH.record_encode_blocks(
-                    "mesh" if parity is not None
-                    else "lane" if self._use_device else "host", nb)
-                if parity is not None:
-                    if pending is not None:
-                        yield flush(pending)
-                    pending = ("arr", blocks, parity, None)
-                    continue
-                fused_dev = (algo in fused.DEVICE_ALGOS
-                             and self._use_device
-                             and bitrot_io.device_preferred(algo))
-                if co is not None:
-                    tag = ("fd" if fused_dev
-                           else "dev" if self._use_device else "nat")
-                    h = co.submit(
-                        ("enc", tag, k, m, algo, shard_size), blocks,
-                        self._enc_kernel(k, m, algo, fused_dev,
-                                         device=self.device_idx),
-                        weight=nb, device=self.device_idx)
-                    if pending is not None:
-                        yield flush(pending)
-                    pending = ("co", blocks, h)
-                    continue
-                if fused_dev:
-                    parity, digests = fused.encode_and_hash(
-                        blocks, k, m, algo=algo, device=self.device_idx)
-                elif self._use_device:
-                    # Host-hashed algorithms (sha256, or HighwayHash
-                    # with its faster native host kernel): device
-                    # encodes, the framing pass hashes.
-                    parity, digests = \
-                        self._codec(k, m).encode_blocks(blocks), None
-                else:
-                    # No TPU: native AVX codec; frame_shards_batch
-                    # hashes on the host.
-                    parity, digests = \
-                        self._native(k, m).encode_blocks(blocks), None
+                nxt = enc.encode(blocks)
                 if pending is not None:
-                    yield flush(pending)
-                pending = ("arr", blocks, parity, digests)
+                    yield enc.frames(pending)
+                pending = nxt
+                if not enc.overlaps:
+                    yield enc.frames(pending)
+                    pending = None
 
             tail = buf[n_full * BLOCK_SIZE:]
             if is_last:
                 if pending is not None:
-                    yield flush(pending)
+                    yield enc.frames(pending)
                     pending = None
                 if tail.size:
-                    cpu = self._cpu(k, m)
+                    cpu = self.math.cpu(k, m)
                     shards = cpu.encode_data(tail.tobytes())  # k+m arrays
                     tail_shard = shards[0].size
                     yield [bitrot_io.frame_shard(s, tail_shard, algo)
@@ -1573,14 +1148,10 @@ class ErasureSet:
                        length: int) -> list[tuple[int, int, int]]:
         """Map an object byte range onto batch-aligned per-part segments.
 
-        Segment size: one bounded device dispatch per segment on TPU; on
-        the host path, 16 MiB keeps the gather buffer under glibc's
-        mmap threshold so successive segments recycle the same pages
-        (a fresh 32 MiB allocation pays ~0.5 ms/MiB in page faults).
-        Each part is an independent EC stream (cf. ObjectToPartOffset,
+        Segment size: `ShardMath.segment_blocks`.  Each part is an
+        independent EC stream (cf. ObjectToPartOffset,
         cmd/erasure-metadata.go)."""
-        batch_bytes = (BATCH_BLOCKS if self._use_device
-                       else BATCH_BLOCKS // 2) * BLOCK_SIZE
+        batch_bytes = self.math.segment_blocks() * BLOCK_SIZE
         segs: list[tuple[int, int, int]] = []   # (part_number, off, len)
         part_start = 0
         remaining = length
@@ -1863,7 +1434,7 @@ class ErasureSet:
                     offs[s] += chunk
                 missing = [s for s in range(k) if block_rows[s] is None]
                 if missing:
-                    rec = self._cpu(k, m).reconstruct(block_rows,
+                    rec = self.math.cpu(k, m).reconstruct(block_rows,
                                                       data_only=True)
                     for s in missing:
                         block_rows[s] = rec[s]
@@ -2007,13 +1578,8 @@ class ErasureSet:
         has_tail, tail_shard = geo["has_tail"], geo["tail_shard"]
         # Host fast path: shard files mmap'd straight into the fused
         # native verify+gather+reconstruct kernel — object bytes are
-        # never copied by Python and never cross read() (north-star
-        # config #5, host edition). Width-gated like the PUT side.
-        fused_host = None
-        if (not self._use_device and algo == "mxh256"
-                and not _mesh_mode() and k + m <= 64):
-            fused_host = _ecio_mod()
-        co = coalesce.get() if coalesce.enabled() else None
+        # never copied by Python and never cross read().
+        fused_host = self.math.host_fused(k, m, algo)
         # Device-resident shard cache (ops/devcache.py): generation is
         # captured BEFORE any shard read so a racing write invalidates
         # the fill rather than the fill masking the write.  Only fully
@@ -2075,6 +1641,31 @@ class ErasureSet:
         degraded = any(s < k for s in range(k + m) if s not in candidates)
         t_deg = time.monotonic() if degraded else 0.0
         lo = offset - b0 * BLOCK_SIZE
+        full_bytes = nb * k * shard_size       # == nb * BLOCK_SIZE
+        aligned = dst is not None and lo == 0 and length >= full_bytes
+
+        def deliver(y, tail_np, placed=False):
+            """The read's range of the verified rows `y` and the tail
+            fragment: into `dst` where given (`placed`: `y` lies there
+            already), else returned."""
+            if aligned:
+                if nb and not placed:
+                    dst[:full_bytes] = memoryview(y.reshape(-1))
+                if tail_np is not None and length > full_bytes:
+                    dst[full_bytes:length] = memoryview(
+                        np.ascontiguousarray(
+                            tail_np[:length - full_bytes]))
+                return None
+            flat = y.reshape(-1) if nb else np.zeros(0, dtype=np.uint8)
+            data = (np.concatenate([flat, tail_np])
+                    if tail_np is not None else flat)
+            view = data[lo:lo + length]
+            if dst is not None:
+                dst[:length] = memoryview(np.ascontiguousarray(view))
+                return None
+            if view.size == data.size:
+                return memoryview(view)
+            return view.tobytes()
 
         def fast_path():
             """Verify-only healthy read.  Returns (res,) on success or
@@ -2119,23 +1710,11 @@ class ErasureSet:
                         first_err = first_err or e
                 if first_err is not None:
                     raise first_err
-            full_bytes = nb * k * shard_size       # == nb * BLOCK_SIZE
-            aligned = (dst is not None and lo == 0
-                       and length >= full_bytes)
             body = dst[:full_bytes] if aligned else None
             t_read = time.monotonic()
             asm_s = 0.0
             y = None
-            # Verify routing: under concurrent traffic (coalescer hot —
-            # work queued/dispatching, recent occupancy >1, or another
-            # read in flight) the bitrot digest rides the shared
-            # dispatcher so many GETs verify in one kernel launch; a
-            # lone stream keeps the direct fused path — no thread
-            # handoff on the single-client latency path.  Byte-exact
-            # either way (same digests, same comparisons).
-            use_co = (co is not None and nb > 0
-                      and (self._use_device
-                           or co.hot(self.device_idx)))
+            use_co = self.math.digest_rides(nb)
             if nb and fused_host is not None and not use_co:
                 # mxh256 host: ONE C pass verifies every frame AND
                 # gathers the systematic rows straight into the final
@@ -2165,34 +1744,8 @@ class ErasureSet:
                     y[:, s, :] = rows[s][1]
                 asm_s += time.monotonic() - tg
                 ospan.record("engine.assemble", asm_s)
-                if use_co:
-                    # Coalesced digest over the already-gathered rows
-                    # (the gather IS the assembly, so this adds no
-                    # copy): stacked with other requests' verify/encode
-                    # digest work into one batched hash kernel, sized
-                    # by the ladder of BATCH_BLOCKS * k rows.
-                    pad_rows = BATCH_BLOCKS * k if self._use_device else 0
-                    h = co.submit(
-                        ("digest", algo, shard_size, pad_rows),
-                        y.reshape(nb * k, shard_size),
-                        coalesce.make_digest_kernel(
-                            algo, pad_rows, device=self.device_idx),
-                        weight=nb, device=self.device_idx)
-                    try:
-                        digests = h.result().reshape(nb, k, hs)
-                        h.release()
-                    except Exception:  # noqa: BLE001 — direct fallback
-                        DATA_PATH.record_co_fallback()
-                        digests = bitrot_io._hash_batch(
-                            y.reshape(nb * k, shard_size),
-                            algo).reshape(nb, k, hs)
-                    got = [digests[:, s] for s in range(k)]
-                elif algo in fused.DEVICE_ALGOS and self._use_device \
-                        and bitrot_io.device_preferred(algo) \
-                        and not _mesh_mode():
-                    digests = np.asarray(fused.verify_and_transform(
-                        y, k, m, tuple(range(k)), (), algo=algo,
-                        device=self.device_idx)[0])
+                digests = self.math.digest(y, k, m, algo, use_co)
+                if digests is not None:
                     got = [digests[:, s] for s in range(k)]
                 else:
                     got = self._hash_shard_frames(
@@ -2210,25 +1763,7 @@ class ErasureSet:
             if has_tail:
                 tail_np = np.concatenate(
                     [rows[s][2] for s in range(k)])[:geo["tail_len"]]
-            if aligned:
-                if tail_np is not None and length > full_bytes:
-                    dst[full_bytes:length] = memoryview(
-                        np.ascontiguousarray(
-                            tail_np[:length - full_bytes]))
-                res = None
-            else:
-                flat = (y.reshape(-1) if nb
-                        else np.zeros(0, dtype=np.uint8))
-                data = (np.concatenate([flat, tail_np])
-                        if tail_np is not None else flat)
-                view = data[lo:lo + length]
-                if dst is not None:
-                    dst[:length] = memoryview(np.ascontiguousarray(view))
-                    res = None
-                elif view.size == data.size:
-                    res = memoryview(view)
-                else:
-                    res = view.tobytes()
+            res = deliver(y, tail_np, placed=True)
             done = time.monotonic()
             DATA_PATH.record_healthy_read(
                 length, read_s=t_read - t0, verify_s=t_verify - t_read,
@@ -2268,30 +1803,7 @@ class ErasureSet:
                 return None
             y = e.host[boff:boff + nb] if nb else None
             tail_np = e.tail[:geo["tail_len"]] if has_tail else None
-            full_bytes = nb * k * shard_size
-            aligned = (dst is not None and lo == 0
-                       and length >= full_bytes)
-            if aligned:
-                if nb:
-                    dst[:full_bytes] = memoryview(y.reshape(-1))
-                if tail_np is not None and length > full_bytes:
-                    dst[full_bytes:length] = memoryview(
-                        np.ascontiguousarray(
-                            tail_np[:length - full_bytes]))
-                res = None
-            else:
-                flat = (y.reshape(-1) if nb
-                        else np.zeros(0, dtype=np.uint8))
-                data = (np.concatenate([flat, tail_np])
-                        if tail_np is not None else flat)
-                view = data[lo:lo + length]
-                if dst is not None:
-                    dst[:length] = memoryview(np.ascontiguousarray(view))
-                    res = None
-                elif view.size == data.size:
-                    res = memoryview(view)
-                else:
-                    res = view.tobytes()
+            res = deliver(y, tail_np)
             done = time.monotonic()
             DATA_PATH.record_healthy_read(
                 length, read_s=0.0, verify_s=0.0, assemble_s=done - t0)
@@ -2313,18 +1825,13 @@ class ErasureSet:
                     got = devcache_hit(*found)
                     if got is not None:
                         return got[0]
-            # Inflight-read signal: a GET-only storm queues no encode
-            # work, so concurrency is only visible to hot() through
-            # this counter.
-            if co is not None:
-                co.note_read(1, device=self.device_idx)
+            self.math.note_read(1)
             try:
                 got = fast_path()
             except (StorageError, OSError):
                 got = None
             finally:
-                if co is not None:
-                    co.note_read(-1, device=self.device_idx)
+                self.math.note_read(-1)
             if got is not None:
                 return got[0]
             DATA_PATH.record_fastpath_fallback()
@@ -2410,63 +1917,11 @@ class ErasureSet:
             for i, s in enumerate(sel):
                 x[:, i, :] = rows[s][1]                      # (nb, K, S)
             with ospan.span("engine.verify_decode"):
-                if algo in fused.DEVICE_ALGOS and self._use_device \
-                        and bitrot_io.device_preferred(algo) \
-                        and not _mesh_mode():
-                    if co is not None:
-                        # Coalesced fused verify(+reconstruct): the
-                        # same (sel, missing) geometry from concurrent
-                        # degraded reads shares one device launch.
-                        h = co.submit(
-                            ("vt", k, m, tuple(sel), tuple(missing),
-                             algo, shard_size), x,
-                            self._vt_kernel(k, m, tuple(sel),
-                                            tuple(missing), algo,
-                                            device=self.device_idx),
-                            weight=nb, device=self.device_idx)
-                        try:
-                            digests, dev_out = h.result()
-                            h.release()
-                        except Exception:  # noqa: BLE001 — fallback
-                            DATA_PATH.record_co_fallback()
-                            digests, dev_out = fused.verify_and_transform(
-                                x, k, m, tuple(sel), tuple(missing),
-                                algo=algo, device=self.device_idx)
-                            digests = np.asarray(digests)
-                    else:
-                        digests, dev_out = fused.verify_and_transform(
-                            x, k, m, tuple(sel), tuple(missing),
-                            algo=algo, device=self.device_idx)
-                        digests = np.asarray(digests)
-                else:
-                    # Host path (host-hashed algorithm, no TPU, or an
-                    # algo whose native host kernel beats its device
-                    # verify — bitrot_io.device_preferred): digest on
-                    # host, reconstruct via the backend picker only if
-                    # rows are missing.
-                    flat = x.reshape(nb * k, shard_size)
-                    if co is not None and co.hot(self.device_idx):
-                        h = co.submit(
-                            ("digest", algo, shard_size, 0), flat,
-                            coalesce.make_digest_kernel(algo),
-                            weight=nb, device=self.device_idx)
-                        try:
-                            digests = h.result().reshape(nb, k, hs)
-                            h.release()
-                        except Exception:  # noqa: BLE001 — fallback
-                            DATA_PATH.record_co_fallback()
-                            digests = bitrot_io._hash_batch(
-                                flat, algo).reshape(nb, k, hs)
-                    else:
-                        digests = bitrot_io._hash_batch(
-                            flat, algo).reshape(nb, k, hs)
-                    dev_out = self._transform(
-                        k, m, x, tuple(sel), tuple(missing)) if missing \
-                        else None
+                digests, out = self.math.verify_transform(
+                    x, k, m, tuple(sel), tuple(missing), algo)
             bad = [sel[i] for i in range(k)
                    if not np.array_equal(digests[:, i], rows[sel[i]][0])]
             if not bad:
-                out = np.asarray(dev_out) if missing else None
                 break
             for s in bad:
                 del rows[s]
@@ -2498,7 +1953,8 @@ class ErasureSet:
             t_missing = [s for s in range(k) if s not in tails]
             if t_missing:
                 shards_in = [tails.get(s) for s in range(k + m)]
-                rec = self._cpu(k, m).reconstruct(shards_in, data_only=True)
+                rec = self.math.cpu(k, m).reconstruct(shards_in,
+                                                      data_only=True)
                 for s in t_missing:
                     tails[s] = rec[s]
 
@@ -2644,14 +2100,15 @@ class ErasureSet:
         if missing and nb_full:
             avail = [s for s in range(k + m) if full_mat[s] is not None][:k]
             x = np.stack([full_mat[s] for s in avail], axis=1)  # (B, K, S)
-            out = self._transform(k, m, x, tuple(avail), tuple(missing))
+            out = self.math.transform(k, m, x, tuple(avail),
+                                      tuple(missing))
             for j, s in enumerate(missing):
                 full_mat[s] = out[:, j, :]
         if has_tail:
             t_missing = [s for s in range(k) if tails[s] is None]
             if t_missing:
                 t_avail = [s for s in range(k + m) if tails[s] is not None]
-                cpu = self._cpu(k, m)
+                cpu = self.math.cpu(k, m)
                 shards_in = [tails[s] if s in t_avail else None
                              for s in range(k + m)]
                 rec = cpu.reconstruct(shards_in, data_only=True)

@@ -5,8 +5,9 @@ The reference's healing stack rebuilt on the batched device codec:
 - ``heal_object`` classifies every drive's copy of an object version
   (ok / offline / missing / outdated / corrupt), elects the latest
   quorum metadata, and reconstructs outdated drives with ONE batched
-  decode->re-encode device dispatch per part instead of the reference's
-  streaming per-block pipe (cf. healObject,
+  verify+transform per batch of a part (the set's `math`,
+  engine/shardmath.py) instead of the reference's streaming per-block
+  pipe (cf. healObject,
   /root/reference/cmd/erasure-healing.go:244, and Erasure.Heal,
   /root/reference/cmd/erasure-lowlevel-heal.go:31).
 - Dangling objects (provably unrecoverable) are purged
@@ -378,14 +379,10 @@ def _reconstruct_rows(es: ErasureSet, fi: FileInfo,
     # blocks AND tail in one go) transform with per-row pointers — no
     # batch stacking, no per-block loop (native ec_gf_rows, GFNI when
     # the CPU has it).
-    if not es._use_device and k + m <= 64:
-        from native import ecio_native
-        from native._build import BuildError
-        try:
-            return ecio_native.gf_transform_rows(
-                [rows[s] for s in use], list(use), k, m, list(need))
-        except BuildError:  # no toolchain: batch path
-            pass
+    host = es.math.host_fused(k, m)
+    if host is not None:
+        return host.gf_transform_rows(
+            [rows[s] for s in use], list(use), k, m, list(need))
     # Split logical shard into full-block matrix + tail.
     n_full = logical // shard_size
     tail_len = logical - n_full * shard_size
@@ -394,14 +391,14 @@ def _reconstruct_rows(es: ErasureSet, fi: FileInfo,
         x = np.stack([rows[s][:n_full * shard_size].reshape(n_full,
                                                             shard_size)
                       for s in use], axis=1)  # (B, K, S)
-        y = es._transform(k, m, x, tuple(use), tuple(need))  # (B, T, S)
+        y = es.math.transform(k, m, x, tuple(use), tuple(need))  # (B, T, S)
         for j in range(len(need)):
             out_rows[j][:n_full * shard_size] = y[:, j, :].reshape(-1)
     if tail_len:
         shards_in: list[np.ndarray | None] = [None] * (k + m)
         for s in avail:
             shards_in[s] = rows[s][n_full * shard_size:]
-        full = es._cpu(k, m).reconstruct(shards_in)
+        full = es.math.cpu(k, m).reconstruct(shards_in)
         for j, s in enumerate(need):
             out_rows[j][n_full * shard_size:] = full[s]
     return out_rows
@@ -526,9 +523,7 @@ def _heal_part_pipelined(es: ErasureSet, bucket: str, obj: str,
     i's decode and batch i-1's writes. A bitrot hit or read failure
     drops the source and promotes a spare for that batch onward, exactly
     like the GET path's spare-read policy."""
-    from ..ops import coalesce, fused
     from ..ops import devcache as devcache_mod
-    from .erasure_set import _ecio_mod, _mesh_mode
     ec = fi.erasure
     dist = ec.distribution
     k, m = ec.data_blocks, ec.parity_blocks
@@ -573,10 +568,7 @@ def _heal_part_pipelined(es: ErasureSet, bucket: str, obj: str,
     sel = good[:k]          # kept sorted; mutated on bitrot/read failure
     spares = good[k:]
 
-    fused_host = None
-    if not es._use_device and algo == "mxh256" and k + m <= 64 \
-            and not _mesh_mode():
-        fused_host = _ecio_mod()
+    fused_host = es.math.host_fused(k, m, algo)
     # Device-resident shard cache: a prior healthy GET's verified data
     # matrix can cover a heal batch — the rebuild then runs straight
     # off residency (host copy, or the already-placed device array):
@@ -636,19 +628,9 @@ def _heal_part_pipelined(es: ErasureSet, bucket: str, obj: str,
                 rebuilt = None
                 if need:
                     xd = e.dev
-                    if es._use_device and xd is not None \
-                            and algo in fused.DEVICE_ALGOS \
-                            and not _mesh_mode():
-                        # Already device-resident: dispatch against the
-                        # placed array — zero upload.
-                        _, reb_d = fused.verify_and_transform(
-                            xd[boff:boff + nb], k, m, tuple(range(k)),
-                            tuple(need), algo=algo,
-                            device=es.device_idx)
-                        rebuilt = np.asarray(reb_d)
-                    else:
-                        rebuilt = np.asarray(es._transform(
-                            k, m, y, tuple(range(k)), tuple(need)))
+                    rebuilt = es.math.transform(
+                        k, m, y, tuple(range(k)), tuple(need),
+                        None if xd is None else xd[boff:boff + nb], algo)
                 for j, s in enumerate(need):
                     out[s] = rebuilt[:, j, :]
                 stack = np.stack([out[s] for s in need])
@@ -695,7 +677,7 @@ def _heal_part_pipelined(es: ErasureSet, bucket: str, obj: str,
                 for s in need_data:
                     out[s] = y[:, s, :]
                 if need_parity:
-                    prows = np.asarray(es._native(k, m).transform_blocks(
+                    prows = np.asarray(es.math.native(k, m).transform_blocks(
                         y, tuple(range(k)), tuple(need_parity)))
                     for j, s in enumerate(need_parity):
                         out[s] = prows[:, j, :]
@@ -708,55 +690,10 @@ def _heal_part_pipelined(es: ErasureSet, bucket: str, obj: str,
             x = np.empty((nb, k, S), dtype=np.uint8)
             for i, s in enumerate(cur):
                 x[:, i, :] = bufs[s][:, hs:]
-            co = coalesce.get() if coalesce.enabled() else None
-            if es._use_device and algo in fused.DEVICE_ALGOS \
-                    and bitrot_io.device_preferred(algo) \
-                    and not _mesh_mode():
-                if co is not None:
-                    # Heal shares the verify_and_transform queue with
-                    # degraded GETs — concurrent heals of sibling parts
-                    # (same damage pattern) pack into one dispatch.
-                    h = co.submit(
-                        ("vt", k, m, tuple(cur), tuple(need), algo, S),
-                        x, es._vt_kernel(k, m, tuple(cur), tuple(need),
-                                         algo, device=es.device_idx),
-                        weight=nb, device=es.device_idx)
-                    try:
-                        digests, rebuilt = h.result()
-                        h.release()
-                    except Exception:  # noqa: BLE001 — direct fallback
-                        DATA_PATH.record_co_fallback()
-                        digests, rebuilt = fused.verify_and_transform(
-                            x, k, m, tuple(cur), tuple(need), algo=algo,
-                            device=es.device_idx)
-                        digests = np.asarray(digests)
-                        rebuilt = np.asarray(rebuilt) if need else None
-                    if not need:
-                        rebuilt = None
-                else:
-                    digests, rebuilt = fused.verify_and_transform(
-                        x, k, m, tuple(cur), tuple(need), algo=algo,
-                        device=es.device_idx)
-                    digests = np.asarray(digests)
-                    rebuilt = np.asarray(rebuilt) if need else None
-            else:
-                if co is not None and co.hot(es.device_idx):
-                    h = co.submit(("digest", algo, S, 0),
-                                  x.reshape(nb * k, S),
-                                  coalesce.make_digest_kernel(algo),
-                                  weight=nb, device=es.device_idx)
-                    try:
-                        digests = h.result().reshape(nb, k, hs)
-                        h.release()
-                    except Exception:  # noqa: BLE001 — direct fallback
-                        DATA_PATH.record_co_fallback()
-                        digests = bitrot_io._hash_batch(
-                            x.reshape(nb * k, S), algo).reshape(nb, k, hs)
-                else:
-                    digests = bitrot_io._hash_batch(
-                        x.reshape(nb * k, S), algo).reshape(nb, k, hs)
-                rebuilt = np.asarray(es._transform(
-                    k, m, x, tuple(cur), tuple(need))) if need else None
+            # Heal shares the verify_and_transform queue with degraded
+            # GETs (`ShardMath.verify_transform`).
+            digests, rebuilt = es.math.verify_transform(
+                x, k, m, tuple(cur), tuple(need), algo)
             bad = [cur[i] for i in range(k)
                    if not np.array_equal(digests[:, i],
                                          bufs[cur[i]][:, :hs])]
@@ -833,7 +770,7 @@ def _heal_part_pipelined(es: ErasureSet, bucket: str, obj: str,
         if got < k:
             raise quorum_err(got)
         if any(shards_in[s] is None for s in need):
-            full = es._cpu(k, m).reconstruct(shards_in)
+            full = es.math.cpu(k, m).reconstruct(shards_in)
             for s in need:
                 if shards_in[s] is None:
                     shards_in[s] = full[s]

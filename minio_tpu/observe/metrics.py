@@ -394,7 +394,7 @@ class DataPathStats:
 
     def record_encode_blocks(self, plane: str, blocks: int) -> None:
         """`blocks` full 1 MiB blocks of a PUT went to `plane` ("lane",
-        "mesh" or "host") for their parity (engine/_encode_chunks)."""
+        "mesh" or "host") for their parity (engine/shardmath.py)."""
         with self._mu:
             self.encode_blocks[plane] += blocks
 

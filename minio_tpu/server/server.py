@@ -2329,7 +2329,9 @@ class S3Server:
                 self.warming = not coalesce.ladder_idle()
             if self.draining or self.warming:
                 return Response(503, headers={"Retry-After": "1"})
-            return Response(200 if self.pools is not None else 503)
+            # `handlers` is bound last (bind_object_layer): S3 requests
+            # answer ServerNotInitialized until then, so ready waits too.
+            return Response(200 if self.handlers is not None else 503)
         if self.pools is None:
             return Response(503)
         if path == "/minio/health/cluster":

@@ -639,7 +639,7 @@ class TestShapeLadder:
             self, ladder, tmp_path, targets, want):
         es = make_set(tmp_path, name="vt")
         sources = (1, 2) if targets else (0, 1)
-        fn = es._vt_kernel(K, M, sources, targets, ALGO, device=0)
+        fn = es.math.vt_kernel(K, M, sources, targets, ALGO, device=0)
         assert fn.ladder == (not targets) and fn.program is not None
         _built_ladder(fn, (K, S))
         seen = _spy(fn)
@@ -660,7 +660,7 @@ class TestShapeLadder:
         enters the jit."""
         from minio_tpu.ops import fused
         sources = (1, 2) if targets else (0, 1)
-        fn = make_set(tmp_path, name="cold")._vt_kernel(
+        fn = make_set(tmp_path, name="cold").math.vt_kernel(
             K, M, sources, targets, ALGO, device=0)
         prog = fn.program()
         prog._built.clear()

@@ -18,7 +18,7 @@ import shutil
 import numpy as np
 import pytest
 
-import minio_tpu.engine.erasure_set as es_mod
+from minio_tpu.engine import shardmath
 from minio_tpu.engine import heal
 from minio_tpu.engine.erasure_set import BATCH_BLOCKS, BLOCK_SIZE, ErasureSet
 from minio_tpu.ops import coalesce, devcache
@@ -66,11 +66,9 @@ def forced_device(monkeypatch):
     enough that a parity shard wins, and a degraded read neither
     fills the cache nor crosses the boundary once."""
     monkeypatch.setenv("MTPU_HEDGE", "0")
-    old = es_mod._USE_DEVICE
     coalesce.reset()
-    es_mod._USE_DEVICE = True
+    monkeypatch.setattr(shardmath, "platform", lambda: (True, False))
     yield
-    es_mod._USE_DEVICE = old
     coalesce.reset()
 
 

@@ -549,10 +549,11 @@ class TestBootLadder:
     def test_each_set_asks_for_its_geometry_on_its_lane(
             self, tmp_path, ndev, monkeypatch, asked, use_device, parity,
             want):
-        from minio_tpu.engine import erasure_set as esmod
+        from minio_tpu.engine import shardmath
         from minio_tpu.engine.pools import ServerPools
         ndev(8)
-        monkeypatch.setattr(esmod, "_USE_DEVICE", use_device)
+        monkeypatch.setattr(shardmath, "platform",
+                            lambda: (use_device, False))
         pools = ServerPools([make_ring(tmp_path, nsets=3)])
         pools.build_ladders(parity)
         assert asked == [w + (lane,) for lane in range(3) for w in want]
@@ -561,11 +562,11 @@ class TestBootLadder:
                                                     asked, monkeypatch):
         """Boot and an admin `config set storage_class` both end in
         build_ladders(): the sets' default parity and the classes'."""
-        from minio_tpu.engine import erasure_set as esmod
+        from minio_tpu.engine import shardmath
         from minio_tpu.engine.pools import ServerPools
         from minio_tpu.server.server import S3Server
         from minio_tpu.server.sigv4 import Credentials
-        monkeypatch.setattr(esmod, "_USE_DEVICE", True)
+        monkeypatch.setattr(shardmath, "platform", lambda: (True, False))
         pools = ServerPools([make_ring(tmp_path, nsets=1, parity=2)])
         srv = S3Server(pools, Credentials("minioadmin", "minioadmin"),
                        port=0)
@@ -619,7 +620,7 @@ class TestBootLadder:
             fused, "encode_hash_program",
             lambda *a: pytest.fail("a worker reached for a program"))
         pools = ServerPools([make_ring(tmp_path, nsets=1, parity=2)])
-        assert pools.pools[0].sets[0]._use_device
+        assert pools.pools[0].sets[0].math.use_device
         pools.build_ladders()
         assert coalesce.ladder_idle() and not coalesce._BUILD_Q
 

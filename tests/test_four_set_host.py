@@ -7,10 +7,12 @@ Two references, both independent of the program: the placement is a plain
 SipHash-2-4(key) mod sets written here; the bytes on the drives are
 `benchmark/reference.py`'s (numpy Reed-Solomon, mxh256, frame layout).
 
-The rule that picks the plane for the shard math (`erasure_set.mesh_rule`)
-is a pure function and is tested as one: on the CPU backend the automatic
-choice stays off, so the served runs force each plane in turn (MTPU_MESH=1,
-MTPU_MESH=0, one device) and the shard files must be byte-identical.
+The rule that picks the plane for the shard math (`shardmath.mesh_rule`)
+is a pure function and is tested as one.  The served runs force each plane
+in turn (MTPU_MESH=1, MTPU_MESH=0, one device) and then leave the choice to
+the rule (`auto`: no MTPU_MESH, the seam's platform predicate answering "a
+TPU this process holds", four chips visible): the shard files must be
+byte-identical between all four.
 """
 
 from __future__ import annotations
@@ -23,7 +25,7 @@ import weakref
 import numpy as np
 import pytest
 
-from minio_tpu.engine import erasure_set as esmod
+from minio_tpu.engine import shardmath
 from minio_tpu.engine.pools import ServerPools
 from minio_tpu.engine.sets import ErasureSets
 from minio_tpu.observe.metrics import DATA_PATH
@@ -50,6 +52,7 @@ PLANES = {                              # plane -> (environment, lanes)
     "mesh": ({"MTPU_MESH": "1", "MTPU_DEVICES": "4"}, 4),
     "lane": ({"MTPU_MESH": "0", "MTPU_DEVICES": "4"}, 4),
     "one_lane": ({"MTPU_MESH": "0", "MTPU_DEVICES": "1"}, 1),
+    "auto": ({"MTPU_DEVICES": "4"}, 4),   # the rule decides: a set a lane
 }
 
 
@@ -120,7 +123,7 @@ def body_of(key: str) -> bytes:
     (False, 1, 0, "1", True),
 ])
 def test_mesh_rule(local_tpu, chips, sets, forced, want):
-    assert esmod.mesh_rule(local_tpu, chips, sets, forced) is want
+    assert shardmath.mesh_rule(local_tpu, chips, sets, forced) is want
 
 
 @pytest.mark.parametrize("nsets,env,want", [
@@ -130,9 +133,9 @@ def test_mesh_rule(local_tpu, chips, sets, forced, want):
 ])
 def test_mesh_mode_counts_chips_held_and_sets_served(
         tmp_path, monkeypatch, nsets, env, want):
-    """`_mesh_mode()` feeds the rule what this process observes: a TPU
+    """`mesh_mode()` feeds the rule what this process observes: a TPU
     host with four chips (stood in for here), and its live sets."""
-    monkeypatch.setattr(esmod, "_LOCAL_SETS", weakref.WeakSet())
+    monkeypatch.setattr(shardmath, "_LOCAL_SETS", weakref.WeakSet())
     monkeypatch.setattr(devices_mod, "_VISIBLE",
                         ([object()] * 4, "tpu", "TPU v5 lite", 4))
     monkeypatch.delenv("MTPU_MESH", raising=False)
@@ -143,7 +146,7 @@ def test_mesh_mode_counts_chips_held_and_sets_served(
         [LocalDrive(str(tmp_path / f"d{i}")) for i in range(nsets * 4)],
         set_drive_count=4, default_parity=2, deployment_id=DEP_ID)
     assert len(ring.sets) == nsets
-    assert esmod._mesh_mode() is want
+    assert shardmath.mesh_mode() is want
 
 
 # -- the served runs ---------------------------------------------------------------
@@ -177,7 +180,15 @@ def serve(root: str, plane: str) -> dict:
     references."""
     env, lanes = PLANES[plane]
     mp = pytest.MonkeyPatch()
-    mp.setattr(esmod, "_USE_DEVICE", True)        # device codec on the CPU
+    # The device codec on the CPU backend; `auto` also says the chips are
+    # this process's, four of them, and counts this run's sets only.
+    mp.setattr(shardmath, "platform", lambda: (True, plane == "auto"))
+    if plane == "auto":
+        import jax
+        mp.setattr(devices_mod, "_VISIBLE",
+                   (list(jax.devices())[:4], "cpu", "cpu", 4))
+        mp.setattr(shardmath, "_LOCAL_SETS", weakref.WeakSet())
+    mp.delenv("MTPU_MESH", raising=False)
     for name, value in env.items():
         mp.setenv(name, value)
     coalesce.reset()
@@ -188,7 +199,10 @@ def serve(root: str, plane: str) -> dict:
         deployment_id=DEP_ID)])
     srv = S3Server(pools, Credentials(ACCESS, SECRET)).start()
     out = {"plane": plane, "lanes": lanes, "files": {}, "got": {},
-           "stat": {}, "put_lanes": {}, "get_lanes": {}, "gone": {}}
+           "stat": {}, "put_lanes": {}, "get_lanes": {}, "gone": {},
+           # what the seam answers for each set once all four are built
+           "chose": [("mesh" if shardmath.mesh_mode() else "lane",
+                      es.math.device_idx) for es in pools.pools[0].sets]}
     try:
         cli = S3Client(srv.endpoint, ACCESS, SECRET)
         cli.make_bucket(BUCKET)
@@ -286,6 +300,7 @@ def test_set_dispatches_on_its_own_lane_and_no_other(run):
 
 def test_encode_blocks_counted_under_the_plane_that_served(run):
     plane = "mesh" if run["plane"] == "mesh" else "lane"
+    assert run["chose"] == [(plane, i % run["lanes"]) for i in range(SETS)]
     assert run["encode_blocks"] == {plane: BLOCKS * len(KEYS)}
     assert f'mtpu_encode_blocks_total{{plane="{plane}"}}' \
         in run["metrics_page"]
@@ -293,5 +308,5 @@ def test_encode_blocks_counted_under_the_plane_that_served(run):
 
 def test_shard_files_identical_across_planes(runs):
     want = runs["one_lane"]["files"]
-    for plane in ("mesh", "lane"):
+    for plane in ("mesh", "lane", "auto"):
         assert runs[plane]["files"] == want, plane

@@ -505,9 +505,9 @@ class TestSpanTimeline:
 @pytest.fixture()
 def device_path(monkeypatch):
     """The device codec on the CPU backend, through a cold coalescer."""
-    from minio_tpu.engine import erasure_set as esmod
+    from minio_tpu.engine import shardmath
     from minio_tpu.ops import coalesce
-    monkeypatch.setattr(esmod, "_USE_DEVICE", True)
+    monkeypatch.setattr(shardmath, "platform", lambda: (True, False))
     coalesce.reset()
     yield coalesce
     coalesce.reset()
