@@ -292,6 +292,12 @@ class DataPathStats:
             self.zerocopy_vectored_writes = 0
             self.zerocopy_vectored_write_bytes = 0
             self.zerocopy_fallbacks = 0
+            # Request-body pulls (utils/streams.SocketBodyReader): one
+            # fill of the caller's view from the connection, by how it
+            # ran (native: one ec_recv_exact call; buffered: rfile),
+            # and the recvs those pulls made.
+            self.body_pulls = {"native": 0, "buffered": 0}
+            self.body_pull_recvs = {"native": 0, "buffered": 0}
             # Small-object metadata plane (PR 19, ops/metalanes.py):
             # xl.meta publishes and the fsyncs paying for them (solo
             # write_metadata: 1 fsync per publish; group commit: 1
@@ -520,6 +526,13 @@ class DataPathStats:
         with self._mu:
             self.zerocopy_fallbacks += 1
 
+    def record_body_pull(self, path: str, recvs: int) -> None:
+        """One pull of a request body from its connection on `path`
+        ("native" or "buffered"), which made `recvs` recvs."""
+        with self._mu:
+            self.body_pulls[path] += 1
+            self.body_pull_recvs[path] += recvs
+
     def record_meta_publish(self) -> None:
         """One solo xl.meta publish (drive.write_metadata): one
         fsynced rename-into-place, one fsync."""
@@ -642,6 +655,8 @@ class DataPathStats:
                 "zerocopy_vectored_write_bytes":
                     self.zerocopy_vectored_write_bytes,
                 "zerocopy_fallbacks": self.zerocopy_fallbacks,
+                "body_pulls": dict(self.body_pulls),
+                "body_pull_recvs": dict(self.body_pull_recvs),
                 "meta_publishes": self.meta_publishes,
                 "meta_fsyncs": self.meta_fsyncs,
                 "meta_group_commits": self.meta_group_commits,
@@ -1035,6 +1050,17 @@ class MetricsRegistry:
         self.zerocopy_fallbacks = Gauge(
             "mtpu_zerocopy_fallbacks_total",
             "Eligible responses that fell back to the buffered writer")
+        self.body_pulls = Gauge(
+            "mtpu_body_pulls_total",
+            "Request-body pulls (one fill of the caller's view from "
+            "the connection) by how they ran: native (plain TCP + "
+            "Content-Length: one GIL-released poll+recv loop a pull) "
+            "or buffered (TLS, chunked transfer encoding, no native "
+            "library: through rfile)", ("path",))
+        self.body_pull_recvs = Gauge(
+            "mtpu_body_pull_recvs_total",
+            "recvs those pulls made; over mtpu_body_pulls_total: "
+            "recvs a pull", ("path",))
         # Small-object metadata plane (ops/metalanes.py; cf. the
         # reference's format-v2 inline discipline,
         # cmd/xl-storage-format-v2.go).  Synced from DATA_PATH.
@@ -1639,6 +1665,10 @@ class MetricsRegistry:
         self.zerocopy_vectored_write_bytes.set(
             snap["zerocopy_vectored_write_bytes"])
         self.zerocopy_fallbacks.set(snap["zerocopy_fallbacks"])
+        for path, n in snap["body_pulls"].items():
+            self.body_pulls.set(n, path=path)
+            self.body_pull_recvs.set(snap["body_pull_recvs"][path],
+                                     path=path)
         self.meta_publishes.set(snap["meta_publishes"])
         self.meta_fsyncs.set(snap["meta_fsyncs"])
         self.meta_fsyncs_per_object.set(

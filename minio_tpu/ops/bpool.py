@@ -18,7 +18,12 @@ never block on scratch.
 
 Knobs: MTPU_BPOOL=0 kills the pool (every get is a fallback
 allocation — the no-pooling oracle); MTPU_BPOOL_MB sizes the arena
-(default 32).  Stats feed the mtpu_bpool_* gauge family.
+(default 512: the PUT-ingest ring of utils/streams.py leases 32 MiB a
+pull, two or three a streamed request, and a lease the arena cannot
+serve is a fresh mapping whose every page faults on first touch, 80 ms
+a 32 MiB pull on the chip host, PERF.md §6 PR 34; pages are touched as
+they are leased, so the arena costs what its busiest moment used).
+Stats feed the mtpu_bpool_* gauge family.
 """
 
 from __future__ import annotations
@@ -48,9 +53,9 @@ def bpool_enabled() -> bool:
 
 def bpool_bytes() -> int:
     try:
-        mb = int(os.environ.get("MTPU_BPOOL_MB", "32"))
+        mb = int(os.environ.get("MTPU_BPOOL_MB", "512"))
     except ValueError:
-        mb = 32
+        mb = 512
     return max(1, mb) << 20
 
 

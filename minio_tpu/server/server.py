@@ -199,6 +199,9 @@ class S3Server:
         # control block whose slabs feed /metrics and admin-info.
         self.worker_plane = worker_plane
         self.worker_id = worker_id
+        # How a streamed body's pulls leave Python (_body_reader): built
+        # and loaded here, at boot, never on a request's thread.
+        self._recv_exact = streams.native_recv_exact()
         # Site-hook single-flight state is created EAGERLY: the lazy
         # `if getattr(...) is None: self._site_hook_mu = Lock()` dance
         # raced — two first-ever mutations on different handler threads
@@ -971,6 +974,15 @@ class S3Server:
             return streams.MaxSizeReader(
                 streams.HTTPChunkedReader(req.rfile), MAX_HEADER_BODY,
                 exc=lambda msg: S3Error("EntityTooLarge"))
+        # Plain TCP + a declared length: the body's pulls are one native
+        # call each.  An SSLSocket's record layer is in Python's hands
+        # (the same branch as _respond's zero-copy writer), and without
+        # the library rfile serves.
+        sock = req.connection
+        if (self._recv_exact is not None
+                and not isinstance(sock, _ssl.SSLSocket)):
+            return streams.SocketBodyReader(req.rfile, length, sock,
+                                            self._recv_exact)
         return streams.LimitedReader(req.rfile, length)
 
     def _authenticate_streaming(self, req, path: str, query: dict):

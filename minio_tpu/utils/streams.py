@@ -14,6 +14,7 @@ import hashlib
 import queue as _queue
 
 from ..observe import span as ospan
+from ..observe.metrics import DATA_PATH
 
 #: Dedicated digest workers for PipelinedMD5.  They must NOT share an
 #: engine pool: an md5 worker occupies its slot for a whole PUT, and a
@@ -180,24 +181,50 @@ class BytesReader:
         return n
 
 
+def _note_pull(path: str, recvs: int) -> None:
+    """Count one pull of a request body and say on the `http.read_body`
+    span it ran under (where there is one) how it ran."""
+    DATA_PATH.record_body_pull(path, recvs)
+    cur = ospan.current()
+    if cur is not None and cur.name == "http.read_body":
+        cur.tag(path=path, recvs=cur.tags.get("recvs", 0) + recvs)
+
+
 class LimitedReader:
-    """Reads exactly `limit` bytes from `raw` then reports EOF; a short
-    source raises IOError (truncated body)."""
+    """Reads exactly `limit` bytes from `raw` (a connection's rfile)
+    then reports EOF; a short source raises StreamError (truncated
+    body).  One readinto()/read() that wants bytes is one *pull*: it
+    fills what was asked for, short only at the source's end, and is
+    counted (mtpu_body_pulls_total, mtpu_body_pull_recvs_total) under
+    `path`."""
+
+    path = "buffered"
 
     def __init__(self, raw, limit: int):
         self._raw = raw
         self._left = limit
+
+    def _pull(self, mv) -> tuple[int, int]:
+        """Fill `mv` from the source: (bytes filled, recvs made)."""
+        # BufferedReader.readinto's own loop, run here so that the
+        # recvs can be counted: readinto1 makes at most one a call (the
+        # first of a body may be served from rfile's buffer instead).
+        filled = recvs = 0
+        while filled < len(mv):
+            n = self._raw.readinto1(mv[filled:])
+            if not n:
+                break
+            filled += n
+            recvs += 1
+        return filled, recvs
 
     def read(self, n: int = -1) -> bytes:
         if self._left <= 0:
             return b""
         if n is None or n < 0:
             n = self._left
-        piece = self._raw.read(min(n, self._left))
-        if not piece and self._left:
-            raise StreamError(f"body truncated ({self._left} bytes short)")
-        self._left -= len(piece)
-        return piece
+        buf = bytearray(min(n, self._left))
+        return bytes(memoryview(buf)[:self.readinto(buf)])
 
     def readinto(self, b) -> int:
         if self._left <= 0:
@@ -206,14 +233,64 @@ class LimitedReader:
         want = min(len(mv), self._left)
         if not want:
             return 0
-        ri = getattr(self._raw, "readinto", None)
-        n = (ri(mv[:want]) if ri is not None
-             else _readinto_via_read(self._raw.read, mv[:want]))
-        n = n or 0
-        if not n and self._left:
+        n, recvs = self._pull(mv[:want])
+        _note_pull(self.path, recvs)
+        if not n:
             raise StreamError(f"body truncated ({self._left} bytes short)")
         self._left -= n
         return n
+
+
+class SocketBodyReader(LimitedReader):
+    """LimitedReader over a plain TCP connection whose pulls leave
+    Python once: what rfile already holds (the header parse may have
+    read the body's first bytes) is handed out first, then the caller's
+    view is filled from the socket's descriptor by one
+    `recv_exact(fd, view, timeout_ms)` (native/ecio_native.py): poll +
+    recv until the view is full, the GIL released for all of it, where
+    rfile gives it away and asks for it again twice a recv.  Never past
+    `limit`, so a keep-alive connection's next request stays where it
+    is.  The socket's own timeout is the idle limit of every wait."""
+
+    path = "native"
+
+    def __init__(self, rfile, limit: int, sock, recv_exact):
+        super().__init__(rfile, limit)
+        self._fd = sock.fileno()
+        timeout = sock.gettimeout()
+        self._timeout_ms = -1 if timeout is None else int(timeout * 1000)
+        self._recv_exact = recv_exact
+        self._held = True        # rfile may hold bytes of this body
+
+    def _pull(self, mv) -> tuple[int, int]:
+        filled = 0
+        if self._held:
+            # peek() returns all that rfile holds without moving (after
+            # one recv of its own where it held nothing); readinto() of
+            # no more than that is a copy out of its buffer.
+            held = len(self._raw.peek())
+            take = min(held, len(mv))
+            if take:
+                filled = self._raw.readinto(mv[:take])
+            self._held = held > take
+            if filled == len(mv) or not held:
+                return filled, 0
+        got, recvs = self._recv_exact(self._fd, mv[filled:],
+                                      self._timeout_ms)
+        return filled + got, recvs
+
+
+def native_recv_exact():
+    """`recv_exact` of the native library (native/ecio.cc), or None on
+    a host whose toolchain cannot build it: bodies are then read
+    through rfile."""
+    from native import ecio_native
+    from native._build import BuildError
+    try:
+        ecio_native.load()
+    except BuildError:
+        return None
+    return ecio_native.recv_exact
 
 
 class ExactLengthReader:
@@ -352,6 +429,7 @@ class HTTPChunkedReader:
         if self._eof:
             return b""
         out = bytearray()
+        recvs = 0
         while n < 0 or len(out) < n:
             if self._chunk_left == 0:
                 self._next_chunk()
@@ -359,13 +437,16 @@ class HTTPChunkedReader:
                     break
             want = self._chunk_left if n < 0 \
                 else min(self._chunk_left, n - len(out))
-            piece = self._rf.read(want)
+            piece = self._rf.read1(want)    # at most one recv: counted
             if not piece:
                 raise StreamError("truncated chunked body")
+            recvs += 1
             out += piece
             self._chunk_left -= len(piece)
             if self._chunk_left == 0:
                 self._rf.read(2)         # chunk CRLF
+        if recvs:
+            _note_pull("buffered", recvs)
         return bytes(out)
 
 

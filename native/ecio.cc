@@ -22,13 +22,22 @@
 //                  cmd/bitrot-streaming.go:142, host edition of
 //                  north-star config #5).
 //
+//   ec_recv_exact  a connected stream socket -> the caller's buffer, the
+//                  poll + recv loop of a request body's pull in ONE call
+//                  (one GIL release a pull instead of two a recv).
+//
 // The mxh256 tree hash and the vpshufb GF(2^8) row multiply are pulled
 // in from their single sources of truth (mxh256.cc / rs_cpu.cc) so the
 // bytes are provably identical to the spec paths.
 
+#include <cerrno>
 #include <cstdint>
 #include <cstring>
 #include <cstddef>
+#include <ctime>
+
+#include <poll.h>
+#include <sys/socket.h>
 
 #include "mxh256.cc"   // chunk_words/level + mxh256_rows (exported too)
 #include "rs_cpu.cc"   // rs_encode + rs_isa
@@ -241,6 +250,49 @@ void ec_gf_rows(const uint8_t* tables, const uint64_t* mats,
     rs_row_ptrs(tables + (size_t)t * nsrc * 32,
                 mats + (size_t)t * nsrc, srcs, nsrc, dsts[t], len);
   }
+}
+
+static int64_t mono_ms() {
+  struct timespec ts;
+  clock_gettime(CLOCK_MONOTONIC, &ts);
+  return (int64_t)ts.tv_sec * 1000 + ts.tv_nsec / 1000000;
+}
+
+// Fill buf[0..n) from the connected stream socket `fd`: recv until the
+// buffer is full or the peer closes, waiting in poll whenever the
+// socket is dry.  Returns the bytes filled (< n only at the peer's
+// close) or -errno; -ETIMEDOUT when `timeout_ms` passed without a byte
+// (an idle limit per wait, not a limit on the call; < 0 waits for
+// ever).  EINTR and EAGAIN are retried here.  *recvs gets the recv
+// calls that returned bytes.  MSG_DONTWAIT: the call never depends on
+// the descriptor's own blocking mode (Python keeps a socket with a
+// timeout non-blocking).
+ssize_t ec_recv_exact(int fd, uint8_t* buf, size_t n, int timeout_ms,
+                      int* recvs) {
+  size_t got = 0;
+  int calls = 0;
+  ssize_t rc = 0;
+  while (got < n) {
+    ssize_t r = recv(fd, buf + got, n - got, MSG_DONTWAIT);
+    if (r > 0) { got += (size_t)r; ++calls; continue; }
+    if (r == 0) break;                         // peer closed
+    if (errno == EINTR) continue;
+    if (errno != EAGAIN && errno != EWOULDBLOCK) { rc = -errno; break; }
+    struct pollfd p = {fd, POLLIN, 0};
+    int64_t until = mono_ms() + timeout_ms;
+    int left = timeout_ms, pr;
+    while ((pr = poll(&p, 1, left)) < 0 && errno == EINTR) {
+      if (timeout_ms >= 0) {
+        int64_t l = until - mono_ms();
+        left = l > 0 ? (int)l : 0;
+      }
+    }
+    if (pr == 0) { rc = -ETIMEDOUT; break; }
+    if (pr < 0) { rc = -errno; break; }
+    // POLLIN, POLLHUP or POLLERR: the next recv says which.
+  }
+  if (recvs) *recvs = calls;
+  return rc < 0 ? rc : (ssize_t)got;
 }
 
 // GFNI<->field self-check material: y = c * x in GF(2^8)/0x11D for the

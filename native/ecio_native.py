@@ -81,6 +81,10 @@ def _load_inner():
         ctypes.POINTER(ctypes.c_void_p), ctypes.c_int,
         ctypes.POINTER(ctypes.c_void_p), ctypes.c_int,
         ctypes.c_size_t]
+    lib.ec_recv_exact.restype = ctypes.c_ssize_t
+    lib.ec_recv_exact.argtypes = [
+        ctypes.c_int, ctypes.c_void_p, ctypes.c_size_t, ctypes.c_int,
+        ctypes.POINTER(ctypes.c_int)]
     lib.ec_selftest_mul.restype = ctypes.c_int
     lib.ec_selftest_mul.argtypes = [ctypes.c_void_p, ctypes.c_int]
     if b"gfni" in lib.ec_isa():
@@ -347,3 +351,28 @@ def gf_transform_rows(srcs: list, sel: list[int], k: int, m: int,
     lib.ec_gf_rows(tabs.ctypes.data, mats.ctypes.data, sptr, len(sel),
                    dptr, len(targets), L)
     return outs
+
+
+def recv_exact(fd: int, view, timeout_ms: int) -> tuple[int, int]:
+    """Fill the writable contiguous buffer `view` from the connected
+    stream socket `fd` in one native call: poll + recv until it is
+    full or the peer closes, the GIL released once for all of it.
+
+    Returns (bytes filled, recvs made); filled < len(view) only at the
+    peer's close.  `timeout_ms` is an idle limit (that long without a
+    byte, not that long a call; negative waits for ever).  Raises the
+    OSError of the socket's errno: TimeoutError when the idle limit
+    passed, ConnectionResetError on a reset."""
+    lib = load()
+    mv = memoryview(view)
+    if mv.readonly or not mv.c_contiguous:
+        raise ValueError("recv_exact needs a writable contiguous buffer")
+    n = mv.nbytes
+    if not n:
+        return 0, 0
+    recvs = ctypes.c_int(0)
+    got = lib.ec_recv_exact(fd, _addr(mv), n, int(timeout_ms),
+                            ctypes.byref(recvs))
+    if got < 0:
+        raise OSError(-got, os.strerror(-got))
+    return got, recvs.value
