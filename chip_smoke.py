@@ -388,6 +388,42 @@ class Run:
         need(got == src[lo:hi + 1], f"ranged GET {key} [{lo},{hi}] differs")
         self.phase("get_ranged", dt, len(got), key=key, range=f"{lo}-{hi}")
 
+    def get_with_shards_gone(self, key: str, gone: tuple[int, ...]) -> None:
+        """Move the part files of shards `gone` (1-based, a data shard
+        among them) away, GET byte-exact, and see in the server's
+        counters that the rows were rebuilt (for a pattern not seen
+        before, unless a hedged read happened to choose the same rows
+        twice) by a program that was built before the request came:
+        nothing compiled.  The files go back afterwards."""
+        size, want, _ = self.objects[key]
+        files = shard_files(self.srv, key)
+        parts = [os.path.join(files[i][0], files[i][1].data_dir, "part.1")
+                 for i in gone]
+        for p in parts:
+            os.rename(p, p + ".away")
+        try:
+            m0 = self.srv.metrics()
+            t0 = time.monotonic()
+            got = self.cli.get_object(BUCKET, key)
+            dt = time.monotonic() - t0
+            m1 = self.srv.metrics()
+        finally:
+            for p in parts:
+                os.rename(p + ".away", p)
+        need(len(got) == size and hashlib.sha256(got).hexdigest() == want,
+             f"GET {key} with shards {gone} gone: bytes differ from source")
+        grew = {n: counter(m1, f"mtpu_{n}_total")
+                - counter(m0, f"mtpu_{n}_total")
+                for n in ("healthy_reads", "decode_blocks",
+                          "decode_patterns", "jit_compiles")}
+        need(grew.pop("decode_patterns") <= 1
+             and grew == {"healthy_reads": 0, "jit_compiles": 0,
+                          "decode_blocks": size // BLOCK},
+             f"GET {key} with shards {gone} gone: counters grew by {grew}")
+        self.get_bytes += size
+        self.phase("get_shards_gone", dt, size, key=key,
+                   shards_gone=",".join(map(str, gone)))
+
     def degraded_then_heal(self, key: str) -> None:
         """Drop the object from the drives holding data shards 1 and 2
         (BASELINE config 3), GET it byte-exact, heal (config 4), and see
@@ -486,12 +522,16 @@ def one_chip(args, root: str, log) -> dict:
         run.phase("boot", boot_s)
         cli = srv.client
 
-        # EC:8+4 through the storage-class config the server already has.
-        st, _, data = cli.request(
-            "POST", "/minio/admin/v1/config",
-            body=json.dumps({"subsys": "storage_class", "key": "standard",
-                             "value": f"EC:{m}"}).encode())
-        need(st == 200, f"config set: HTTP {st} {data[:200]!r}")
+        def set_parity(parity: int) -> None:
+            """The STANDARD class through the admin config route."""
+            st, _, data = cli.request(
+                "POST", "/minio/admin/v1/config",
+                body=json.dumps({"subsys": "storage_class",
+                                 "key": "standard",
+                                 "value": f"EC:{parity}"}).encode())
+            need(st == 200, f"config set: HTTP {st} {data[:200]!r}")
+
+        set_parity(m)                    # EC:8+4 on the 12 drives
         cli.make_bucket(BUCKET)
 
         # 4 KiB: inline in xl.meta, never reaches the codec.
@@ -528,6 +568,23 @@ def one_chip(args, root: str, log) -> dict:
         run.get_ranged("10MiB-0", 3 * MIB - 5, 7 * MIB + 9,
                        body_for(args.seed, "10MiB-0", 10 * MIB))
         run.degraded_then_heal("victim")
+
+        # Reads that rebuild rows with the geometry's one decode
+        # program (PR 35), at warp's 10 MiB: one and two data shards of
+        # 8+4; then the same 12 drives at EC:6, where K divides no
+        # block: one shard, three, and all six data shards gone.
+        lost = 3 * MIB if small else 10 * MIB
+        run.put("lost-8p4", lost)
+        for gone in ((1,), (1, 2)):
+            run.get_with_shards_gone("lost-8p4", gone)
+        set_parity(6)
+        run.put("lost-6p6", lost)
+        frames = check_against_reference(
+            srv, "lost-6p6", body_for(args.seed, "lost-6p6", lost), 6, 6)
+        run.phase("reference_check", 0.0, lost, key="lost-6p6",
+                  frames=frames)
+        for gone in ((1,), (2, 5, 9), (1, 2, 3, 4, 5, 6)):
+            run.get_with_shards_gone("lost-6p6", gone)
 
         m1 = srv.metrics()
         counters = check_counters(run, m0, m1, 1, log)

@@ -918,6 +918,7 @@ class ErasureSet:
                         blocks[:, :BLOCK_SIZE] = batch.reshape(
                             nb, BLOCK_SIZE)
                         blocks = blocks.reshape(nb, k, shard_size)
+                    DATA_PATH.record_stage_pad(batch.size)
                 nxt = enc.encode(blocks)
                 if pending is not None:
                     yield enc.frames(pending)
@@ -1715,6 +1716,7 @@ class ErasureSet:
             asm_s = 0.0
             y = None
             use_co = self.math.digest_rides(nb)
+            DATA_PATH.record_verify_blocks(nb)
             if nb and fused_host is not None and not use_co:
                 # mxh256 host: ONE C pass verifies every frame AND
                 # gathers the systematic rows straight into the final
@@ -1904,6 +1906,9 @@ class ErasureSet:
                     y_fused, okf, nbad = fused_host.get_verify(
                         [rows[s][3] for s in sel], sel, nb, shard_size,
                         k, m, missing)
+                DATA_PATH.record_verify_blocks(
+                    nb, (k, m, tuple(sel), tuple(missing))
+                    if missing else None)
                 if nbad:
                     for j, s in enumerate(sel):
                         if not okf[j]:

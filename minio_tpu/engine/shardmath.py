@@ -336,14 +336,17 @@ class ShardMath:
 
     def build_ladder(self, k: int, m: int) -> None:
         """Ask for the shape ladder of the device programs this set's
-        PUTs and GETs run at (k, m), on its lane: the fused encode and
-        the GET digest of the write algorithm.  Built off the calling
-        thread (ops/coalesce.build_ladder); nothing on the host."""
+        PUTs and GETs run at (k, m), on its lane: the fused encode, the
+        GET digest and the decode of the write algorithm, and where K
+        does not divide the block (every GET then takes the generic
+        read) its verify-only hash.  Built off the calling thread
+        (ops/coalesce.build_ladder); nothing on the host."""
         if not self.use_device:
             return
         coalesce.build_geometry_ladder(
             k, m, -(-BLOCK_SIZE // k), bitrot_io.write_algo(),
-            BATCH_BLOCKS, self.device_idx)
+            BATCH_BLOCKS, self.device_idx,
+            padded_blocks=BLOCK_SIZE % k != 0)
 
     # -- operations ----------------------------------------------------------
 
@@ -414,14 +417,18 @@ class ShardMath:
         where there are none): a degraded GET's decode, a heal batch.
         On the lane ONE dispatch, digests + reconstruction from the
         same HBM-resident bytes, shared by concurrent degraded reads
-        and heals of one (sources, targets) geometry."""
+        and heals of one (sources, targets) pattern; every pattern of
+        a geometry runs one program, built ahead (`build_ladder`)."""
         nb, _, shard_size = x.shape
+        DATA_PATH.record_verify_blocks(
+            nb, (k, m, sources, targets) if targets else None)
         if self._fused_dev(algo) and not mesh_mode():
             def direct():
-                digests, out = fused.verify_and_transform(
+                digests, rows = fused.verify_and_transform(
                     x, k, m, sources, targets, algo=algo,
                     device=self.device_idx)
-                return np.asarray(digests), out
+                return (np.asarray(digests),
+                        fused.rows_on_host(rows) if targets else None)
             co = self._co()
             if co is None:
                 digests, out = direct()
@@ -430,7 +437,7 @@ class ShardMath:
                     co, ("vt", k, m, sources, targets, algo, shard_size), x,
                     self.vt_kernel(k, m, sources, targets, algo,
                                    device=self.device_idx), nb, direct)
-            return digests, np.asarray(out) if targets else None
+            return digests, out
         # Host path (host-hashed algorithm, no TPU, or an algo whose
         # native host kernel beats its device verify —
         # bitrot_io.device_preferred): digest on host, reconstruct via
@@ -462,7 +469,7 @@ class ShardMath:
                 return out
         elif resident is not None and self.use_device \
                 and algo in fused.DEVICE_ALGOS:
-            return np.asarray(fused.verify_and_transform(
+            return fused.rows_on_host(fused.verify_and_transform(
                 resident, k, m, tuple(sources), tuple(targets),
                 algo=algo, device=self.device_idx)[1])
         if self.use_device:

@@ -226,6 +226,15 @@ class DataPathStats:
             # 1 MiB blocks whose PUT parity was computed on each plane:
             # the owning set's device lane, the SPMD mesh, the host.
             self.encode_blocks = {"lane": 0, "mesh": 0, "host": 0}
+            # Full blocks whose K chosen rows a read (or a heal batch)
+            # digest-verified; those of them it also rebuilt rows for;
+            # and the (k, m, sources, targets) it has rebuilt from so
+            # far.  Bytes a PUT copied into the zero-padded block
+            # layout of a K that does not divide the block.
+            self.verify_blocks = 0
+            self.decode_blocks = 0
+            self._decode_patterns: set[tuple] = set()
+            self.stage_pad_bytes = 0
             # Cross-process dispatch (ops/ipc_dispatch.py, worker pool):
             # items shipped to the device owner, results received,
             # fallbacks (arena/ring full -> computed locally), and
@@ -403,6 +412,24 @@ class DataPathStats:
         "mesh" or "host") for their parity (engine/shardmath.py)."""
         with self._mu:
             self.encode_blocks[plane] += blocks
+
+    def record_verify_blocks(self, blocks: int,
+                             pattern: tuple | None = None) -> None:
+        """`blocks` full blocks had their K chosen rows verified
+        (engine/erasure_set.py: the healthy read; engine/shardmath.py:
+        `verify_transform`); `pattern` = (k, m, sources, targets) where
+        rows were rebuilt from them in the same pass."""
+        with self._mu:
+            self.verify_blocks += blocks
+            if pattern is not None:
+                self.decode_blocks += blocks
+                self._decode_patterns.add(pattern)
+
+    def record_stage_pad(self, nbytes: int) -> None:
+        """`nbytes` of a PUT body were copied into the zero-padded
+        block layout (engine/erasure_set.py: `engine.stage`)."""
+        with self._mu:
+            self.stage_pad_bytes += nbytes
 
     def record_co_fault(self, members: int) -> None:
         """A coalesced dispatch raised; `members` spans were retried
@@ -615,6 +642,10 @@ class DataPathStats:
                 "jit_compiles": self.jit_compiles,
                 "jit_compile_s": self.jit_compile_s,
                 "encode_blocks": dict(self.encode_blocks),
+                "verify_blocks": self.verify_blocks,
+                "decode_blocks": self.decode_blocks,
+                "decode_patterns": len(self._decode_patterns),
+                "stage_pad_bytes": self.stage_pad_bytes,
                 "ipc_submits": self.ipc_submits,
                 "ipc_rows": self.ipc_rows,
                 "ipc_results": self.ipc_results,
@@ -822,6 +853,22 @@ class MetricsRegistry:
             "1 MiB blocks of PUT bodies whose parity was computed on "
             "this plane: lane (the owning set's device), mesh (SPMD "
             "over all chips), host", ("plane",))
+        self.verify_blocks = Gauge(
+            "mtpu_verify_blocks_total",
+            "Full blocks whose K chosen shard rows a read or a heal "
+            "batch digest-verified, on any plane")
+        self.decode_blocks = Gauge(
+            "mtpu_decode_blocks_total",
+            "Those of mtpu_verify_blocks_total that had rows rebuilt "
+            "from the K verified ones in the same pass")
+        self.decode_patterns = Gauge(
+            "mtpu_decode_patterns_total",
+            "Distinct (k, m, sources, targets) rows were rebuilt for "
+            "so far; on a device lane they share one program a (k, m)")
+        self.stage_pad_bytes = Gauge(
+            "mtpu_stage_pad_bytes_total",
+            "Bytes of PUT bodies copied into the zero-padded block "
+            "layout (engine.stage: a K that does not divide 1 MiB)")
         # Cross-process dispatch families (worker pool, PR 9).
         self.ipc_submits = Gauge(
             "mtpu_ipc_dispatch_submits_total",
@@ -1625,6 +1672,10 @@ class MetricsRegistry:
         self.jit_compile_seconds.set(snap["jit_compile_s"])
         for plane, blocks in snap["encode_blocks"].items():
             self.encode_blocks.set(blocks, plane=plane)
+        self.verify_blocks.set(snap["verify_blocks"])
+        self.decode_blocks.set(snap["decode_blocks"])
+        self.decode_patterns.set(snap["decode_patterns"])
+        self.stage_pad_bytes.set(snap["stage_pad_bytes"])
         self.ipc_submits.set(snap["ipc_submits"])
         self.ipc_results.set(snap["ipc_results"])
         self.ipc_fallbacks.set(snap["ipc_fallbacks"])

@@ -124,9 +124,8 @@ def _pad_rows(x: np.ndarray, rows: int) -> np.ndarray:
 # is not built (a geometry nobody announced, a program met for the first
 # time) the submitter builds it on its own thread before it queues the
 # item (`DispatchLane.submit`): that one request waits for the compile,
-# the lane and everyone on it do not.  A kernel whose `ladder` is off
-# (one program per variant, as verify+transform with targets) keeps the
-# single shape P.
+# the lane and everyone on it do not.  A kernel that names no program
+# (a device codec with host hashing) keeps the single shape P.
 
 LADDER = (1, 2, 4, 8, 16, 32)
 
@@ -416,18 +415,21 @@ class DispatchLane:
             out[self._state] += now - self._state_t
         return out
 
-    def _dispatch_span(self, key: tuple, items: list[tuple], rows: int,
-                       padded: int):
+    def _dispatch_span(self, key: tuple, fn, items: list[tuple],
+                       rows: int, padded: int):
         """The `lane.dispatch` span of one batch: nested under the
         request when the dispatch runs inline on its thread, else a
-        root of the lane thread's own, naming the requests it serves."""
+        root of the lane thread's own, naming the requests it serves.
+        `program` is the batch's key, or what the kernel says of itself
+        (`span_tags`: a decode's one program name and its `targets`)."""
         if not ospan.TRACER.enabled:
             return ospan.NOOP
+        tags = getattr(fn, "span_tags", None) \
+            or {"program": "/".join(str(p) for p in key)}
         return ospan.span_or_root(
-            "lane.dispatch", device=self.device,
-            program="/".join(str(p) for p in key), items=len(items),
+            "lane.dispatch", device=self.device, items=len(items),
             rows=rows, padded_rows=padded,
-            members=sorted({h.rid for _, h in items if h.rid}))
+            members=sorted({h.rid for _, h in items if h.rid}), **tags)
 
     # -- submission ----------------------------------------------------------
 
@@ -643,7 +645,7 @@ class DispatchLane:
         t_disp = time.monotonic()
         n = sum(h.nrows for _, h in items)
         padded = kernel_rows(fn, n, items[0][0].shape[1:])
-        with self._dispatch_span(key, items, n, padded):
+        with self._dispatch_span(key, fn, items, n, padded):
             self._dispatch_serial(items, w, fn, t_disp, inline, padded)
 
     def _dispatch_serial(self, items: list[tuple], w: int, fn,
@@ -782,7 +784,7 @@ class DispatchLane:
         need = padded * row_bytes
         # Open from this pack to this batch's resolve, one dispatch
         # later: suspended in between, while the lane packs the next.
-        root = self._dispatch_span(key, items, n, padded).__enter__()
+        root = self._dispatch_span(key, fn, items, n, padded).__enter__()
         with self._stage("pack"):
             slot = self._staging_flip
             self._staging_flip ^= 1
@@ -1071,18 +1073,18 @@ class DispatchCoalescer:
 # kernel from the same key.
 
 def _device_kernel(launch, pad_rows: int, device: int | None,
-                   program=None, ladder: bool = False):
+                   program=None):
     """The dispatch kernel around a `launch(x, n, spans, ctx) ->
     resolve` pair.  The lanes drive the pair themselves (pack, upload,
     launch, then resolve one dispatch later); called whole (a solo
     retry, a direct call) the kernel pads to its step, uploads, and
     resolves at once.  `program()` gives the ops/fused.Program the
     launch runs, where it is one: its `pad_rows` shape is then built
-    before a lane sees a batch.  It is asked for only where a batch is
-    dispatched: a pool worker builds kernels (their keys travel to the
-    owner) and must not reach for JAX.  With `ladder` the batch runs
-    at the smallest built step of the shape ladder, else at a multiple
-    of `pad_rows`."""
+    before a lane sees a batch, and the batch runs at the smallest
+    built step of the shape ladder (`ladder`); a kernel that names no
+    program runs at a multiple of `pad_rows`.  The program is asked
+    for only where a batch is dispatched: a pool worker builds kernels
+    (their keys travel to the owner) and must not reach for JAX."""
     from . import devices
 
     def kernel(stacked, spans, ctx):
@@ -1094,7 +1096,7 @@ def _device_kernel(launch, pad_rows: int, device: int | None,
     kernel.pad_rows = pad_rows
     kernel.device = device
     kernel.program = program
-    kernel.ladder = ladder
+    kernel.ladder = program is not None
     return kernel
 
 
@@ -1137,8 +1139,7 @@ def make_encode_kernel(k: int, m: int, algo: str, pad_rows: int,
         return _device_kernel(launch, pad_rows, device)
     return _device_kernel(
         launch, pad_rows, device,
-        functools.partial(fused.encode_hash_program, k, m, algo),
-        ladder=True)
+        functools.partial(fused.encode_hash_program, k, m, algo))
 
 
 def make_verify_kernel(k: int, m: int, sources: tuple, targets: tuple,
@@ -1147,29 +1148,35 @@ def make_verify_kernel(k: int, m: int, sources: tuple, targets: tuple,
     """Fused device verify(+reconstruct) over stacked (B, K, S) gathers
     — the healthy-verify / degraded-decode / heal work item.  Digest
     layout is (B, K, hs): axis 0 is the concat axis of both outputs.
-    With no targets it is one hash program per algorithm and takes the
-    ladder; with targets there is one program per (sources, targets),
-    met on first sight, and it keeps the single shape `pad_rows`."""
+    With no targets it is one hash program per algorithm; with targets
+    the geometry's one decode program (ops/fused.py), (sources,
+    targets) its matrix operand: a matrix a dispatch, so a batch holds
+    one pattern (the key carries it) and every pattern runs the same
+    executables.  Both take the ladder."""
     from . import fused
 
     def launch(x, n, spans, ctx):
-        digests_d, out_d = fused.verify_and_transform(
+        digests_d, rows_d = fused.verify_and_transform(
             x, k, m, sources, targets, algo=algo, device=device)
 
         def resolve():
             digests = devcache.fetch(digests_d)[:n]
-            out = devcache.fetch(out_d)[:n] if targets else None
+            out = fused.rows_on_host(rows_d, n) if targets else None
             return [(digests[lo:hi],
                      out[lo:hi] if out is not None else None)
                     for lo, hi in spans]
 
         return resolve
 
-    return _device_kernel(
+    kernel = _device_kernel(
         launch, pad_rows, device,
         functools.partial(fused.verify_transform_program, k, m, sources,
-                          targets, algo),
-        ladder=not targets)
+                          targets, algo))
+    if targets:
+        kernel.span_tags = {
+            "program": fused.verify_transform_name(k, m, algo),
+            "targets": len(targets)}
+    return kernel
 
 
 def make_digest_kernel(algo: str, pad_rows: int = 0,
@@ -1197,8 +1204,7 @@ def make_digest_kernel(algo: str, pad_rows: int = 0,
 
             return _device_kernel(
                 launch, pad_rows, device,
-                functools.partial(fused.hash_rows_program, algo),
-                ladder=True)
+                functools.partial(fused.hash_rows_program, algo))
 
     def kernel(stacked, spans, ctx):
         out = bitrot_io._hash_batch(stacked, algo)
@@ -1208,14 +1214,18 @@ def make_digest_kernel(algo: str, pad_rows: int = 0,
 
 
 def build_geometry_ladder(k: int, m: int, shard_size: int, algo: str,
-                          pad_blocks: int, device: int) -> None:
-    """Ask for the ladders of the two programs every PUT and healthy
-    GET of a set of geometry (k, m, shard_size) writing `algo` runs on
-    lane `device`: its fused encode and its GET digest, each built from
-    the parameters the engine's keys carry.  (The verify-only hash of
-    the slow read path can take a ladder too, but nothing asks for it
-    here: each program costs seconds to compile once.)  Nothing where
-    the algorithm hashes on the host."""
+                          pad_blocks: int, device: int,
+                          padded_blocks: bool = False) -> None:
+    """Ask for the ladders of the programs the PUTs and GETs of a set
+    of geometry (k, m, shard_size) writing `algo` run on lane `device`,
+    each built from the parameters the engine's keys carry: its fused
+    encode, its GET digest, and its decode (one program whatever rows a
+    read has to rebuild: any pattern names it).  With `padded_blocks`
+    (K * shard_size is more than a block: every GET takes the engine's
+    generic read) the verify-only hash of that read too, which a
+    geometry whose healthy GETs are digested in place meets too seldom
+    to pay seconds of compile for.  Nothing where the algorithm hashes
+    on the host."""
     from ..storage import bitrot_io
     from . import fused
 
@@ -1226,6 +1236,13 @@ def build_geometry_ladder(k: int, m: int, shard_size: int, algo: str,
                  (k, shard_size))
     build_ladder(make_digest_kernel(algo, pad_blocks * k, device),
                  (shard_size,))
+    patterns = [(tuple(range(1, k + 1)), (0,))]
+    if padded_blocks:
+        patterns.append((tuple(range(k)), ()))
+    for sources, targets in patterns:
+        build_ladder(make_verify_kernel(k, m, sources, targets, algo,
+                                        pad_blocks, device),
+                     (k, shard_size))
 
 
 # -- process singleton -------------------------------------------------------

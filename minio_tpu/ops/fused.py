@@ -30,6 +30,7 @@ import threading
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from . import devcache
 from . import devices as devices_mod
@@ -50,17 +51,23 @@ class Program:
     shape, lane device).  Calling a shape that is built compiles
     nothing, whatever thread calls; any other shape goes through the
     jit and compiles on first sight.  The dispatch lanes size a batch
-    by what is built here (ops/coalesce.py: the shape ladder)."""
+    by what is built here (ops/coalesce.py: the shape ladder).
 
-    def __init__(self, name: str, fn):
+    `operand` is the (shape, dtype) of a second input whose shape does
+    not follow the batch's (the decode program's matrix): what varies
+    between calls of one executable without compiling another."""
+
+    def __init__(self, name: str, fn, operand: tuple | None = None):
         fn.__name__ = fn.__qualname__ = name
+        self.name = name
         self.jit = jax.jit(fn)
+        self._operand = operand
         self._built: dict[tuple, object] = {}
         self._build_mu = threading.Lock()
 
-    def __call__(self, x, device: int | None = None):
+    def __call__(self, x, device: int | None = None, *operand):
         exe = self._built.get((x.shape, device))
-        return self.jit(x) if exe is None else exe(x)
+        return (self.jit if exe is None else exe)(x, *operand)
 
     def built(self, shape: tuple, device: int | None) -> bool:
         return (tuple(shape), device) in self._built
@@ -76,11 +83,14 @@ class Program:
         with self._build_mu:        # two who ask at once: one compiles
             if self.built(shape, device):
                 return
-            spec = jax.ShapeDtypeStruct(
-                tuple(shape), jnp.uint8,
-                sharding=jax.sharding.SingleDeviceSharding(dev))
+            on_dev = jax.sharding.SingleDeviceSharding(dev)
+            specs = [jax.ShapeDtypeStruct(tuple(shape), jnp.uint8,
+                                          sharding=on_dev)]
+            if self._operand is not None:
+                specs.append(jax.ShapeDtypeStruct(*self._operand,
+                                                  sharding=on_dev))
             self._built[tuple(shape), device] = \
-                self.jit.lower(spec).compile()
+                self.jit.lower(*specs).compile()
 
 
 def _placed(x, device: int | None):
@@ -149,26 +159,42 @@ def _hash_rows_jit(algo: str, key: bytes):
     return Program(f"verify_{algo}", fn)
 
 
+def verify_transform_name(k: int, m: int, algo: str) -> str:
+    """The decode program of a geometry, as the profiler, the compile
+    log and the lanes' `lane.dispatch` span name it."""
+    return f"verify_transform_k{k}m{m}_{algo}"
 
 
-@functools.lru_cache(maxsize=512)
-def _verify_transform_jit(k: int, m: int, sources: tuple[int, ...],
-                          targets: tuple[int, ...], algo: str, key: bytes):
-    mat = jnp.asarray(
-        erasure_jax._transform_matrix_bits(k, m, sources, targets),
-        dtype=jnp.bfloat16)
-    rows = len(targets)
-
-    def fn(x):  # x: (B, K, S) uint8 — rows in `sources` order
+@functools.lru_cache(maxsize=64)
+def _verify_transform_jit(k: int, m: int, algo: str, key: bytes):
+    def fn(x, mat):  # x: (B, K, S) uint8 rows; mat: their `decode_matrix`
         b, kk, s = x.shape
         digests = _digest_rows(x.reshape(b * kk, s), algo, key).reshape(
             b, kk, 32)
-        out = erasure_pallas.gf_matmul_blocks(mat, x, rows)
-        return digests, out
+        out = erasure_pallas.gf_matmul_blocks(mat, x, m)
+        # A target row an output of its own: the caller fetches the T
+        # it asked for, and the pad rows never leave the device.
+        return digests, tuple(out[:, j] for j in range(m))
 
-    return Program(
-        f"verify_transform_k{k}m{m}_s{'_'.join(map(str, sources))}"
-        f"_t{'_'.join(map(str, targets))}_{algo}", fn)
+    return Program(verify_transform_name(k, m, algo), fn,
+                   operand=((8 * m, 8 * k), jnp.bfloat16))
+
+
+def decode_matrix(k: int, m: int, sources: tuple[int, ...],
+                  targets: tuple[int, ...]) -> np.ndarray:
+    """The decode program's operand: the plane-major bit matrix that
+    maps rows `sources` to rows `targets`
+    (`erasure_jax._transform_matrix_bits`, cached on the host),
+    zero-padded from T to M target rows, so that its shape is the
+    geometry's and not the loss pattern's."""
+    t = len(targets)
+    if not 0 < t <= m:
+        raise ValueError(f"{t} rows to rebuild at EC:{k}+{m}")
+    bits = erasure_jax._transform_matrix_bits(
+        k, m, tuple(sources), tuple(targets))        # row j*T + target
+    mat = np.zeros((8, m, 8 * k), dtype=jnp.bfloat16)
+    mat[:, :t] = bits.reshape(8, t, 8 * k)
+    return mat.reshape(8 * m, 8 * k)
 
 
 def verify_and_transform(x, k: int, m: int, sources: tuple[int, ...],
@@ -176,28 +202,42 @@ def verify_and_transform(x, k: int, m: int, sources: tuple[int, ...],
                          algo: str = "highwayhash256S",
                          key: bytes = MAGIC_KEY,
                          device: int | None = None):
-    """((B, K, S) shard rows) -> ((B, K, 32) digests, (B, T, S) rebuilt rows).
+    """((B, K, S) shard rows) -> ((B, K, 32) digests, T rebuilt rows of
+    (B, S) each, in `targets` order; None where there are none).
 
     Digests are of the INPUT rows (callers compare them against the bitrot
-    frame hashes); rebuilt rows are the GF transform sources->targets.
-    With no targets (nothing missing) only the hash runs.  `device` is
-    the coalescer-lane index the dispatch is placed on (None = default
+    frame hashes); rebuilt rows are the GF transform sources->targets
+    (`rows_on_host` brings them back as one (B, T, S) array).  With no
+    targets (nothing missing) only the hash runs.  `device` is the
+    coalescer-lane index the dispatch is placed on (None = default
     device, the pre-sharding behavior).
     """
-    out = verify_transform_program(k, m, sources, targets, algo, key)(
-        _placed(x, device), device)
-    return out if targets else (out, None)
+    prog = verify_transform_program(k, m, sources, targets, algo, key)
+    x = _placed(x, device)
+    if not targets:
+        return prog(x, device), None
+    mat = decode_matrix(k, m, sources, targets)
+    devcache.note_h2d(mat.nbytes, device)
+    digests, rows = prog(x, device, mat)
+    return digests, rows[:len(targets)]
+
+
+def rows_on_host(rows, n: int | None = None) -> np.ndarray:
+    """`verify_and_transform`'s rebuilt rows as one (n, T, S) array on
+    the host, the crossing counted (the first `n` blocks: a lane's
+    batch carries pad blocks behind them)."""
+    return np.stack([devcache.fetch(r)[:n] for r in rows], axis=1)
 
 
 def verify_transform_program(k: int, m: int, sources: tuple[int, ...],
                              targets: tuple[int, ...], algo: str,
                              key: bytes = MAGIC_KEY) -> Program:
     """The program `verify_and_transform` runs: the hash alone where
-    nothing is to be rebuilt, else one per (sources, targets)."""
+    nothing is to be rebuilt, else the geometry's one decode program,
+    whatever (sources, targets): they reach it as its matrix operand."""
     if not targets:
         return _hash_rows_jit(algo, key)
-    return _verify_transform_jit(k, m, tuple(sources), tuple(targets),
-                                 algo, key)
+    return _verify_transform_jit(k, m, algo, key)
 
 
 @functools.lru_cache(maxsize=64)

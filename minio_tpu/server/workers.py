@@ -454,7 +454,7 @@ def _owner_ladders(cfg: dict) -> None:
                      for i in range(len(paths) // n)}:
             coalesce.build_geometry_ladder(
                 k, n // 2, -(-BLOCK_SIZE // k), write_algo(),
-                BATCH_BLOCKS, lane)
+                BATCH_BLOCKS, lane, padded_blocks=BLOCK_SIZE % k != 0)
 
 
 def _owner_main(plane: WorkerPlane, cfg: dict) -> int:

@@ -105,14 +105,17 @@ def test_fused_mxh256_programs(one_chip, program, kernels):
     fn = {
         "encode_and_hash": lambda: fused._encode_hash_jit(
             k, m, "mxh256", MAGIC_KEY),
-        # row 0 lost: rebuilt from rows 1..8
+        # any rows lost: they reach the one program as its matrix
         "verify_and_transform": lambda: fused._verify_transform_jit(
-            k, m, tuple(range(1, k + 1)), (0,), "mxh256", MAGIC_KEY),
+            k, m, "mxh256", MAGIC_KEY),
         "verify": lambda: fused._hash_rows_jit("mxh256", MAGIC_KEY),
     }[program]()
-    x = jax.ShapeDtypeStruct((BATCH_BLOCKS, k, 131072), jnp.uint8,
-                             sharding=one_chip)
-    _check(fn.jit.lower(x).compile(), kernels)
+    args = [jax.ShapeDtypeStruct((BATCH_BLOCKS, k, 131072), jnp.uint8,
+                                 sharding=one_chip)]
+    if program == "verify_and_transform":
+        args.append(jax.ShapeDtypeStruct((8 * m, 8 * k), jnp.bfloat16,
+                                         sharding=one_chip))
+    _check(fn.jit.lower(*args).compile(), kernels)
 
 
 # The shape ladder (ops/coalesce.py) runs the same programs at 1, 2, 4, 8
@@ -132,6 +135,24 @@ def test_ladder_steps_of_encode_and_get_digest(one_chip, k, m, s, blocks):
                              sharding=one_chip)).compile()
     _check(dig, kernels=0)
     assert dig.out_info.shape == (blocks * k, 32)
+
+
+# The one decode program a geometry (PR 35): its matrix an operand, a
+# rebuilt row an output of its own.  EC:6+6 reaches the kernel padded
+# (174,763 -> 174,848) and is sliced back before the rows are split.
+@pytest.mark.parametrize("k,m,s,blocks", [
+    (6, 6, 174763, 16), (6, 6, 174763, 1), (8, 4, 131072, 16),
+    (2, 2, 524288, 32)])
+def test_ladder_steps_of_the_decode_program(one_chip, k, m, s, blocks):
+    prog = fused.verify_transform_program(k, m, (), (0,), "mxh256")
+    dec = prog.jit.lower(
+        jax.ShapeDtypeStruct((blocks, k, s), jnp.uint8, sharding=one_chip),
+        jax.ShapeDtypeStruct((8 * m, 8 * k), jnp.bfloat16,
+                             sharding=one_chip)).compile()
+    _check(dec, kernels=1)
+    digests, rows = dec.out_info
+    assert digests.shape == (blocks, k, 32)
+    assert [r.shape for r in rows] == [(blocks, s)] * m
 
 
 @pytest.mark.parametrize("program", ["encode", "gather_reconstruct"])

@@ -444,7 +444,8 @@ def _programs():
     from minio_tpu.ops import fused
     return (fused.encode_hash_program(K, M, ALGO),
             fused.hash_rows_program(ALGO),
-            fused.verify_transform_program(K, M, (0, 1), (), ALGO))
+            fused.verify_transform_program(K, M, (0, 1), (), ALGO),
+            fused.verify_transform_program(K, M, (1, 2), (0,), ALGO))
 
 
 @pytest.fixture
@@ -598,7 +599,12 @@ class TestShapeLadder:
         snap = DATA_PATH.snapshot()["jit_compiles"]
         assert compiles_built - compiles <= 1 and snap == compiles_built
 
-    def test_ladder_is_built_top_step_first(self, ladder, monkeypatch):
+    @pytest.mark.parametrize("padded_blocks", [False, True])
+    def test_ladder_is_built_top_step_first(self, ladder, monkeypatch,
+                                            padded_blocks):
+        """Encode, GET digest and the geometry's one decode program;
+        the verify-only hash only where every GET takes the generic
+        read (`padded_blocks`: K does not divide the block)."""
         from minio_tpu.ops import fused
         order = []
         build = fused.Program.build
@@ -608,16 +614,20 @@ class TestShapeLadder:
             return build(self, shape, device)
 
         monkeypatch.setattr(fused.Program, "build", spy)
-        coalesce.build_geometry_ladder(K, M, S, ALGO, PAD, 0)
+        coalesce.build_geometry_ladder(K, M, S, ALGO, PAD, 0,
+                                       padded_blocks=padded_blocks)
         coalesce.ladder_wait()
+        steps = (32, 16, 8, 4, 2, 1)
         assert order == (
-            [(f"encode_hash_k{K}m{M}_{ALGO}", r)
-             for r in (32, 16, 8, 4, 2, 1)]
-            + [(f"hash_rows_{ALGO}", r * K) for r in (32, 16, 8, 4, 2, 1)])
-        enc, dig, verify = _programs()
+            [(f"encode_hash_k{K}m{M}_{ALGO}", r) for r in steps]
+            + [(f"hash_rows_{ALGO}", r * K) for r in steps]
+            + [(f"verify_transform_k{K}m{M}_{ALGO}", r) for r in steps]
+            + [(f"verify_{ALGO}", r) for r in steps] * padded_blocks)
+        enc, dig, verify, decode = _programs()
         assert all(enc.built((r, K, S), 0) and dig.built((r * K, S), 0)
+                   and decode.built((r, K, S), 0)
                    for r in coalesce.LADDER)
-        assert not verify._built
+        assert bool(verify._built) == padded_blocks
 
     def test_a_step_that_fails_to_build_leaves_the_next_one_up(
             self, ladder, monkeypatch, capsys):
@@ -634,13 +644,16 @@ class TestShapeLadder:
         assert "not built" in capsys.readouterr().err
         assert coalesce.kernel_rows(fn, 1, (K, S)) == 2
 
-    @pytest.mark.parametrize("targets,want", [((), 1), ((0,), PAD)])
-    def test_verify_kernel_keeps_one_shape_when_it_has_targets(
+    @pytest.mark.parametrize("targets,want", [((), 1), ((0,), 1)])
+    def test_verify_kernel_takes_the_ladder_with_targets_too(
             self, ladder, tmp_path, targets, want):
+        """PR 35: the rows to rebuild reach the geometry's one decode
+        program as its matrix operand, so it has a ladder like the
+        hash-only program (before: a program a pattern, one shape)."""
         es = make_set(tmp_path, name="vt")
         sources = (1, 2) if targets else (0, 1)
         fn = es.math.vt_kernel(K, M, sources, targets, ALGO, device=0)
-        assert fn.ladder == (not targets) and fn.program is not None
+        assert fn.ladder and fn.program is not None
         _built_ladder(fn, (K, S))
         seen = _spy(fn)
         x = _blocks(1)

@@ -537,13 +537,17 @@ class TestBootLadder:
     @pytest.fixture
     def asked(self, monkeypatch):
         calls = []
-        monkeypatch.setattr(coalesce, "build_geometry_ladder",
-                            lambda *a: calls.append(a))
+        monkeypatch.setattr(
+            coalesce, "build_geometry_ladder",
+            lambda *a, padded_blocks: calls.append(a + (padded_blocks,)))
         return calls
 
+    # want: (k, m, shard size, algo, pad blocks), then whether K leaves
+    # the block padded (1 MiB / 3: yes, and its GETs take the generic
+    # read, whose verify-only hash is then built ahead too).
     @pytest.mark.parametrize("use_device,parity,want", [
-        (True, None, [(3, 1, 349526, "mxh256", 32)]),
-        (True, 2, [(2, 2, 524288, "mxh256", 32)]),
+        (True, None, [((3, 1, 349526, "mxh256", 32), True)]),
+        (True, 2, [((2, 2, 524288, "mxh256", 32), False)]),
         (False, None, []),
     ])
     def test_each_set_asks_for_its_geometry_on_its_lane(
@@ -556,7 +560,8 @@ class TestBootLadder:
                             lambda: (use_device, False))
         pools = ServerPools([make_ring(tmp_path, nsets=3)])
         pools.build_ladders(parity)
-        assert asked == [w + (lane,) for lane in range(3) for w in want]
+        assert asked == [w + (lane, padded) for lane in range(3)
+                         for w, padded in want]
 
     def test_server_asks_at_every_configured_parity(self, tmp_path,
                                                     asked, monkeypatch):
@@ -634,7 +639,7 @@ class TestBootLadder:
         monkeypatch.setattr(devices, "on_tpu", lambda: True)
         monkeypatch.setenv("MTPU_DEVICES", "2")
         workers._owner_ladders(cfg)
-        assert asked == [(2, 2, 524288, "mxh256", 32, lane)
+        assert asked == [(2, 2, 524288, "mxh256", 32, lane, False)
                          for lane in (0, 1)]
 
 

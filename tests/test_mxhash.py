@@ -126,9 +126,9 @@ def test_fused_verify_transform_mxh():
     full = np.stack(blocks)                              # (2, k+m, s)
     sources = (1, 2, 3, 4)
     x = full[:, list(sources), :]
-    digests, out = fused.verify_and_transform(x, k, m, sources, (0,),
-                                              algo="mxh256")
-    digests, out = np.asarray(digests), np.asarray(out)
+    digests, rows = fused.verify_and_transform(x, k, m, sources, (0,),
+                                               algo="mxh256")
+    digests, out = np.asarray(digests), fused.rows_on_host(rows)
     assert np.array_equal(out[:, 0, :], full[:, 0, :])
     for i, srow in enumerate(sources):
         want = mxhash.mxh256_batch(full[:, srow, :])

@@ -125,7 +125,7 @@ def backends(monkeypatch):
     monkeypatch.setattr(
         fused, "verify_and_transform",
         note("lane", (np.zeros((1, K, 32), np.uint8),
-                      np.zeros((1, 1, S), np.uint8))))
+                      (np.zeros((1, S), np.uint8),))))
     monkeypatch.setattr(bitrot_io, "_hash_batch",
                         note("host_hash", np.zeros((K, 32), np.uint8)))
     return called
