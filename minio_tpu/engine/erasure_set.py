@@ -452,7 +452,8 @@ class ErasureSet:
         def counted_chunks():
             nonlocal total
             for chunk, is_last in streams.batched_chunks(
-                    data, stream, BATCH_BLOCKS * BLOCK_SIZE):
+                    data, stream, BATCH_BLOCKS * BLOCK_SIZE,
+                    digest=md5 if stream is not None else None):
                 if stream is not None:
                     with ospan.span("engine.etag"):
                         md5.update(chunk)  # bytes path already has its etag

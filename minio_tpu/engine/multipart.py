@@ -211,7 +211,7 @@ def put_object_part(es: ErasureSet, bucket: str, obj: str, upload_id: str,
     def counted_chunks():
         nonlocal total
         for chunk, is_last in streams.batched_chunks(
-                data, stream, BATCH_BLOCKS * BLOCK_SIZE):
+                data, stream, BATCH_BLOCKS * BLOCK_SIZE, digest=md5):
             with ospan.span("mp.md5"):
                 md5.update(chunk)
             total += len(chunk)
@@ -246,7 +246,8 @@ def put_object_part(es: ErasureSet, bucket: str, obj: str, upload_id: str,
         # Encode of batch i+1 (the `reads` pull) overlaps the shard
         # appends of batch i (one write in flight keeps per-drive
         # append order).  double_buffer: the async batch must survive
-        # the next fused put_frame's arena reuse.
+        # the next fused put_frame's reuse of its thread's framing
+        # buffer (the other planes alternate between two always).
         pl.StagePipeline(es._iter_pool).run(
             es._encode_chunks(counted_chunks(), k, m, algo,
                               double_buffer=True),

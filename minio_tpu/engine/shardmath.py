@@ -76,20 +76,36 @@ def ecio_mod():
 # Process-wide mesh for multi-device codec placement (built lazily).
 _MESH = None
 
-# Per-thread pair of alternating fused-encode output buffers for the
-# double-buffered pipeline (a fresh 2x ~50 MB allocation per multipart
-# part would cost more in page faults than the overlap saves).  One
-# pipelined encode per thread at a time, and StagePipeline joins its
-# in-flight write before returning, so reuse across calls is safe.
+# Per-thread pair of framing buffers, reused batch after batch and
+# stream after stream: a page of fresh anonymous memory costs ~10 us at
+# first touch on the chip's host and glibc maps an allocation this
+# large anew every time, so a fresh ~50 MB output a batch is ~120 ms of
+# page faults, twice a 64 MiB part (PERF.md §6, PR 34 and PR 36).  Each
+# grows to the largest batch its thread has framed, is never zeroed and
+# lives while the thread does (a connection's request thread: at 8+4
+# two 50.3 MB buffers, one where no stream of the thread ran past one
+# batch).  One encode per thread at a time; the callers' write
+# fan-outs return only after every drive call has (`list(pool.map)` or
+# inline in `_map_drives_positions`; no drive call is abandoned on a
+# timeout: the health wrapper times a call on its own thread and an
+# RPC drive's timeout raises inside it), and StagePipeline joins its
+# in-flight write before it returns, so nobody reads a buffer when it
+# comes round again.
 _DB_ARENAS = threading.local()
 
 
-def _db_arenas(nbytes: int) -> list:
+def _db_arena(slot: int, nbytes: int) -> np.ndarray:
+    """Framing buffer `slot` (0 or 1) of the calling thread, at least
+    `nbytes` long.  What had to be allocated is counted
+    (mtpu_put_fresh_buffer_bytes_total); reuse is not."""
     pair = getattr(_DB_ARENAS, "pair", None)
-    if pair is None or pair[0].size < nbytes:
-        pair = [np.empty(nbytes, dtype=np.uint8) for _ in range(2)]
-        _DB_ARENAS.pair = pair
-    return pair
+    if pair is None:
+        pair = _DB_ARENAS.pair = [None, None]
+    arena = pair[slot]
+    if arena is None or arena.size < nbytes:
+        arena = pair[slot] = np.empty(nbytes, dtype=np.uint8)
+        DATA_PATH.record_put_fresh_buffer(nbytes)
+    return arena
 
 
 # The erasure sets this process serves, by their `ShardMath` (weak: a
@@ -503,21 +519,26 @@ class Encoder:
 
     `overlaps` is False where a batch is one native pass on the calling
     thread (the fused host kernel, direct): `frames` runs it, and the
-    caller asks at once.  It writes into one reused per-thread arena,
-    valid until the next pass; with `double_buffer` into alternating
-    ones, so batch i may be consumed while batch i+1 encodes.  The
-    other planes allocate fresh frames per batch."""
+    caller asks at once.
+
+    On every plane a batch is framed into a buffer its thread already
+    holds (`_db_arena`), sized from the batch itself: a 1-block PUT
+    touches one block's frames.  The views `frames` returns are valid
+    until the buffer comes round again: on the lane, mesh and native
+    planes after the next batch but one, always (two alternating
+    buffers: batch i is written by the drive pool while batch i+1 is
+    framed); the fused host kernel's after the next batch, or with
+    `double_buffer` the next but one."""
 
     def __init__(self, sm: ShardMath, k: int, m: int, algo: str,
                  double_buffer: bool):
         self.sm, self.k, self.m, self.algo = sm, k, m, algo
         self.shard_size = -(-BLOCK_SIZE // k)
-        self.frame_len = bitrot_io.digest_size("mxh256") + self.shard_size
+        self.frame_len = bitrot_io.digest_size(algo) + self.shard_size
         self.fused_host = sm.host_fused(k, m, algo)
         self.co = sm._co()
         self.overlaps = self.fused_host is None or self.co is not None
-        self.double_buffer = double_buffer
-        self._arenas = None     # two alternating fused-output buffers
+        self._alternate = double_buffer or self.fused_host is None
         self._flip = 0
         # Retired coalesced put_frame handles: their results alias a
         # POOLED dispatch buffer, and a pipelined consumer may still be
@@ -562,24 +583,26 @@ class Encoder:
             sm.enc_kernel(k, m, algo, fused_dev, device=sm.device_idx),
             weight=nb, device=sm.device_idx), None
 
+    def _out(self, nb: int) -> np.ndarray:
+        """The buffer the stream's next batch of `nb` blocks is framed
+        into: the thread's, the other one after each where batches
+        alternate."""
+        slot = self._flip
+        if self._alternate:
+            self._flip ^= 1
+        return _db_arena(slot, (self.k + self.m) * nb * self.frame_len)
+
     def _direct(self, blocks: np.ndarray):
-        """The stream's direct pass: the fused host kernel's frames
-        (into the per-thread arena, or one of two alternating ones
-        with `double_buffer`), else `direct_encode`'s pair."""
+        """The stream's direct pass: the fused host kernel's frames,
+        else `direct_encode`'s pair."""
         k, m = self.k, self.m
         if self.fused_host is None:
             return self.sm.direct_encode(blocks, k, m, self.algo)
-        if not self.double_buffer:
-            return self.fused_host.put_frame(blocks, k, m)
-        nb = blocks.shape[0]
-        per = BATCH_BLOCKS * self.frame_len
-        if self._arenas is None:
-            self._arenas = _db_arenas((k + m) * per)
-        a = self._arenas[self._flip]
-        self._flip ^= 1
-        outs = [a[i * per:i * per + nb * self.frame_len]
-                for i in range(k + m)]
-        return self.fused_host.put_frame(blocks, k, m, outs=outs)
+        per = blocks.shape[0] * self.frame_len
+        a = self._out(blocks.shape[0])
+        return self.fused_host.put_frame(
+            blocks, k, m,
+            outs=[a[i * per:(i + 1) * per] for i in range(k + m)])
 
     def frames(self, p: tuple) -> list:
         """n framed shard-chunks of a batch `encode` started."""
@@ -597,11 +620,12 @@ class Encoder:
         parity, digests = out
         # np.asarray here is the device sync point; by the time we
         # take it, the NEXT batch's dispatch is already in flight.
-        # frame_shard_views fills the framed layout in one pass and
-        # returns zero-copy per-shard views.
+        # frame_shard_views fills the framed layout in one pass, into
+        # the stream's buffer, and returns zero-copy per-shard views.
         if digests is not None:
             digests = np.asarray(digests)
         parity = np.asarray(parity)
         with ospan.span("engine.frame"):
             return bitrot_io.frame_shard_views(
-                blocks, parity, digests, self.algo)
+                blocks, parity, digests, self.algo,
+                out=self._out(blocks.shape[0]))

@@ -235,6 +235,9 @@ class DataPathStats:
             self.decode_blocks = 0
             self._decode_patterns: set[tuple] = set()
             self.stage_pad_bytes = 0
+            # Bytes of framing and digest-stabilising buffers PUT
+            # streams allocated anew (never the reuse).
+            self.put_fresh_buffer_bytes = 0
             # Cross-process dispatch (ops/ipc_dispatch.py, worker pool):
             # items shipped to the device owner, results received,
             # fallbacks (arena/ring full -> computed locally), and
@@ -430,6 +433,15 @@ class DataPathStats:
         block layout (engine/erasure_set.py: `engine.stage`)."""
         with self._mu:
             self.stage_pad_bytes += nbytes
+
+    def record_put_fresh_buffer(self, nbytes: int) -> None:
+        """A PUT stream allocated `nbytes` anew for a per-batch buffer:
+        a framing buffer's first acquisition or growth
+        (engine/shardmath.py), a copy that stabilises a digest piece
+        (utils/streams.py, utils/digestlanes.py).  Reuse never
+        counts."""
+        with self._mu:
+            self.put_fresh_buffer_bytes += nbytes
 
     def record_co_fault(self, members: int) -> None:
         """A coalesced dispatch raised; `members` spans were retried
@@ -646,6 +658,7 @@ class DataPathStats:
                 "decode_blocks": self.decode_blocks,
                 "decode_patterns": len(self._decode_patterns),
                 "stage_pad_bytes": self.stage_pad_bytes,
+                "put_fresh_buffer_bytes": self.put_fresh_buffer_bytes,
                 "ipc_submits": self.ipc_submits,
                 "ipc_rows": self.ipc_rows,
                 "ipc_results": self.ipc_results,
@@ -869,6 +882,12 @@ class MetricsRegistry:
             "mtpu_stage_pad_bytes_total",
             "Bytes of PUT bodies copied into the zero-padded block "
             "layout (engine.stage: a K that does not divide 1 MiB)")
+        self.put_fresh_buffer_bytes = Gauge(
+            "mtpu_put_fresh_buffer_bytes_total",
+            "Bytes of per-batch framing and digest-stabilising buffers "
+            "PUT streams allocated anew (first use, growth, a copy of "
+            "a digest piece); 0 while every batch reuses what its "
+            "thread and its ring already hold")
         # Cross-process dispatch families (worker pool, PR 9).
         self.ipc_submits = Gauge(
             "mtpu_ipc_dispatch_submits_total",
@@ -1676,6 +1695,7 @@ class MetricsRegistry:
         self.decode_blocks.set(snap["decode_blocks"])
         self.decode_patterns.set(snap["decode_patterns"])
         self.stage_pad_bytes.set(snap["stage_pad_bytes"])
+        self.put_fresh_buffer_bytes.set(snap["put_fresh_buffer_bytes"])
         self.ipc_submits.set(snap["ipc_submits"])
         self.ipc_results.set(snap["ipc_results"])
         self.ipc_fallbacks.set(snap["ipc_fallbacks"])

@@ -249,7 +249,8 @@ def frame_shard_views(blocks: np.ndarray | None,
                       parity: np.ndarray | None,
                       digests: np.ndarray | None,
                       algo: str = DEFAULT_ALGO,
-                      shards: np.ndarray | None = None) -> list[np.ndarray]:
+                      shards: np.ndarray | None = None,
+                      out: np.ndarray | None = None) -> list[np.ndarray]:
     """The ONE implementation of the on-disk frame layout
     ([32B digest | shard bytes] per block), producing zero-copy
     per-shard views over a single (n_shards, n_blocks, hs+S) buffer.
@@ -258,12 +259,31 @@ def frame_shard_views(blocks: np.ndarray | None,
     ((n_shards, n_blocks, S)), or `blocks`/`parity` in the codec's
     block-major layout ((n_blocks, K, S) and (n_blocks, M, S)) —
     the latter avoids the caller materializing a transposed copy.
-    Digests, when absent, are hashed from the contiguous inputs."""
+    Digests, when absent, are hashed from the contiguous inputs.
+
+    `out`: the buffer to fill, a contiguous 1-D uint8 array of at least
+    n_shards * n_blocks * (hs + S) bytes that the caller owns (a PUT
+    stream's reused one: engine/shardmath.py); the views are over its
+    head and live as long as the caller leaves it alone.  Without one
+    a fresh buffer is allocated per call."""
     hs = digest_size(algo)
     if shards is not None:
         n_shards, n_blocks, shard_size = shards.shape
-        framed = np.empty((n_shards, n_blocks, hs + shard_size),
-                          dtype=np.uint8)
+    else:
+        n_blocks, k, shard_size = blocks.shape
+        n_shards = k + parity.shape[1]
+    shape = (n_shards, n_blocks, hs + shard_size)
+    if out is None:
+        framed = np.empty(shape, dtype=np.uint8)
+    else:
+        need = shape[0] * shape[1] * shape[2]
+        if (out.dtype != np.uint8 or out.ndim != 1
+                or not out.flags.c_contiguous or out.size < need):
+            raise ValueError(
+                f"frame_shard_views out: need {need} contiguous uint8 "
+                f"bytes, got {out.dtype}{out.shape}")
+        framed = out[:need].reshape(shape)
+    if shards is not None:
         framed[:, :, hs:] = shards
         if digests is None:
             flat = np.ascontiguousarray(shards).reshape(
@@ -273,9 +293,7 @@ def frame_shard_views(blocks: np.ndarray | None,
         framed[:, :, :hs] = digests
         return [framed[i].reshape(-1) for i in range(n_shards)]
 
-    nb, k, shard_size = blocks.shape
-    m = parity.shape[1]
-    framed = np.empty((k + m, nb, hs + shard_size), dtype=np.uint8)
+    nb, m = n_blocks, n_shards - k
     framed[:k, :, hs:] = blocks.transpose(1, 0, 2)
     framed[k:, :, hs:] = parity.transpose(1, 0, 2)
     if digests is not None:

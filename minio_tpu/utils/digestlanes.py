@@ -8,8 +8,11 @@ SSE2 4-wide), so the aggregate rate on one core is lane-parallel.  This
 module owns the process-wide scheduler that multiplexes PipelinedMD5
 streams onto those shared lanes:
 
-  * producers append pieces to their stream (zero-copy: the views are
-    held, not copied, same contract as the hashlib queue path);
+  * producers append pieces to their stream (zero-copy: immutable
+    views are held, not copied; a writable one is held too where its
+    owner lends it and waits for `wait_consumed` before it overwrites,
+    as the PUT-ingest ring of utils/streams.py does, and copied
+    otherwise);
   * one worker thread carves 64-byte-aligned runs from EVERY active
     stream and advances them all in ONE GIL-released native call;
   * finalize appends the RFC 1321 padding into the same lockstep call,
@@ -66,14 +69,16 @@ def use_native() -> bool:
 
 
 class _Stream:
-    __slots__ = ("pieces", "carry", "total", "pending", "finalizing",
-                 "row", "done", "result", "error")
+    __slots__ = ("pieces", "carry", "total", "pending", "consumed",
+                 "busy", "finalizing", "row", "done", "result", "error")
 
     def __init__(self, row: int):
         self.pieces: list = []
         self.carry = b""
         self.total = 0
         self.pending = 0           # bytes queued but not yet hashed
+        self.consumed = 0          # leading bytes no piece is held of
+        self.busy = False          # a tick in flight holds pieces of it
         self.finalizing = False
         self.row = row
         self.done = threading.Event()
@@ -100,11 +105,10 @@ class LaneScheduler:
         self._cap = 16
         self._states = np.empty((self._cap, 4), dtype=np.uint32)
         self._free = list(range(self._cap))
-        # rows the worker's in-flight native call is writing: their
-        # reuse is deferred to tick end so open() can never hand a row
-        # to a new stream while the (lock-free) native update still
-        # targets it
-        self._inflight_rows: set[int] = set()
+        # a row the worker's in-flight native call is writing (its
+        # stream is `busy`) is given back only at tick end, so open()
+        # can never hand it to a new stream while the (lock-free)
+        # native update still targets it
         self._deferred_free: list[int] = []
         self._thread: threading.Thread | None = None
         self._tick_cap = int(os.environ.get(
@@ -134,19 +138,42 @@ class LaneScheduler:
                 self._thread.start()
             return s
 
-    def update(self, s: _Stream, piece) -> None:
-        if not isinstance(piece, (bytes, memoryview)):
-            piece = bytes(piece)     # bytearray callers may mutate after
-        elif isinstance(piece, memoryview) and not piece.readonly:
-            piece = bytes(piece)     # pooled-ring views recycle underneath
+    def update(self, s: _Stream, piece, lent: bool = False) -> None:
+        """Queue `piece` behind what the stream already holds.  The
+        scheduler keeps the object until a tick has hashed it, so what
+        may change underneath is copied first (a bytearray, a writable
+        view) — unless it is `lent`: the caller then leaves the bytes
+        alone until `wait_consumed` says the scheduler is done with
+        them."""
+        if not isinstance(piece, (bytes, memoryview)) or (
+                isinstance(piece, memoryview) and not piece.readonly
+                and not lent):
+            piece = bytes(piece)
+            self._dp.record_put_fresh_buffer(len(piece))
         with self._cv:
             while (s.pending > self._max_pending and not s.finalizing
                    and s.error is None):
                 self._cv.wait(timeout=1.0)
+            s.total += len(piece)
+            if s.error is not None:         # hashes no further: not kept
+                if not s.busy:
+                    s.consumed = s.total
+                return
+            if not len(piece):
+                return
             s.pieces.append(piece)
             s.pending += len(piece)
-            s.total += len(piece)
             self._cv.notify_all()
+
+    def wait_consumed(self, s: _Stream, upto: int) -> None:
+        """Block until the first `upto` bytes handed to `update` are
+        hashed (or copied into the stream's sub-block carry): no tick
+        reads those pieces' memory any more, so their owner may
+        overwrite or free it.  This wait, and nothing about ring depth
+        or the lanes' speed, is what makes a lent piece safe."""
+        with self._cv:
+            while s.consumed < upto:
+                self._cv.wait()
 
     def finalize_async(self, s: _Stream) -> None:
         """Ask the worker to pad+close the stream without waiting for
@@ -184,13 +211,25 @@ class LaneScheduler:
         with self._cv:
             if s in self._streams:
                 self._streams.discard(s)
-                if s.row in self._inflight_rows:
+                if s.busy:
                     self._deferred_free.append(s.row)
                 else:
                     self._free.append(s.row)
                 s.error = RuntimeError("digest stream abandoned")
+                if not s.busy:
+                    self._drop_locked(s)    # else: at that tick's end
                 s.done.set()
                 self._cv.notify_all()
+
+    @staticmethod
+    def _drop_locked(s: _Stream) -> None:
+        """A failed or abandoned stream hashes no further: let go of
+        what it still has queued.  Only while no tick holds pieces of
+        it (`consumed` is a prefix: it may not pass a piece in
+        flight)."""
+        s.pending -= sum(len(p) for p in s.pieces)
+        s.pieces.clear()
+        s.consumed = s.total
 
     # -- worker side ---------------------------------------------------------
 
@@ -203,7 +242,8 @@ class LaneScheduler:
                     work = self._collect_locked()
                 states = self._states
                 nrows = self._cap
-                self._inflight_rows = {s.row for s, *_ in work}
+                for s, *_ in work:
+                    s.busy = True
             chunks = [b""] * nrows
             closing = []
             for s, pieces, carry, finalizing, total in work:
@@ -261,9 +301,14 @@ class LaneScheduler:
                     # whole collected run is consumed here, and any
                     # unhashed remainder was re-added when s.carry was
                     # set during assembly
-                    s.pending -= sum(len(p) for p in pieces) + len(carry)
+                    taken = sum(len(p) for p in pieces)
+                    s.pending -= taken + len(carry)
+                    s.consumed += taken
+                    s.busy = False
                     if err is not None:
                         s.error = err
+                    if s.error is not None:
+                        self._drop_locked(s)
                 for s, total in closing:
                     if s in self._streams:
                         self._streams.discard(s)
@@ -274,7 +319,6 @@ class LaneScheduler:
                         s.done.set()
                 self._free.extend(self._deferred_free)
                 self._deferred_free.clear()
-                self._inflight_rows.clear()
                 self._cv.notify_all()
 
     def _collect_locked(self):
@@ -293,6 +337,14 @@ class LaneScheduler:
                     carry = s.carry
                     s.carry = b""
                     work.append((s, take, carry, s.finalizing, s.total))
+            elif s.pieces:
+                # Under one block in all: keep the bytes, not the
+                # pieces, so that nobody waits (`wait_consumed`) for a
+                # tick that only more bytes or a finalize would bring.
+                s.consumed += avail - len(s.carry)
+                s.carry += b"".join(s.pieces)
+                s.pieces.clear()
+                self._cv.notify_all()
         return work
 
 

@@ -60,6 +60,7 @@ class PipelinedMD5:
         from . import digestlanes
         self._stream = None
         self._hex = None
+        self._lent = False       # writable views are lent, not copied
         if digestlanes.use_native():
             self._sched = digestlanes.scheduler()
             self._stream = self._sched.open()
@@ -80,18 +81,40 @@ class PipelinedMD5:
             h.update(piece)
 
     def update(self, piece) -> None:
-        # Writable views are VOLATILE: the pooled PUT-ingest ring
-        # (batched_chunks) recycles its buffers after a few pulls, and
-        # both digest engines hold queued pieces instead of consuming
-        # them synchronously — stabilize with one copy here.  Immutable
-        # pieces (bytes, readonly views from the bytes path) stay
-        # zero-copy as before.
+        # Both digest engines hold queued pieces instead of consuming
+        # them synchronously, and a writable view is VOLATILE (the
+        # pooled PUT-ingest ring refills its buffers).  Once the ring
+        # has borrowed this digest (`lend`) it is held as it is: the
+        # ring waits for `wait_consumed` before it refills.  From
+        # anyone else it is stabilized with one copy here, a fresh
+        # buffer a piece (counted).  Immutable pieces (bytes, readonly
+        # views from the bytes path) are never copied.
+        if self._stream is not None:
+            self._sched.update(self._stream, piece, lent=self._lent)
+            return
         if isinstance(piece, memoryview) and not piece.readonly:
             piece = bytes(piece)
-        if self._stream is not None:
-            self._sched.update(self._stream, piece)
-        else:
-            self._q.put(piece)
+            DATA_PATH.record_put_fresh_buffer(len(piece))
+        self._q.put(piece)
+
+    def lend(self) -> bool:
+        """The caller owns the writable views this digest will be fed
+        and promises `wait_consumed(queued())` before it overwrites or
+        frees what it has fed so far: `update` then holds them without
+        a copy.  False where the engine cannot say when it is done with
+        a piece (the hashlib oracle's queue): it goes on copying and
+        there is nothing to wait for."""
+        self._lent = self._stream is not None
+        return self._lent
+
+    def queued(self) -> int:
+        """Bytes handed to `update` so far (the lanes' count)."""
+        return self._stream.total
+
+    def wait_consumed(self, upto: int) -> None:
+        """Block until the first `upto` bytes handed to `update` are
+        hashed (utils/digestlanes.py: `wait_consumed`)."""
+        self._sched.wait_consumed(self._stream, upto)
 
     def feed(self, data, chunk_len: int = 1 << 20) -> None:
         """Queue an entire in-memory body as chunk-sized views (no
@@ -454,6 +477,8 @@ class HTTPChunkedReader:
 #: _RING_DEPTH - 1 further pulls.  The encode pipeline holds at most
 #: one batch pending (chunk i is consumed while chunk i+1 is read), so
 #: 2 would suffice; 4 leaves margin for a prefetching stage pipeline.
+#: The ETag digest is no such consumer: what it was fed from a slot is
+#: waited for before the slot is refilled, however deep the ring.
 _RING_DEPTH = 4
 
 
@@ -482,16 +507,24 @@ def _fill_from(stream, view) -> int:
     return filled
 
 
-def _pooled_chunks(head: bytes, stream, chunk_len: int):
+def _pooled_chunks(head: bytes, stream, chunk_len: int, digest=None):
     """Streaming chunker over a ring of page-aligned buffer-pool leases
     (the PUT-ingest half of MTPU_ZEROCOPY): each chunk is filled in
     place via readinto instead of per-piece bytes allocs plus a final
     bytes() copy.  Yields writable memoryviews — valid until
-    _RING_DEPTH - 1 further pulls; consumers that defer (PipelinedMD5's
-    digest queue) stabilize volatile views with one copy on their side."""
+    _RING_DEPTH - 1 further pulls.
+
+    `digest` (a PipelinedMD5 the consumer feeds every chunk to before
+    it pulls the next) may defer: the ring lends it the views, and
+    refills a slot, or gives its leases back, only after the digest
+    has consumed all it was fed while that slot's chunk was out.  Any
+    other consumer that defers stabilizes the view with a copy of its
+    own (PipelinedMD5 does, where nothing was lent)."""
     from ..ops import bpool
     pool = bpool.default_pool()
     slots: list = [None] * _RING_DEPTH
+    lent = digest is not None and digest.lend()
+    fed = [0] * _RING_DEPTH     # digest.queued() when slot's chunk came back
     try:
         carry = memoryview(head)
         i = 0
@@ -499,6 +532,8 @@ def _pooled_chunks(head: bytes, stream, chunk_len: int):
             slot = i % _RING_DEPTH
             if slots[slot] is None:
                 slots[slot] = pool.get(chunk_len)
+            elif lent:
+                digest.wait_consumed(fed[slot])
             view = memoryview(slots[slot].view)
             pre = min(len(carry), chunk_len)
             if pre:
@@ -514,17 +549,26 @@ def _pooled_chunks(head: bytes, stream, chunk_len: int):
                 yield view[:filled], True    # final chunk (may be empty)
                 return
             yield view, False
+            if lent:
+                fed[slot] = digest.queued()
             i += 1
     finally:
+        if lent:
+            # The lanes may still be hashing the last chunks: the
+            # leases go back (to be overwritten by whoever leases them
+            # next) only when nothing of this body is read any more.
+            digest.wait_consumed(digest.queued())
         for lease in slots:
             if lease is not None:
                 lease.release()
 
 
-def batched_chunks(head: bytes, stream, chunk_len: int):
+def batched_chunks(head: bytes, stream, chunk_len: int, digest=None):
     """Yield (chunk, is_last) with every chunk exactly chunk_len bytes
     except the final one (which may be empty when the total length is an
-    exact multiple).  `head` is bytes already consumed from `stream`."""
+    exact multiple).  `head` is bytes already consumed from `stream`.
+    `digest`: the PipelinedMD5 the consumer feeds each chunk to before
+    it pulls the next one, where it has one (`_pooled_chunks`)."""
     if stream is None:
         # Pure-bytes source: zero-copy memoryview windows (the caller's
         # numpy frombuffer views them without materializing).
@@ -537,7 +581,7 @@ def batched_chunks(head: bytes, stream, chunk_len: int):
         return
     from ..ops import zerocopy as _zc
     if _zc.zerocopy_enabled():
-        yield from _pooled_chunks(head, stream, chunk_len)
+        yield from _pooled_chunks(head, stream, chunk_len, digest)
         return
     buf = bytearray(head)
     eof = False

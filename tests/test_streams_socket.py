@@ -402,7 +402,9 @@ def test_one_pull_is_one_native_call(tmp_path, monkeypatch):
     finally:
         srv.shutdown()
     assert got["pulls"] == {"native": 1, "buffered": 0}
-    assert len(calls) == 1 and calls[0] > 32 * MIB - 8 * KIB
+    # All but what rfile held: at most its 8 KiB buffer, and exactly
+    # that where the header parse left it empty and peek() filled it.
+    assert len(calls) == 1 and calls[0] >= 32 * MIB - 8 * KIB
     assert [s["tags"]["path"] for s in tagged] == ["native"]
     assert tagged[0]["tags"]["recvs"] == got["recvs"]["native"] >= 1
 
