@@ -45,6 +45,10 @@ class Client:
         self.ops = traffic.op_blocks(spec["seed"], self.idx, self.wl["mix"])
         self.pick = traffic.picker(spec["seed"], self.idx)
         self.live: list[tuple[str, int]] = []     # (key, generation)
+        # Where the mix says so, every GET's body lands in this one buffer
+        # and is compared there; else in fresh bytes a GET.
+        self.got = bytearray(self.wl["object_bytes"]) \
+            if self.wl.get("reuse_get_buffer") else None
         self.nput = 0                             # PUTs begun, ever
         self.records: list[dict] = []
         self.phase = "setup"
@@ -98,7 +102,8 @@ class Client:
 
     def get(self, key: str, gen: int) -> None:
         got = self._timed("GET", key, self.wl["object_bytes"],
-                          lambda: self.cli.get_object(self.bucket, key),
+                          lambda: self.cli.get_object(self.bucket, key,
+                                                      into=self.got),
                           gen=gen)
         if got is not None:
             # Compared after the clock stopped: the request's time is the

@@ -95,14 +95,21 @@ def parity_rows(k: int, m: int) -> np.ndarray:
     return full[k:].copy()
 
 
-def encode_block(block: bytes | memoryview, k: int, m: int) -> np.ndarray:
-    """One erasure block (<= 1 MiB) -> (k+m, ceil(len/k)) shard rows:
-    the data split in k, zero-padded, then the m parity rows."""
+def data_rows(block: bytes | memoryview, k: int) -> np.ndarray:
+    """One erasure block (<= 1 MiB) -> its (k, ceil(len/k)) data rows: the
+    block split in k, zero-padded.  The code is systematic, so these are
+    the shard blocks of data shards 1..k as written."""
     buf = np.frombuffer(block, dtype=np.uint8)
     s = -(-buf.size // k)
     data = np.zeros(k * s, dtype=np.uint8)
     data[:buf.size] = buf
-    data = data.reshape(k, s)
+    return data.reshape(k, s)
+
+
+def encode_block(block: bytes | memoryview, k: int, m: int) -> np.ndarray:
+    """One erasure block (<= 1 MiB) -> (k+m, ceil(len/k)) shard rows:
+    the data rows, then the m parity rows."""
+    data = data_rows(block, k)
     return np.concatenate([data, _gf_matmul(parity_rows(k, m), data)])
 
 

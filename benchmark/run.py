@@ -15,7 +15,9 @@ in a private mount namespace (`ram_backed_dir`).  A run writes nothing to the
 machine's disk but the compile cache, and nothing outside its checkout, HOME
 and TMPDIR.
 
-Phases: boot -> configure -> prefill -> warm-up (every kind of request the
+Phases: boot -> configure -> prefill -> where the mix says `hide_shards`,
+that many data shards of every prefilled object are taken off the drives
+(`hide_shards`) -> warm-up (every kind of request the
 cell sends, at the cell's concurrency) -> barrier -> window of --seconds ->
 drain (requests begun in the window are waited for) -> checks of what the
 window wrote and read (`benchmark/reference.py`) -> SIGTERM, exit code 0
@@ -433,18 +435,106 @@ def disk_files(srv: Server, key: str, part: int) -> list[bytes]:
     return files
 
 
+def sample_in_turn(rng, objects: list[tuple]) -> list[tuple]:
+    """`objects` ((client, ...) tuples) in an order drawn from `rng` that
+    takes the clients in turn: the first `clients` of them hold every
+    client's stream."""
+    per_client: dict[int, list] = {}
+    for i in rng.permutation(len(objects)):
+        per_client.setdefault(objects[i][0], []).append(objects[i])
+    return [w for turn in itertools.zip_longest(*per_client.values())
+            for w in turn if w is not None]
+
+
+def first_blocks(seed: int, wl: dict) -> dict:
+    """{(client, n): the first erasure block of the body} of every
+    client's n-th prefilled object, made again from the seed."""
+    out = {}
+    for c in range(wl["clients"]):
+        b = traffic.Bodies(seed, c, wl)
+        for n in range(wl["prefill_per_client"]):
+            tag, rest = b.chunks(traffic.new_key(c, n), 0, 0)
+            out[c, n] = tag + bytes(rest[:reference.BLOCK - len(tag)])
+    return out
+
+
+def data_shard_files(drives: list[str], key: str, head: bytes,
+                     k: int) -> dict[int, str]:
+    """{data shard i (0-based): its `part.1`} of an object whose first
+    erasure block is `head`.  Which drive holds which shard is the
+    program's choice, so the files are told apart by content: the code is
+    systematic, and the first frame of data shard i's file is [digest | row
+    i of that block] (`reference.data_rows`).  A shard on no drive is left
+    out."""
+    rows = reference.data_rows(head, k)
+    where: dict[int, str] = {}
+    for d in drives:
+        for path in glob.glob(os.path.join(d, BUCKET, key, "*", "part.1")):
+            with open(path, "rb") as f:
+                row = np.frombuffer(f.read(reference.DIGEST + rows.shape[1]),
+                                    dtype=np.uint8)[reference.DIGEST:]
+            where.update((i, path) for i in range(k)
+                         if np.array_equal(row, rows[i]))
+    return where
+
+
+def hide_shards(srv: Server, seed: int, wl: dict, cfg: dict,
+                heads: dict) -> dict:
+    """Take `hide_shards` data shards of every prefilled object off the
+    drives: their `part.1` is unlinked, xl.meta stays.  Which shards go is
+    drawn from (seed, object) (`traffic.hidden_shards`).
+
+    First the look that an acknowledged PUT is owed, on the very objects
+    the window will read: the K+M shard files of a sample of them (drawn
+    from the seed, clients in turn) are read whole, and `check_correct`
+    holds them against the reference once the window has closed.  A drive
+    without the file, or a data shard on no drive, is that look's to report
+    (`shards_missing`), not this step's.
+
+    Returns {"looked": [(client, key, files)], "paths": the files
+    unlinked}."""
+    k, h = cfg["data_shards"], wl["hide_shards"]
+    order = sample_in_turn(np.random.default_rng([seed, 0xC0DE]),
+                           sorted(heads))
+    looked = []
+    for c, n in order[:wl["check"]["disk_parts"]]:
+        key = traffic.new_key(c, n)
+        looked.append((c, key, disk_files(srv, key, 1)))
+    paths = []
+    for (c, n), head in sorted(heads.items()):
+        where = data_shard_files(srv.drives, traffic.new_key(c, n), head, k)
+        for i in traffic.hidden_shards(seed, c, n, k, h):
+            if i in where:
+                os.unlink(where[i])
+                paths.append(where[i])
+    return {"looked": looked, "paths": paths}
+
+
 def check_correct(srv: Server, seed: int, wl: dict, cfg: dict,
-                  win: list[dict], live: list[list],
-                  fallbacks: float) -> tuple[bool, dict]:
+                  win: list[dict], live: list[list], grew,
+                  hidden: dict | None = None) -> tuple[bool, dict]:
     """Every number compared, beside its limit.  All are exact
-    comparisons: the limit is 0 (and a floor of 1 on what was compared)."""
+    comparisons: the limit is 0 (and a floor of 1 on what was compared).
+    `grew(family)` is a counter's growth over the window; `hidden` is
+    what `hide_shards` returned, where the cell hides shards."""
     k, m = cfg["data_shards"], cfg["parity_shards"]
     gets = [r for r in win if r["op"] == "GET" and r["ok"]]
     nums = {
         "requests_failed": sum(not r["ok"] for r in win),
         "get_mismatch": sum(not r["match"] for r in gets),
-        "fallbacks": fallbacks,
+        "fallbacks": sum(grew(n) for n in FALLBACK_COUNTERS),
     }
+    if hidden is not None:
+        # Every block of every GET had rows rebuilt: no read found its K
+        # data shards, no cache or shortcut answered, and nothing healed
+        # under the measurement.
+        nums.update(
+            gets_served_healthy=grew("mtpu_healthy_reads_total"),
+            decode_blocks_short=max(
+                0.0, len(gets) * (wl["object_bytes"] // MIB)
+                - grew("mtpu_decode_blocks_total")),
+            hidden_files_back=sum(os.path.exists(p)
+                                  for p in hidden["paths"]))
     # Objects the window wrote whole and that are still the newest under
     # their key: a sample drawn from the seed, read back through the front
     # door and looked up on every drive.  The sample takes the clients in
@@ -458,11 +548,7 @@ def check_correct(srv: Server, seed: int, wl: dict, cfg: dict,
     alive = sorted(w for w in written
                    if [w[1], w[2]] in live[w[0]])
     rng = np.random.default_rng([seed, 0xC0DE])
-    per_client: dict[int, list] = {}
-    for i in rng.permutation(len(alive)):
-        per_client.setdefault(alive[i][0], []).append(alive[i])
-    order = [w for turn in itertools.zip_longest(*per_client.values())
-             for w in turn if w is not None]
+    order = sample_in_turn(rng, alive)
     parts = traffic.parts_of(wl)
     frames = bad_bytes = bad_digest = missing = readback_bad = 0
     nread = nparts = 0
@@ -474,11 +560,17 @@ def check_correct(srv: Server, seed: int, wl: dict, cfg: dict,
         got = srv.client.get_object(BUCKET, key)
         readback_bad += not b.matches(got, key, gen, wl)
         nread += 1
-    for c, key, gen, p in todo[:wl["check"]["disk_parts"]]:
+    # Where shards were hidden, the files are those read before that.
+    held = [(c, key, 0, 0, files)
+            for c, key, files in (hidden or {}).get("looked", [])]
+    held += [(c, key, gen, p, None)
+             for c, key, gen, p in todo[:wl["check"]["disk_parts"]]]
+    for c, key, gen, p, files in held:
         b = bodies.setdefault(c, traffic.Bodies(seed, c, wl))
         body = b"".join(b.chunks(key, p, gen))
-        res = reference.compare_part(body, k, m,
-                                     disk_files(srv, key, max(p, 1)))
+        res = reference.compare_part(
+            body, k, m, disk_files(srv, key, max(p, 1)) if files is None
+            else files)
         frames += res["frames"]
         bad_bytes += res["bad_bytes"]
         bad_digest += res["bad_digest"]
@@ -507,7 +599,9 @@ def check_correct(srv: Server, seed: int, wl: dict, cfg: dict,
         # What is compared has to be there to compare.
         floor = 0 if (name == "gets_compared" and not wl["mix"].get("GET")
                       or name == "deleted_compared"
-                      and not wl["mix"].get("DELETE")) else 1
+                      and not wl["mix"].get("DELETE")
+                      or name == "readbacks_compared"
+                      and not wl["mix"].get("PUT")) else 1
         table[name] = {"value": v, "at_least": floor}
         ok = ok and v >= floor
     return ok, table
@@ -554,9 +648,16 @@ def read_metric(m: dict, q: dict, cfg: dict) -> float | None:
         data = quantity(m["bytes"], q)
         if not busy or not data:
             return None
-        least = work.least_seconds(data, cfg["data_shards"],
-                                   cfg["parity_shards"], q["device_kind"])
-        return 100.0 * least["seconds"] / busy
+        if m.get("work", "encode") == "encode":
+            w = work.encode_work(data, cfg["data_shards"],
+                                 cfg["parity_shards"])
+        elif m["work"] == "decode" and q["hidden"]:
+            # Every decode of a cell that hides shards rebuilds that many.
+            w = work.decode_work(data, cfg["data_shards"], q["hidden"])
+        else:
+            return None
+        return 100.0 * work.least_seconds(w, q["device_kind"])["seconds"] \
+            / busy
     raise RunFailure(f"metrics/{m['name']}.json: unknown kind {kind!r}")
 
 
@@ -598,8 +699,13 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool,
         srv.configure()
         clients.gather()
         t_pool = time.monotonic()
-        filled = clients.all("prefill")
+        clients.tell("prefill")
+        hide = wl.get("hide_shards", 0)
+        heads = first_blocks(seed, wl) if hide else None    # meanwhile
+        filled = clients.gather()
         t_prefill = time.monotonic()
+        hidden = hide_shards(srv, seed, wl, cfg, heads) if hide else None
+        t_hidden = time.monotonic()
         warmed = clients.all("warmup")
         if trace:
             srv.ask("trace.start", "trace.started")
@@ -612,8 +718,10 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool,
         log(f"setup boot_s={t_boot - T_START:.3f} "
             f"pool_wait_s={t_pool - t_boot:.3f} "
             f"prefill_s={t_prefill - t_pool:.3f} "
-            f"warmup_s={t0 - t_prefill:.3f} setup_s={setup_s:.3f} "
+            f"hide_s={t_hidden - t_prefill:.3f} "
+            f"warmup_s={t0 - t_hidden:.3f} setup_s={setup_s:.3f} "
             f"prefilled={sum(f['objects'] for f in filled)} "
+            f"hidden={len(hidden['paths']) if hidden else 0} "
             f"warmup_requests={sum(w['requests'] for w in warmed)}")
 
         ran = clients.all(f"run {t0!r} {t1!r}")        # window + drain
@@ -638,18 +746,17 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool,
             f"gbps_per_5s={timeline(win, t0, t1)} "
             f"ops={json.dumps(op_table(win))}")
 
-        fallbacks = sum((counter(m1, n) or 0) - (counter(m0, n) or 0)
-                        for n in FALLBACK_COUNTERS)
         t_check = time.monotonic()
         correct, compared = check_correct(
-            srv, seed, wl, cfg, win, [r["live"] for r in ran], fallbacks)
+            srv, seed, wl, cfg, win, [r["live"] for r in ran],
+            lambda n: (counter(m1, n) or 0) - (counter(m0, n) or 0), hidden)
         log(f"check seconds={time.monotonic() - t_check:.3f}")
         rc = srv.stop()
         need(rc == 0, f"the server exited {rc} on SIGTERM:\n{srv.log_tail()}")
 
         q = {"metrics0": m0, "metrics1": m1, "device_kind": device["kind"],
              "client": client_quantities(win, t0, t_drained),
-             "proc": {"cpu_s": cpu1 - cpu0}, "trace": None}
+             "proc": {"cpu_s": cpu1 - cpu0}, "trace": None, "hidden": hide}
         out_device = {"platform": device["platform"], "kind": device["kind"],
                       "count": device["count"],
                       "memory_peak_bytes": device["memory_peak_bytes"]}

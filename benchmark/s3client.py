@@ -93,10 +93,13 @@ class S3Client:
     def request(self, method: str, path: str,
                 query: dict[str, str] | None = None, body=b"",
                 headers: dict[str, str] | None = None,
-                unsigned_len: int | None = None):
+                unsigned_len: int | None = None, into=None):
         """`body` is bytes, signed by its SHA-256; or, with `unsigned_len`
         set, an iterable of byte chunks of that total length sent as
-        UNSIGNED-PAYLOAD (never hashed or joined on this side)."""
+        UNSIGNED-PAYLOAD (never hashed or joined on this side).  `into` is
+        a writable buffer of the caller's: a 200's body that fits is read
+        into it and returned as a view of it, so a client that reads 64 MiB
+        objects in a loop maps no fresh 64 MiB for each."""
         query = dict(query or {})
         headers = dict(headers or {})
         headers["Host"] = f"{self.host}:{self.port}"
@@ -118,7 +121,18 @@ class S3Client:
                 self._conn.request(method, url, body=body or None,
                                    headers=headers)
                 resp = self._conn.getresponse()
-                data = resp.read()
+                size = resp.length
+                if (into is None or resp.status != 200 or size is None
+                        or size > len(into)):
+                    data = resp.read()
+                else:
+                    data, got = memoryview(into)[:size], 0
+                    while got < size:
+                        n = resp.readinto(data[got:])
+                        if not n:
+                            raise http.client.IncompleteRead(
+                                bytes(data[:got]), size - got)
+                        got += n
             except (http.client.RemoteDisconnected, BrokenPipeError,
                     ConnectionResetError):
                 # The server closed or reset the connection before it
@@ -162,8 +176,10 @@ class S3Client:
                                       unsigned_len=size))
         return h.get("ETag", "").strip('"')
 
-    def get_object(self, bucket: str, key: str) -> bytes:
-        return self._ok(*self.request("GET", f"/{bucket}/{key}"))[1]
+    def get_object(self, bucket: str, key: str, into=None):
+        """The object's bytes; a view of `into` where it holds them."""
+        return self._ok(*self.request("GET", f"/{bucket}/{key}",
+                                      into=into))[1]
 
     def head_object(self, bucket: str, key: str) -> dict:
         status, h, _ = self.request("HEAD", f"/{bucket}/{key}")

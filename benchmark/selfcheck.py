@@ -10,6 +10,7 @@ from __future__ import annotations
 import json
 import os
 import sys
+import tempfile
 
 HERE = os.path.dirname(os.path.abspath(__file__))
 sys.path.insert(0, HERE)
@@ -40,9 +41,22 @@ def main() -> int:
     # work.py against ROADMAP Speed 7's two figures for EC:8+4.
     assert close(work.bf16_parity_gbps(4, V5E), 385.0), "bf16-bound GB/s"
     assert close(work.hbm_gbps(8, 4, V5E), 546.0), "HBM-bound GB/s"
-    least = work.least_seconds(1e9, 8, 4, V5E)
+    least = work.least_seconds(work.encode_work(1e9, 8, 4), V5E)
     assert least["bound"] == "hbm" and close(least["seconds"], 1.5 / 819,
                                              1e-3), least
+    # A degraded GET at 8+4 with two data shards rebuilt: D in, D/4 out, so
+    # HBM binds at 819 / 1.25 GB/s of GET data; with all M rows rebuilt the
+    # work is an encode's parity (less the digests of the M rows written).
+    assert close(work.hbm_gbps(8, 2, V5E), 655.2, 1e-6), "decode HBM GB/s"
+    least = work.least_seconds(work.decode_work(1e9, 8, 2), V5E)
+    assert least["bound"] == "hbm" and close(least["seconds"], 1.25 / 819,
+                                             1e-3), least
+    dec, enc = work.decode_work(1e9, 8, 4), work.encode_work(1e9, 8, 4)
+    mxh = work.MXH_OPS_PER_BYTE * 1e9
+    assert close(dec["ops"] - mxh, 128.0 * 4 * 1e9, 1e-9)
+    assert close(enc["ops"] - 1.5 * mxh, 128.0 * 4 * 1e9, 1e-9)
+    assert close(dec["hbm_bytes"], enc["hbm_bytes"], 1e-4)
+    assert dec["hbm_bytes"] == 1.5e9 + 32 * 8 * 1e9 / work.BLOCK
     # EC:2+2 moves 2 bytes per data byte and needs half the parity rows.
     assert close(work.hbm_gbps(2, 2, V5E), 409.5)
     try:
@@ -64,7 +78,8 @@ def main() -> int:
     for cell in bench["workloads"]:
         for tdir in (None, os.path.join("tests", "traffic")):
             wl, cfg = traffic.load_cell(bench, cell["name"], tdir)
-            assert cfg["drives"] == cfg["data_shards"] + cfg["parity_shards"]
+            assert cfg["drives"] == cfg.get("sets", 1) * (
+                cfg["data_shards"] + cfg["parity_shards"]), cfg["name"]
             block = sum(wl["mix"].values())
             ops = traffic.op_blocks(3, 0, wl["mix"])
             first = [next(ops) for _ in range(block)]
@@ -73,6 +88,32 @@ def main() -> int:
             how = traffic.load_metric(m["name"])
             assert m["name"].startswith(how["name"]) and how["kind"] in (
                 "ratio", "trace_idle", "trace_roofline"), how
+            assert how["kind"] != "trace_roofline" or how.get(
+                "work", "encode") in ("encode", "decode"), how
+    # A mix that hides shards: from 1 to M of them, of prefilled single-part
+    # objects under new keys, and nothing written or deleted in the window.
+    hid = next(c["name"] for c in bench["workloads"] if traffic.load_cell(
+        bench, c["name"])[0].get("hide_shards"))
+    wl, cfg = traffic.load_cell(bench, hid)
+    for change in ({"hide_shards": cfg["parity_shards"] + 1},
+                   {"prefill_per_client": 0}, {"mix": {"GET": 1, "PUT": 1}},
+                   {"mix": {"GET": 1, "DELETE": 1}}, {"put_key_ring": 2},
+                   {"part_bytes": wl["object_bytes"]}):
+        with tempfile.TemporaryDirectory() as tmp:
+            with open(os.path.join(tmp, f"{wl['name']}.json"), "w") as f:
+                json.dump(dict(wl, **change), f)
+            try:
+                traffic.load_cell(bench, hid, tmp)
+            except ValueError as e:
+                assert f"{wl['name']}.json: hide_shards" in str(e), e
+            else:
+                raise AssertionError(f"the loader admitted {change}")
+    picks = [traffic.hidden_shards(5, c, n, 8, 2)
+             for c in range(8) for n in range(4)]
+    assert all(len(p) == 2 and 0 <= p[0] < p[1] < 8 for p in picks)
+    assert len({tuple(p) for p in picks}) > 8, "pairs differ by object"
+    assert picks != [traffic.hidden_shards(6, c, n, 8, 2)
+                     for c in range(8) for n in range(4)], "and by seed"
     # Two seeds send the same operations in another order.
     a = traffic.op_blocks(1, 0, {"GET": 3, "PUT": 1})
     b = traffic.op_blocks(2 ** 31 + 5, 0, {"GET": 3, "PUT": 1})
@@ -92,6 +133,7 @@ def main() -> int:
     block = np.random.default_rng(4).bytes(5000)
     rows = reference.encode_block(block, 2, 2)
     assert rows.shape == (4, 2500)
+    assert np.array_equal(rows[:2], reference.data_rows(block, 2))
     assert bytes(rows[:2].reshape(-1)[:5000]) == block
     files = reference.shard_files(block, 2, 2)
     assert reference.compare_part(block, 2, 2, files)["frames"] == 4

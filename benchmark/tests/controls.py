@@ -8,6 +8,11 @@ One run per (fault, seed), each a whole run of `run.py` with `faulty_serve.py`
 as the serving process, and one sound run per seed.  Prints one line per run:
 the fault, the seed, `correct`, and the numbers compared.  Exits 0 when every
 sound run read correct and every faulty run did not.
+
+For a cell that hides shards (`hide_shards` in its traffic file) name its own
+faults: `--faults no_hide flip_rebuilt flip_get`.  `no_hide` breaks the
+guarantee the cell states (every read finds two data shards gone) in the
+parent, not in the server: the step looks at the drives and hides nothing.
 """
 
 from __future__ import annotations
@@ -23,6 +28,12 @@ sys.path.insert(0, os.path.dirname(HERE))
 import run  # noqa: E402
 
 FAULTS = ("lose_shard", "bad_digest", "flip_parity", "flip_get")
+HIDE_SHARDS = run.hide_shards
+
+
+def look_only(srv, seed, wl, cfg, heads):
+    """`run.hide_shards` that takes nothing away."""
+    return HIDE_SHARDS(srv, seed, dict(wl, hide_shards=0), cfg, heads)
 
 
 def main() -> int:
@@ -36,12 +47,14 @@ def main() -> int:
     for fault in [None, *args.faults]:
         for seed in args.seeds:
             os.environ.pop("BENCH_FAULT", None)
-            if fault:
+            served = fault not in (None, "no_hide")
+            if served:
                 os.environ["BENCH_FAULT"] = fault
+            run.hide_shards = look_only if fault == "no_hide" else HIDE_SHARDS
             result = run.run_cell(
                 args.workload, seed, args.seconds, False,
                 serve_script=os.path.join(
-                    HERE, "faulty_serve.py") if fault else os.path.join(
+                    HERE, "faulty_serve.py") if served else os.path.join(
                     run.HERE, "serve.py"))
             if result is None:
                 print("controls: no chip", file=sys.stderr)

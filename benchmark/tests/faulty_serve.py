@@ -14,6 +14,10 @@ process, at a point that every backend's path goes through:
   faults (an answer altered where it is produced)
     flip_parity  one bit of the first parity shard of every encoded batch
     flip_get     one bit of every GET body
+    flip_rebuilt one bit of the first row a read's decode rebuilds, on the
+                 device's program (`ShardMath.verify_transform`) and in the
+                 fused host kernel (`ecio_native.get_verify`) alike: only a
+                 read that finds a data shard missing returns it
     keep_deleted a DELETE is acknowledged and the object stays
 """
 
@@ -68,6 +72,27 @@ def install(fault: str) -> None:
                     yield chunk
             return fi, altered()
         ServerPools.get_object_iter = get_object_iter
+    elif fault == "flip_rebuilt":
+        import numpy as np
+
+        from minio_tpu.engine.shardmath import ShardMath
+        from native import ecio_native
+        orig_vt, orig_gv = ShardMath.verify_transform, ecio_native.get_verify
+
+        def verify_transform(self, x, k, m, sources, targets, algo):
+            digests, out = orig_vt(self, x, k, m, sources, targets, algo)
+            if out is not None:
+                out = np.array(out)
+                out[0, 0, 0] ^= 1
+            return digests, out
+
+        def get_verify(frames, sel, nb, S, k, m, targets, out=None):
+            y, ok, nbad = orig_gv(frames, sel, nb, S, k, m, targets, out=out)
+            if targets and not nbad:
+                y[0, targets[0], 0] ^= 1
+            return y, ok, nbad
+        ShardMath.verify_transform = verify_transform
+        ecio_native.get_verify = get_verify
     elif fault == "keep_deleted":
         ServerPools.delete_object = lambda self, *args, **kwargs: None
     elif fault == "lose_shard":

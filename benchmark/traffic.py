@@ -13,7 +13,9 @@ knows a cell by name.  Everything a client sends is a pure function of
   - an object's bytes: a 32-byte tag, SHA-256 of (seed, key, part, generation),
     followed by one of the client's few seeded pool buffers from byte 32 on,
     the buffer chosen by the tag.  A body is never kept: whoever knows the
-    key, the part and the generation can make it again.
+    key, the part and the generation can make it again;
+  - with `hide_shards` = h: which h data shards of each prefilled object the
+    parent takes away before the warm-up (`hidden_shards`, `run.hide_shards`).
 """
 
 from __future__ import annotations
@@ -57,16 +59,30 @@ def load_cell(bench: dict, cell: str,
     if wl["part_bytes"] and wl["object_bytes"] % wl["part_bytes"]:
         raise ValueError(f"traffic/{entry['traffic']}.json: object_bytes is "
                          f"not a whole number of parts")
-    if wl.get("hide_shards"):
-        raise ValueError(f"traffic/{entry['traffic']}.json: hide_shards is "
-                         f"reserved for the degraded-GET cell, whose PR "
-                         f"brings the code that hides them")
     cfg = load_json("configs", f"{entry['config']}.json")
     for key in ("drives", "data_shards", "parity_shards", "chips",
                 "storage_class_standard", "put_headers", "env",
                 "server_args"):
         if key not in cfg:
             raise ValueError(f"configs/{entry['config']}.json: no {key!r}")
+    hide = wl.get("hide_shards", 0)
+    if hide:
+        # Shards taken away between prefill and warm-up (`run.hide_shards`).
+        # What the window wrote would be healthy, and what it deleted could
+        # not be told from what was hidden: loss under writes is a later
+        # cell's.  The step knows single-part objects under new keys.
+        refused = [why for bad, why in (
+            (not 0 < hide <= cfg["parity_shards"],
+             f"not from 1 to the {cfg['parity_shards']} parity shards of "
+             f"configs/{entry['config']}.json"),
+            (not wl["prefill_per_client"], "nothing is prefilled"),
+            (wl["mix"].get("PUT") or wl["mix"].get("DELETE"),
+             "the mix writes or deletes"),
+            (wl["part_bytes"] or wl["put_key_ring"],
+             "objects are multipart or on a key ring")) if bad]
+        if refused:
+            raise ValueError(f"traffic/{entry['traffic']}.json: hide_shards "
+                             f"{hide}: {'; '.join(refused)}")
     if cfg["chips"] != entry["chips"]:
         raise ValueError(f"cell {cell!r} asks for {entry['chips']} chip(s), "
                          f"its configuration maps onto {cfg['chips']}")
@@ -157,6 +173,14 @@ def start_offset(seed: int, client: int, wl: dict) -> float:
     seed, so that a closed loop does not begin in lock-step."""
     order = np.random.default_rng([seed, 0x57A6]).permutation(wl["clients"])
     return wl.get("stagger_s", 0.0) * int(order[client]) / wl["clients"]
+
+
+def hidden_shards(seed: int, client: int, n: int, k: int, h: int) -> list[int]:
+    """Which h of the k data shards (0-based) of the client's n-th
+    prefilled object are taken away: drawn from (seed, object), so the
+    sets differ from object to object and from seed to seed."""
+    rng = np.random.default_rng([seed, client, n, 0x41DE])
+    return sorted(int(i) for i in rng.permutation(k)[:h])
 
 
 def ring_key(client: int, i: int) -> str:

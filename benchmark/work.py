@@ -1,5 +1,5 @@
 """The least work the chip must do for the users' bytes, and the least time
-it can take: the roofline under `encode_roofline_pct`.
+it can take: the roofline under `encode_roofline` and `decode_roofline`.
 
 The work is what D bytes of acknowledged PUT data need at K data + M parity
 shards, whatever implements it.  Padding, staging copies and extra launches
@@ -14,6 +14,11 @@ are not work: they show as a lower share.
                  + mxh256 over all K+M shards: a (256 x 8) product per
                  256-byte chunk, 16 operations per hashed byte, and the tree
                  above it an eighth of that per level: 16 * 8/7.
+
+A degraded GET's work (`decode_work`) is the same arithmetic turned round:
+the K rows read are D bytes in, the T rebuilt rows D * T/K out, 32 bytes of
+digest for each of the K shard blocks verified, 128 * T operations a data
+byte and mxh256 over the D bytes read.  With T = M it is an encode's parity.
 
 Both products are exact in int8 with int32 sums, so the least time counts
 them at the chip's int8 peak, the faster of its two: a share of this bound
@@ -50,10 +55,18 @@ def encode_work(data_bytes: float, k: int, m: int) -> dict:
             "hbm_bytes": shard_bytes + DIGEST * (k + m) * blocks}
 
 
-def least_seconds(data_bytes: float, k: int, m: int,
-                  device_kind: str) -> dict:
-    """The larger of operations / peak and bytes / peak, and which."""
-    p, w = peaks(device_kind), encode_work(data_bytes, k, m)
+def decode_work(data_bytes: float, k: int, t: int) -> dict:
+    """{"ops", "hbm_bytes"} for `data_bytes` of GET data read from k
+    shards, of which t data shards are rebuilt from the k rows read."""
+    blocks = data_bytes / BLOCK
+    return {"ops": (128.0 * t + MXH_OPS_PER_BYTE) * data_bytes,
+            "hbm_bytes": data_bytes * (1.0 + t / k) + DIGEST * k * blocks}
+
+
+def least_seconds(w: dict, device_kind: str) -> dict:
+    """The larger of operations / peak and bytes / peak, and which, for
+    the work `w` (`encode_work`, `decode_work`)."""
+    p = peaks(device_kind)
     t_ops = w["ops"] / p["int8_ops_per_s"]
     t_hbm = w["hbm_bytes"] / p["hbm_bytes_per_s"]
     return {"seconds": max(t_ops, t_hbm), "ops_s": t_ops, "hbm_s": t_hbm,
@@ -61,7 +74,8 @@ def least_seconds(data_bytes: float, k: int, m: int,
 
 
 def hbm_gbps(k: int, m: int, device_kind: str) -> float:
-    """Data GB/s at which parity alone saturates HBM (digests left out)."""
+    """Data GB/s at which parity alone saturates HBM (digests left out);
+    with m = the rows rebuilt, a decode's."""
     return peaks(device_kind)["hbm_bytes_per_s"] / (1.0 + m / k) / 1e9
 
 
