@@ -6,8 +6,12 @@ A copy, independent of the program and of any device code, of
     klauspost/reedsolomon's default, which MinIO writes), in numpy;
   - the mxh256 bitrot digest, from its spec (exact int8 x int8 -> int32
     matrix products in a tree, XOR a length tag), in numpy;
+  - HighwayHash-256 under MinIO's fixed bitrot key (`highwayhash256S`,
+    MinIO's DefaultBitrotAlgorithm), from the published algorithm, in numpy:
+    many messages of one length advance in lock-step as rows of one array;
   - the shard file layout: one file per drive and part, a sequence of frames
-    [32-byte digest | shard block], one frame per 1 MiB erasure block.
+    [32-byte digest | shard block], one frame per 1 MiB erasure block, the
+    digest by the algorithm the configuration states (`ALGOS`).
 Imports numpy and hashlib only.
 """
 
@@ -157,16 +161,137 @@ def mxh256_rows(rows: np.ndarray) -> np.ndarray:
     return cur ^ np.frombuffer(tag, dtype=np.uint8)[None, :]
 
 
+# -- HighwayHash-256 (google/highwayhash, portable C; key: minio cmd/bitrot.go) ----
+
+# magicHighwayHash256Key: public, the same in every MinIO deployment.
+HH_KEY = bytes.fromhex("4be734fa8e238acd263e83e6bb968552"
+                       "040f935da39f441497e09d1322de36a0")
+_U64 = np.dtype("<u8")
+_HH_MUL0 = np.array([0xdbe6d5d5fe4cce2f, 0xa4093822299f31d0,
+                     0x13198a2e03707344, 0x243f6a8885a308d3], dtype=_U64)
+_HH_MUL1 = np.array([0x3bd39e10cb0ef593, 0xc0acf169b5f18a8c,
+                     0xbe5466cf34e90c6c, 0x452821e638d01377], dtype=_U64)
+_LOW32, _S32 = np.uint64(0xFFFFFFFF), np.uint64(32)
+# The zipper merge is a permutation of the 16 bytes of a pair of lanes
+# (v0 = bytes 0-7, v1 = bytes 8-15; out: the byte added to lane 0, lane 1),
+# the same on both pairs of a state's four lanes.
+_ZIP16 = [3, 12, 2, 5, 14, 1, 15, 0, 11, 4, 10, 13, 9, 6, 8, 7]
+_ZIP32 = np.array(_ZIP16 + [16 + i for i in _ZIP16])
+
+
+def _swap32(v: np.ndarray) -> np.ndarray:
+    """The two 32-bit halves of every 64-bit lane exchanged."""
+    return (v >> _S32) | (v << _S32)
+
+
+class _HHState:
+    """n HighwayHash states, each four 64-bit lanes of v0, v1, mul0, mul1."""
+
+    def __init__(self, n: int):
+        key = np.frombuffer(HH_KEY, dtype=_U64)
+        self.mul0 = np.tile(_HH_MUL0, (n, 1))
+        self.mul1 = np.tile(_HH_MUL1, (n, 1))
+        self.v0 = self.mul0 ^ key
+        self.v1 = self.mul1 ^ _swap32(key)
+
+    def update(self, packet: np.ndarray) -> None:
+        """One 32-byte packet a state: (n, 4) little-endian lanes.  Sums
+        and products wrap at 64 bits, as numpy's uint64 does."""
+        self.v1 += self.mul0 + packet
+        self.mul0 ^= (self.v1 & _LOW32) * (self.v0 >> _S32)
+        self.v0 += self.mul1
+        self.mul1 ^= (self.v0 & _LOW32) * (self.v1 >> _S32)
+        self.v0 += self._zipped(self.v1)
+        self.v1 += self._zipped(self.v0)
+
+    @staticmethod
+    def _zipped(v: np.ndarray) -> np.ndarray:
+        return np.ascontiguousarray(
+            v.view(np.uint8)[:, _ZIP32]).view(_U64)
+
+    def remainder(self, tail: np.ndarray) -> None:
+        """The last 1..31 bytes of each message, (n, r) uint8."""
+        n, r = tail.shape
+        self.v0 += np.uint64((r << 32) + r)
+        half = self.v1.view("<u4")          # each 32-bit half rotated by r
+        half[:] = (half << np.uint32(r)) | (half >> np.uint32(32 - r))
+        whole, mod4 = r & ~3, r & 3
+        packet = np.zeros((n, 32), dtype=np.uint8)
+        packet[:, :whole] = tail[:, :whole]
+        if r & 16:
+            packet[:, 28:] = tail[:, r - 4:]
+        elif mod4:
+            packet[:, 16] = tail[:, whole]
+            packet[:, 17] = tail[:, whole + (mod4 >> 1)]
+            packet[:, 18] = tail[:, r - 1]
+        self.update(packet.view(_U64))
+
+    def digest(self) -> np.ndarray:
+        for _ in range(10):
+            self.update(_swap32(self.v0[:, [2, 3, 0, 1]]))
+        out = np.empty_like(self.v0)
+        for lo in (0, 2):                   # 256 bits -> 128, a pair a time
+            a0 = self.v0[:, lo] + self.mul0[:, lo]
+            a1 = self.v0[:, lo + 1] + self.mul0[:, lo + 1]
+            a2 = self.v1[:, lo] + self.mul1[:, lo]
+            a3 = (self.v1[:, lo + 1] + self.mul1[:, lo + 1]) \
+                & np.uint64(0x3FFFFFFFFFFFFFFF)
+            one, two = np.uint64(1), np.uint64(2)
+            out[:, lo] = a0 ^ (a2 << one) ^ (a2 << two)
+            out[:, lo + 1] = a1 ^ ((a3 << one) | (a2 >> np.uint64(63))) \
+                ^ ((a3 << two) | (a2 >> np.uint64(62)))
+        return out.view(np.uint8)
+
+
+def highwayhash256_rows(rows: np.ndarray) -> np.ndarray:
+    """(n, L) uint8 -> (n, 32) uint8: HighwayHash-256 of each row under
+    `HH_KEY`.  The n messages advance together, a packet a step."""
+    rows = np.ascontiguousarray(rows, dtype=np.uint8)
+    n, length = rows.shape
+    whole = length & ~31
+    state = _HHState(n)
+    # (steps, n, 4): a step's packets lie together.
+    packets = np.ascontiguousarray(
+        rows[:, :whole].reshape(n, -1, 32).transpose(1, 0, 2)).view(_U64)
+    for packet in packets:
+        state.update(packet)
+    if length > whole:
+        state.remainder(rows[:, whole:])
+    return state.digest()
+
+
 # -- the shard files of one part -------------------------------------------------
 
-def shard_files(body: bytes | memoryview, k: int, m: int) -> list[bytes]:
+# What a configuration's `bitrot_algo` may say -> the digest in front of each
+# shard block.  `highwayhash256S` is MinIO's streaming layout, which is the
+# layout here: one digest a shard block.
+ALGOS = {"mxh256": mxh256_rows, "highwayhash256S": highwayhash256_rows}
+DEFAULT_ALGO = "mxh256"         # where a configuration states none
+
+
+def frame_digests(blocks: list[np.ndarray], algo: str) -> list[np.ndarray]:
+    """The (k+m, 32) digests of each block's (k+m, L) shard rows.  mxh256
+    goes a block at a time (its products are float32: four bytes a byte);
+    HighwayHash takes a part's full blocks in one lock-step pass, and the
+    shorter block a part may end on in another."""
+    hash_rows = ALGOS[algo]
+    if algo == "mxh256" or not blocks:
+        return [hash_rows(b) for b in blocks]
+    full = sum(b.shape[1] == blocks[0].shape[1] for b in blocks)
+    digests = hash_rows(np.concatenate(blocks[:full]))
+    return [*digests.reshape(full, -1, DIGEST),
+            *(hash_rows(b) for b in blocks[full:])]
+
+
+def shard_files(body: bytes | memoryview, k: int, m: int,
+                algo: str = DEFAULT_ALGO) -> list[bytes]:
     """The k+m shard files (frames and all) of an object or part whose
-    bytes are `body`, in shard order 1..k+m."""
+    bytes are `body`, in shard order 1..k+m, framed with `algo`'s digests."""
     body = memoryview(body)
+    blocks = [encode_block(body[off:off + BLOCK], k, m)
+              for off in range(0, len(body), BLOCK)]
     files: list[list[bytes]] = [[] for _ in range(k + m)]
-    for off in range(0, len(body), BLOCK):
-        rows = encode_block(body[off:off + BLOCK], k, m)
-        digests = mxh256_rows(rows)
+    for rows, digests in zip(blocks, frame_digests(blocks, algo)):
         for i in range(k + m):
             files[i].append(digests[i].tobytes())
             files[i].append(rows[i].tobytes())
@@ -174,14 +299,14 @@ def shard_files(body: bytes | memoryview, k: int, m: int) -> list[bytes]:
 
 
 def compare_part(body: bytes | memoryview, k: int, m: int,
-                 on_disk: list[bytes]) -> dict:
+                 on_disk: list[bytes], algo: str = DEFAULT_ALGO) -> dict:
     """Hold the files found on the drives for one part against the
     reference.  `on_disk` has one entry per drive that holds the part, in
     any order (which drive holds which shard is the program's choice; that
     each shard is there exactly once is not).  Returns counts: frames
     compared, frames whose data or parity bytes differ, frames whose digest
     differs, shards missing or doubled."""
-    want = shard_files(body, k, m)
+    want = shard_files(body, k, m, algo)
     index = {hashlib.sha256(w).digest(): i for i, w in enumerate(want)}
     # (offset in the shard file, shard block length) of every frame.
     layout, pos = [], 0

@@ -177,6 +177,9 @@ class Server:
         # took: `compiles_between` finds what compiled inside the window.
         env.setdefault("JAX_LOG_COMPILES", "1")
         env.update(cfg["env"])
+        # The digest the configuration states (`traffic.load_cell`: mxh256,
+        # the program's own default, where it states none).
+        env["MTPU_BITROT_ALGO"] = cfg["bitrot_algo"]
         with open(self.log_path, "ab") as out:
             self.proc = subprocess.Popen(
                 [sys.executable, serve_script, self.ctl,
@@ -288,11 +291,14 @@ class Server:
 
     def kill(self) -> None:
         """Leave no process behind.  Reached with the server still up only
-        when a phase failed: then its log is the evidence."""
+        when a phase failed, and with it gone by itself when that failed a
+        phase: either way its log is the evidence."""
         if self.proc.poll() is None:
             self.proc.kill()
             self.proc.wait(timeout=30)
-            log(f"--- server log (tail) ---\n{self.log_tail(20000)}")
+        if self.proc.returncode != 0:
+            log(f"--- server exited {self.proc.returncode}; its log (tail) "
+                f"---\n{self.log_tail(20000)}")
 
 
 def counter(metrics: dict, spec: str) -> float | None:
@@ -570,7 +576,7 @@ def check_correct(srv: Server, seed: int, wl: dict, cfg: dict,
         body = b"".join(b.chunks(key, p, gen))
         res = reference.compare_part(
             body, k, m, disk_files(srv, key, max(p, 1)) if files is None
-            else files)
+            else files, cfg["bitrot_algo"])
         frames += res["frames"]
         bad_bytes += res["bad_bytes"]
         bad_digest += res["bad_digest"]
@@ -648,12 +654,14 @@ def read_metric(m: dict, q: dict, cfg: dict) -> float | None:
         data = quantity(m["bytes"], q)
         if not busy or not data:
             return None
+        algo = cfg["bitrot_algo"]
         if m.get("work", "encode") == "encode":
             w = work.encode_work(data, cfg["data_shards"],
-                                 cfg["parity_shards"])
+                                 cfg["parity_shards"], algo)
         elif m["work"] == "decode" and q["hidden"]:
             # Every decode of a cell that hides shards rebuilds that many.
-            w = work.decode_work(data, cfg["data_shards"], q["hidden"])
+            w = work.decode_work(data, cfg["data_shards"], q["hidden"],
+                                 algo)
         else:
             return None
         return 100.0 * work.least_seconds(w, q["device_kind"])["seconds"] \

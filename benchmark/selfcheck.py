@@ -57,6 +57,26 @@ def main() -> int:
     assert close(enc["ops"] - 1.5 * mxh, 128.0 * 4 * 1e9, 1e-9)
     assert close(dec["hbm_bytes"], enc["hbm_bytes"], 1e-4)
     assert dec["hbm_bytes"] == 1.5e9 + 32 * 8 * 1e9 / work.BLOCK
+    # Under a digest the host computes (`highwayhash256S`) the chip's work is
+    # the same less the digest term: mxh256's operations over the bytes
+    # hashed, 32 bytes a shard block.
+    hh = "highwayhash256S"
+    for k, m, t in ((8, 4, 2), (6, 6, 6), (2, 2, 2)):
+        enc, dec = work.encode_work(1e9, k, m), work.decode_work(1e9, k, t)
+        assert enc == work.encode_work(1e9, k, m, "mxh256")
+        assert dec == work.decode_work(1e9, k, t, "mxh256")
+        enc_hh = work.encode_work(1e9, k, m, hh)
+        dec_hh = work.decode_work(1e9, k, t, hh)
+        blocks = 1e9 / work.BLOCK
+        assert enc_hh["ops"] == 128.0 * m * 1e9
+        assert close(enc["ops"] - enc_hh["ops"], mxh * (1 + m / k), 1e-9)
+        assert close(enc["hbm_bytes"] - enc_hh["hbm_bytes"],
+                     32 * (k + m) * blocks, 1e-6)
+        assert dec_hh == {"ops": 128.0 * t * 1e9,
+                          "hbm_bytes": 1e9 * (1 + t / k)}
+        assert close(dec["ops"] - dec_hh["ops"], mxh, 1e-9)
+        assert close(dec["hbm_bytes"] - dec_hh["hbm_bytes"],
+                     32 * k * blocks, 1e-6)
     # EC:2+2 moves 2 bytes per data byte and needs half the parity rows.
     assert close(work.hbm_gbps(2, 2, V5E), 409.5)
     try:
@@ -80,6 +100,8 @@ def main() -> int:
             wl, cfg = traffic.load_cell(bench, cell["name"], tdir)
             assert cfg["drives"] == cfg.get("sets", 1) * (
                 cfg["data_shards"] + cfg["parity_shards"]), cfg["name"]
+            assert cfg["bitrot_algo"] in reference.ALGOS, cfg["name"]
+            assert "MTPU_BITROT_ALGO" not in cfg["env"], cfg["name"]
             block = sum(wl["mix"].values())
             ops = traffic.op_blocks(3, 0, wl["mix"])
             first = [next(ops) for _ in range(block)]
@@ -90,6 +112,17 @@ def main() -> int:
                 "ratio", "trace_idle", "trace_roofline"), how
             assert how["kind"] != "trace_roofline" or how.get(
                 "work", "encode") in ("encode", "decode"), how
+    # The lists of `ec6p6-64m-degraded-get` are whole: it reads what the 8+4
+    # degraded cell reads, end to end and layer by layer, and end to end
+    # that is every metric a GET-only cell can report.
+    lists = {section: [[m["name"] for m in run.cell_metrics(bench, section, c)]
+                       for c in ("ec6p6-64m-degraded-get",
+                                 "ec8p4-64m-degraded-get")]
+             for section in ("end_to_end", "per_layer")}
+    assert all(mine == its for mine, its in lists.values()), lists
+    assert set(lists["end_to_end"][0]) == {"get_gbps", "get_p95_ms",
+                                           "setup_s"}
+    assert len(lists["per_layer"][0]) == 16
     # A mix that hides shards: from 1 to M of them, of prefilled single-part
     # objects under new keys, and nothing written or deleted in the window.
     hid = next(c["name"] for c in bench["workloads"] if traffic.load_cell(
@@ -144,6 +177,30 @@ def main() -> int:
         assert res[what] == 1, res
     assert reference.compare_part(block, 2, 2, files[:3])["shards_missing"] \
         == 1
+    # HighwayHash-256 under MinIO's key: the golden chain of MinIO's own
+    # `bitrotSelfTest` (32 rounds of hash(msg), msg growing by each digest),
+    # and the same four cases under `highwayhash256S`.
+    msg = digest = b""
+    for _ in range(32):
+        digest = reference.highwayhash256_rows(
+            np.frombuffer(msg, dtype=np.uint8)[None, :])[0].tobytes()
+        msg += digest
+    assert digest.hex() == ("39c0407ed3f01b18d22c85db4aeff11e"
+                            "060ca5f43131b0126731ca197cd42313"), "golden chain"
+    files_hh = reference.shard_files(block, 2, 2, hh)
+    assert [f[32:] for f in files_hh] == [f[32:] for f in files]
+    assert all(a[:32] != b[:32] for a, b in zip(files_hh, files))
+    assert reference.compare_part(block, 2, 2, files_hh, hh) == {
+        "frames": 4, "bad_bytes": 0, "bad_digest": 0, "shards_missing": 0}
+    assert reference.compare_part(block, 2, 2, files_hh)["bad_digest"] == 4
+    for at, what in ((40, "bad_bytes"), (5, "bad_digest")):
+        bad = bytearray(files_hh[3])
+        bad[at] ^= 1
+        res = reference.compare_part(block, 2, 2,
+                                     files_hh[:3] + [bytes(bad)], hh)
+        assert res[what] == 1, res
+    assert reference.compare_part(block, 2, 2, files_hh[:3],
+                                  hh)["shards_missing"] == 1
     print("selfcheck: ok")
     return 0
 

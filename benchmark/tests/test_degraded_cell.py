@@ -89,7 +89,7 @@ def test_decode_roofline_reads_decode_work_where_shards_are_hidden():
     how = traffic.load_metric("decode_roofline")
     q = {"client": {"get_bytes": 4e9, "put_bytes": 0.0},
          "trace": {"busy_s": 0.5}, "device_kind": "TPU v5 lite", "hidden": 2}
-    cfg = {"data_shards": 8, "parity_shards": 4}
+    cfg = {"data_shards": 8, "parity_shards": 4, "bitrot_algo": "mxh256"}
     least = work.least_seconds(work.decode_work(4e9, 8, 2), "TPU v5 lite")
     assert run.read_metric(how, q, cfg) == 100.0 * least["seconds"] / 0.5
     assert run.read_metric(how, dict(q, hidden=0), cfg) is None

@@ -103,14 +103,16 @@ def test_new_metrics_read_nothing_where_the_program_lacks_the_counters():
                     "hedge_wins_per_100_reads.get": 25.0}
 
 
+@pytest.mark.parametrize("algo", ["mxh256", "highwayhash256S"])
 @pytest.mark.parametrize("nbytes", [3 * (1 << 20) + 4321, 10 << 20, 5])
-def test_reference_at_k6_on_a_body_that_is_no_multiple_of_6(nbytes):
+def test_reference_at_k6_on_a_body_that_is_no_multiple_of_6(nbytes, algo):
     """1 MiB is no multiple of 6: every full block is zero-padded by 2
-    bytes into 6 rows of 174,763; the tail block by its own rule."""
+    bytes into 6 rows of 174,763; the tail block by its own rule.  Under
+    either digest a configuration may state (PR 38)."""
     k, m = 6, 6
     assert nbytes % k
     body = np.random.default_rng(nbytes).bytes(nbytes)
-    files = reference.shard_files(body, k, m)
+    files = reference.shard_files(body, k, m, algo)
     blocks = [min(reference.BLOCK, nbytes - off)
               for off in range(0, nbytes, reference.BLOCK)]
     sizes = [-(-b // k) for b in blocks]
@@ -128,13 +130,22 @@ def test_reference_at_k6_on_a_body_that_is_no_multiple_of_6(nbytes):
         got.append(rows[:b])
         pos += reference.DIGEST + s
     assert b"".join(got) == body
-    res = reference.compare_part(body, k, m, files[::-1])
+    res = reference.compare_part(body, k, m, files[::-1], algo)
     assert res == {"frames": (k + m) * len(blocks), "bad_bytes": 0,
                    "bad_digest": 0, "shards_missing": 0}
     broken = list(files)
     broken[7] = broken[7][:3] + bytes([broken[7][3] ^ 1]) + broken[7][4:]
-    res = reference.compare_part(body, k, m, broken[:-1])
+    res = reference.compare_part(body, k, m, broken[:-1], algo)
     assert res["bad_digest"] == 1 and res["shards_missing"] == 1
+    assert res["bad_bytes"] == 0
+    # A data byte flipped in the last frame of a parity shard's file.
+    broken = list(files)
+    broken[9] = broken[9][:-1] + bytes([broken[9][-1] ^ 1])
+    res = reference.compare_part(body, k, m, broken, algo)
+    # (A file whose first block is wrong is no shard's: one frame of
+    # wrong bytes, and its shard is missing.)
+    assert (res["bad_bytes"], res["bad_digest"], res["shards_missing"]) == \
+        (1, 0, len(blocks) == 1)
 
 
 def test_sound_run_is_correct(monkeypatch):

@@ -26,6 +26,8 @@ import os
 
 import numpy as np
 
+import reference
+
 HERE = os.path.dirname(os.path.abspath(__file__))
 TAG = 32
 OPS = ("PUT", "GET", "STAT", "DELETE")
@@ -65,6 +67,16 @@ def load_cell(bench: dict, cell: str,
                 "server_args"):
         if key not in cfg:
             raise ValueError(f"configs/{entry['config']}.json: no {key!r}")
+    # The digest the server frames shard blocks with is the configuration's
+    # one statement: the server is told it (`run.Server`), and the reference
+    # and the rooflines follow it.  A second statement could disagree.
+    algo = cfg.setdefault("bitrot_algo", reference.DEFAULT_ALGO)
+    if algo not in reference.ALGOS:
+        raise ValueError(f"configs/{entry['config']}.json: bitrot_algo "
+                         f"{algo!r} is not one of {sorted(reference.ALGOS)}")
+    if "MTPU_BITROT_ALGO" in cfg["env"]:
+        raise ValueError(f"configs/{entry['config']}.json: env names "
+                         f"MTPU_BITROT_ALGO: say it as bitrot_algo, once")
     hide = wl.get("hide_shards", 0)
     if hide:
         # Shards taken away between prefill and warm-up (`run.hide_shards`).

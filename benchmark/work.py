@@ -20,6 +20,12 @@ the K rows read are D bytes in, the T rebuilt rows D * T/K out, 32 bytes of
 digest for each of the K shard blocks verified, 128 * T operations a data
 byte and mxh256 over the D bytes read.  With T = M it is an encode's parity.
 
+The digest term is the chip's only under a digest the chip computes.  Under
+`highwayhash256S` the program hashes on the host (its native kernel beats
+the device form: `storage/bitrot_io.device_preferred`), so the chip is asked
+for the parity or the rebuilt rows alone and both functions leave the digest's
+operations and bytes out (`HOST_HASHED`).
+
 Both products are exact in int8 with int32 sums, so the least time counts
 them at the chip's int8 peak, the faster of its two: a share of this bound
 cannot pass 100 % by a change of number format.  (Held against the bf16 peak
@@ -35,6 +41,8 @@ import os
 BLOCK = 1 << 20
 DIGEST = 32
 MXH_OPS_PER_BYTE = 16.0 * 8.0 / 7.0
+# Configurations' `bitrot_algo`s whose digests are the host's work.
+HOST_HASHED = ("highwayhash256S",)
 
 
 def peaks(device_kind: str) -> dict:
@@ -47,20 +55,28 @@ def peaks(device_kind: str) -> dict:
     return table[device_kind]
 
 
-def encode_work(data_bytes: float, k: int, m: int) -> dict:
-    """{"ops", "hbm_bytes"} for `data_bytes` of PUT data at k+m."""
+def encode_work(data_bytes: float, k: int, m: int,
+                algo: str = "mxh256") -> dict:
+    """{"ops", "hbm_bytes"} for `data_bytes` of PUT data at k+m, framed
+    with the bitrot digest `algo`."""
     shard_bytes = data_bytes * (1.0 + m / k)
     blocks = data_bytes / BLOCK
-    return {"ops": 128.0 * m * data_bytes + MXH_OPS_PER_BYTE * shard_bytes,
-            "hbm_bytes": shard_bytes + DIGEST * (k + m) * blocks}
+    hashed = algo not in HOST_HASHED
+    return {"ops": 128.0 * m * data_bytes
+            + hashed * MXH_OPS_PER_BYTE * shard_bytes,
+            "hbm_bytes": shard_bytes + hashed * DIGEST * (k + m) * blocks}
 
 
-def decode_work(data_bytes: float, k: int, t: int) -> dict:
+def decode_work(data_bytes: float, k: int, t: int,
+                algo: str = "mxh256") -> dict:
     """{"ops", "hbm_bytes"} for `data_bytes` of GET data read from k
-    shards, of which t data shards are rebuilt from the k rows read."""
+    shards, of which t data shards are rebuilt from the k rows read and
+    verified against `algo`'s digests."""
     blocks = data_bytes / BLOCK
-    return {"ops": (128.0 * t + MXH_OPS_PER_BYTE) * data_bytes,
-            "hbm_bytes": data_bytes * (1.0 + t / k) + DIGEST * k * blocks}
+    hashed = algo not in HOST_HASHED
+    return {"ops": (128.0 * t + hashed * MXH_OPS_PER_BYTE) * data_bytes,
+            "hbm_bytes": data_bytes * (1.0 + t / k)
+            + hashed * DIGEST * k * blocks}
 
 
 def least_seconds(w: dict, device_kind: str) -> dict:
