@@ -1949,7 +1949,7 @@ class ErasureSet:
                     if s in sel:
                         y[:, s] = x[:, sel.index(s)]
                     else:
-                        y[:, s] = out[:, missing.index(s)]
+                        y[:, s] = out[missing.index(s)]
 
         # Tail fragment: reconstruct missing rows via the CPU oracle codec
         # (a partial block is tiny — not worth a device dispatch).

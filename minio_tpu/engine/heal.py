@@ -703,7 +703,7 @@ def _heal_part_pipelined(es: ErasureSet, bucket: str, obj: str,
                     data.pop(s, None)
                 continue
             for j, s in enumerate(need):
-                out[s] = rebuilt[:, j, :]
+                out[s] = rebuilt[j]
             break
         # Vectorized framing of the rebuilt rows (same frame layout the
         # serial frame_shard produces, batch-concatenation identical).

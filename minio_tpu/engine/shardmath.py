@@ -33,7 +33,7 @@ import numpy as np
 
 from ..observe import span as ospan
 from ..observe.metrics import DATA_PATH
-from ..ops import coalesce, fused
+from ..ops import coalesce, devcache, fused
 from ..ops import devices as devices_mod
 from ..ops.erasure_cpu import ReedSolomonCPU
 from ..ops.erasure_jax import ReedSolomonTPU
@@ -429,12 +429,16 @@ class ShardMath:
     def verify_transform(self, x: np.ndarray, k: int, m: int,
                          sources: tuple, targets: tuple, algo: str):
         """Digests (nb, k, hs) of the K chosen rows `x` (nb, k, S) of
-        shards `sources`, and shards `targets` rebuilt from them (None
-        where there are none): a degraded GET's decode, a heal batch.
-        On the lane ONE dispatch, digests + reconstruction from the
-        same HBM-resident bytes, shared by concurrent degraded reads
-        and heals of one (sources, targets) pattern; every pattern of
-        a geometry runs one program, built ahead (`build_ladder`)."""
+        shards `sources`, and shards `targets` rebuilt from them: a
+        degraded GET's decode, a heal batch.  The rebuilt rows are T
+        arrays of (nb, S) in `targets` order (None where there are no
+        targets), on every plane views of what the plane produced: the
+        reader's copy into its own layout is the one copy a rebuilt
+        byte gets.  On the lane ONE dispatch, digests + reconstruction
+        from the same HBM-resident bytes, shared by concurrent degraded
+        reads and heals of one (sources, targets) pattern; every
+        pattern of a geometry runs one program, built ahead
+        (`build_ladder`)."""
         nb, _, shard_size = x.shape
         DATA_PATH.record_verify_blocks(
             nb, (k, m, sources, targets) if targets else None)
@@ -444,16 +448,15 @@ class ShardMath:
                     x, k, m, sources, targets, algo=algo,
                     device=self.device_idx)
                 return (np.asarray(digests),
-                        fused.rows_on_host(rows) if targets else None)
+                        tuple(devcache.fetch(r) for r in rows)
+                        if targets else None)
             co = self._co()
             if co is None:
-                digests, out = direct()
-            else:
-                digests, out = self._ride(
-                    co, ("vt", k, m, sources, targets, algo, shard_size), x,
-                    self.vt_kernel(k, m, sources, targets, algo,
-                                   device=self.device_idx), nb, direct)
-            return digests, out
+                return direct()
+            return self._ride(
+                co, ("vt", k, m, sources, targets, algo, shard_size), x,
+                self.vt_kernel(k, m, sources, targets, algo,
+                               device=self.device_idx), nb, direct)
         # Host path (host-hashed algorithm, no TPU, or an algo whose
         # native host kernel beats its device verify —
         # bitrot_io.device_preferred): digest on host, reconstruct via
@@ -468,9 +471,11 @@ class ShardMath:
                 lambda: bitrot_io._hash_batch(flat, algo))
         else:
             digests = bitrot_io._hash_batch(flat, algo)
-        return (digests.reshape(nb, k, hs),
-                self.transform(k, m, x, sources, targets)
-                if targets else None)
+        rows = None
+        if targets:
+            out = self.transform(k, m, x, sources, targets)    # (nb, T, S)
+            rows = tuple(out[:, j] for j in range(len(targets)))
+        return digests.reshape(nb, k, hs), rows
 
     def transform(self, k: int, m: int, x, sources, targets,
                   resident=None, algo: str = "") -> np.ndarray:

@@ -1229,6 +1229,17 @@ class MetricsRegistry:
             "mtpu_d2h_bytes_total",
             "Bytes of results the dispatch kernels brought back from "
             "the device, pad rows included")
+        self.d2h_fetches = Gauge(
+            "mtpu_d2h_fetches_total",
+            "Device arrays the dispatch kernels brought back")
+        self.d2h_early_starts = Gauge(
+            "mtpu_d2h_early_starts_total",
+            "Device arrays whose way back was begun at their launch")
+        self.lane_result_copy_bytes = Gauge(
+            "mtpu_lane_result_copy_bytes_total",
+            "Bytes of dispatch results a kernel's resolve copied on the "
+            "host after the fetch (0: every result a view of an array "
+            "the runtime filled)")
         self.h2d_dispatches = Gauge(
             "mtpu_h2d_dispatches_total",
             "Host->device upload crossings (device_put calls)")
@@ -1784,6 +1795,9 @@ class MetricsRegistry:
         hsnap = _devcache.h2d_stats()
         self.h2d_bytes.set(hsnap["h2d_bytes"])
         self.d2h_bytes.set(hsnap["d2h_bytes"])
+        self.d2h_fetches.set(hsnap["d2h_fetches"])
+        self.d2h_early_starts.set(hsnap["d2h_early_starts"])
+        self.lane_result_copy_bytes.set(hsnap["result_copy_bytes"])
         self.h2d_dispatches.set(hsnap["h2d_dispatches"])
         for dev, row in hsnap["lanes"].items():
             self.h2d_lane_bytes.set(row["h2d_bytes"], device=str(dev))

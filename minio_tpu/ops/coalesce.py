@@ -324,9 +324,15 @@ class DispatchLane:
     #: bookkeeping), then the four phases of a dispatch — `pack`
     #: (concatenate / copy into staging, pad), `h2d` (device_put),
     #: `launch` (the kernel call; a kernel without a launch/resolve
-    #: split — host kernels, verify+transform — runs whole under it),
-    #: `device_wait` (the resolve: blocked until the device's result is
-    #: on the host).  The lane thread's state wins while it is awake; a
+    #: split, a host kernel, runs whole under it), `device_wait` (the
+    #: resolve: blocked until the device's result is on the host.  A
+    #: device kernel's way back is begun at its launch and the lane
+    #: thread resolves a batch one dispatch after it launched it, so
+    #: there the state holds what is left of the program and of the
+    #: transfer once the next batch is packed, uploaded and launched,
+    #: and no copy: results are views of the arrays the runtime filled.
+    #: A dispatch run whole, inline or serial, waits here for all of
+    #: both).  The lane thread's state wins while it is awake; a
     #: dispatch run inline on a request thread is charged while the
     #: thread is parked.  One set of timers: the `lane.*` spans open at
     #: the same edges, and `pack_s` / `h2d_s` / `resolve_s` in stats()
@@ -1072,20 +1078,43 @@ class DispatchCoalescer:
 # pool's device owner (ops/ipc_dispatch.kernel_from_key) build the same
 # kernel from the same key.
 
-def _device_kernel(launch, pad_rows: int, device: int | None,
+def _device_kernel(start, pad_rows: int, device: int | None,
                    program=None):
-    """The dispatch kernel around a `launch(x, n, spans, ctx) ->
-    resolve` pair.  The lanes drive the pair themselves (pack, upload,
-    launch, then resolve one dispatch later); called whole (a solo
-    retry, a direct call) the kernel pads to its step, uploads, and
-    resolves at once.  `program()` gives the ops/fused.Program the
-    launch runs, where it is one: its `pad_rows` shape is then built
-    before a lane sees a batch, and the batch runs at the smallest
-    built step of the shape ladder (`ladder`); a kernel that names no
-    program runs at a multiple of `pad_rows`.  The program is asked
-    for only where a batch is dispatched: a pool worker builds kernels
-    (their keys travel to the owner) and must not reach for JAX."""
+    """The dispatch kernel around `start(x, spans) -> (outputs,
+    scatter)`: `start` runs the program on the placed batch and names
+    the device arrays that have to come back; `scatter(*host)` slices
+    those arrays, once on the host, into one result per span.  The way
+    back is kept here, one rule for every device kernel:
+    `launch` asks the runtime for the outputs at once
+    (`devcache.start_fetch`), so their way back runs on the runtime's
+    threads while the lane packs and uploads the next batch, and the
+    `resolve` it returns fetches them (`devcache.fetch`: the arrays the
+    runtime filled), scatters, and counts what a scatter copied
+    (`devcache.note_result_copies`: views cost nothing).
+
+    The lanes drive the pair themselves (pack, upload, launch, then
+    resolve one dispatch later); called whole (a solo retry, a direct
+    call) the kernel pads to its step, uploads, and resolves at once.
+    `program()` gives the ops/fused.Program the launch runs, where it
+    is one: its `pad_rows` shape is then built before a lane sees a
+    batch, and the batch runs at the smallest built step of the shape
+    ladder (`ladder`); a kernel that names no program runs at a
+    multiple of `pad_rows`.  The program is asked for only where a
+    batch is dispatched: a pool worker builds kernels (their keys
+    travel to the owner) and must not reach for JAX."""
     from . import devices
+
+    def launch(x, n, spans, ctx):
+        outputs, scatter = start(x, spans)
+        devcache.start_fetch(outputs)
+
+        def resolve():
+            host = [devcache.fetch(o) for o in outputs]
+            results = scatter(*host)
+            devcache.note_result_copies(host, results)
+            return results
+
+        return resolve
 
     def kernel(stacked, spans, ctx):
         n = stacked.shape[0]
@@ -1117,28 +1146,19 @@ def make_encode_kernel(k: int, m: int, algo: str, pad_rows: int,
         return kernel
     from . import devices, fused
 
-    def launch(x, n, spans, ctx):
-        if codec is None:
-            parity_d, digests_d = fused.encode_and_hash(
-                x, k, m, algo=algo, device=device)
-        else:
+    def start(x, spans):
+        if codec is not None:
             parity_d = codec.encode_blocks(devices.put(x, device))
-            digests_d = None
-
-        def resolve():
-            parity = devcache.fetch(parity_d)[:n]
-            if digests_d is None:
-                return [(parity[lo:hi], None) for lo, hi in spans]
-            digests = devcache.fetch(digests_d)[:, :n]
-            return [(parity[lo:hi], digests[:, lo:hi])
-                    for lo, hi in spans]
-
-        return resolve
+            return (parity_d,), lambda parity: [
+                (parity[lo:hi], None) for lo, hi in spans]
+        outputs = fused.encode_and_hash(x, k, m, algo=algo, device=device)
+        return outputs, lambda parity, digests: [
+            (parity[lo:hi], digests[:, lo:hi]) for lo, hi in spans]
 
     if codec is not None:
-        return _device_kernel(launch, pad_rows, device)
+        return _device_kernel(start, pad_rows, device)
     return _device_kernel(
-        launch, pad_rows, device,
+        start, pad_rows, device,
         functools.partial(fused.encode_hash_program, k, m, algo))
 
 
@@ -1146,8 +1166,11 @@ def make_verify_kernel(k: int, m: int, sources: tuple, targets: tuple,
                        algo: str, pad_rows: int,
                        device: int | None = None):
     """Fused device verify(+reconstruct) over stacked (B, K, S) gathers
-    — the healthy-verify / degraded-decode / heal work item.  Digest
-    layout is (B, K, hs): axis 0 is the concat axis of both outputs.
+    — the healthy-verify / degraded-decode / heal work item.  A span's
+    result is (digests (n, K, hs), the T rebuilt rows or None): the
+    rows as T arrays of (n, S) in `targets` order, each a view of the
+    array the runtime filled for that target, so a rebuilt byte is
+    copied once, by the reader that assembles it, and never here.
     With no targets it is one hash program per algorithm; with targets
     the geometry's one decode program (ops/fused.py), (sources,
     targets) its matrix operand: a matrix a dispatch, so a batch holds
@@ -1155,21 +1178,19 @@ def make_verify_kernel(k: int, m: int, sources: tuple, targets: tuple,
     executables.  Both take the ladder."""
     from . import fused
 
-    def launch(x, n, spans, ctx):
+    def start(x, spans):
         digests_d, rows_d = fused.verify_and_transform(
             x, k, m, sources, targets, algo=algo, device=device)
 
-        def resolve():
-            digests = devcache.fetch(digests_d)[:n]
-            out = fused.rows_on_host(rows_d, n) if targets else None
+        def scatter(digests, *rows):
             return [(digests[lo:hi],
-                     out[lo:hi] if out is not None else None)
+                     tuple(r[lo:hi] for r in rows) if targets else None)
                     for lo, hi in spans]
 
-        return resolve
+        return (digests_d, *(rows_d or ())), scatter
 
     kernel = _device_kernel(
-        launch, pad_rows, device,
+        start, pad_rows, device,
         functools.partial(fused.verify_transform_program, k, m, sources,
                           targets, algo))
     if targets:
@@ -1193,17 +1214,13 @@ def make_digest_kernel(algo: str, pad_rows: int = 0,
         from . import fused
 
         if algo in fused.DEVICE_ALGOS and bitrot_io.device_preferred(algo):
-            def launch(x, n, spans, ctx):
+            def start(x, spans):
                 out_dev = fused.hash_rows_async(x, algo, device=device)
-
-                def resolve():
-                    out = devcache.fetch(out_dev)[:n]
-                    return [out[lo:hi] for lo, hi in spans]
-
-                return resolve
+                return (out_dev,), lambda out: [
+                    out[lo:hi] for lo, hi in spans]
 
             return _device_kernel(
-                launch, pad_rows, device,
+                start, pad_rows, device,
                 functools.partial(fused.hash_rows_program, algo))
 
     def kernel(stacked, spans, ctx):

@@ -24,7 +24,10 @@ The same module owns the process-wide H2D boundary ledger: every
 host->device byte crossing (`fused._placed`, `devices.put`, the
 coalescer lanes' pipelined staging uploads) is recorded here, per lane,
 so benches and tests can assert bytes-crossing-per-byte-served ~= 1.0 on
-first touch and ~0 on cache hits without a chip attached.
+first touch and ~0 on cache hits without a chip attached.  And the way
+back: `start_fetch` / `fetch` are the one place a dispatch kernel's
+result crosses to the host (begun at the launch, counted at the fetch),
+and `note_result_copies` counts what a kernel copied after it.
 
 Env (read per call so tests flip them without re-importing):
 
@@ -67,6 +70,9 @@ _H2D_BYTES = 0
 _H2D_DISPATCHES = 0
 _H2D_LANES: dict[int, dict] = {}
 _D2H_BYTES = 0
+_D2H_FETCHES = 0
+_D2H_EARLY_STARTS = 0
+_RESULT_COPY_BYTES = 0
 
 
 def note_h2d(nbytes: int, device: int | None = None) -> None:
@@ -84,15 +90,52 @@ def note_h2d(nbytes: int, device: int | None = None) -> None:
             lane["h2d_dispatches"] += 1
 
 
+def start_fetch(arrs) -> None:
+    """Ask the runtime to begin bringing the device arrays `arrs` back
+    now, while whoever launched them does something else: `fetch` then
+    finds the bytes on the host, or waits for what is left of the
+    transfer and no more.  The transfers of several arrays run side by
+    side on the runtime's threads."""
+    global _D2H_EARLY_STARTS
+    for a in arrs:
+        a.copy_to_host_async()
+    with _H2D_MU:
+        _D2H_EARLY_STARTS += len(arrs)
+
+
 def fetch(arr) -> np.ndarray:
     """A dispatch kernel's result on the host, the crossing counted:
     the whole array comes back, pad rows and all, so the ledger shows
-    what a dispatch's shape costs on the way back too."""
-    global _D2H_BYTES
+    what a dispatch's shape costs on the way back too.  The array is
+    the one the runtime filled: nothing is copied here."""
+    global _D2H_BYTES, _D2H_FETCHES
     out = np.asarray(arr)
     with _H2D_MU:
         _D2H_BYTES += out.nbytes
+        _D2H_FETCHES += 1
     return out
+
+
+def _arrays(res):
+    """The arrays of a kernel's results, however nested."""
+    if isinstance(res, np.ndarray):
+        yield res
+    elif isinstance(res, (list, tuple)):
+        for r in res:
+            yield from _arrays(r)
+
+
+def note_result_copies(fetched: list, results) -> None:
+    """Count the bytes of a kernel's `results` that are not views of
+    the arrays it `fetched`: what its resolve copied host-to-host after
+    the crossing (a restack, a trim that copies).  A result is a view
+    of what the runtime filled, or it is counted."""
+    global _RESULT_COPY_BYTES
+    copied = sum(a.nbytes for a in _arrays(results)
+                 if not any(np.may_share_memory(a, f) for f in fetched))
+    if copied:
+        with _H2D_MU:
+            _RESULT_COPY_BYTES += copied
 
 
 def h2d_stats() -> dict:
@@ -101,16 +144,23 @@ def h2d_stats() -> dict:
             "h2d_bytes": _H2D_BYTES,
             "h2d_dispatches": _H2D_DISPATCHES,
             "d2h_bytes": _D2H_BYTES,
+            "d2h_fetches": _D2H_FETCHES,
+            "d2h_early_starts": _D2H_EARLY_STARTS,
+            "result_copy_bytes": _RESULT_COPY_BYTES,
             "lanes": {d: dict(v) for d, v in sorted(_H2D_LANES.items())},
         }
 
 
 def reset_h2d() -> None:
-    global _H2D_BYTES, _H2D_DISPATCHES, _D2H_BYTES
+    global _H2D_BYTES, _H2D_DISPATCHES, _D2H_BYTES, _D2H_FETCHES, \
+        _D2H_EARLY_STARTS, _RESULT_COPY_BYTES
     with _H2D_MU:
         _H2D_BYTES = 0
         _H2D_DISPATCHES = 0
         _D2H_BYTES = 0
+        _D2H_FETCHES = 0
+        _D2H_EARLY_STARTS = 0
+        _RESULT_COPY_BYTES = 0
         _H2D_LANES.clear()
 
 

@@ -206,8 +206,10 @@ def _flatten_result(kind: str, res):
         return [np.stack([np.asarray(r) for r in res])]
     if kind == "digest":
         return [np.asarray(res)]
-    a, b = res                       # enc: (parity, digests?) / vt: (dg, out?)
-    return [np.asarray(a), None if b is None else np.asarray(b)]
+    a, b = res
+    if kind == "vt":                 # (digests, the T rebuilt rows or None)
+        return [a, *(b or ())]
+    return [np.asarray(a), None if b is None else np.asarray(b)]   # enc
 
 
 def _rebuild_result(kind: str, arrays: list):
@@ -215,6 +217,8 @@ def _rebuild_result(kind: str, arrays: list):
         return list(arrays[0])
     if kind == "digest":
         return arrays[0]
+    if kind == "vt":
+        return arrays[0], tuple(arrays[1:]) or None
     return arrays[0], arrays[1]
 
 

@@ -163,7 +163,9 @@ def test_lane_builds_one_executable_for_every_pattern(monkeypatch):
             x = np.ascontiguousarray(full[:, list(sources)])
             _, out = coalesce.get().submit(
                 ("vt", k, m, sources, targets, ALGO, S), x, fn).result(60)
-            assert np.array_equal(out, full[:, list(targets)])
+            assert len(out) == len(targets)
+            for row, t in zip(out, targets):
+                assert np.array_equal(row, full[:, t])
         assert builds == [(prog.name, (32, k, S))]
         assert prog.jit._cache_size() <= 1
     finally:
