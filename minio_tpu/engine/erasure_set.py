@@ -1003,6 +1003,7 @@ class ErasureSet:
         # (~0.3 ms/MiB of page faults) — price it as its own stage.
         with ospan.span("engine.alloc"):
             buf = bytearray(length)
+        DATA_PATH.record_get_fresh_buffer("response", length)
         mv = memoryview(buf)
         segs = self._plan_segments(fi, offset, length)
         offs = []
@@ -1659,14 +1660,17 @@ class ErasureSet:
                             tail_np[:length - full_bytes]))
                 return None
             flat = y.reshape(-1) if nb else np.zeros(0, dtype=np.uint8)
-            data = (np.concatenate([flat, tail_np])
-                    if tail_np is not None else flat)
+            data = flat
+            if tail_np is not None:
+                data = np.concatenate([flat, tail_np])
+                DATA_PATH.record_get_fresh_buffer("join", data.nbytes)
             view = data[lo:lo + length]
             if dst is not None:
                 dst[:length] = memoryview(np.ascontiguousarray(view))
                 return None
             if view.size == data.size:
                 return memoryview(view)
+            DATA_PATH.record_get_fresh_buffer("join", view.nbytes)
             return view.tobytes()
 
         def fast_path():
@@ -1726,6 +1730,8 @@ class ErasureSet:
                 y, okf, nbad = fused_host.get_verify(
                     [rows[s][3] for s in range(k)], list(range(k)),
                     nb, shard_size, k, m, [], out=body)
+                if body is None:
+                    DATA_PATH.record_get_fresh_buffer("assemble", y.nbytes)
                 if nbad:
                     for j in range(k):
                         if not okf[j]:
@@ -1743,6 +1749,7 @@ class ErasureSet:
                         nb, k, shard_size)
                 else:
                     y = np.empty((nb, k, shard_size), dtype=np.uint8)
+                    DATA_PATH.record_get_fresh_buffer("assemble", y.nbytes)
                 for s in range(k):
                     y[:, s, :] = rows[s][1]
                 asm_s += time.monotonic() - tg
@@ -1764,8 +1771,10 @@ class ErasureSet:
             ta = t_verify
             tail_np = None
             if has_tail:
-                tail_np = np.concatenate(
-                    [rows[s][2] for s in range(k)])[:geo["tail_len"]]
+                tail_np = np.concatenate([rows[s][2] for s in range(k)])
+                DATA_PATH.record_get_fresh_buffer("assemble",
+                                                  tail_np.nbytes)
+                tail_np = tail_np[:geo["tail_len"]]
             res = deliver(y, tail_np, placed=True)
             done = time.monotonic()
             DATA_PATH.record_healthy_read(
@@ -1919,9 +1928,12 @@ class ErasureSet:
                 break
             # ONE dispatch: digests of the K chosen rows + reconstruction
             # of the missing data rows from those same HBM-resident bytes.
-            x = np.empty((nb, k, shard_size), dtype=np.uint8)
-            for i, s in enumerate(sel):
-                x[:, i, :] = rows[s][1]                      # (nb, K, S)
+            with ospan.span("engine.gather") as sp:
+                x = np.empty((nb, k, shard_size), dtype=np.uint8)
+                for i, s in enumerate(sel):
+                    x[:, i, :] = rows[s][1]                  # (nb, K, S)
+                sp.tag(bytes=x.nbytes)
+            DATA_PATH.record_get_fresh_buffer("gather", x.nbytes)
             with ospan.span("engine.verify_decode"):
                 digests, out = self.math.verify_transform(
                     x, k, m, tuple(sel), tuple(missing), algo)
@@ -1936,73 +1948,90 @@ class ErasureSet:
         # missing, sel IS [0..k), so x already holds them — the full
         # blocks then flow to the caller with no further copy (when
         # BLOCK_SIZE divides evenly, x's natural layout IS the data).
-        ta_asm = time.monotonic()
-        y = None
-        if nb:
-            if y_fused is not None:
-                y = y_fused
-            elif not missing:
-                y = x
-            else:
-                y = np.empty((nb, k, shard_size), dtype=np.uint8)
-                for s in range(k):
-                    if s in sel:
-                        y[:, s] = x[:, sel.index(s)]
-                    else:
-                        y[:, s] = out[missing.index(s)]
+        with ospan.span("engine.assemble") as sp:
+            fresh = 0
+            y = None
+            if nb:
+                if y_fused is not None:
+                    y = y_fused
+                    fresh = y.nbytes
+                elif not missing:
+                    y = x
+                else:
+                    y = np.empty((nb, k, shard_size), dtype=np.uint8)
+                    fresh = y.nbytes
+                    for s in range(k):
+                        if s in sel:
+                            y[:, s] = x[:, sel.index(s)]
+                        else:
+                            y[:, s] = out[missing.index(s)]
 
-        # Tail fragment: reconstruct missing rows via the CPU oracle codec
-        # (a partial block is tiny — not worth a device dispatch).
-        tails: dict[int, np.ndarray] = {}
-        if has_tail:
-            tails = {s: rows[s][2] for s in sel}
-            t_missing = [s for s in range(k) if s not in tails]
-            if t_missing:
-                shards_in = [tails.get(s) for s in range(k + m)]
-                rec = self.math.cpu(k, m).reconstruct(shards_in,
-                                                      data_only=True)
-                for s in t_missing:
-                    tails[s] = rec[s]
+            # Tail fragment: reconstruct missing rows via the CPU oracle
+            # codec (a partial block is tiny — not worth a device
+            # dispatch).
+            tail_block = None
+            if has_tail:
+                tails = {s: rows[s][2] for s in sel}
+                t_missing = [s for s in range(k) if s not in tails]
+                if t_missing:
+                    shards_in = [tails.get(s) for s in range(k + m)]
+                    rec = self.math.cpu(k, m).reconstruct(shards_in,
+                                                          data_only=True)
+                    for s in t_missing:
+                        tails[s] = rec[s]
+                tail_block = np.concatenate([tails[s] for s in range(k)])
+                fresh += tail_block.nbytes
+            sp.tag(bytes=fresh)
+        DATA_PATH.record_get_fresh_buffer("assemble", fresh)
 
-        pieces = []
-        if nb:
-            if BLOCK_SIZE % k == 0:
-                # k*shard_size == BLOCK_SIZE: zero-pad-free layout,
-                # the whole full-block range is one contiguous view.
-                pieces.append(y.reshape(-1))
+        # The read's range of the assembled blocks: a view where one
+        # piece covers it, else the one copy that joins the pieces, and
+        # the copy into the caller's buffer where one was given.
+        with ospan.span("engine.join") as sp:
+            pieces = []
+            if nb:
+                if BLOCK_SIZE % k == 0:
+                    # k*shard_size == BLOCK_SIZE: zero-pad-free layout,
+                    # the whole full-block range is one contiguous view.
+                    pieces.append(y.reshape(-1))
+                else:
+                    flat = y.reshape(nb, k * shard_size)
+                    for bi in range(nb):
+                        pieces.append(flat[bi, :BLOCK_SIZE])
+            if has_tail:
+                pieces.append(tail_block[:geo["tail_len"]])
+            joined = length
+            if not pieces:
+                res: bytes | memoryview = b""
+                joined = 0
+            elif len(pieces) == 1:
+                view = pieces[0][lo:lo + length]
+                # Full aligned segment: hand the caller a view of the
+                # gather buffer (freshly allocated per call, never
+                # reused) — skipping the final tobytes copy, ~25% of a
+                # cached GET.
+                if view.size == pieces[0].size:
+                    res = memoryview(view)
+                    joined = 0
+                else:
+                    res = view.tobytes()
+            elif lo == 0 and sum(p.size for p in pieces) == length:
+                res = b"".join(memoryview(np.ascontiguousarray(p))
+                               for p in pieces)
             else:
-                flat = y.reshape(nb, k * shard_size)
-                for bi in range(nb):
-                    pieces.append(flat[bi, :BLOCK_SIZE])
-        if has_tail:
-            tail_block = np.concatenate([tails[s] for s in range(k)])
-            pieces.append(tail_block[:geo["tail_len"]])
-        if not pieces:
-            res: bytes | memoryview = b""
-        elif len(pieces) == 1:
-            view = pieces[0][lo:lo + length]
-            # Full aligned segment: hand the caller a view of the
-            # gather buffer (freshly allocated per call, never reused)
-            # — skipping the final tobytes copy, ~25% of a cached GET.
-            if view.size == pieces[0].size:
-                res = memoryview(view)
-            else:
-                res = view.tobytes()
-        elif lo == 0 and sum(p.size for p in pieces) == length:
-            res = b"".join(memoryview(np.ascontiguousarray(p))
-                           for p in pieces)
-        else:
-            data = np.concatenate(pieces)
-            res = data[lo:lo + length].tobytes()
-        ospan.record("engine.assemble", time.monotonic() - ta_asm)
-        if degraded:
-            DATA_PATH.record_degraded_read(length,
-                                           time.monotonic() - t_deg)
-        if dst is not None:
-            # Fallback/decode result lands in the caller's buffer too —
-            # one copy, same as the join it replaces.
-            dst[:length] = res
-            return None
+                data = np.concatenate(pieces)
+                joined += data.nbytes
+                res = data[lo:lo + length].tobytes()
+            if degraded:
+                DATA_PATH.record_degraded_read(length,
+                                               time.monotonic() - t_deg)
+            if dst is not None:
+                # Fallback/decode result lands in the caller's buffer
+                # too — one copy, same as the join it replaces.
+                dst[:length] = res
+                res = None
+            sp.tag(bytes=joined, pieces=len(pieces))
+        DATA_PATH.record_get_fresh_buffer("join", joined)
         return res
 
     def _hash_shard_frames(self, bufs: list, nb: int, shard_size: int,

@@ -155,6 +155,11 @@ class BandwidthMonitor:
         return out
 
 
+#: Where the GET path allocates anew for a segment
+#: (`mtpu_get_fresh_buffer_bytes_total{site}`).
+GET_FRESH_SITES = ("gather", "assemble", "join", "response")
+
+
 class DataPathStats:
     """Process-global heal / degraded-read data-path accounting.
 
@@ -238,6 +243,13 @@ class DataPathStats:
             # Bytes of framing and digest-stabilising buffers PUT
             # streams allocated anew (never the reuse).
             self.put_fresh_buffer_bytes = 0
+            # Bytes of arrays and byte strings the GET path allocated
+            # anew for a segment, by site (never a view, never a reuse).
+            self.get_fresh_buffer_bytes = dict.fromkeys(
+                GET_FRESH_SITES, 0)
+            # Episodes in which requests were in flight and none
+            # completed for the stall watcher's limit (server.py).
+            self.request_stall_episodes = 0
             # Cross-process dispatch (ops/ipc_dispatch.py, worker pool):
             # items shipped to the device owner, results received,
             # fallbacks (arena/ring full -> computed locally), and
@@ -442,6 +454,21 @@ class DataPathStats:
         counts."""
         with self._mu:
             self.put_fresh_buffer_bytes += nbytes
+
+    def record_get_fresh_buffer(self, site: str, nbytes: int) -> None:
+        """The GET path allocated `nbytes` anew for a segment at `site`
+        (GET_FRESH_SITES; engine/erasure_set.py): the gather's `x`, the
+        assembled `y` and a tail's concatenation, the join's byte
+        string, the response's bytearray.  A view costs nothing and is
+        not counted; the host arrays the runtime fills on a result's
+        way back are `mtpu_d2h_bytes_total`'s."""
+        if nbytes:
+            with self._mu:
+                self.get_fresh_buffer_bytes[site] += nbytes
+
+    def record_request_stall(self) -> None:
+        with self._mu:
+            self.request_stall_episodes += 1
 
     def record_co_fault(self, members: int) -> None:
         """A coalesced dispatch raised; `members` spans were retried
@@ -659,6 +686,9 @@ class DataPathStats:
                 "decode_patterns": len(self._decode_patterns),
                 "stage_pad_bytes": self.stage_pad_bytes,
                 "put_fresh_buffer_bytes": self.put_fresh_buffer_bytes,
+                "get_fresh_buffer_bytes": dict(
+                    self.get_fresh_buffer_bytes),
+                "request_stall_episodes": self.request_stall_episodes,
                 "ipc_submits": self.ipc_submits,
                 "ipc_rows": self.ipc_rows,
                 "ipc_results": self.ipc_results,
@@ -888,6 +918,21 @@ class MetricsRegistry:
             "PUT streams allocated anew (first use, growth, a copy of "
             "a digest piece); 0 while every batch reuses what its "
             "thread and its ring already hold")
+        self.get_fresh_buffer_bytes = Gauge(
+            "mtpu_get_fresh_buffer_bytes_total",
+            "Bytes of arrays and byte strings the GET path allocated "
+            "anew for a segment, by site: gather (the K chosen rows "
+            "into x), assemble (y, a tail's concatenation), join (the "
+            "byte string joined or copied out of the pieces), response "
+            "(the object's bytearray); a view is not counted",
+            ("site",))
+        self.request_stall_episodes = Gauge(
+            "mtpu_request_stall_episodes_total",
+            "Episodes in which requests were in flight and for 3 s none "
+            "began or completed, no streamed response handed on a "
+            "chunk and no request body was pulled; each wrote every "
+            "thread's stack, the lanes' states and MemAvailable to the "
+            "server's log once")
         # Cross-process dispatch families (worker pool, PR 9).
         self.ipc_submits = Gauge(
             "mtpu_ipc_dispatch_submits_total",
@@ -998,6 +1043,23 @@ class MetricsRegistry:
             "Summed span self time (duration minus the union of its "
             "children) by API, stage and layer in ms; a request "
             "root's own is stage http.other",
+            ("api", "stage", "layer"))
+        self.trace_stage_self_cpu_ms = Gauge(
+            "mtpu_trace_stage_self_cpu_ms_total",
+            "The part of mtpu_trace_stage_self_ms_total in which the "
+            "thread ran: the thread-CPU clock of the stage's spans that "
+            "read it (roots, pool hops, layer changes, "
+            "span.CPU_STAGES), less that of the clocked spans below on "
+            "the same thread; a stage that reads none has its time in "
+            "the figure of the stage above it, so sum over a layer",
+            ("api", "stage", "layer"))
+        self.trace_stage_self_wait_ms = Gauge(
+            "mtpu_trace_stage_self_wait_ms_total",
+            "The part of mtpu_trace_stage_self_ms_total in which the "
+            "thread did not run (slept, queued for the GIL or a lock): "
+            "the self time the stage's CPU reading covers less that "
+            "CPU, floored on the stage's sums, not a span at a time; "
+            "per layer cpu + wait = self where every reading exists",
             ("api", "stage", "layer"))
         self.trace_stage_count = Gauge(
             "mtpu_trace_stage_spans_total",
@@ -1707,6 +1769,9 @@ class MetricsRegistry:
         self.decode_patterns.set(snap["decode_patterns"])
         self.stage_pad_bytes.set(snap["stage_pad_bytes"])
         self.put_fresh_buffer_bytes.set(snap["put_fresh_buffer_bytes"])
+        for site, n in snap["get_fresh_buffer_bytes"].items():
+            self.get_fresh_buffer_bytes.set(n, site=site)
+        self.request_stall_episodes.set(snap["request_stall_episodes"])
         self.ipc_submits.set(snap["ipc_submits"])
         self.ipc_results.set(snap["ipc_results"])
         self.ipc_fallbacks.set(snap["ipc_fallbacks"])
@@ -1829,16 +1894,25 @@ class MetricsRegistry:
     def _sync_spans(self) -> None:
         # Imported lazily: span.py is the one observe module allowed to
         # stay import-light (it sits on every request's hot path).
-        from .span import BUCKETS_MS, ROOT_SELF_STAGE, TRACER, layer_of
+        from .span import BUCKETS_MS, TRACER, layer_of, root_self_stage
         snap = TRACER.snapshot()
+
+        def set_self(agg: dict, api: str, stage: str) -> None:
+            # A stage's self time and its two parts: ran, waited.
+            labels = {"api": api, "stage": stage,
+                      "layer": layer_of(stage)}
+            self.trace_stage_self_ms.set(agg["self_ms"], **labels)
+            self.trace_stage_self_cpu_ms.set(agg["self_cpu_ms"],
+                                             **labels)
+            self.trace_stage_self_wait_ms.set(agg["self_wait_ms"],
+                                              **labels)
+
         for api, a in snap["apis"].items():
             self.trace_api_count.set(a["count"], api=api)
             self.trace_api_errors.set(a["errors"], api=api)
             # A request root's own self time is the front door's; a
             # lane dispatch's root is its own stage.
-            own = api if layer_of(api) == "lane" else ROOT_SELF_STAGE
-            self.trace_stage_self_ms.set(a["self_ms"], api=api, stage=own,
-                                         layer=layer_of(own))
+            set_self(a, api, root_self_stage(api))
             for q in ("p50", "p90", "p99"):
                 self.trace_api_latency.set(a[f"{q}_ms"], api=api,
                                            quantile=q)
@@ -1847,9 +1921,7 @@ class MetricsRegistry:
                                            stage=stage)
                 self.trace_stage_ms.set(st["total_ms"], api=api,
                                         stage=stage)
-                self.trace_stage_self_ms.set(
-                    st["self_ms"], api=api, stage=stage,
-                    layer=layer_of(stage))
+                set_self(st, api, stage)
                 cum = 0
                 for i, bound in enumerate(BUCKETS_MS):
                     cum += st["buckets"][i]

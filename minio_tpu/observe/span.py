@@ -21,6 +21,33 @@ minus the union of its children's intervals (children that ran side by
 side in pool threads are not subtracted twice).  ``layer_of`` maps a
 stage name to the layer of the system it belongs to; the exporter sums
 self time per layer, which is what says where a request's time went.
+
+Ran or waited: beside the wall clock a span reads its thread's CPU
+clock (``time.thread_time()``) where it is entered and left, so a
+record says how much of the span its thread ran: ``cpu_ms`` (enter to
+exit, the stretch a suspended span sat out not counted).  The read is a
+system call (6 us under a sandboxed kernel, PERF.md section 3), so a
+span makes it only where a reader needs it: a root, a span entered on
+another thread than its parent's (a ``wrap_ctx`` pool hop), a span
+whose layer differs from its parent's, and the stages of ``CPU_STAGES``.
+Every other span (same thread, same layer: ``record()`` / ``bracket()``
+stages too) reads no clock and belongs to the *clock group* of the
+nearest span above it that does: ``self_cpu_ms`` = that span's
+``cpu_ms`` minus the ``cpu_ms`` of the clocked spans below the group on
+the same thread (a pool thread's CPU is its own), and
+``clocked_self_ms`` = the self time it is the CPU of, the group's.
+``self_wait_ms`` = ``clocked_self_ms`` - ``self_cpu_ms`` floored at 0
+(the thread slept, or queued for the GIL or a lock) is one span's, for
+a reader of trees; the exporter floors the *sums* a stage instead, so a
+coarse clock's lumps cancel.  A reading that does not exist is absent,
+never guessed: a span left on another thread than it was entered on has
+no ``cpu_ms``, and a group that holds such a span, or a stage of
+another layer that read no clock (a ``record()`` of a compile), has no
+``self_cpu_ms`` / ``clocked_self_ms`` / ``self_wait_ms``.
+The clock is the host's: where it ticks coarsely (10 ms under a
+sandboxed kernel; ``tools/thread_clock.py`` says) one span's ``cpu_ms``
+is a multiple of the tick and only the sums the exporter serves mean
+what they say.
 While a span is real and jax is loaded it is also a
 ``jax.profiler.TraceAnnotation``: with a profiler running, every span
 lands in the device trace on its host thread's line, stamped by the
@@ -32,10 +59,12 @@ Cost model (the whole point):
   bool check returning the shared ``NOOP`` singleton, and ``span()`` /
   ``record()`` are a single contextvar read — no Span object is ever
   allocated (``SPAN_ALLOCS`` is the test sentinel for that).
-- Tracing ON: spans cost one object, two clock reads and (with jax
+- Tracing ON: spans cost one object, two reads of
+  ``time.monotonic()``, one ``threading.get_ident()``, for a clocked
+  span (above) two reads of ``time.thread_time()`` and (with jax
   loaded) one TraceAnnotation each, paid only by requests actually
-  being traced (``MTPU_TRACE_SAMPLE``
-  down-samples root creation; untraced requests fall back to NOOP).
+  being traced (``MTPU_TRACE_SAMPLE`` down-samples root creation;
+  untraced requests fall back to NOOP).
 
 Completed root spans become plain-dict trace records that fan out to:
 a bounded ring of recent traces (``MTPU_TRACE_RING``, newest-N kept),
@@ -97,11 +126,36 @@ LAYERS = (
 ROOT_SELF_STAGE = "http.other"
 
 
+_LAYER_OF: dict[str, str] = {}
+
+
 def layer_of(stage: str) -> str:
-    for prefix, layer in LAYERS:
-        if stage.startswith(prefix):
-            return layer
-    return "other"
+    layer = _LAYER_OF.get(stage)
+    if layer is None:
+        layer = next((la for prefix, la in LAYERS
+                      if stage.startswith(prefix)), "other")
+        if len(_LAYER_OF) < 1024:       # stage names are code's own
+            _LAYER_OF[stage] = layer
+    return layer
+
+
+def root_self_stage(api: str) -> str:
+    """The stage a root's own self time is aggregated under: a lane
+    dispatch's is its own, a request's the front door's."""
+    return api if layer_of(api) == "lane" else ROOT_SELF_STAGE
+
+
+#: Stages that read their thread's CPU clock though they run on their
+#: parent's thread in their parent's layer, because a reader asks for
+#: their own split: the lane's host-side work (`lane_host_wait_pct`
+#: reads pack, h2d, launch and scatter; PERF.md section 5 the resolve's
+#: program_wait and fetch beside them) and the degraded read's three
+#: copies (PERF.md section 5: is a copy's time page faults, on the
+#: clock, or a queue for the GIL, off it).  Tens of spans a second.
+CPU_STAGES = frozenset((
+    "lane.pack", "lane.h2d", "lane.launch", "lane.program_wait",
+    "lane.fetch", "lane.scatter",
+    "engine.gather", "engine.assemble", "engine.join"))
 
 
 def _annotation(name: str):
@@ -170,8 +224,9 @@ NOOP = _NoopSpan()
 
 
 class Span:
-    __slots__ = ("name", "tags", "t0", "dur_s", "children",
-                 "_parent", "_token", "_tracer", "_ann", "_dropped")
+    __slots__ = ("name", "tags", "t0", "dur_s", "children", "tid",
+                 "cpu_s", "_c0", "_clk", "_layer", "_parent", "_token",
+                 "_tracer", "_ann", "_dropped")
 
     def __init__(self, tracer, name: str, tags: dict | None = None):
         global SPAN_ALLOCS
@@ -182,6 +237,14 @@ class Span:
         self.t0 = 0.0
         self.dur_s = 0.0
         self.children: list[Span] = []
+        # The thread the span was entered on; whether it reads that
+        # thread's CPU clock (the module docstring says which do), and
+        # the CPU seconds inside it: None = no reading.
+        self.tid = 0
+        self.cpu_s: float | None = None
+        self._c0 = 0.0
+        self._clk = False
+        self._layer = ""
         self._parent = None
         self._token = None
         self._ann = None
@@ -198,13 +261,34 @@ class Span:
         return self
 
     def __enter__(self):
-        self._parent = _current.get()
+        p = self._parent = _current.get()
         self._token = _current.set(self)
         self._ann = _annotation(self.name)
         if self._ann is not None:
             self._ann.__enter__()
+        self.tid = tid = threading.get_ident()
+        if p is None:
+            self._layer = layer_of(root_self_stage(self.name))
+            self._clk = True
+        else:
+            self._layer = layer = layer_of(self.name)
+            self._clk = (p.tid != tid or layer != p._layer
+                         or self.name in CPU_STAGES)
         self.t0 = time.monotonic()
+        if self._clk:
+            self.cpu_s = 0.0
+            self._c0 = time.thread_time()
         return self
+
+    def _stop_cpu(self) -> None:
+        """Close the CPU reading that __enter__ / resume() opened; none
+        where the calling thread is not the one that opened it (its
+        clock is another clock)."""
+        if self.cpu_s is not None:
+            if threading.get_ident() == self.tid:
+                self.cpu_s += time.thread_time() - self._c0
+            else:
+                self.cpu_s = None
 
     def _leave(self) -> None:
         try:
@@ -217,15 +301,26 @@ class Span:
     def suspend(self):
         """Step out of an open span without ending it, and back in with
         resume(): a pipelined lane dispatch stays open from its pack to
-        its resolve while the lane packs the next one."""
+        its resolve while the lane packs the next one.  The thread's
+        CPU in between is the next batch's, not this span's."""
+        self._stop_cpu()
         self._leave()
         return self
 
     def resume(self):
         self._token = _current.set(self)
+        if self.cpu_s is not None:
+            if threading.get_ident() != self.tid:
+                self.cpu_s = None
+            else:
+                self._c0 = time.thread_time()
         return self
 
     def __exit__(self, et, ev, tb):
+        # The clock read is a system call: made before the span's end
+        # is taken, its cost lies inside the span that asked for it and
+        # not in the gap before the next one.
+        self._stop_cpu()
         self.dur_s = time.monotonic() - self.t0
         if self._ann is not None:
             self._ann.__exit__(et, ev, tb)
@@ -246,6 +341,29 @@ class Span:
             return self.dur_s
         return _uncovered_s(self.t0, self.t0 + self.dur_s, self.children)
 
+    def _clock_group(self) -> tuple[float, float] | None:
+        """(self seconds, CPU seconds to take off) of the span's clock
+        group: the span and the spans below it on its thread that read
+        no clock of their own (their time is time of this span's
+        reading), and the readings of the clocked spans below those.
+        None where a span on the thread has no reading and cannot be
+        folded: it lost its reading, or it is a stage of another layer
+        (a `record()` of a compile)."""
+        own, sub = self.self_s(), 0.0
+        for c in self.children:
+            if c.tid != self.tid:
+                continue                        # another thread's clock
+            if c.cpu_s is not None:
+                sub += c.cpu_s
+                continue
+            g = (None if c._clk or c._layer != self._layer
+                 else c._clock_group())
+            if g is None:
+                return None
+            own += g[0]
+            sub += g[1]
+        return own, sub
+
     def root_tag(self, key: str):
         sp = self
         while sp._parent is not None:
@@ -259,6 +377,15 @@ class Span:
              "start_ms": round((self.t0 - t_root) * 1e3, 4),
              "dur_ms": round(self.dur_s * 1e3, 4),
              "self_ms": round(self.self_s() * 1e3, 4)}
+        if self.cpu_s is not None:
+            d["cpu_ms"] = round(self.cpu_s * 1e3, 4)
+            group = self._clock_group()
+            if group is not None:
+                own = round(group[0] * 1e3, 4)
+                cpu = round(max(0.0, self.cpu_s - group[1]) * 1e3, 4)
+                d["self_cpu_ms"] = cpu
+                d["clocked_self_ms"] = own
+                d["self_wait_ms"] = round(max(0.0, own - cpu), 4)
         if self.tags:
             d["tags"] = dict(self.tags)
         if self.children:
@@ -313,17 +440,27 @@ _PCTL_WINDOW = 512     # per-API root durations kept for percentiles
 
 
 class _ApiAgg:
-    __slots__ = ("count", "errors", "total_ms", "self_ms", "durs_ms",
-                 "stages")
+    __slots__ = ("count", "errors", "total_ms", "self_ms", "self_cpu_ms",
+                 "clocked_ms", "durs_ms", "stages")
 
     def __init__(self):
         self.count = 0
         self.errors = 0
         self.total_ms = 0.0
-        self.self_ms = 0.0          # the roots' own self time
+        self.self_ms = 0.0          # the roots' own self time; their
+        self.self_cpu_ms = 0.0      # CPU where known, and the self time
+        self.clocked_ms = 0.0       # that CPU is of (`clocked_self_ms`)
         self.durs_ms: deque = deque(maxlen=_PCTL_WINDOW)
-        # stage name -> [count, total_ms, per-bucket counts, self_ms]
+        # stage name -> [count, total_ms, per-bucket counts, self_ms,
+        #                self_cpu_ms, clocked_self_ms]
         self.stages: dict[str, list] = {}
+
+
+def _wait_ms(clocked_ms: float, cpu_ms: float) -> float:
+    """A stage's wait: the self time of its clocked spans less their
+    CPU, floored here, on the sums: the whole ticks a coarse clock
+    hands to one span and keeps from its neighbours cancel."""
+    return round(max(0.0, clocked_ms - cpu_ms), 4)
 
 
 def _pctl(sorted_ms: list, q: float) -> float:
@@ -426,6 +563,8 @@ class SpanTracer:
         agg.errors += err
         agg.total_ms += rec["dur_ms"]
         agg.self_ms += rec["self_ms"]
+        agg.self_cpu_ms += rec.get("self_cpu_ms", 0.0)
+        agg.clocked_ms += rec.get("clocked_self_ms", 0.0)
         agg.durs_ms.append(rec["dur_ms"])
         stack = list(rec.get("spans", ()))
         while stack:
@@ -436,11 +575,13 @@ class SpanTracer:
                 if len(agg.stages) >= _MAX_STAGES:
                     continue
                 st = agg.stages[sp["name"]] = [
-                    0, 0.0, [0] * len(BUCKETS_MS), 0.0]
+                    0, 0.0, [0] * len(BUCKETS_MS), 0.0, 0.0, 0.0]
             ms = sp["dur_ms"]
             st[0] += 1
             st[1] += ms
             st[3] += sp["self_ms"]
+            st[4] += sp.get("self_cpu_ms", 0.0)
+            st[5] += sp.get("clocked_self_ms", 0.0)
             for i, b in enumerate(BUCKETS_MS):
                 if ms <= b:
                     st[2][i] += 1
@@ -467,6 +608,9 @@ class SpanTracer:
                     "errors": a.errors,
                     "total_ms": round(a.total_ms, 4),
                     "self_ms": round(a.self_ms, 4),
+                    "self_cpu_ms": round(a.self_cpu_ms, 4),
+                    "self_wait_ms": _wait_ms(a.clocked_ms,
+                                             a.self_cpu_ms),
                     "avg_ms": round(a.total_ms / a.count, 4)
                     if a.count else 0.0,
                     "p50_ms": round(_pctl(durs, 0.50), 4),
@@ -476,6 +620,8 @@ class SpanTracer:
                         name: {"count": st[0],
                                "total_ms": round(st[1], 4),
                                "self_ms": round(st[3], 4),
+                               "self_cpu_ms": round(st[4], 4),
+                               "self_wait_ms": _wait_ms(st[5], st[4]),
                                "buckets": list(st[2])}
                         for name, st in sorted(a.stages.items())},
                 }
@@ -519,9 +665,14 @@ def span_or_root(name: str, **tags):
 
 def _attach(parent: Span, name: str, start: float, seconds: float,
             tags: dict | None) -> Span:
+    """A pre-measured child: it read no clock, so its time is time of
+    the reading of the clocked span above it where that is its layer's,
+    and unknown where it is not (Span._clock_group)."""
     sp = Span(TRACER, name, tags)
     sp.t0 = start
     sp.dur_s = seconds
+    sp.tid = threading.get_ident()
+    sp._layer = layer_of(name)
     if len(parent.children) < MAX_CHILDREN:
         parent.children.append(sp)
     return sp
@@ -562,11 +713,6 @@ def request_id():
 
 def current():
     return _current.get()
-
-
-def active() -> bool:
-    """True when the calling context is inside a traced request."""
-    return _current.get() is not None
 
 
 def wrap_ctx(fn):

@@ -413,13 +413,17 @@ class DispatchLane:
         self._clock(state, inline)
         return ospan.span(_LANE_SPAN[state])
 
-    def state_seconds(self) -> dict[str, float]:
-        """Seconds per state up to now; they sum to the lane's age."""
+    def state_now(self) -> tuple[str, dict[str, float]]:
+        """The state the lane is in, and seconds per state up to now;
+        they sum to the lane's age."""
         now = time.monotonic()
         with self._clk_mu:
             out = dict(self._state_s)
             out[self._state] += now - self._state_t
-        return out
+            return self._state, out
+
+    def state_seconds(self) -> dict[str, float]:
+        return self.state_now()[1]
 
     def _dispatch_span(self, key: tuple, fn, items: list[tuple],
                        rows: int, padded: int):
@@ -1088,9 +1092,12 @@ def _device_kernel(start, pad_rows: int, device: int | None,
     `launch` asks the runtime for the outputs at once
     (`devcache.start_fetch`), so their way back runs on the runtime's
     threads while the lane packs and uploads the next batch, and the
-    `resolve` it returns fetches them (`devcache.fetch`: the arrays the
-    runtime filled), scatters, and counts what a scatter copied
-    (`devcache.note_result_copies`: views cost nothing).
+    `resolve` it returns waits for the program (`devcache.wait_ready`),
+    fetches them (`devcache.fetch`: the arrays the runtime filled),
+    scatters, and counts what a scatter copied
+    (`devcache.note_result_copies`: views cost nothing): under the
+    lane's `device_wait`, `lane.program_wait`, `lane.fetch` and
+    `lane.scatter`.
 
     The lanes drive the pair themselves (pack, upload, launch, then
     resolve one dispatch later); called whole (a solo retry, a direct
@@ -1109,9 +1116,16 @@ def _device_kernel(start, pad_rows: int, device: int | None,
         devcache.start_fetch(outputs)
 
         def resolve():
-            host = [devcache.fetch(o) for o in outputs]
-            results = scatter(*host)
-            devcache.note_result_copies(host, results)
+            # The resolve in its parts, spans inside a traced dispatch:
+            # what is left of the program, what is left of the transfer
+            # begun above, and the host's own slicing.
+            with ospan.span("lane.program_wait"):
+                devcache.wait_ready(outputs)
+            with ospan.span("lane.fetch"):
+                host = [devcache.fetch(o) for o in outputs]
+            with ospan.span("lane.scatter"):
+                results = scatter(*host)
+                devcache.note_result_copies(host, results)
             return results
 
         return resolve
@@ -1289,6 +1303,16 @@ def get():
                 _CO = DispatchCoalescer()
             co = _CO
     return co
+
+
+def lanes_report() -> list[tuple[int, str, dict[str, float]]]:
+    """(device, state it is in, seconds per state) of each lane this
+    process has started, for the server's stall report; takes no lock a
+    stuck dispatch could hold."""
+    co = _CO
+    if co is None:
+        return []
+    return [(d, *ln.state_now()) for d, ln in sorted(co._lanes.items())]
 
 
 def attach_remote(remote) -> None:

@@ -103,6 +103,13 @@ def start_fetch(arrs) -> None:
         _D2H_EARLY_STARTS += len(arrs)
 
 
+def wait_ready(arrs) -> None:
+    """Block until the program that writes the device arrays `arrs`
+    has ended: after it a `fetch` waits for a transfer alone."""
+    for a in arrs:
+        a.block_until_ready()
+
+
 def fetch(arr) -> np.ndarray:
     """A dispatch kernel's result on the host, the crossing counted:
     the whole array comes back, pad rows and all, so the ledger shows

@@ -13,11 +13,15 @@ happen inline before dispatch, mirroring cmd/generic-handlers.go.
 
 from __future__ import annotations
 
+import faulthandler
 import os as _os
 import secrets
 import socket
 import ssl as _ssl
+import sys
 import threading
+import time
+import traceback
 import urllib.parse
 from http.server import BaseHTTPRequestHandler, ThreadingHTTPServer
 
@@ -185,6 +189,14 @@ class S3Server:
         self.warming = False
         self._inflight = 0
         self._drain_cv = threading.Condition()
+        # What the stall watcher reads (see `_watch_stalls`): when each
+        # request in flight began, by its thread (a stream that ends
+        # only when its client hangs up takes itself out), when a
+        # request last completed or a streamed response last handed a
+        # chunk to its socket.
+        self._began: dict[int, float] = {}
+        self._last_progress = time.monotonic()
+        self._stall_stop = threading.Event()
         # Overload plane (server/qos.py): the process-tree singleton —
         # in pool mode WorkerPlane already created it BEFORE the fork,
         # so this reference is the SAME fork-shared mapping in every
@@ -419,6 +431,7 @@ class S3Server:
                     qos_slot = True
                 with outer._drain_cv:
                     outer._inflight += 1
+                outer._began[threading.get_ident()] = time.monotonic()
                 if outer.worker_plane is not None:
                     outer.worker_plane.state.note_request(
                         outer.worker_id)
@@ -427,6 +440,8 @@ class S3Server:
                 finally:
                     if qos_slot:
                         outer.qos.release()
+                    outer._began.pop(threading.get_ident(), None)
+                    outer._last_progress = time.monotonic()
                     with outer._drain_cv:
                         outer._inflight -= 1
                         outer._drain_cv.notify_all()
@@ -618,6 +633,10 @@ class S3Server:
                               source_ip=self.client_address[0])
                 try:
                     if resp.status != 499:
+                        if resp.body_iter is not None \
+                                and "Transfer-Encoding" not in resp.headers:
+                            resp.body_iter = outer._marking_progress(
+                                resp.body_iter)
                         with ospan.span("http.respond"):
                             self._respond(resp)
                 except (BrokenPipeError, ConnectionResetError,
@@ -762,7 +781,85 @@ class S3Server:
         self._thread = threading.Thread(target=self._httpd.serve_forever,
                                         daemon=True)
         self._thread.start()
+        self._stall_thread = threading.Thread(
+            target=self._watch_stalls, daemon=True, name="stall-watch")
+        self._stall_thread.start()
         return self
+
+    #: Requests in flight and nothing moved for this long: a stall.
+    STALL_S = 3.0
+
+    def _marking_progress(self, chunks):
+        """A streamed response's chunks, each noted as progress as it
+        leaves for the socket: a 256 MiB GET that takes its client four
+        seconds is no stall."""
+        try:
+            for chunk in chunks:
+                self._last_progress = time.monotonic()
+                yield chunk
+        finally:
+            close = getattr(chunks, "close", None)
+            if close is not None:
+                close()
+
+    def _watch_stalls(self) -> None:
+        """A stalled process finishes no span, so this is no span: once
+        a second, where requests are in flight (streams that only the
+        client ends not counted) and for STALL_S none has begun, none
+        has completed, no streamed response has handed on a chunk and
+        no request body has been pulled from its connection, write
+        what every thread is doing to the server's log, once per
+        episode; an episode ends when any of those moves or no request
+        is left in flight."""
+        episode = warned = False
+        pulls = sum(DATA_PATH.body_pulls.values())
+        while not self._stall_stop.wait(1.0):
+            try:
+                now = time.monotonic()
+                n = sum(DATA_PATH.body_pulls.values())
+                if n != pulls:      # a body moved since the last look
+                    pulls, self._last_progress = n, now
+                began = list(self._began.values())
+                idle = (now - max(self._last_progress, *began)
+                        if began else 0.0)
+                if idle < self.STALL_S:
+                    episode = False
+                elif not episode:
+                    episode = True
+                    self._report_stall(len(began), idle)
+            except Exception:       # a watcher that died would say
+                if not warned:      # "no stall" for the process's life
+                    warned = True
+                    print("minio_tpu: stall watcher: a look failed "
+                          "(said once):\n" + traceback.format_exc(),
+                          file=sys.stderr, flush=True)
+
+    def _report_stall(self, stuck: int, idle_s: float) -> None:
+        """One episode's evidence, to the server's log (standard
+        error): every thread's stack, each lane's state and seconds per
+        state, the host's MemAvailable."""
+        from ..ops import coalesce
+        DATA_PATH.record_request_stall()
+        out = sys.stderr
+        avail = "unknown"
+        try:
+            with open("/proc/meminfo") as f:
+                for line in f:
+                    if line.startswith("MemAvailable:"):
+                        avail = " ".join(line.split()[1:])
+        except OSError:
+            pass
+        lines = [f"minio_tpu: request stall: {time.strftime('%H:%M:%S')} "
+                 f"{stuck} in flight, nothing began, completed or moved "
+                 f"a chunk for {idle_s:.1f} s; MemAvailable {avail}"]
+        for dev, state, seconds in coalesce.lanes_report():
+            secs = " ".join(f"{st}={v:.3f}" for st, v in seconds.items())
+            lines.append(f"minio_tpu: request stall: lane {dev} in "
+                         f"{state}: {secs}")
+        print("\n".join(lines), file=out, flush=True)
+        faulthandler.dump_traceback(file=out, all_threads=True)
+        print("minio_tpu: request stall: end of stacks", file=out,
+              flush=True)
 
     def build_ladders(self, hold_ready: bool = False) -> None:
         """Ask for the device programs' shape ladders (ops/coalesce.py)
@@ -785,6 +882,7 @@ class S3Server:
         # a service RESTART tears this server down but must keep (or
         # rebuild) the scanner; stopping it here would end background
         # healing for the life of the process.
+        self._stall_stop.set()
         self._httpd.shutdown()
         self._httpd.server_close()
         # The polling trace subscription is this server's: the span
@@ -1587,6 +1685,13 @@ class S3Server:
                     deep=query.get("deep", [""])[0] == "true")
                 return j(seq.status())
             return j({"sequences": self.heal_state.statuses()})
+        if sub == "trace" and method == "GET" \
+                and query.get("trees", [""])[0] == "1":
+            # The retention ring's whole records (MTPU_TRACE_RING),
+            # oldest first, request roots and `lane.dispatch` roots
+            # alike; reads, does not drain, and leaves the flat poll's
+            # queue alone.
+            return j({"traces": ospan.TRACER.traces()})
         if sub == "trace" and method == "GET":
             # Polling form of the one trace plane: the first call
             # subscribes (which turns span tracing on); each call
@@ -2240,6 +2345,7 @@ class S3Server:
         import json as _json
         import time as _time
         q = tracer.subscribe(2000)
+        self._began.pop(threading.get_ident(), None)    # no stall: a stream
         try:
             deadline = (_time.monotonic() + max_s) if max_s > 0 else None
             last = _time.monotonic()
@@ -2294,6 +2400,7 @@ class S3Server:
         import time as _time
         from fnmatch import fnmatch
         q = notify.subscribe_events(2000)
+        self._began.pop(threading.get_ident(), None)    # no stall: a stream
         try:
             deadline = (_time.monotonic() + max_s) if max_s > 0 else None
             last = _time.monotonic()

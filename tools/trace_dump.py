@@ -14,7 +14,14 @@ and pretty-prints each request as an indented stage tree, newest last:
 
 ``--json`` emits the raw NDJSON records instead.  ``--top`` skips the
 stream and prints ``GET /minio/admin/v3/top/apis`` aggregates (count,
-errors, avg/p50/p90/p99, hottest stages per API).
+errors, avg/p50/p90/p99, hottest stages per API with how much of each
+stage's self time its thread ran and waited).  ``--ring`` skips the
+stream too and prints what the server's retention ring holds
+(``MTPU_TRACE_RING``; ``GET /minio/admin/v3/trace?trees=1``): request
+roots and the lanes' ``lane.dispatch`` roots, oldest first, each stage
+with its wall time and, where the span read its thread's CPU clock,
+that time and the split of its own time (with that of the spans below
+it that read none) into ran and waited.
 
 Filters mirror `mc admin trace`: ``--err`` (errors only), ``--path``
 (request-path prefix), ``--min-duration-ms``.  Credentials fall back to
@@ -82,8 +89,15 @@ def print_rec(rec: dict) -> None:
     while stack:
         sp, depth = stack.pop()
         pad = "  " * depth
+        cpu = (f'  cpu {sp["cpu_ms"]:>9.2f}ms' if "cpu_ms" in sp else "")
+        if "self_wait_ms" in sp:
+            # Its own time, with that of the spans it keeps the clock
+            # for; one span's wait is floored at 0.
+            cpu += (f'  own {sp["clocked_self_ms"]:.2f} = ran '
+                    f'{sp["self_cpu_ms"]:.2f} + waited '
+                    f'{sp["self_wait_ms"]:.2f}')
         print(f'{pad}{sp["name"]:<{34 - 2 * depth}} '
-              f'{sp["dur_ms"]:>9.2f}ms')
+              f'{sp["dur_ms"]:>9.2f}ms{cpu}')
         stack.extend((c, depth + 1)
                      for c in reversed(sp.get("spans", [])))
 
@@ -113,7 +127,28 @@ def dump_top(cli: S3Client) -> int:
                      key=lambda kv: -kv[1]["total_ms"])[:5]
         for name, st_ in top:
             print(f'    {name:<28} x{st_["count"]:<5} '
-                  f'{st_["total_ms"]:>9.2f}ms total')
+                  f'{st_["total_ms"]:>9.2f}ms total  self '
+                  f'{st_["self_ms"]:.2f} = ran '
+                  f'{st_.get("self_cpu_ms", 0.0):.2f} + waited '
+                  f'{st_.get("self_wait_ms", 0.0):.2f}')
+    return 0
+
+
+def dump_ring(cli: S3Client, raw: bool) -> int:
+    st, _, body = cli.request("GET", "/minio/admin/v3/trace",
+                              query={"trees": "1"})
+    if st != 200:
+        print(f"trace?trees=1 failed: HTTP {st}: {body[:200]!r}",
+              file=sys.stderr)
+        return 1
+    recs = json.loads(body)["traces"]
+    for rec in recs:
+        if raw:
+            print(json.dumps(rec))
+        else:
+            print_rec(rec)
+    if not raw:
+        print(f"-- {len(recs)} root(s) in the ring --")
     return 0
 
 
@@ -136,6 +171,8 @@ def main(argv=None) -> int:
                     help="raw NDJSON records instead of trees")
     ap.add_argument("--top", action="store_true",
                     help="print top/apis aggregates and exit")
+    ap.add_argument("--ring", action="store_true",
+                    help="print the retention ring's trees and exit")
     args = ap.parse_args(argv)
     if not args.access_key or not args.secret_key:
         ap.error("--access-key/--secret-key (or MTPU_ACCESS_KEY/"
@@ -144,6 +181,8 @@ def main(argv=None) -> int:
     cli = S3Client(args.endpoint, args.access_key, args.secret_key)
     if args.top:
         return dump_top(cli)
+    if args.ring:
+        return dump_ring(cli, args.json)
 
     query = {"duration": str(args.duration)}
     if args.err:
