@@ -52,6 +52,7 @@ from ..storage.xlmeta import (ErasureInfo, FileInfo, ObjectPartInfo, XLMeta,
 from ..utils import streams
 from ..utils.crashpoints import crash_point
 from . import quorum as Q
+from . import segarena
 from .shardmath import BATCH_BLOCKS, BLOCK_SIZE, ShardMath
 # ops/ipc_dispatch.py (the pool owner's "pf" kernel) imports the fused host
 # kernel's loader from here under this name (ROADMAP Design 1: ops asking up).
@@ -103,6 +104,47 @@ def _tag_pool_thread(tag: str) -> None:
 
 def _now_ns() -> int:
     return time.time_ns()
+
+
+def _join_range(blocks, tail, lo: int, length: int, dst=None):
+    """Bytes [lo, lo + length) of the rows of `blocks` ((nb, W) uint8,
+    or None) followed by `tail` (1-D, or None).
+
+    Where one contiguous piece IS that range and no `dst` was given, a
+    memoryview of it and nothing is copied.  Else one strided copy (in
+    numpy, so without the GIL) into `dst` (a writable buffer of at least
+    `length` bytes; None is returned) or into a leased arena, returned
+    as a memoryview.  Either view keeps its arena out of the segment
+    pool while anything holds it (engine/segarena.py), so what is handed
+    out is never written again.  Returns (result, bytes copied)."""
+    if length <= 0:
+        return (None if dst is not None else b""), 0
+    if dst is None and lo == 0 and (blocks is None or tail is None):
+        piece = tail if blocks is None else blocks
+        if piece.size == length and piece.flags.c_contiguous:
+            return memoryview(piece.reshape(-1)), 0
+    out = (np.frombuffer(dst, dtype=np.uint8)[:length] if dst is not None
+           else segarena.lease(length, "join"))
+    pos = 0
+    if blocks is not None:
+        nb, w = blocks.shape
+        r, c = divmod(lo, w)
+        if c and r < nb:                # the range starts inside a row
+            pos = min(w - c, length)
+            out[:pos] = blocks[r, c:c + pos]
+            r += 1
+        n = min(nb - r, (length - pos) // w)
+        if n > 0:                       # whole rows: one strided copy
+            out[pos:pos + n * w].reshape(n, w)[:] = blocks[r:r + n]
+            pos += n * w
+            r += n
+        if r < nb and pos < length:     # ... and ends inside one
+            out[pos:] = blocks[r, :length - pos]
+            pos = length
+        lo = max(lo - nb * w, 0)
+    if pos < length:
+        out[pos:] = tail[lo:lo + length - pos]
+    return (None if dst is not None else memoryview(out)), length
 
 
 class ErasureSet:
@@ -1644,7 +1686,7 @@ class ErasureSet:
         degraded = any(s < k for s in range(k + m) if s not in candidates)
         t_deg = time.monotonic() if degraded else 0.0
         lo = offset - b0 * BLOCK_SIZE
-        full_bytes = nb * k * shard_size       # == nb * BLOCK_SIZE
+        full_bytes = nb * k * shard_size   # nb * BLOCK_SIZE where K divides it
         aligned = dst is not None and lo == 0 and length >= full_bytes
 
         def deliver(y, tail_np, placed=False):
@@ -1659,19 +1701,9 @@ class ErasureSet:
                         np.ascontiguousarray(
                             tail_np[:length - full_bytes]))
                 return None
-            flat = y.reshape(-1) if nb else np.zeros(0, dtype=np.uint8)
-            data = flat
-            if tail_np is not None:
-                data = np.concatenate([flat, tail_np])
-                DATA_PATH.record_get_fresh_buffer("join", data.nbytes)
-            view = data[lo:lo + length]
-            if dst is not None:
-                dst[:length] = memoryview(np.ascontiguousarray(view))
-                return None
-            if view.size == data.size:
-                return memoryview(view)
-            DATA_PATH.record_get_fresh_buffer("join", view.nbytes)
-            return view.tobytes()
+            # K divides the block on this read: a row of `y` is a block.
+            return _join_range(y.reshape(nb, BLOCK_SIZE) if nb else None,
+                               tail_np, lo, length, dst)[0]
 
         def fast_path():
             """Verify-only healthy read.  Returns (res,) on success or
@@ -1748,8 +1780,8 @@ class ErasureSet:
                     y = np.frombuffer(body, dtype=np.uint8).reshape(
                         nb, k, shard_size)
                 else:
-                    y = np.empty((nb, k, shard_size), dtype=np.uint8)
-                    DATA_PATH.record_get_fresh_buffer("assemble", y.nbytes)
+                    y = segarena.lease(full_bytes, "assemble").reshape(
+                        nb, k, shard_size)
                 for s in range(k):
                     y[:, s, :] = rows[s][1]
                 asm_s += time.monotonic() - tg
@@ -1929,11 +1961,11 @@ class ErasureSet:
             # ONE dispatch: digests of the K chosen rows + reconstruction
             # of the missing data rows from those same HBM-resident bytes.
             with ospan.span("engine.gather") as sp:
-                x = np.empty((nb, k, shard_size), dtype=np.uint8)
+                x = segarena.lease(full_bytes, "gather").reshape(
+                    nb, k, shard_size)
                 for i, s in enumerate(sel):
                     x[:, i, :] = rows[s][1]                  # (nb, K, S)
                 sp.tag(bytes=x.nbytes)
-            DATA_PATH.record_get_fresh_buffer("gather", x.nbytes)
             with ospan.span("engine.verify_decode"):
                 digests, out = self.math.verify_transform(
                     x, k, m, tuple(sel), tuple(missing), algo)
@@ -1949,22 +1981,27 @@ class ErasureSet:
         # blocks then flow to the caller with no further copy (when
         # BLOCK_SIZE divides evenly, x's natural layout IS the data).
         with ospan.span("engine.assemble") as sp:
-            fresh = 0
+            copied = 0
             y = None
             if nb:
                 if y_fused is not None:
                     y = y_fused
-                    fresh = y.nbytes
+                    copied = y.nbytes
+                    DATA_PATH.record_get_fresh_buffer("assemble", copied)
                 elif not missing:
                     y = x
                 else:
-                    y = np.empty((nb, k, shard_size), dtype=np.uint8)
-                    fresh = y.nbytes
+                    y = segarena.lease(full_bytes, "assemble").reshape(
+                        nb, k, shard_size)
+                    copied = y.nbytes
                     for s in range(k):
                         if s in sel:
                             y[:, s] = x[:, sel.index(s)]
                         else:
                             y[:, s] = out[missing.index(s)]
+                # `x` goes back to the segment pool here unless `y` is
+                # it, while the chunk is still on its way to the socket.
+                x = None
 
             # Tail fragment: reconstruct missing rows via the CPU oracle
             # codec (a partial block is tiny — not worth a device
@@ -1980,58 +2017,32 @@ class ErasureSet:
                     for s in t_missing:
                         tails[s] = rec[s]
                 tail_block = np.concatenate([tails[s] for s in range(k)])
-                fresh += tail_block.nbytes
-            sp.tag(bytes=fresh)
-        DATA_PATH.record_get_fresh_buffer("assemble", fresh)
+                copied += tail_block.nbytes
+                DATA_PATH.record_get_fresh_buffer("assemble",
+                                                  tail_block.nbytes)
+                tail_block = tail_block[:geo["tail_len"]]
+            sp.tag(bytes=copied)
 
         # The read's range of the assembled blocks: a view where one
-        # piece covers it, else the one copy that joins the pieces, and
-        # the copy into the caller's buffer where one was given.
+        # piece covers it, else the one copy that joins the pieces,
+        # straight into the caller's buffer where one was given.
         with ospan.span("engine.join") as sp:
-            pieces = []
+            blocks = None
             if nb:
-                if BLOCK_SIZE % k == 0:
-                    # k*shard_size == BLOCK_SIZE: zero-pad-free layout,
-                    # the whole full-block range is one contiguous view.
-                    pieces.append(y.reshape(-1))
-                else:
-                    flat = y.reshape(nb, k * shard_size)
-                    for bi in range(nb):
-                        pieces.append(flat[bi, :BLOCK_SIZE])
-            if has_tail:
-                pieces.append(tail_block[:geo["tail_len"]])
-            joined = length
-            if not pieces:
-                res: bytes | memoryview = b""
-                joined = 0
-            elif len(pieces) == 1:
-                view = pieces[0][lo:lo + length]
-                # Full aligned segment: hand the caller a view of the
-                # gather buffer (freshly allocated per call, never
-                # reused) — skipping the final tobytes copy, ~25% of a
-                # cached GET.
-                if view.size == pieces[0].size:
-                    res = memoryview(view)
-                    joined = 0
-                else:
-                    res = view.tobytes()
-            elif lo == 0 and sum(p.size for p in pieces) == length:
-                res = b"".join(memoryview(np.ascontiguousarray(p))
-                               for p in pieces)
-            else:
-                data = np.concatenate(pieces)
-                joined += data.nbytes
-                res = data[lo:lo + length].tobytes()
+                # A block is the first BLOCK_SIZE bytes of its K rows:
+                # all of them where K divides it (the whole full-block
+                # range is then one contiguous piece), else without the
+                # zero pad that ends the last row.
+                blocks = y.reshape(nb, k * shard_size)[:, :BLOCK_SIZE]
+            res, joined = _join_range(blocks, tail_block, lo, length, dst)
+            # `y` goes back to the pool with this frame where the join
+            # copied out of it; where `res` is a view of it, `res` is
+            # what keeps its arena leased until the last view dies.
             if degraded:
                 DATA_PATH.record_degraded_read(length,
                                                time.monotonic() - t_deg)
-            if dst is not None:
-                # Fallback/decode result lands in the caller's buffer
-                # too — one copy, same as the join it replaces.
-                dst[:length] = res
-                res = None
-            sp.tag(bytes=joined, pieces=len(pieces))
-        DATA_PATH.record_get_fresh_buffer("join", joined)
+            sp.tag(bytes=joined, pieces=int(has_tail) + (
+                nb if BLOCK_SIZE % k else min(nb, 1)))
         return res
 
     def _hash_shard_frames(self, bufs: list, nb: int, shard_size: int,

@@ -156,7 +156,8 @@ class BandwidthMonitor:
 
 
 #: Where the GET path allocates anew for a segment
-#: (`mtpu_get_fresh_buffer_bytes_total{site}`).
+#: (`mtpu_get_fresh_buffer_bytes_total{site}`), or leases an arena it
+#: already holds (`mtpu_get_leased_buffer_bytes_total{site}`).
 GET_FRESH_SITES = ("gather", "assemble", "join", "response")
 
 
@@ -246,6 +247,11 @@ class DataPathStats:
             # Bytes of arrays and byte strings the GET path allocated
             # anew for a segment, by site (never a view, never a reuse).
             self.get_fresh_buffer_bytes = dict.fromkeys(
+                GET_FRESH_SITES, 0)
+            # Bytes of segment buffers leased from an arena that was
+            # already mapped (engine/segarena.py), by site: the hits
+            # beside the misses above.
+            self.get_leased_buffer_bytes = dict.fromkeys(
                 GET_FRESH_SITES, 0)
             # Episodes in which requests were in flight and none
             # completed for the stall watcher's limit (server.py).
@@ -457,14 +463,22 @@ class DataPathStats:
 
     def record_get_fresh_buffer(self, site: str, nbytes: int) -> None:
         """The GET path allocated `nbytes` anew for a segment at `site`
-        (GET_FRESH_SITES; engine/erasure_set.py): the gather's `x`, the
-        assembled `y` and a tail's concatenation, the join's byte
-        string, the response's bytearray.  A view costs nothing and is
-        not counted; the host arrays the runtime fills on a result's
-        way back are `mtpu_d2h_bytes_total`'s."""
+        (GET_FRESH_SITES; engine/erasure_set.py): an arena the segment
+        pool had to map for the gather's `x`, the assembled `y` or the
+        join (engine/segarena.py), a tail's concatenation, the
+        response's bytearray.  A view costs nothing and is not counted,
+        nor is a lease of an arena already mapped
+        (`record_get_leased_buffer`); the host arrays the runtime fills
+        on a result's way back are `mtpu_d2h_bytes_total`'s."""
         if nbytes:
             with self._mu:
                 self.get_fresh_buffer_bytes[site] += nbytes
+
+    def record_get_leased_buffer(self, site: str, nbytes: int) -> None:
+        """The GET path leased `nbytes` at `site` from an arena the
+        segment pool already held: nothing was mapped."""
+        with self._mu:
+            self.get_leased_buffer_bytes[site] += nbytes
 
     def record_request_stall(self) -> None:
         with self._mu:
@@ -688,6 +702,8 @@ class DataPathStats:
                 "put_fresh_buffer_bytes": self.put_fresh_buffer_bytes,
                 "get_fresh_buffer_bytes": dict(
                     self.get_fresh_buffer_bytes),
+                "get_leased_buffer_bytes": dict(
+                    self.get_leased_buffer_bytes),
                 "request_stall_episodes": self.request_stall_episodes,
                 "ipc_submits": self.ipc_submits,
                 "ipc_rows": self.ipc_rows,
@@ -920,12 +936,23 @@ class MetricsRegistry:
             "thread and its ring already hold")
         self.get_fresh_buffer_bytes = Gauge(
             "mtpu_get_fresh_buffer_bytes_total",
-            "Bytes of arrays and byte strings the GET path allocated "
-            "anew for a segment, by site: gather (the K chosen rows "
-            "into x), assemble (y, a tail's concatenation), join (the "
-            "byte string joined or copied out of the pieces), response "
-            "(the object's bytearray); a view is not counted",
+            "Bytes the GET path allocated anew for a segment, by site: "
+            "gather (the K chosen rows into x), assemble (y, a tail's "
+            "concatenation), join (the pieces copied into one range), "
+            "response (the object's bytearray); an arena the segment "
+            "pool had to map counts here, a view or a lease of one "
+            "already mapped does not",
             ("site",))
+        self.get_leased_buffer_bytes = Gauge(
+            "mtpu_get_leased_buffer_bytes_total",
+            "Bytes of segment buffers the GET path leased from an arena "
+            "already mapped, by site: the hits beside "
+            "mtpu_get_fresh_buffer_bytes_total's misses",
+            ("site",))
+        self.get_arena_free_bytes = Gauge(
+            "mtpu_get_arena_free_bytes",
+            "Bytes of segment arenas on the pool's free list (mapped, "
+            "leased to nobody; at most engine/segarena.FREE_CAP_BYTES)")
         self.request_stall_episodes = Gauge(
             "mtpu_request_stall_episodes_total",
             "Episodes in which requests were in flight and for 3 s none "
@@ -1771,6 +1798,10 @@ class MetricsRegistry:
         self.put_fresh_buffer_bytes.set(snap["put_fresh_buffer_bytes"])
         for site, n in snap["get_fresh_buffer_bytes"].items():
             self.get_fresh_buffer_bytes.set(n, site=site)
+        for site, n in snap["get_leased_buffer_bytes"].items():
+            self.get_leased_buffer_bytes.set(n, site=site)
+        from ..engine import segarena as _segarena
+        self.get_arena_free_bytes.set(_segarena.POOL.free_bytes())
         self.request_stall_episodes.set(snap["request_stall_episodes"])
         self.ipc_submits.set(snap["ipc_submits"])
         self.ipc_results.set(snap["ipc_results"])

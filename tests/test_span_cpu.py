@@ -22,7 +22,7 @@ from concurrent.futures import ThreadPoolExecutor
 import numpy as np
 import pytest
 
-from minio_tpu.engine import shardmath
+from minio_tpu.engine import segarena, shardmath
 from minio_tpu.engine.erasure_set import BLOCK_SIZE, ErasureSet
 from minio_tpu.engine.pools import ServerPools
 from minio_tpu.engine.sets import ErasureSets
@@ -344,8 +344,23 @@ def fresh() -> dict:
     return DATA_PATH.snapshot()["get_fresh_buffer_bytes"]
 
 
+@pytest.fixture
+def empty_arenas(monkeypatch):
+    """A segment pool of the test's own: whatever its first read leases
+    has to be mapped, so its sizes read as fresh whatever ran before."""
+    monkeypatch.setattr(segarena, "POOL", segarena.SegmentArenas())
+
+
+def fresh_growth(fn) -> dict:
+    """What `fn()` grew each site's fresh counter by."""
+    before = fresh()
+    fn()
+    return {s: fresh()[s] - before[s] for s in GET_FRESH_SITES}
+
+
 @pytest.mark.parametrize("k,m", [(2, 2), (3, 3)], ids=["2+2", "3+3"])
-def test_degraded_read_names_and_counts_its_copies(device_codec, tmp_path,
+def test_degraded_read_names_and_counts_its_copies(device_codec,
+                                                   empty_arenas, tmp_path,
                                                    k, m):
     """One data shard gone: the gather, the assembly and the join are
     spans under `engine.read_part`, and each site's counter grows by
@@ -354,25 +369,33 @@ def test_degraded_read_names_and_counts_its_copies(device_codec, tmp_path,
     shard = fi.erasure.shard_size
     assert (BLOCK_SIZE % k == 0) == (k == 2)
     tail_shard = -(-4321 // k)
-    before = fresh()
     ospan.TRACER.configure(ring=8, sample=1.0)
-    with ospan.TRACER.root("api.GetObject"):
-        _, got = es.get_object("b", "o")
-    assert bytes(got) == body
-    grew = {s: fresh()[s] - before[s] for s in GET_FRESH_SITES}
-    assert grew == {"gather": 2 * k * shard,
-                    "assemble": 2 * k * shard + k * tail_shard,
-                    "join": SIZE, "response": SIZE}
-    rec = ospan.TRACER.traces()[-1]
-    part = child(rec, "engine.read_part")
-    names = [c["name"] for c in part["spans"]]
-    for name in ("engine.gather", "engine.assemble", "engine.join"):
-        assert names.count(name) == 1, names
-    assert child(part, "engine.gather")["tags"]["bytes"] == grew["gather"]
-    assert child(part, "engine.assemble")["tags"]["bytes"] == \
-        grew["assemble"]
-    join = child(part, "engine.join")["tags"]
-    assert join == {"bytes": SIZE, "pieces": 2 if k == 2 else 3}
+
+    def get():
+        with ospan.TRACER.root("api.GetObject"):
+            _, got = es.get_object("b", "o")
+        assert bytes(got) == body
+    grew = fresh_growth(get)
+    copied = {"gather": 2 * k * shard,
+              "assemble": 2 * k * shard + k * tail_shard}
+    # The join copies the pieces straight into the response's buffer.
+    assert grew == {**copied, "join": 0, "response": SIZE}
+    # `x` and `y` again, out of the pool.
+    assert fresh_growth(get) == {"gather": 0, "assemble": k * tail_shard,
+                                 "join": 0, "response": SIZE}
+    for rec in ospan.TRACER.traces()[-2:]:
+        # The spans' `bytes` are what was copied there, whatever had
+        # to be mapped for it.
+        part = child(rec, "engine.read_part")
+        names = [c["name"] for c in part["spans"]]
+        for name in ("engine.gather", "engine.assemble", "engine.join"):
+            assert names.count(name) == 1, names
+        assert child(part, "engine.gather")["tags"]["bytes"] == \
+            copied["gather"]
+        assert child(part, "engine.assemble")["tags"]["bytes"] == \
+            copied["assemble"]
+        join = child(part, "engine.join")["tags"]
+        assert join == {"bytes": SIZE, "pieces": 2 if k == 2 else 3}
     for name in ("engine.gather", "engine.assemble", "engine.join"):
         sp = child(part, name)
         assert sp["self_cpu_ms"] + sp["self_wait_ms"] == pytest.approx(
@@ -385,28 +408,31 @@ def test_degraded_read_names_and_counts_its_copies(device_codec, tmp_path,
                                                         rel=1e-5)
 
 
-def test_degraded_read_on_the_fused_host_path_gathers_nothing(tmp_path):
+def test_degraded_read_on_the_fused_host_path_gathers_nothing(empty_arenas,
+                                                              tmp_path):
     """The host's one native pass writes `y` itself: no `x`."""
     es, fi, body = make_set(tmp_path, 2, 2, SIZE, lose=1)
     if es.math.host_fused(2, 2, "mxh256") is None:
         pytest.skip("no native library here")
-    before = fresh()
-    _, got = es.get_object("b", "o")
-    assert bytes(got) == body
-    grew = {s: fresh()[s] - before[s] for s in GET_FRESH_SITES}
+    grew = fresh_growth(lambda: es.get_object("b", "o"))
     assert grew == {"gather": 0,
                     "assemble": 2 * BLOCK_SIZE + 2 * -(-4321 // 2),
-                    "join": SIZE, "response": SIZE}
+                    "join": 0, "response": SIZE}
+    assert bytes(es.get_object("b", "o")[1]) == body
 
 
 def test_healthy_read_that_hands_out_a_view_joins_nothing(device_codec,
+                                                          empty_arenas,
+                                                          monkeypatch,
                                                           tmp_path):
+    # No hedge: on a loaded host a parity spare that wins the race sends
+    # the read through the decode path, which gathers.
+    monkeypatch.setenv("MTPU_HEDGE", "0")
     es, fi, body = make_set(tmp_path, 2, 2, 2 * BLOCK_SIZE, lose=0)
-    before = fresh()
-    got = es._read_part("b", "o", fi, part_number=1, offset=0,
-                        length=2 * BLOCK_SIZE)
-    assert isinstance(got, memoryview) and bytes(got) == body
-    grew = {s: fresh()[s] - before[s] for s in GET_FRESH_SITES}
+    got = []
+    grew = fresh_growth(lambda: got.append(es._read_part(
+        "b", "o", fi, part_number=1, offset=0, length=2 * BLOCK_SIZE)))
+    assert isinstance(got[0], memoryview) and bytes(got[0]) == body
     assert grew == {"gather": 0, "assemble": 2 * BLOCK_SIZE, "join": 0,
                     "response": 0}
 
