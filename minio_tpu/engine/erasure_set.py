@@ -1752,9 +1752,9 @@ class ErasureSet:
             t_read = time.monotonic()
             asm_s = 0.0
             y = None
-            use_co = self.math.digest_rides(nb)
+            use_co = self.math.digest_rides(nb, algo)
             DATA_PATH.record_verify_blocks(nb)
-            if nb and fused_host is not None and not use_co:
+            if nb and fused_host is not None:
                 # mxh256 host: ONE C pass verifies every frame AND
                 # gathers the systematic rows straight into the final
                 # object buffer — targets=[] means the GF unit is never
@@ -1869,13 +1869,10 @@ class ErasureSet:
                     got = devcache_hit(*found)
                     if got is not None:
                         return got[0]
-            self.math.note_read(1)
             try:
                 got = fast_path()
             except (StorageError, OSError):
                 got = None
-            finally:
-                self.math.note_read(-1)
             if got is not None:
                 return got[0]
             DATA_PATH.record_fastpath_fallback()

@@ -229,13 +229,6 @@ class ShardMath:
         front end, ops/ipc_dispatch.py), or None (MTPU_COALESCE=0)."""
         return coalesce.get() if coalesce.enabled() else None
 
-    def note_read(self, delta: int) -> None:
-        """Inflight-read signal: a GET-only storm queues no encode
-        work, so only this counter shows the lane's hot() its reads."""
-        co = self._co()
-        if co is not None:
-            co.note_read(delta, device=self.device_idx)
-
     # -- codecs --------------------------------------------------------------
 
     def _cached(self, kind: str, k: int, m: int, make):
@@ -388,32 +381,33 @@ class ShardMath:
         # No TPU: native AVX codec; the framing pass hashes.
         return self.native(k, m).encode_blocks(blocks), None
 
-    def digest_rides(self, nb: int) -> bool:
-        """Verify routing of a healthy GET: on the device, and under
-        concurrent traffic (coalescer hot — work queued/dispatching,
-        recent occupancy >1, another read in flight) the digest rides
-        the shared dispatcher, many GETs to a launch; a lone host
-        stream keeps the direct path (no thread handoff).  Byte-exact
-        either way."""
-        co = self._co()
-        return (co is not None and nb > 0
-                and (self.use_device or co.hot(self.device_idx)))
+    def digest_rides(self, nb: int, algo: str) -> bool:
+        """Whether a healthy GET's digests of `algo` ride the set's
+        lane: only where the kernel is a device program
+        (`_fused_dev`), so concurrent GETs share a launch.  A digest
+        the host computes runs on the thread that holds its rows,
+        whatever the lane is doing: a host kernel on the lane would
+        share no launch, only add a stacking copy and one serial
+        thread.  Byte-exact either way."""
+        return (self._co() is not None and nb > 0
+                and self._fused_dev(algo))
 
     def digest(self, y: np.ndarray, k: int, m: int, algo: str,
                rides: bool) -> np.ndarray | None:
         """Bitrot digests (nb, k, hs) of a healthy GET's gathered rows
         `y` (nb, k, S), or None: this plane hashes the frames where
-        they lie, with the host kernels (`_hash_shard_frames`).
-        `rides` is `digest_rides(nb)`, asked once by the caller."""
+        they lie, on the calling thread, with the host kernels
+        (`_hash_shard_frames`).  `rides` is `digest_rides(nb, algo)`,
+        asked once by the caller."""
         nb, _, shard_size = y.shape
         co = self._co() if rides else None
         if co is not None:
             # Over the already-gathered rows (the gather IS the
             # assembly: no copy), stacked with other requests' digest
-            # work into one hash kernel, sized by the ladder of
-            # BATCH_BLOCKS * k rows.
+            # work into one device hash program, sized by the ladder
+            # of BATCH_BLOCKS * k rows.
             flat = y.reshape(nb * k, shard_size)
-            pad_rows = BATCH_BLOCKS * k if self.use_device else 0
+            pad_rows = BATCH_BLOCKS * k
             return self._ride(
                 co, ("digest", algo, shard_size, pad_rows), flat,
                 coalesce.make_digest_kernel(
@@ -459,23 +453,14 @@ class ShardMath:
                                device=self.device_idx), nb, direct)
         # Host path (host-hashed algorithm, no TPU, or an algo whose
         # native host kernel beats its device verify —
-        # bitrot_io.device_preferred): digest on host, reconstruct via
-        # the backend picker only if rows are missing.
-        flat = x.reshape(nb * k, shard_size)
-        hs = bitrot_io.digest_size(algo)
-        co = self._co()
-        if co is not None and co.hot(self.device_idx):
-            digests = self._ride(
-                co, ("digest", algo, shard_size, 0), flat,
-                coalesce.make_digest_kernel(algo), nb,
-                lambda: bitrot_io._hash_batch(flat, algo))
-        else:
-            digests = bitrot_io._hash_batch(flat, algo)
+        # bitrot_io.device_preferred): digest on the calling thread,
+        # reconstruct via the backend picker only if rows are missing.
+        digests = bitrot_io._hash_batch(x.reshape(nb * k, shard_size), algo)
         rows = None
         if targets:
             out = self.transform(k, m, x, sources, targets)    # (nb, T, S)
             rows = tuple(out[:, j] for j in range(len(targets)))
-        return digests.reshape(nb, k, hs), rows
+        return digests.reshape(nb, k, bitrot_io.digest_size(algo)), rows
 
     def transform(self, k: int, m: int, x, sources, targets,
                   resident=None, algo: str = "") -> np.ndarray:

@@ -356,7 +356,6 @@ class DispatchLane:
         self._pending_items = 0
         self._dispatching = False
         self._inline = 0
-        self._inflight_reads = 0
         # Occupancy EMA drives the adaptive window: ~1.0 means lone
         # requests (fire immediately), >1 means concurrent traffic is
         # actually packing (waiting the window pays for itself).
@@ -510,23 +509,6 @@ class DispatchLane:
                 with self._mu:
                     self._inline -= 1
         return h
-
-    # -- routing signals -----------------------------------------------------
-
-    def hot(self) -> bool:
-        """Whether routing MORE work through this lane is likely to
-        batch (vs. adding a thread handoff to a lone request): work is
-        queued or dispatching right now, recent dispatches packed >1
-        item, or >1 read is concurrently in flight."""
-        return (self._pending_items > 0 or self._dispatching
-                or self._inline > 0 or self._ema > 1.05
-                or self._inflight_reads > 1)
-
-    def note_read(self, delta: int) -> None:
-        """Healthy-GET concurrency signal (GET-only storms never queue
-        encode work, so queue depth alone cannot ignite hot())."""
-        with self._mu:
-            self._inflight_reads += delta
 
     # -- dispatcher ----------------------------------------------------------
 
@@ -996,9 +978,9 @@ class DispatchCoalescer:
                 if lane is None:
                     lane = DispatchLane(device=d)
                     if self._closed:
-                        # Post-close stragglers (a late note_read in a
-                        # request's finally) get a lane that refuses
-                        # submits but never hangs or raises elsewhere.
+                        # Post-close stragglers (a request still in
+                        # flight when the coalescer closed) get a lane
+                        # that refuses submits but never hangs.
                         lane._stopped = True
                     self._lanes[d] = lane
         return lane
@@ -1008,14 +990,6 @@ class DispatchCoalescer:
     def submit(self, key: tuple, payload: np.ndarray, fn,
                weight: int | None = None, device: int = 0) -> Handle:
         return self.lane(device).submit(key, payload, fn, weight)
-
-    def hot(self, device: int | None = None) -> bool:
-        if device is not None:
-            return self.lane(device).hot()
-        return any(ln.hot() for ln in list(self._lanes.values()))
-
-    def note_read(self, delta: int, device: int = 0) -> None:
-        self.lane(device).note_read(delta)
 
     # -- single-lane compatibility surface ----------------------------------
     # The scheduler unit tests (and the idle fast-path contract) poke
@@ -1214,34 +1188,22 @@ def make_verify_kernel(k: int, m: int, sources: tuple, targets: tuple,
     return kernel
 
 
-def make_digest_kernel(algo: str, pad_rows: int = 0,
+def make_digest_kernel(algo: str, pad_rows: int,
                        device: int | None = None):
-    """Batched bitrot digest over stacked (N, S) rows — the healthy-GET
-    verify and heal-verify workhorse.  `pad_rows` > 0 asks for the
-    device digest program, on lane `device`, sized by the ladder of
-    `pad_rows`; 0 (or an algorithm whose host kernel is preferred)
-    hashes with the host kernels.  The submitter's key carries
-    `pad_rows`, like every parameter a kernel closes over."""
-    from ..storage import bitrot_io
+    """Batched bitrot digest over stacked (N, S) rows — a healthy GET's
+    verify: the device digest program of `algo` (one of
+    fused.DEVICE_ALGOS), on lane `device`, sized by the ladder of
+    `pad_rows`.  A digest the host computes never rides a lane.  The
+    submitter's key carries `pad_rows`, like every parameter a kernel
+    closes over."""
+    from . import fused
 
-    if pad_rows:
-        from . import fused
+    def start(x, spans):
+        out_dev = fused.hash_rows_async(x, algo, device=device)
+        return (out_dev,), lambda out: [out[lo:hi] for lo, hi in spans]
 
-        if algo in fused.DEVICE_ALGOS and bitrot_io.device_preferred(algo):
-            def start(x, spans):
-                out_dev = fused.hash_rows_async(x, algo, device=device)
-                return (out_dev,), lambda out: [
-                    out[lo:hi] for lo, hi in spans]
-
-            return _device_kernel(
-                start, pad_rows, device,
-                functools.partial(fused.hash_rows_program, algo))
-
-    def kernel(stacked, spans, ctx):
-        out = bitrot_io._hash_batch(stacked, algo)
-        return [out[lo:hi] for lo, hi in spans]
-
-    return kernel
+    return _device_kernel(start, pad_rows, device,
+                          functools.partial(fused.hash_rows_program, algo))
 
 
 def build_geometry_ladder(k: int, m: int, shard_size: int, algo: str,

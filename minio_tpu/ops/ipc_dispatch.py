@@ -336,16 +336,6 @@ class RemoteCoalescer:
             return self.local.submit(key, payload, fn, weight,
                                      device=device)
 
-    def hot(self, device: int | None = None) -> bool:
-        # Remote routing means digest piggybacking still batches (on the
-        # owner) even when this worker's local queues are idle.
-        if self._remote_active() and mode() == "all":
-            return True
-        return self.local.hot(device)
-
-    def note_read(self, delta: int, device: int = 0) -> None:
-        self.local.note_read(delta, device=device)
-
     def lane_stats(self) -> dict:
         return self.local.lane_stats()
 

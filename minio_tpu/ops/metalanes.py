@@ -317,9 +317,8 @@ class MetaLane:
 
 class MetaBatcher:
     """Facade owning one write lane per drive, plus the request-level
-    concurrency counter that ignites packing (the note_read role of
-    the shard coalescer: queue depth alone cannot prove concurrency
-    when every idle submit runs inline)."""
+    concurrency counter that ignites packing (queue depth alone cannot
+    prove concurrency when every idle submit runs inline)."""
 
     def __init__(self):
         self._mu = threading.Lock()

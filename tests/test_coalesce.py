@@ -280,9 +280,9 @@ class TestFaultContainment:
 
     def test_engine_falls_back_when_handles_fail(self, tmp_path,
                                                  monkeypatch):
-        """A coalescer whose every handle errors must not fail reads:
-        the engine's verify sites fall back to the direct kernel and
-        count the fallback."""
+        """A coalescer whose every handle errors must not fail PUTs or
+        reads: the engine's coalesced sites fall back to the direct
+        kernel and count the fallback."""
         class FailHandle:
             def result(self, timeout=None):
                 raise RuntimeError("coalescer dispatcher died: stub")
@@ -293,12 +293,6 @@ class TestFaultContainment:
         class BrokenCoalescer:
             def submit(self, key, payload, fn, weight=None, device=0):
                 return FailHandle()
-
-            def hot(self, device=None):
-                return True           # force the coalesced verify route
-
-            def note_read(self, delta, device=0):
-                pass
 
         monkeypatch.setenv("MTPU_COALESCE", "1")
         monkeypatch.setattr(coalesce, "get", lambda: BrokenCoalescer())

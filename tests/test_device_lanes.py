@@ -131,9 +131,18 @@ class TestLaneFacade:
         co = coalesce.get()
         co.lane(3)._ema = 5.0
         assert co.lane(0)._ema <= 1.05
-        assert co.lane(0).hot() is False and co.lane(3).hot() is True
-        assert co.hot(device=0) is False and co.hot(device=3) is True
-        assert co.hot() is True            # any-lane view for admin
+
+        def where(stacked, spans, ctx):
+            return [threading.current_thread().name for _ in spans]
+
+        # Lane 0 runs an idle submit inline on the caller; lane 3, whose
+        # EMA says concurrent traffic is packing, queues it for its
+        # own thread.
+        h0 = co.submit(("where",), np.ones(1, np.uint8), where, device=0)
+        h3 = co.submit(("where",), np.ones(1, np.uint8), where, device=3)
+        assert h0.result(5.0) == threading.current_thread().name
+        assert h3.result(5.0) == "mtpu-coalesce-d3"
+        assert co.lane(0)._ema <= 1.05 and co.lane(3)._ema > 1.05
 
     def test_lane_fault_never_fails_another_lane(self, ndev,
                                                  monkeypatch):

@@ -2,24 +2,31 @@
 
 The table: what the seam chooses, over platform x chips x sets x algorithm
 x native HighwayHash x pool worker x MTPU_COALESCE / MTPU_MESH, read off
-what it calls (every backend is stood in for, nothing is computed).  The
-fallbacks: a failed coalescer handle gives the direct function's bytes and
-counts one fallback, for each operation, computed for real.
+what it calls (every backend is stood in for, nothing is computed); what
+the lane is doing is not an input.  The fallbacks: a failed coalescer
+handle gives the direct function's bytes and counts one fallback, for
+each operation, computed for real.  Concurrent GETs on the CPU plane:
+every digest checked on the thread that holds its rows.
 """
 
 from __future__ import annotations
 
+import os
+import threading
 import weakref
+from concurrent.futures import ThreadPoolExecutor
 
 import numpy as np
 import pytest
 
 from minio_tpu.engine import shardmath
+from minio_tpu.engine.erasure_set import ErasureSet
 from minio_tpu.engine.shardmath import BLOCK_SIZE, ShardMath
 from minio_tpu.observe.metrics import DATA_PATH
 from minio_tpu.ops import coalesce, fused
 from minio_tpu.ops import devices as devices_mod
 from minio_tpu.storage import bitrot_io
+from minio_tpu.storage.drive import LocalDrive
 
 K, M = 2, 2
 S = BLOCK_SIZE // K
@@ -43,20 +50,13 @@ class Coalescer:
     """Stands in for ops/coalesce's scheduler (or a worker's remote front
     end): records what is submitted, answers with `make_handle`."""
 
-    def __init__(self, make_handle=Handle, hot=False):
-        self.make_handle, self.is_hot, self.seen, self.reads = \
-            make_handle, hot, [], 0
+    def __init__(self, make_handle=Handle):
+        self.make_handle, self.seen = make_handle, []
 
     def submit(self, key, payload, fn, weight=None, device=0):
         self.seen.append({"key": key, "weight": weight, "device": device,
                           "rows": payload.shape[0], "fn": fn})
         return self.make_handle()
-
-    def hot(self, device=None):
-        return self.is_hot
-
-    def note_read(self, delta, device=0):
-        self.reads += delta
 
 
 @pytest.fixture()
@@ -67,7 +67,7 @@ def host(monkeypatch):
     keep = []
 
     def build(platform, chips=1, sets=1, hh_native=True, coalesced=True,
-              mesh="", hot=False):
+              mesh=""):
         monkeypatch.setattr(shardmath, "platform", lambda: platform)
         monkeypatch.setattr(
             devices_mod, "_VISIBLE",
@@ -80,7 +80,7 @@ def host(monkeypatch):
         else:
             monkeypatch.delenv("MTPU_MESH", raising=False)
         monkeypatch.setattr(bitrot_io, "_hh_native", lambda: hh_native)
-        co = Coalescer(hot=hot)
+        co = Coalescer()
         if platform == WORKER:
             # A pool worker: coalesce.get() answers its remote front end.
             monkeypatch.setattr(coalesce, "_REMOTE", co)
@@ -246,21 +246,28 @@ def test_the_seams_choice(host, backends, platform, chips, sets, algo,
     assert (sm.host_fused(K, M) is not None) == (not platform[0])
     assert sm.host_fused(40, 30, "mxh256") is None      # 64 row pointers
     assert sm.segment_blocks() == (32 if platform[0] else 16)
+    # a healthy GET's digest rides the lane only as a device program
+    device_hashed = algo == "mxh256" or (
+        algo.startswith("highwayhash") and not hh_native)
+    assert sm.digest_rides(2, algo) is (
+        coalesced and platform[0] and device_hashed)
 
 
-@pytest.mark.parametrize("platform,algo,hot,rides,key", [
-    (TPU, "mxh256", False, True, ("digest", "mxh256", S, 32 * K)),
-    (WORKER, "mxh256", False, True, ("digest", "mxh256", S, 32 * K)),
-    (TPU, "sha256", False, True, ("digest", "sha256", S, 32 * K)),
-    (HOST, "mxh256", True, True, ("digest", "mxh256", S, 0)),
-    (HOST, "mxh256", False, False, None),       # a lone stream: direct
-    (HOST, "sha256", False, False, None),
+@pytest.mark.parametrize("platform,algo,rides,key", [
+    (TPU, "mxh256", True, ("digest", "mxh256", S, 32 * K)),
+    (WORKER, "mxh256", True, ("digest", "mxh256", S, 32 * K)),
+    (TPU, "sha256", False, None),               # the host hashes it
+    (TPU, "highwayhash256S", False, None),      # its native kernel wins
+    (WORKER, "highwayhash256S", False, None),
+    (HOST, "mxh256", False, None),              # get_verify, not a digest
+    (HOST, "sha256", False, None),
 ])
 def test_healthy_get_digest_rides_or_not(host, backends, platform, algo,
-                                         hot, rides, key):
-    sm, co = host(platform, hot=hot)
+                                         rides, key):
+    sm, co = host(platform)
     co.make_handle = lambda: Handle(np.zeros((2 * K, 32), np.uint8))
-    assert sm.digest_rides(2) is rides and sm.digest_rides(0) is False
+    assert sm.digest_rides(2, algo) is rides
+    assert sm.digest_rides(0, algo) is False
     y = np.zeros((2, K, S), np.uint8)
     digests = sm.digest(y, K, M, algo, rides)
     if rides:
@@ -270,19 +277,64 @@ def test_healthy_get_digest_rides_or_not(host, backends, platform, algo,
         assert (sub["device"], sub["weight"]) == (0, 2)
     else:               # the host kernels hash the frames where they lie
         assert digests is None and co.seen == [] and backends == []
-    sm.note_read(1)
-    sm.note_read(-1)
-    assert co.reads == 0
 
 
 def test_digest_direct_on_the_lane_without_the_coalescer(host, backends):
     sm, co = host(TPU, coalesced=False)
-    assert sm.digest_rides(1) is False
+    assert sm.digest_rides(1, "mxh256") is False
     assert sm.digest(np.zeros((1, K, S), np.uint8), K, M, "mxh256",
                      False).shape == (1, K, 32)
     assert backends == ["lane"] and co.seen == []
-    sm.note_read(1)                     # no coalescer: nothing to tell
-    assert co.reads == 0
+
+
+# What a busy lane looks like: the states in which the lane once took
+# host-computed digests too.
+LANE_STATES = {
+    "items_pending": {"_pending_items": 3},
+    "dispatching": {"_dispatching": True},
+    "inline": {"_inline": 1},
+    "packing": {"_ema": 5.0},
+}
+
+
+@pytest.mark.parametrize("state", list(LANE_STATES))
+def test_a_host_digest_stays_on_its_thread_however_busy_the_lane(
+        host, backends, monkeypatch, state):
+    """Where a digest is computed is a function of platform, algorithm
+    and MTPU_COALESCE: on a lane in any state a host-hashed digest is
+    submitted nowhere, and an on-chip mxh256 digest still rides."""
+    co = coalesce.DispatchCoalescer()
+    seen = []
+
+    def submit(key, payload, fn, weight=None, device=0):
+        seen.append(key)
+        return Handle(np.zeros((payload.shape[0], 32), np.uint8))
+
+    monkeypatch.setattr(co, "submit", submit)
+    try:
+        for platform, algo in [(HOST, "mxh256"), (HOST, "sha256"),
+                               (HOST, "highwayhash256S"), (TPU, "sha256"),
+                               (TPU, "highwayhash256S"), (TPU, "mxh256")]:
+            sm, _ = host(platform)
+            monkeypatch.setattr(coalesce, "get", lambda: co)
+            lane = co.lane(sm.device_idx)
+            for name, value in LANE_STATES[state].items():
+                setattr(lane, name, value)
+            del seen[:]
+            rides = sm.digest_rides(2, algo)
+            digests = sm.digest(np.zeros((2, K, S), np.uint8), K, M, algo,
+                                rides)
+            if (platform, algo) == (TPU, "mxh256"):
+                assert rides and digests.shape == (2, K, 32)
+                assert seen == [("digest", "mxh256", S, 32 * K)]
+                continue
+            assert not rides and digests is None
+            got, rebuilt = sm.verify_transform(
+                np.zeros((1, K, S), np.uint8), K, M, (1, 2), (0,), algo)
+            assert got.shape == (1, K, 32) and len(rebuilt) == 1
+            assert seen == [], (platform, algo)
+    finally:
+        co.close()
 
 
 def test_census_counts_lanes_that_own_a_live_set(host):
@@ -322,7 +374,7 @@ def _encode(sm, algo):
 
 def _digest(sm, algo):
     y = _blocks(2, 2)
-    return sm.digest(y, K, M, algo, sm.digest_rides(2)).tobytes()
+    return sm.digest(y, K, M, algo, sm.digest_rides(2, algo)).tobytes()
 
 
 def _verify_transform(sm, algo):
@@ -336,7 +388,7 @@ def _verify_transform(sm, algo):
     (_encode, HOST, "sha256", "enc"),
     (_encode, WORKER, "mxh256", "enc"),     # the device codec on the CPU
     (_digest, WORKER, "mxh256", "digest"),
-    (_verify_transform, HOST, "mxh256", "digest"),
+    (_digest, TPU, "mxh256", "digest"),     # the chip's owner
     (_verify_transform, WORKER, "mxh256", "vt"),
 ])
 def test_failed_handle_gives_the_direct_bytes_and_counts_one_fallback(
@@ -349,11 +401,12 @@ def test_failed_handle_gives_the_direct_bytes_and_counts_one_fallback(
 
     monkeypatch.setattr(shardmath, "_LOCAL_SETS", weakref.WeakSet())
     monkeypatch.setattr(shardmath, "platform", lambda: platform)
+    monkeypatch.setenv("MTPU_MESH", "0")
     sm = ShardMath(0)
     monkeypatch.setenv("MTPU_COALESCE", "0")
     want = op(sm, algo)                         # the direct function
     monkeypatch.setenv("MTPU_COALESCE", "1")
-    co = Coalescer(broken, hot=True)
+    co = Coalescer(broken)
     monkeypatch.setattr(coalesce, "get", lambda: co)
     before = DATA_PATH.snapshot()["co_fallbacks"]
     assert op(sm, algo) == want
@@ -382,3 +435,96 @@ def test_coalesced_put_frame_buffers_are_released_two_batches_later(
         assert enc.frames(enc.encode(_blocks(1, i))) == ["frames"]
         assert [h.released for h in handles] == \
             [1] * max(0, i - 1) + [0] * min(i + 1, 2)
+
+
+# -- many GETs at once: every digest where its rows are ----------------------
+
+NB = 2                          # full blocks an object; a tail besides
+
+
+def _spy(monkeypatch, owner, name, count, calls):
+    """Wrap `owner.name`: each call appends (digests it checked, the
+    calling thread's name) to `calls`."""
+    real = getattr(owner, name)
+
+    def spy(*a, **kw):
+        out = real(*a, **kw)
+        calls.append((count(a, out), threading.current_thread().name))
+        return out
+
+    monkeypatch.setattr(owner, name, spy)
+
+
+@pytest.mark.parametrize("lose", [0, 1], ids=["healthy", "hidden"])
+@pytest.mark.parametrize("k,m", [(2, 2), (3, 3)], ids=["2+2", "3+3"])
+@pytest.mark.parametrize("algo", ["highwayhash256S", "mxh256"])
+def test_eight_threads_of_gets_check_every_digest_off_the_lane(
+        tmp_path, monkeypatch, algo, k, m, lose):
+    """Eight threads x 10 GETs of a set written under `algo` on the CPU
+    plane, with `lose` data shards of every object unlinked: every body
+    exact, every full block's K digests checked on a request's thread
+    (HighwayHash by the host hash, mxh256 by `get_verify`), and not one
+    digest or verify submitted to the coalescer."""
+    monkeypatch.setattr(shardmath, "_LOCAL_SETS", weakref.WeakSet())
+    monkeypatch.setattr(shardmath, "platform", lambda: HOST)
+    monkeypatch.delenv("MTPU_MESH", raising=False)
+    monkeypatch.setenv("MTPU_COALESCE", "1")
+    monkeypatch.setenv("MTPU_BITROT_ALGO", algo)
+    monkeypatch.setenv("MTPU_HOTCACHE", "0")      # every GET reads shards
+    monkeypatch.setenv("MTPU_DEVCACHE", "0")
+    coalesce.reset()
+    drives = [LocalDrive(str(tmp_path / f"d{i}")) for i in range(k + m)]
+    es = ErasureSet(drives, default_parity=m)
+    es.make_bucket("b")
+    bodies = []
+    for i in range(4):
+        body = np.random.default_rng([43, k, m, i]).bytes(
+            NB * BLOCK_SIZE + 999)
+        fi = es.put_object("b", f"o{i}", body)
+        bodies.append(body)
+        dist = fi.erasure.distribution
+        for p in sorted(range(es.n), key=lambda p: dist[p])[:lose]:
+            for dirpath, _, names in os.walk(
+                    os.path.join(drives[p].root, "b", f"o{i}")):
+                for n in names:
+                    if n.startswith("part."):
+                        os.unlink(os.path.join(dirpath, n))
+
+    submitted, calls = [], []
+    real_submit = coalesce.DispatchCoalescer.submit
+
+    def submit(self, key, *a, **kw):
+        submitted.append(key)
+        return real_submit(self, key, *a, **kw)
+
+    monkeypatch.setattr(coalesce.DispatchCoalescer, "submit", submit)
+    if algo == "mxh256":
+        ecio = shardmath.ecio_mod()
+        assert ecio is not None
+        _spy(monkeypatch, ecio, "get_verify",
+             lambda a, out: a[2] * len(a[1]), calls)
+    else:
+        _spy(monkeypatch, ShardMath, "verify_transform",
+             lambda a, out: out[0].shape[0] * out[0].shape[1], calls)
+        _spy(monkeypatch, ShardMath, "digest",
+             lambda a, out: 0 if out is None else out.shape[0] * k, calls)
+        _spy(monkeypatch, ErasureSet, "_hash_shard_frames",
+             lambda a, out: sum(len(d) for d in out), calls)
+
+    def client(c: int) -> int:
+        for i in range(10):
+            j = (c + i) % len(bodies)
+            _, it = es.get_object_iter("b", f"o{j}")
+            assert b"".join(it) == bodies[j]
+        return c
+
+    try:
+        with ThreadPoolExecutor(8) as ex:
+            assert [f.result(timeout=300) for f in
+                    [ex.submit(client, c) for c in range(8)]] == \
+                list(range(8))
+    finally:
+        coalesce.reset()
+    assert submitted == []
+    assert sum(n for n, _ in calls) >= 8 * 10 * NB * k
+    assert not [t for _, t in calls if t.startswith("mtpu-coalesce")]

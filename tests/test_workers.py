@@ -45,6 +45,7 @@ from minio_tpu.ops.ipc_ring import REC, ShmRing
 from minio_tpu.ops.shm_arena import ArenaFull, ShmArena
 from minio_tpu.server.client import S3Client
 from minio_tpu.server.workers import SharedState, WorkerPlane, nworkers_env
+from minio_tpu.storage import bitrot_io
 
 _REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 _MB = 1 << 20
@@ -262,12 +263,24 @@ def ipc_plane(monkeypatch):
     co.close()
 
 
+def host_digest_kernel(stacked, spans, ctx):
+    """A dispatch kernel of the coalescer's shape: the host's mxh256
+    digests of the stacked rows, a span's rows each."""
+    out = bitrot_io._hash_batch(stacked, "mxh256")
+    return [out[lo:hi] for lo, hi in spans]
+
+
+# The device digest program's key, as the engine submits it: the owner
+# rebuilds its kernel from the key alone (ipc_dispatch.kernel_from_key).
+PAD = 32
+
+
 class TestRemoteProtocol:
     def test_digest_roundtrip_matches_local_oracle(self, ipc_plane):
         rng = np.random.default_rng(7)
         payload = rng.integers(0, 256, size=(8, 4096), dtype=np.uint8)
-        key = ("digest", "mxh256", 4096, 0)
-        fn = coalesce.make_digest_kernel("mxh256")
+        key = ("digest", "mxh256", 4096, PAD)
+        fn = host_digest_kernel
 
         local = coalesce.DispatchCoalescer()
         try:
@@ -292,12 +305,12 @@ class TestRemoteProtocol:
 
     def test_arena_slots_returned_after_roundtrips(self, ipc_plane):
         payload = np.zeros((4, 1024), dtype=np.uint8)
-        fn = coalesce.make_digest_kernel("mxh256")
         rc = ipc.RemoteCoalescer(ipc_plane, 0)
         try:
             for _ in range(5):
                 ipc_plane.state.owner_beat()
-                h = rc.submit(("digest", "mxh256", 1024, 0), payload, fn)
+                h = rc.submit(("digest", "mxh256", 1024, PAD), payload,
+                              host_digest_kernel)
                 h.result(timeout=60.0)
             deadline = time.monotonic() + 10
             while (ipc_plane.arena.stats()["in_use_bytes"]
@@ -312,7 +325,7 @@ class TestRemoteProtocol:
         try:
             ipc_plane.state.owner_beat()
             h = rc.submit(("bogus", 1), np.zeros((2, 8), np.uint8),
-                          coalesce.make_digest_kernel("mxh256"))
+                          host_digest_kernel)
             with pytest.raises(RuntimeError):
                 h.result(timeout=60.0)
             assert rc.stats()["remote_errors"] == 1
@@ -327,9 +340,8 @@ class TestRemoteProtocol:
         plane.state.owner_beat()
         rc = ipc.RemoteCoalescer(plane, 0)
         try:
-            h = rc.submit(("digest", "mxh256", 64, 0),
-                          np.zeros((1, 64), np.uint8),
-                          coalesce.make_digest_kernel("mxh256"))
+            h = rc.submit(("digest", "mxh256", 64, PAD),
+                          np.zeros((1, 64), np.uint8), host_digest_kernel)
             np.asarray(h.result(timeout=60.0))
             h.release()
             st = rc.stats()
@@ -348,9 +360,8 @@ class TestRemoteProtocol:
         plane.state.owner_beat()
         rc = ipc.RemoteCoalescer(plane, 0)
         try:
-            h = rc.submit(("digest", "mxh256", 64, 0),
-                          np.zeros((1, 64), np.uint8),
-                          coalesce.make_digest_kernel("mxh256"))
+            h = rc.submit(("digest", "mxh256", 64, PAD),
+                          np.zeros((1, 64), np.uint8), host_digest_kernel)
             assert rc.stats()["remote_submits"] == 1
             plane.state._a[2] = 0          # heartbeat goes stale NOW
             with pytest.raises(RuntimeError):
