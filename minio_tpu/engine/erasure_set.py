@@ -38,7 +38,7 @@ from ..ops import zerocopy as zc
 from ..parallel import pipeline as pl
 from ..storage import bitrot_io
 from ..storage.drive import (SMALL_FILE_THRESHOLD, SYS_VOL, TMP_DIR,
-                             LocalDrive)
+                             LocalDrive, read_rows, rows_readable)
 from ..storage.errors import (ErrBucketExists, ErrBucketNotFound,
                               ErrDiskNotFound, ErrErasureReadQuorum,
                               ErrErasureWriteQuorum, ErrFileCorrupt,
@@ -1634,13 +1634,30 @@ class ErasureSet:
         dc_gen0 = (dcache.current_gen(self._devcache_owner, bucket)
                    if dcache is not None else 0)
 
-        def read_shard(pos: int):
-            """Fetch + structurally parse one shard's frame range.
+        # A shard's segment: nb full frames, then the tail's frame, which
+        # ends the shard file.
+        expect = nb * frame + ((hs + tail_shard) if has_tail else 0)
 
-            Returns (hashes (nb, 32), blocks (nb, S), tail or None, raw);
-            full blocks are NOT hash-verified here — that happens batched
-            on device (or in the fused native pass, which consumes `raw`).
+        def parse_row(buf):
+            """(hashes (nb, 32), blocks (nb, S), tail or None, raw): views
+            of one shard's segment `buf` (`expect` bytes of uint8).  Full
+            blocks are NOT hash-verified here — that happens batched on
+            device (or in the fused native pass, which consumes `raw`).
             The (tiny) tail fragment verifies on host immediately.
+            Views, no copy: the selected rows are gathered into one
+            contiguous (nb, K, S) buffer in a single strided pass below
+            — copying here would double the memory traffic."""
+            frames = buf[:nb * frame].reshape(nb, frame)
+            tail = None
+            if has_tail:
+                tail = bitrot_io.unframe_shard(
+                    buf[nb * frame:].tobytes(), tail_shard, verify=True,
+                    algo=algo)
+            return frames[:, :hs], frames[:, hs:], tail, buf[:nb * frame]
+
+        def read_shard(pos: int):
+            """Fetch + structurally parse one shard's frame range: a
+            drive call (`parse_row`'s views of what it returned).
             Successful reads feed the per-position EWMA that drives
             hedge ignition on serial hosts (failures don't: a fast
             error must not make a drive look fast).
@@ -1656,21 +1673,41 @@ class ErasureSet:
                 raw = d.read_file(bucket, path, b0 * frame,
                                   (b1 - b0) * frame)
             buf = np.frombuffer(raw, dtype=np.uint8)
-            expect = nb * frame + ((hs + tail_shard) if has_tail else 0)
             if buf.size != expect:
                 raise ErrFileCorrupt(
                     f"shard segment {buf.size} != expected {expect}")
-            frames = buf[:nb * frame].reshape(nb, frame)
-            tail = None
-            if has_tail:
-                tail = bitrot_io.unframe_shard(
-                    buf[nb * frame:].tobytes(), tail_shard, verify=True,
-                    algo=algo)
-            # Views, no copy: the selected rows are gathered into one
-            # contiguous (nb, K, S) buffer in a single strided pass
-            # below — copying here would double the memory traffic.
+            row = parse_row(buf)
             self._note_read_ms(pos, (time.monotonic() - t_rs) * 1e3)
-            return frames[:, :hs], frames[:, hs:], tail, buf[:nb * frame]
+            DATA_PATH.record_shard_rows("pool", 1)
+            return row
+
+        def read_batched(first, then):
+            """Shards `first`, and for each that fails the next of `then`,
+            in ONE native call (drive.read_rows): the K rows a round
+            needs, read into one leased buffer with the GIL released
+            once.  Fills `rows` and `tried` as a round of read_shard
+            calls does; a failed read or tail lands in `tried` alone."""
+            if not first:
+                return
+            shards = [*first, *then]
+            out = segarena.lease(len(first) * expect, "read")
+            got = read_rows(
+                [self.drives[order[s]] for s in shards], bucket, path,
+                b0 * frame, expect, len(first), out, exact_end=has_tail)
+            fetched = 0
+            for s, (j, err, secs) in zip(shards, got):
+                if j is None and err is None:
+                    continue                    # not tried: K were in
+                tried.add(s)
+                if err is not None:
+                    continue
+                try:
+                    rows[s] = parse_row(out[j * expect:(j + 1) * expect])
+                except StorageError:            # the tail's digest
+                    continue
+                fetched += 1
+                self._note_read_ms(order[s], secs * 1e3)
+            DATA_PATH.record_shard_rows("batched", fetched)
 
         order = Q.shuffle_by_distribution(list(range(self.n)), dist)
         # order[s] = drive position holding shard s. Data shards first,
@@ -1685,6 +1722,14 @@ class ErasureSet:
                       if drive_available(self.drives[order[s]])]
         degraded = any(s < k for s in range(k + m) if s not in candidates)
         t_deg = time.monotonic() if degraded else 0.0
+        # Each round's rows come from one native call where every
+        # candidate is a drive of this process and no fused host pass
+        # reads mmap views of them (a slow local drive is its breaker's
+        # business, as at every serial-local site).  Remote drives, the
+        # host-fused plane, O_DIRECT and a host with no toolchain read a
+        # row a drive call, through the pool and the hedge.
+        batched = fused_host is None and rows_readable(
+            [self.drives[order[s]] for s in candidates])
         lo = offset - b0 * BLOCK_SIZE
         full_bytes = nb * k * shard_size   # nb * BLOCK_SIZE where K divides it
         aligned = dst is not None and lo == 0 and length >= full_bytes
@@ -1718,18 +1763,21 @@ class ErasureSet:
                 _hedge_enabled() and want and not self._on_drive_pool()
                 and (not self._serial_local()
                      or self._hedge_worthwhile([order[s] for s in want])))
-            if use_hedge:
-                spares = [s for s in candidates
-                          if s >= k and s not in rows]
-                abandoned = self._hedged_fetch(
-                    read_shard, order, rows, tried, want, spares, k)
-                for s in abandoned:
-                    tried.discard(s)
+            spares = [s for s in candidates if s >= k and s not in rows]
+            if batched or use_hedge:
+                if batched:
+                    read_batched(want, spares)
+                else:
+                    abandoned = self._hedged_fetch(
+                        read_shard, order, rows, tried, want, spares, k)
+                    for s in abandoned:
+                        tried.discard(s)
                 if any(s not in rows for s in range(k)):
-                    # A parity spare won the race (or a data read
-                    # failed): the row set isn't purely systematic, so
-                    # the decode loop below reconstructs from these
-                    # rows — no re-read, just GF work for the holes.
+                    # A data read failed and a spare took its slot (or a
+                    # parity spare won the race): the row set isn't
+                    # purely systematic, so the decode loop below
+                    # reconstructs from these rows — no re-read, just GF
+                    # work for the holes.
                     return None
             elif self._serial_local() or self._on_drive_pool():
                 tried.update(want)
@@ -1897,8 +1945,13 @@ class ErasureSet:
             # the GIL, so overlapping them pays even on the 1-core host
             # (unlike the healthy path, where the K reads are page-cache
             # hits and pool hops only add latency).
+            remaining = [s for s in candidates
+                         if s not in tried and s not in rows
+                         and s not in active]
             with ospan.span("engine.read"):
-                if (self._serial_local() and not degraded) \
+                if batched:
+                    read_batched(active, remaining)
+                elif (self._serial_local() and not degraded) \
                         or self._on_drive_pool():
                     for s in active:
                         tried.add(s)
@@ -1911,9 +1964,6 @@ class ErasureSet:
                     # ALL active futures (one tail-slow survivor stalls
                     # the stripe), take the first k arrivals and cover
                     # stragglers/failures from the remaining spares.
-                    remaining = [s for s in candidates
-                                 if s not in tried and s not in rows
-                                 and s not in active]
                     abandoned = self._hedged_fetch(
                         read_shard, order, rows, tried, active,
                         remaining, k)

@@ -36,9 +36,9 @@ from ..observe.metrics import DATA_PATH
 
 # Bytes the free list keeps; what comes back beyond them is unmapped,
 # the longest unused first.  Eight streams at EC:6+6, each with two
-# segments in flight that hold an `x`, a `y` and a join of 33.5 MB,
-# peak at ~1.6 GB (PERF.md §6, PR 41); 2 GiB covers that.
-FREE_CAP_BYTES = 2 << 30
+# segments in flight that hold the shard rows read, an `x`, a `y` and a
+# join of 33.5 MB, peak at ~2.1 GB (PERF.md §6); 3 GiB covers that.
+FREE_CAP_BYTES = 3 << 30
 # Below this a buffer is not leased but allocated: under malloc's mmap
 # threshold (128 KiB unless it has grown) a fresh array never was a
 # mapping, and an arena is whole pages, which a pool of ranged reads'

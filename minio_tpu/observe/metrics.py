@@ -158,7 +158,7 @@ class BandwidthMonitor:
 #: Where the GET path allocates anew for a segment
 #: (`mtpu_get_fresh_buffer_bytes_total{site}`), or leases an arena it
 #: already holds (`mtpu_get_leased_buffer_bytes_total{site}`).
-GET_FRESH_SITES = ("gather", "assemble", "join", "response")
+GET_FRESH_SITES = ("gather", "assemble", "join", "response", "read")
 
 
 class DataPathStats:
@@ -253,6 +253,10 @@ class DataPathStats:
             # beside the misses above.
             self.get_leased_buffer_bytes = dict.fromkeys(
                 GET_FRESH_SITES, 0)
+            # Shard rows a read's segment fetched, by route: batched
+            # (K rows in one native call, storage/drive.read_rows) or
+            # pool (a drive call a row).
+            self.shard_rows_read = {"batched": 0, "pool": 0}
             # Episodes in which requests were in flight and none
             # completed for the stall watcher's limit (server.py).
             self.request_stall_episodes = 0
@@ -464,9 +468,10 @@ class DataPathStats:
     def record_get_fresh_buffer(self, site: str, nbytes: int) -> None:
         """The GET path allocated `nbytes` anew for a segment at `site`
         (GET_FRESH_SITES; engine/erasure_set.py): an arena the segment
-        pool had to map for the gather's `x`, the assembled `y` or the
-        join (engine/segarena.py), a tail's concatenation, the
-        response's bytearray.  A view costs nothing and is not counted,
+        pool had to map for the gather's `x`, the assembled `y`, the
+        join or the shard rows read in one native call
+        (engine/segarena.py), a tail's concatenation, the response's
+        bytearray.  A view costs nothing and is not counted,
         nor is a lease of an arena already mapped
         (`record_get_leased_buffer`); the host arrays the runtime fills
         on a result's way back are `mtpu_d2h_bytes_total`'s."""
@@ -479,6 +484,12 @@ class DataPathStats:
         segment pool already held: nothing was mapped."""
         with self._mu:
             self.get_leased_buffer_bytes[site] += nbytes
+
+    def record_shard_rows(self, path: str, n: int) -> None:
+        """`n` shard rows of a read's segment came back on `path`
+        ("batched" or "pool")."""
+        with self._mu:
+            self.shard_rows_read[path] += n
 
     def record_request_stall(self) -> None:
         with self._mu:
@@ -704,6 +715,7 @@ class DataPathStats:
                     self.get_fresh_buffer_bytes),
                 "get_leased_buffer_bytes": dict(
                     self.get_leased_buffer_bytes),
+                "shard_rows_read": dict(self.shard_rows_read),
                 "request_stall_episodes": self.request_stall_episodes,
                 "ipc_submits": self.ipc_submits,
                 "ipc_rows": self.ipc_rows,
@@ -939,7 +951,8 @@ class MetricsRegistry:
             "Bytes the GET path allocated anew for a segment, by site: "
             "gather (the K chosen rows into x), assemble (y, a tail's "
             "concatenation), join (the pieces copied into one range), "
-            "response (the object's bytearray); an arena the segment "
+            "response (the object's bytearray), read (the buffer the "
+            "shard rows are read into); an arena the segment "
             "pool had to map counts here, a view or a lease of one "
             "already mapped does not",
             ("site",))
@@ -949,6 +962,12 @@ class MetricsRegistry:
             "already mapped, by site: the hits beside "
             "mtpu_get_fresh_buffer_bytes_total's misses",
             ("site",))
+        self.shard_rows_read = Gauge(
+            "mtpu_shard_rows_read_total",
+            "Shard rows a read's segment fetched, by route: batched "
+            "(K rows in one native call, the GIL released once) or pool "
+            "(a drive call a row: remote drives, the host-fused plane, "
+            "O_DIRECT, no native library)", ("path",))
         self.get_arena_free_bytes = Gauge(
             "mtpu_get_arena_free_bytes",
             "Bytes of segment arenas on the pool's free list (mapped, "
@@ -1800,6 +1819,8 @@ class MetricsRegistry:
             self.get_fresh_buffer_bytes.set(n, site=site)
         for site, n in snap["get_leased_buffer_bytes"].items():
             self.get_leased_buffer_bytes.set(n, site=site)
+        for path, n in snap["shard_rows_read"].items():
+            self.shard_rows_read.set(n, path=path)
         from ..engine import segarena as _segarena
         self.get_arena_free_bytes.set(_segarena.POOL.free_bytes())
         self.request_stall_episodes.set(snap["request_stall_episodes"])

@@ -17,6 +17,7 @@ MINIO_DRIVE_SYNC):
 
 from __future__ import annotations
 
+import functools
 import mmap
 import os
 
@@ -27,6 +28,26 @@ BULK = 128 * 1024          # below this, cache behavior is irrelevant
 def mode() -> str:
     m = os.environ.get("MTPU_ODIRECT", "fadvise")
     return m if m in ("off", "fadvise", "direct") else "fadvise"
+
+
+def drops_after_read(length: int) -> bool:
+    """The buffered read's cache policy: a bulk read of `length` bytes
+    drops the file's pages once done, unless the mode is "off"."""
+    return mode() != "off" and length >= BULK
+
+
+@functools.cache
+def native_read_rows():
+    """`read_rows` of the native library (native/ecio.cc: a segment's
+    shard rows in one call), or None on a host whose toolchain cannot
+    build it."""
+    from native import ecio_native
+    from native._build import BuildError
+    try:
+        ecio_native.load()
+    except BuildError:
+        return None
+    return ecio_native.read_rows
 
 
 def osync() -> bool:
@@ -64,7 +85,7 @@ def read_range(path: str, offset: int, length: int) -> bytes:
         if offset:
             f.seek(offset)
         data = f.read(length)
-        if m != "off" and length >= BULK:
+        if drops_after_read(length):
             drop_cache(f.fileno())
         return data
 

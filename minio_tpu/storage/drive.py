@@ -1087,3 +1087,75 @@ class LocalDrive:
 
     def __repr__(self) -> str:
         return f"LocalDrive({self.root!r})"
+
+
+# -- a segment's shard rows in one native call ----------------------------------
+
+
+def rows_readable(drives) -> bool:
+    """Whether `read_rows` may read these drives: each is a LocalDrive of
+    this process, health-wrapped or not, and not a subclass that
+    programs its own reads (storage/naughty.py: the native call would go
+    around them); the cache mode is not O_DIRECT, whose aligned reads
+    are diskio.read_range's alone; and the native library built."""
+    return (all(d.__class__ is LocalDrive for d in drives)
+            and diskio.mode() != "direct"
+            and diskio.native_read_rows() is not None)
+
+
+def read_rows(drives: list, vol: str, path: str, offset: int, length: int,
+              k: int, out, exact_end: bool) -> list:
+    """Bytes [offset, offset + length) of `vol`/`path` on `drives`, one
+    drive after another in that order, each into the next free
+    `length`-byte slot of the writable buffer `out`, until k have
+    answered in full: a GET segment's shard rows in ONE native call,
+    the GIL released once, under one span `storage.read_rows` (tags
+    `rows`, `failed`, `bytes`).  `exact_end`: a file must end where the
+    range does.  The page-cache policy is `read_file`'s.
+
+    Each drive tried is counted as its `read_file` call would be: a
+    `read` in its OS counters and, where it is health-wrapped, a
+    `read_file` call in the wrapper's stats and breaker
+    (HealthWrappedDrive.note_call).  A file shorter than the range (or
+    longer, with `exact_end`) answered that call and fails here, as a
+    short segment fails the engine's parse.
+
+    Returns per drive (slot, error, seconds): (slot, None, s) for a row
+    read into `out`'s slot; (None, error, s) for a failure; (None, None,
+    0.0) for a drive not tried, k rows being in before its turn."""
+    try:
+        paths = [d._file_path(vol, path) for d in drives]
+    except (ErrVolumeNotFound, ErrFileAccessDenied) as e:
+        return [(None, e, 0.0)] * len(drives)
+    from native.ecio_native import ROW_SIZE, ROW_UNTRIED
+    with ospan.span("storage.read_rows") as sp:
+        err, slot, ns = diskio.native_read_rows()(
+            paths, offset, length, k, out, exact_end,
+            diskio.drops_after_read(length))
+        res = []
+        failed = 0
+        for d, e, j, t in zip(drives, err.tolist(), slot.tolist(),
+                              (ns / 1e9).tolist()):
+            if e == ROW_UNTRIED:
+                res.append((None, None, 0.0))
+                continue
+            d._osc.add("read", t)
+            call_err = row_err = None
+            if e == ROW_SIZE:
+                row_err = ErrFileCorrupt(
+                    f"{vol}/{path}: not {length} bytes at {offset}")
+            elif e == errno.ENOENT:
+                call_err = ErrFileNotFound(f"{vol}/{path}")
+            elif e == errno.EISDIR:
+                call_err = ErrIsNotRegular(f"{vol}/{path}")
+            elif e:
+                call_err = OSError(e, os.strerror(e))
+            note = getattr(d, "note_call", None)    # the health wrapper
+            if note is not None:
+                note("read_file", t * 1e3, call_err)
+            row_err = row_err or call_err
+            failed += row_err is not None
+            res.append((None if row_err else j, row_err, t))
+        rows = int((slot >= 0).sum())
+        sp.tag(rows=rows, failed=failed, bytes=rows * length)
+    return res

@@ -157,21 +157,28 @@ class HealthWrappedDrive:
                 err = e
                 raise
             finally:
-                ms = (time.perf_counter() - t0) * 1e3
-                fault = err is not None and not self._benign(err)
-                with self._mu:
-                    st = self._stats.setdefault(name, APIStats())
-                    st.calls += 1
-                    if fault:
-                        st.errors += 1
-                    st.last_ms = ms
-                    st.ewma_ms = (ms if st.calls == 1 else
-                                  self.EWMA_ALPHA * ms
-                                  + (1 - self.EWMA_ALPHA) * st.ewma_ms)
-                self._breaker_record(name, ms, err if fault else None)
+                self.note_call(name, (time.perf_counter() - t0) * 1e3, err)
         timed.__name__ = name
         self._timed_cache[name] = timed
         return timed
+
+    def note_call(self, api: str, ms: float,
+                  err: Exception | None) -> None:
+        """Count one call of `api` that took `ms` and raised `err` (None:
+        it answered) into the stats and the breaker.  Every call through
+        the proxy comes here; so does a drive call made around it, as
+        drive.read_rows' reads of K shard rows in one native call."""
+        fault = err is not None and not self._benign(err)
+        with self._mu:
+            st = self._stats.setdefault(api, APIStats())
+            st.calls += 1
+            if fault:
+                st.errors += 1
+            st.last_ms = ms
+            st.ewma_ms = (ms if st.calls == 1 else
+                          self.EWMA_ALPHA * ms
+                          + (1 - self.EWMA_ALPHA) * st.ewma_ms)
+        self._breaker_record(api, ms, err if fault else None)
 
     # breaker ----------------------------------------------------------------
 

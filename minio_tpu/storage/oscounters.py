@@ -38,10 +38,15 @@ class Counters:
             try:
                 yield
             finally:
-                dt = time.perf_counter() - t0
-                with self._mu:
-                    self._counts[op] += 1
-                    self._seconds[op] += dt
+                self.add(op, time.perf_counter() - t0)
+
+    def add(self, op: str, seconds: float) -> None:
+        """Count one `op` its caller timed: a drive call made with
+        others in one native call (drive.read_rows), whose span is that
+        call's."""
+        with self._mu:
+            self._counts[op] += 1
+            self._seconds[op] += seconds
 
     def snapshot(self) -> dict:
         with self._mu:

@@ -26,6 +26,11 @@
 //                  poll + recv loop of a request body's pull in ONE call
 //                  (one GIL release a pull instead of two a recv).
 //
+//   ec_read_rows   candidate shard files -> K slots of one buffer: the
+//                  open + pread + close of a GET segment's shard rows in
+//                  ONE call (one GIL release a segment instead of ~8 a
+//                  row and a pool hop each).
+//
 // The mxh256 tree hash and the vpshufb GF(2^8) row multiply are pulled
 // in from their single sources of truth (mxh256.cc / rs_cpu.cc) so the
 // bytes are provably identical to the spec paths.
@@ -36,8 +41,10 @@
 #include <cstddef>
 #include <ctime>
 
+#include <fcntl.h>
 #include <poll.h>
 #include <sys/socket.h>
+#include <unistd.h>
 
 #include "mxh256.cc"   // chunk_words/level + mxh256_rows (exported too)
 #include "rs_cpu.cc"   // rs_encode + rs_isa
@@ -293,6 +300,69 @@ ssize_t ec_recv_exact(int fd, uint8_t* buf, size_t n, int timeout_ms,
   }
   if (recvs) *recvs = calls;
   return rc < 0 ? rc : (ssize_t)got;
+}
+
+static int64_t mono_ns() {
+  struct timespec ts;
+  clock_gettime(CLOCK_MONOTONIC, &ts);
+  return (int64_t)ts.tv_sec * 1000000000 + ts.tv_nsec;
+}
+
+// ec_read_rows' per-candidate outcomes besides 0 (read) and an errno.
+#define EC_ROW_SIZE (-1)      // the file ends before offset + length
+                              // (or, with exact_end, after it)
+#define EC_ROW_UNTRIED (-2)   // k slots were filled before its turn
+
+// Read [offset, offset + length) of paths[0..n), one file after another
+// in that order, each into the next free `length`-byte slot of `out`,
+// until k slots are filled.  A file is opened, pread and closed;
+// `exact_end`: it must end at offset + length (the range holds the
+// shard's tail fragment); `drop`: POSIX_FADV_DONTNEED once read (the
+// page-cache policy of storage/diskio.py).  For each candidate i:
+// err[i] 0, the errno of a failed open or read, EC_ROW_SIZE or
+// EC_ROW_UNTRIED; slot[i] the slot it filled or -1; ns[i] its
+// nanoseconds.  Returns the slots filled.
+int ec_read_rows(const char* const* paths, int n, int k, int64_t offset,
+                 size_t length, int exact_end, int drop, uint8_t* out,
+                 int32_t* err, int32_t* slot, int64_t* ns) {
+  int filled = 0;
+  for (int i = 0; i < n; ++i) {
+    slot[i] = -1;
+    ns[i] = 0;
+    if (filled >= k) { err[i] = EC_ROW_UNTRIED; continue; }
+    const int64_t t0 = mono_ns();
+    int e = 0;
+    int fd = open(paths[i], O_RDONLY | O_CLOEXEC);
+    if (fd < 0) {
+      e = errno;
+    } else {
+      uint8_t* dst = out + (size_t)filled * length;
+      size_t got = 0;
+      while (got < length) {
+        ssize_t r = pread(fd, dst + got, length - got, offset + (off_t)got);
+        if (r > 0) { got += (size_t)r; continue; }
+        if (r == 0) break;                       // EOF
+        if (errno == EINTR) continue;
+        e = errno;
+        break;
+      }
+      if (!e && got < length) e = EC_ROW_SIZE;
+      if (!e && exact_end) {
+        uint8_t b;
+        ssize_t r;
+        while ((r = pread(fd, &b, 1, offset + (off_t)length)) < 0
+               && errno == EINTR) {}
+        if (r > 0) e = EC_ROW_SIZE;
+        else if (r < 0) e = errno;
+      }
+      if (drop) posix_fadvise(fd, 0, 0, POSIX_FADV_DONTNEED);
+      close(fd);
+    }
+    err[i] = e;
+    ns[i] = mono_ns() - t0;
+    if (!e) slot[i] = filled++;
+  }
+  return filled;
 }
 
 // GFNI<->field self-check material: y = c * x in GF(2^8)/0x11D for the

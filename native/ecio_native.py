@@ -85,6 +85,12 @@ def _load_inner():
     lib.ec_recv_exact.argtypes = [
         ctypes.c_int, ctypes.c_void_p, ctypes.c_size_t, ctypes.c_int,
         ctypes.POINTER(ctypes.c_int)]
+    lib.ec_read_rows.restype = ctypes.c_int
+    lib.ec_read_rows.argtypes = [
+        ctypes.POINTER(ctypes.c_char_p), ctypes.c_int, ctypes.c_int,
+        ctypes.c_int64, ctypes.c_size_t, ctypes.c_int, ctypes.c_int,
+        ctypes.c_void_p, ctypes.c_void_p, ctypes.c_void_p,
+        ctypes.c_void_p]
     lib.ec_selftest_mul.restype = ctypes.c_int
     lib.ec_selftest_mul.argtypes = [ctypes.c_void_p, ctypes.c_int]
     if b"gfni" in lib.ec_isa():
@@ -376,3 +382,40 @@ def recv_exact(fd: int, view, timeout_ms: int) -> tuple[int, int]:
     if got < 0:
         raise OSError(-got, os.strerror(-got))
     return got, recvs.value
+
+
+# read_rows' outcomes of a candidate besides 0 (read) and an errno.
+ROW_SIZE = -1           # the file ends before the range does (or after it)
+ROW_UNTRIED = -2        # k slots were filled before its turn
+
+
+def read_rows(paths: list[str], offset: int, length: int, k: int, out,
+              exact_end: bool, drop: bool
+              ) -> tuple[np.ndarray, np.ndarray, np.ndarray]:
+    """Read [offset, offset + length) of the files `paths`, one after
+    another in that order, each into the next free `length`-byte slot
+    of the writable buffer `out` (k slots), until k are filled: open,
+    pread, close, in one native call with the GIL released once.
+    `exact_end`: a file must end where the range does; `drop`:
+    POSIX_FADV_DONTNEED once a file is read.
+
+    Returns (err, slot, ns) per candidate: 0, the errno of a failed
+    open or read, ROW_SIZE or ROW_UNTRIED; the slot it filled or -1;
+    the nanoseconds it took."""
+    lib = load()
+    mv = memoryview(out)
+    n = len(paths)
+    if mv.readonly or not mv.c_contiguous or mv.nbytes < k * length:
+        raise ValueError(f"read_rows needs a writable contiguous buffer "
+                         f"of {k} x {length} bytes, not {mv.nbytes}")
+    if length <= 0 or not 0 < k <= n:
+        raise ValueError(f"read_rows: {k} slots of {length} bytes "
+                         f"from {n} files")
+    err = np.zeros(n, dtype=np.int32)
+    slot = np.zeros(n, dtype=np.int32)
+    ns = np.zeros(n, dtype=np.int64)
+    cpaths = (ctypes.c_char_p * n)(*[os.fsencode(p) for p in paths])
+    lib.ec_read_rows(cpaths, n, k, offset, length, int(exact_end),
+                     int(drop), _addr(mv), err.ctypes.data,
+                     slot.ctypes.data, ns.ctypes.data)
+    return err, slot, ns

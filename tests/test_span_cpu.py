@@ -378,11 +378,13 @@ def test_degraded_read_names_and_counts_its_copies(device_codec,
     grew = fresh_growth(get)
     copied = {"gather": 2 * k * shard,
               "assemble": 2 * k * shard + k * tail_shard}
+    # The K rows come into one buffer: two frames and the tail's a row.
+    rows = k * (2 * (32 + shard) + 32 + tail_shard)
     # The join copies the pieces straight into the response's buffer.
-    assert grew == {**copied, "join": 0, "response": SIZE}
-    # `x` and `y` again, out of the pool.
+    assert grew == {**copied, "join": 0, "response": SIZE, "read": rows}
+    # The rows, `x` and `y` again, out of the pool.
     assert fresh_growth(get) == {"gather": 0, "assemble": k * tail_shard,
-                                 "join": 0, "response": SIZE}
+                                 "join": 0, "response": SIZE, "read": 0}
     for rec in ospan.TRACER.traces()[-2:]:
         # The spans' `bytes` are what was copied there, whatever had
         # to be mapped for it.
@@ -417,7 +419,7 @@ def test_degraded_read_on_the_fused_host_path_gathers_nothing(empty_arenas,
     grew = fresh_growth(lambda: es.get_object("b", "o"))
     assert grew == {"gather": 0,
                     "assemble": 2 * BLOCK_SIZE + 2 * -(-4321 // 2),
-                    "join": 0, "response": SIZE}
+                    "join": 0, "response": SIZE, "read": 0}
     assert bytes(es.get_object("b", "o")[1]) == body
 
 
@@ -433,8 +435,9 @@ def test_healthy_read_that_hands_out_a_view_joins_nothing(device_codec,
     grew = fresh_growth(lambda: got.append(es._read_part(
         "b", "o", fi, part_number=1, offset=0, length=2 * BLOCK_SIZE)))
     assert isinstance(got[0], memoryview) and bytes(got[0]) == body
+    shard = fi.erasure.shard_size
     assert grew == {"gather": 0, "assemble": 2 * BLOCK_SIZE, "join": 0,
-                    "response": 0}
+                    "response": 0, "read": 2 * 2 * (32 + shard)}
 
 
 def test_degraded_get_with_tracing_off_allocates_no_span(device_codec,
