@@ -1810,6 +1810,7 @@ class ErasureSet:
                 y, okf, nbad = fused_host.get_verify(
                     [rows[s][3] for s in range(k)], list(range(k)),
                     nb, shard_size, k, m, [], out=body)
+                DATA_PATH.record_host_hash("get", nb * k * shard_size)
                 if body is None:
                     DATA_PATH.record_get_fresh_buffer("assemble", y.nbytes)
                 if nbad:
@@ -1838,9 +1839,12 @@ class ErasureSet:
                 if digests is not None:
                     got = [digests[:, s] for s in range(k)]
                 else:
-                    got = self._hash_shard_frames(
-                        [rows[s][3] for s in range(k)], nb, shard_size,
-                        hs, algo)
+                    with ospan.span("engine.hash") as sp:
+                        sp.tag(bytes=nb * k * shard_size)
+                        got = self._hash_shard_frames(
+                            [rows[s][3] for s in range(k)], nb,
+                            shard_size, hs, algo)
+                    DATA_PATH.record_host_hash("get", nb * k * shard_size)
                 bad = [s for s in range(k)
                        if not np.array_equal(got[s], rows[s][0])]
                 if bad:
@@ -1995,6 +1999,7 @@ class ErasureSet:
                     y_fused, okf, nbad = fused_host.get_verify(
                         [rows[s][3] for s in sel], sel, nb, shard_size,
                         k, m, missing)
+                DATA_PATH.record_host_hash("get", nb * k * shard_size)
                 DATA_PATH.record_verify_blocks(
                     nb, (k, m, tuple(sel), tuple(missing))
                     if missing else None)
@@ -2116,7 +2121,7 @@ class ErasureSet:
                 rows = np.ascontiguousarray(
                     np.frombuffer(buf, dtype=np.uint8).reshape(
                         nb, frame)[:, hs:])
-                return bitrot_io._hash_batch(rows, algo)
+                return bitrot_io.hash_rows(rows, algo)
         if self._serial_local() or self._on_drive_pool():
             return [one(b) for b in bufs]
         return list(self.pool.map(one, bufs))

@@ -636,6 +636,7 @@ def _heal_part_pipelined(es: ErasureSet, bucket: str, obj: str,
                 stack = np.stack([out[s] for s in need])
                 framed = bitrot_io.frame_shard_views(
                     None, None, None, algo, shards=stack)
+                DATA_PATH.record_host_hash("heal", stack.nbytes)
                 return ((b0, nb), dict(zip(need, framed)), read_s,
                         time.perf_counter() - t0)
         while True:
@@ -668,6 +669,7 @@ def _heal_part_pipelined(es: ErasureSet, bucket: str, obj: str,
                     [s for s in dmiss if s in need_data]
                 y, okf, nbad = fused_host.get_verify(
                     [data[s] for s in cur], cur, nb, S, k, m, dtargets)
+                DATA_PATH.record_host_hash("heal", nb * k * S)
                 if nbad:
                     for j, s in enumerate(cur):
                         if not okf[j]:
@@ -693,7 +695,7 @@ def _heal_part_pipelined(es: ErasureSet, bucket: str, obj: str,
             # Heal shares the verify_and_transform queue with degraded
             # GETs (`ShardMath.verify_transform`).
             digests, rebuilt = es.math.verify_transform(
-                x, k, m, tuple(cur), tuple(need), algo)
+                x, k, m, tuple(cur), tuple(need), algo, site="heal")
             bad = [cur[i] for i in range(k)
                    if not np.array_equal(digests[:, i],
                                          bufs[cur[i]][:, :hs])]
@@ -710,6 +712,7 @@ def _heal_part_pipelined(es: ErasureSet, bucket: str, obj: str,
         stack = np.stack([out[s] for s in need])         # (T, nb, S)
         framed = bitrot_io.frame_shard_views(None, None, None, algo,
                                              shards=stack)
+        DATA_PATH.record_host_hash("heal", stack.nbytes)
         payload = dict(zip(need, framed))
         return (b0, nb), payload, read_s, time.perf_counter() - t0
 

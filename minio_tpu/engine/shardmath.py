@@ -7,9 +7,10 @@ it lives the one decision *which plane computes this, and does the
 work ride the coalescer*:
 
 - the plane: the fused host kernel (native/ecio.cc, one C pass), the
-  set's device lane (the fused programs of ops/fused.py), the mesh
-  (parallel/sharded.py, `mesh_rule`), a device codec with host hashing
-  (host-hashed algorithms), or the native host codec;
+  set's device lane (the fused programs of ops/fused.py; for an
+  algorithm the host hashes, their digest-free forms, the digests
+  computed on the thread that holds the rows), the mesh
+  (parallel/sharded.py, `mesh_rule`), or the native host codec;
 - coalesced (ops/coalesce.py, MTPU_COALESCE) or direct.  Each plane has
   ONE direct implementation: it serves MTPU_COALESCE=0 and is what a
   failed coalescer handle falls back to (`_settle` counts it).
@@ -325,21 +326,22 @@ class ShardMath:
         """Device/native encode over the stacked blocks (ops/coalesce
         .make_encode_kernel), a device batch sized by the ladder of
         BATCH_BLOCKS: (parity, digests) per span, the pair
-        `direct_encode` gives.  `device`: the submitting set's lane."""
-        codec = None
-        if not fused_dev:
-            codec = (self._codec(k, m) if self.use_device
-                     else self.native(k, m))
+        `direct_encode` gives; on a chip without `fused_dev` the
+        digest-free program (digests None: the framing pass hashes).
+        `device`: the submitting set's lane."""
+        if self.use_device:
+            return coalesce.make_encode_kernel(
+                k, m, algo if fused_dev else None, BATCH_BLOCKS, device)
         return coalesce.make_encode_kernel(
-            k, m, algo, BATCH_BLOCKS, device, codec,
-            on_device=fused_dev or self.use_device)
+            k, m, algo, BATCH_BLOCKS, device, self.native(k, m))
 
     @staticmethod
     def vt_kernel(k: int, m: int, sources: tuple, targets: tuple,
-                  algo: str, device: int | None = None):
+                  algo: str | None, device: int | None = None):
         """Fused device verify(+reconstruct) over stacked (B, K, S)
-        gathers (ops/coalesce.make_verify_kernel).  `device` places the
-        dispatch on the submitting set's affine lane."""
+        gathers (ops/coalesce.make_verify_kernel); `algo` None the
+        digest-free rebuild.  `device` places the dispatch on the
+        submitting set's affine lane."""
         return coalesce.make_verify_kernel(k, m, sources, targets, algo,
                                            BATCH_BLOCKS, device)
 
@@ -348,8 +350,9 @@ class ShardMath:
         PUTs and GETs run at (k, m), on its lane: the fused encode, the
         GET digest and the decode of the write algorithm, and where K
         does not divide the block (every GET then takes the generic
-        read) its verify-only hash.  Built off the calling thread
-        (ops/coalesce.build_ladder); nothing on the host."""
+        read) its verify-only hash; for an algorithm the host hashes,
+        the digest-free encode and decode alone.  Built off the calling
+        thread (ops/coalesce.build_ladder); nothing on the host."""
         if not self.use_device:
             return
         coalesce.build_geometry_ladder(
@@ -421,21 +424,25 @@ class ShardMath:
         return None
 
     def verify_transform(self, x: np.ndarray, k: int, m: int,
-                         sources: tuple, targets: tuple, algo: str):
+                         sources: tuple, targets: tuple, algo: str,
+                         site: str = "get"):
         """Digests (nb, k, hs) of the K chosen rows `x` (nb, k, S) of
         shards `sources`, and shards `targets` rebuilt from them: a
-        degraded GET's decode, a heal batch.  The rebuilt rows are T
-        arrays of (nb, S) in `targets` order (None where there are no
-        targets), on every plane views of what the plane produced: the
-        reader's copy into its own layout is the one copy a rebuilt
-        byte gets.  On the lane ONE dispatch, digests + reconstruction
-        from the same HBM-resident bytes, shared by concurrent degraded
-        reads and heals of one (sources, targets) pattern; every
-        pattern of a geometry runs one program, built ahead
-        (`build_ladder`)."""
+        degraded GET's decode, a heal batch (`site` "heal").  The
+        rebuilt rows are T arrays of (nb, S) in `targets` order (None
+        where there are no targets), on every plane views of what the
+        plane produced: the reader's copy into its own layout is the
+        one copy a rebuilt byte gets.  On the lane ONE dispatch of the
+        geometry's one decode program a (k, m), built ahead
+        (`build_ladder`), shared by concurrent degraded reads and heals
+        of one (sources, targets) pattern: digests + reconstruction from
+        the same HBM-resident bytes where the chip computes the digest;
+        else the digest-free program, one for every algorithm the host
+        hashes, while this thread hashes the K rows (`host_digests`)."""
         nb, _, shard_size = x.shape
         DATA_PATH.record_verify_blocks(
             nb, (k, m, sources, targets) if targets else None)
+        co = self._co()
         if self._fused_dev(algo) and not mesh_mode():
             def direct():
                 digests, rows = fused.verify_and_transform(
@@ -444,23 +451,49 @@ class ShardMath:
                 return (np.asarray(digests),
                         tuple(devcache.fetch(r) for r in rows)
                         if targets else None)
-            co = self._co()
             if co is None:
                 return direct()
             return self._ride(
                 co, ("vt", k, m, sources, targets, algo, shard_size), x,
                 self.vt_kernel(k, m, sources, targets, algo,
                                device=self.device_idx), nb, direct)
-        # Host path (host-hashed algorithm, no TPU, or an algo whose
-        # native host kernel beats its device verify —
-        # bitrot_io.device_preferred): digest on the calling thread,
-        # reconstruct via the backend picker only if rows are missing.
-        digests = bitrot_io._hash_batch(x.reshape(nb * k, shard_size), algo)
-        rows = None
-        if targets:
+
+        # The host hashes (a host-hashed algorithm, no TPU, or an algo
+        # whose native host kernel beats its device verify —
+        # bitrot_io.device_preferred): submit the rebuild, hash, then
+        # wait.  A round whose digests fail throws its rows away.
+        def rebuilt():
             out = self.transform(k, m, x, sources, targets)    # (nb, T, S)
-            rows = tuple(out[:, j] for j in range(len(targets)))
+            return tuple(out[:, j] for j in range(len(targets)))
+        h = None
+        if targets and co is not None and self.use_device \
+                and not mesh_mode():
+            h = co.submit(
+                ("vt", k, m, sources, targets, None, shard_size), x,
+                self.vt_kernel(k, m, sources, targets, None,
+                               device=self.device_idx),
+                weight=nb, device=self.device_idx)
+        digests = self.host_digests(x.reshape(nb * k, shard_size), algo,
+                                    site)
+        rows = None
+        if h is not None:
+            (_, rows), h = _settle(h, lambda: (None, rebuilt()))
+            if h is not None:
+                h.release()
+        elif targets:
+            rows = rebuilt()
         return digests.reshape(nb, k, bitrot_io.digest_size(algo)), rows
+
+    @staticmethod
+    def host_digests(rows: np.ndarray, algo: str, site: str) -> np.ndarray:
+        """Bitrot digests (n, hs) of full shard blocks `rows` (n, S) with
+        the host's kernel, on the calling thread: span `engine.hash`,
+        counted at `site` (mtpu_host_hash_bytes_total)."""
+        with ospan.span("engine.hash") as sp:
+            sp.tag(bytes=rows.nbytes)
+            out = bitrot_io.hash_rows(rows, algo)
+        DATA_PATH.record_host_hash(site, rows.nbytes)
+        return out
 
     def transform(self, k: int, m: int, x, sources, targets,
                   resident=None, algo: str = "") -> np.ndarray:
@@ -605,6 +638,9 @@ class Encoder:
                     self._retired.pop(0).release()
         elif out is None:
             out = self._direct(blocks)
+        if self.fused_host is not None or out[1] is None:
+            DATA_PATH.record_host_hash(
+                "put", (self.k + self.m) * blocks.shape[0] * self.shard_size)
         if self.fused_host is not None:
             return out
         parity, digests = out

@@ -155,6 +155,10 @@ class BandwidthMonitor:
         return out
 
 
+#: Where the host's bitrot kernels hash a batch's full shard blocks
+#: (`mtpu_host_hash_bytes_total{site}`).
+HOST_HASH_SITES = ("get", "heal", "put")
+
 #: Where the GET path allocates anew for a segment
 #: (`mtpu_get_fresh_buffer_bytes_total{site}`), or leases an arena it
 #: already holds (`mtpu_get_leased_buffer_bytes_total{site}`).
@@ -257,6 +261,9 @@ class DataPathStats:
             # (K rows in one native call, storage/drive.read_rows) or
             # pool (a drive call a row).
             self.shard_rows_read = {"batched": 0, "pool": 0}
+            # Bytes of full shard blocks the host's bitrot kernels
+            # hashed, by site (a digest the chip computes: none).
+            self.host_hash_bytes = dict.fromkeys(HOST_HASH_SITES, 0)
             # Episodes in which requests were in flight and none
             # completed for the stall watcher's limit (server.py).
             self.request_stall_episodes = 0
@@ -491,6 +498,13 @@ class DataPathStats:
         with self._mu:
             self.shard_rows_read[path] += n
 
+    def record_host_hash(self, site: str, nbytes: int) -> None:
+        """The host's bitrot kernels hashed `nbytes` of full shard
+        blocks at `site` (HOST_HASH_SITES): a read's or a heal's K
+        chosen rows, a PUT's or a heal's framed rows."""
+        with self._mu:
+            self.host_hash_bytes[site] += nbytes
+
     def record_request_stall(self) -> None:
         with self._mu:
             self.request_stall_episodes += 1
@@ -716,6 +730,7 @@ class DataPathStats:
                 "get_leased_buffer_bytes": dict(
                     self.get_leased_buffer_bytes),
                 "shard_rows_read": dict(self.shard_rows_read),
+                "host_hash_bytes": dict(self.host_hash_bytes),
                 "request_stall_episodes": self.request_stall_episodes,
                 "ipc_submits": self.ipc_submits,
                 "ipc_rows": self.ipc_rows,
@@ -968,6 +983,13 @@ class MetricsRegistry:
             "(K rows in one native call, the GIL released once) or pool "
             "(a drive call a row: remote drives, the host-fused plane, "
             "O_DIRECT, no native library)", ("path",))
+        self.host_hash_bytes = Gauge(
+            "mtpu_host_hash_bytes_total",
+            "Bytes of full shard blocks the host's bitrot kernels hashed, "
+            "by site: get (a read's K chosen rows), heal (a heal batch's "
+            "K rows and the rows it frames), put (a PUT batch's K+M "
+            "framed rows); a digest the chip computes counts nowhere",
+            ("site",))
         self.get_arena_free_bytes = Gauge(
             "mtpu_get_arena_free_bytes",
             "Bytes of segment arenas on the pool's free list (mapped, "
@@ -1821,6 +1843,8 @@ class MetricsRegistry:
             self.get_leased_buffer_bytes.set(n, site=site)
         for path, n in snap["shard_rows_read"].items():
             self.shard_rows_read.set(n, path=path)
+        for site, n in snap["host_hash_bytes"].items():
+            self.host_hash_bytes.set(n, site=site)
         from ..engine import segarena as _segarena
         self.get_arena_free_bytes.set(_segarena.POOL.free_bytes())
         self.request_stall_episodes.set(snap["request_stall_episodes"])

@@ -149,13 +149,15 @@ def root_self_stage(api: str) -> str:
 #: parent's thread in their parent's layer, because a reader asks for
 #: their own split: the lane's host-side work (`lane_host_wait_pct`
 #: reads pack, h2d, launch and scatter; PERF.md section 5 the resolve's
-#: program_wait and fetch beside them) and the degraded read's three
+#: program_wait and fetch beside them), the degraded read's three
 #: copies (PERF.md section 5: is a copy's time page faults, on the
-#: clock, or a queue for the GIL, off it).  Tens of spans a second.
+#: clock, or a queue for the GIL, off it) and a segment's host digest
+#: of its K rows (`engine.hash`: does the hash run or queue while the
+#: lane rebuilds).  Tens of spans a second.
 CPU_STAGES = frozenset((
     "lane.pack", "lane.h2d", "lane.launch", "lane.program_wait",
     "lane.fetch", "lane.scatter",
-    "engine.gather", "engine.assemble", "engine.join"))
+    "engine.gather", "engine.assemble", "engine.join", "engine.hash"))
 
 
 def _annotation(name: str):

@@ -125,7 +125,7 @@ def _pad_rows(x: np.ndarray, rows: int) -> np.ndarray:
 # time) the submitter builds it on its own thread before it queues the
 # item (`DispatchLane.submit`): that one request waits for the compile,
 # the lane and everyone on it do not.  A kernel that names no program
-# (a device codec with host hashing) keeps the single shape P.
+# keeps the single shape P.
 
 LADDER = (1, 2, 4, 8, 16, 32)
 
@@ -1117,41 +1117,38 @@ def _device_kernel(start, pad_rows: int, device: int | None,
     return kernel
 
 
-def make_encode_kernel(k: int, m: int, algo: str, pad_rows: int,
-                       device: int | None = None, codec=None,
-                       on_device: bool = True):
+def make_encode_kernel(k: int, m: int, algo: str | None, pad_rows: int,
+                       device: int | None = None, codec=None):
     """Encode over stacked (B, K, S) blocks -> (parity, digests) per
     span, the pair the direct dispatch produces.  With no `codec` it is
-    the fused device program (parity AND bitrot digests in one launch,
-    ops/fused.py), sized by the ladder of `pad_rows`; with a device
-    codec (`on_device`, host-hashed algorithms) parity only, at
-    multiples of `pad_rows`; with a host codec parity only, unpadded."""
-    if codec is not None and not on_device:
+    the device program of ops/fused.py, sized by the ladder of
+    `pad_rows`: parity AND bitrot digests in one launch, or with `algo`
+    None (an algorithm the host hashes) the parity alone, digests None.
+    With a host `codec`, parity only, unpadded."""
+    if codec is not None:
         def kernel(stacked, spans, ctx):
             parity = np.asarray(codec.encode_blocks(stacked))
             return [(parity[lo:hi], None) for lo, hi in spans]
 
         return kernel
-    from . import devices, fused
+    from . import fused
 
     def start(x, spans):
-        if codec is not None:
-            parity_d = codec.encode_blocks(devices.put(x, device))
+        parity_d, digests_d = fused.encode_and_hash(x, k, m, algo=algo,
+                                                    device=device)
+        if digests_d is None:
             return (parity_d,), lambda parity: [
                 (parity[lo:hi], None) for lo, hi in spans]
-        outputs = fused.encode_and_hash(x, k, m, algo=algo, device=device)
-        return outputs, lambda parity, digests: [
+        return (parity_d, digests_d), lambda parity, digests: [
             (parity[lo:hi], digests[:, lo:hi]) for lo, hi in spans]
 
-    if codec is not None:
-        return _device_kernel(start, pad_rows, device)
     return _device_kernel(
         start, pad_rows, device,
         functools.partial(fused.encode_hash_program, k, m, algo))
 
 
 def make_verify_kernel(k: int, m: int, sources: tuple, targets: tuple,
-                       algo: str, pad_rows: int,
+                       algo: str | None, pad_rows: int,
                        device: int | None = None):
     """Fused device verify(+reconstruct) over stacked (B, K, S) gathers
     — the healthy-verify / degraded-decode / heal work item.  A span's
@@ -1163,19 +1160,23 @@ def make_verify_kernel(k: int, m: int, sources: tuple, targets: tuple,
     the geometry's one decode program (ops/fused.py), (sources,
     targets) its matrix operand: a matrix a dispatch, so a batch holds
     one pattern (the key carries it) and every pattern runs the same
-    executables.  Both take the ladder."""
+    executables.  `algo` None: the digest-free decode program of an
+    algorithm the host hashes (digests None), one for all of them.
+    Both take the ladder."""
     from . import fused
 
     def start(x, spans):
         digests_d, rows_d = fused.verify_and_transform(
             x, k, m, sources, targets, algo=algo, device=device)
+        head = () if digests_d is None else (digests_d,)
 
-        def scatter(digests, *rows):
-            return [(digests[lo:hi],
+        def scatter(*host):
+            digests, rows = (host[0] if head else None), host[len(head):]
+            return [(None if digests is None else digests[lo:hi],
                      tuple(r[lo:hi] for r in rows) if targets else None)
                     for lo, hi in spans]
 
-        return (digests_d, *(rows_d or ())), scatter
+        return (*head, *(rows_d or ())), scatter
 
     kernel = _device_kernel(
         start, pad_rows, device,
@@ -1217,19 +1218,27 @@ def build_geometry_ladder(k: int, m: int, shard_size: int, algo: str,
     (K * shard_size is more than a block: every GET takes the engine's
     generic read) the verify-only hash of that read too, which a
     geometry whose healthy GETs are digested in place meets too seldom
-    to pay seconds of compile for.  Nothing where the algorithm hashes
-    on the host."""
+    to pay seconds of compile for.  Where the algorithm hashes on the
+    host, the digest-free encode and decode alone: nothing of a device
+    digest program."""
     from ..storage import bitrot_io
     from . import fused
 
-    if not (m and algo in fused.DEVICE_ALGOS
+    if not m:
+        return
+    rebuild = (tuple(range(1, k + 1)), (0,))
+    if not (algo in fused.DEVICE_ALGOS
             and bitrot_io.device_preferred(algo)):
+        build_ladder(make_encode_kernel(k, m, None, pad_blocks, device),
+                     (k, shard_size))
+        build_ladder(make_verify_kernel(k, m, *rebuild, None, pad_blocks,
+                                        device), (k, shard_size))
         return
     build_ladder(make_encode_kernel(k, m, algo, pad_blocks, device),
                  (k, shard_size))
     build_ladder(make_digest_kernel(algo, pad_blocks * k, device),
                  (shard_size,))
-    patterns = [(tuple(range(1, k + 1)), (0,))]
+    patterns = [rebuild]
     if padded_blocks:
         patterns.append((tuple(range(k)), ()))
     for sources, targets in patterns:

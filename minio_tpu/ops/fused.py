@@ -159,18 +159,21 @@ def _hash_rows_jit(algo: str, key: bytes):
     return Program(f"verify_{algo}", fn)
 
 
-def verify_transform_name(k: int, m: int, algo: str) -> str:
+def verify_transform_name(k: int, m: int, algo: str | None) -> str:
     """The decode program of a geometry, as the profiler, the compile
-    log and the lanes' `lane.dispatch` span name it."""
+    log and the lanes' `lane.dispatch` span name it; `algo` None names
+    the digest-free one (the rebuild of a host-hashed algorithm)."""
+    if algo is None:
+        return f"transform_k{k}m{m}"
     return f"verify_transform_k{k}m{m}_{algo}"
 
 
 @functools.lru_cache(maxsize=64)
-def _verify_transform_jit(k: int, m: int, algo: str, key: bytes):
+def _verify_transform_jit(k: int, m: int, algo: str | None, key: bytes):
     def fn(x, mat):  # x: (B, K, S) uint8 rows; mat: their `decode_matrix`
         b, kk, s = x.shape
-        digests = _digest_rows(x.reshape(b * kk, s), algo, key).reshape(
-            b, kk, 32)
+        digests = None if algo is None else _digest_rows(
+            x.reshape(b * kk, s), algo, key).reshape(b, kk, 32)
         out = erasure_pallas.gf_matmul_blocks(mat, x, m)
         # A target row an output of its own: the caller fetches the T
         # it asked for, and the pad rows never leave the device.
@@ -199,7 +202,7 @@ def decode_matrix(k: int, m: int, sources: tuple[int, ...],
 
 def verify_and_transform(x, k: int, m: int, sources: tuple[int, ...],
                          targets: tuple[int, ...],
-                         algo: str = "highwayhash256S",
+                         algo: str | None = "highwayhash256S",
                          key: bytes = MAGIC_KEY,
                          device: int | None = None):
     """((B, K, S) shard rows) -> ((B, K, 32) digests, T rebuilt rows of
@@ -208,9 +211,10 @@ def verify_and_transform(x, k: int, m: int, sources: tuple[int, ...],
     Digests are of the INPUT rows (callers compare them against the bitrot
     frame hashes); rebuilt rows are the GF transform sources->targets
     (`rows_on_host` brings them back as one (B, T, S) array).  With no
-    targets (nothing missing) only the hash runs.  `device` is the
-    coalescer-lane index the dispatch is placed on (None = default
-    device, the pre-sharding behavior).
+    targets (nothing missing) only the hash runs; with `algo` None (an
+    algorithm the host hashes) only the rebuild, and the digests are
+    None.  `device` is the coalescer-lane index the dispatch is placed
+    on (None = default device, the pre-sharding behavior).
     """
     prog = verify_transform_program(k, m, sources, targets, algo, key)
     x = _placed(x, device)
@@ -230,39 +234,46 @@ def rows_on_host(rows, n: int | None = None) -> np.ndarray:
 
 
 def verify_transform_program(k: int, m: int, sources: tuple[int, ...],
-                             targets: tuple[int, ...], algo: str,
+                             targets: tuple[int, ...], algo: str | None,
                              key: bytes = MAGIC_KEY) -> Program:
     """The program `verify_and_transform` runs: the hash alone where
     nothing is to be rebuilt, else the geometry's one decode program,
-    whatever (sources, targets): they reach it as its matrix operand."""
+    whatever (sources, targets): they reach it as its matrix operand.
+    `algo` None: the geometry's one digest-free decode program, shared
+    by every algorithm the host hashes."""
     if not targets:
         return _hash_rows_jit(algo, key)
     return _verify_transform_jit(k, m, algo, key)
 
 
 @functools.lru_cache(maxsize=64)
-def _encode_hash_jit(k: int, m: int, algo: str, key: bytes):
+def _encode_hash_jit(k: int, m: int, algo: str | None, key: bytes):
     mat = jnp.asarray(erasure_jax._encode_matrix_bits(k, m),
                       dtype=jnp.bfloat16)
 
     def fn(x):  # x: (B, K, S) uint8 data shards
         b, kk, s = x.shape
         parity = erasure_pallas.gf_matmul_blocks(mat, x, m)
+        if algo is None:
+            return parity, None
         with jax.named_scope("stack_for_hash"):
             full = jnp.concatenate([x, parity], axis=1)   # (B, K+M, S)
             rows = full.transpose(1, 0, 2).reshape((kk + m) * b, s)
         digests = _digest_rows(rows, algo, key).reshape(kk + m, b, 32)
         return parity, digests
 
-    return Program(f"encode_hash_k{k}m{m}_{algo}", fn)
+    name = f"encode_k{k}m{m}" if algo is None \
+        else f"encode_hash_k{k}m{m}_{algo}"
+    return Program(name, fn)
 
 
-def encode_hash_program(k: int, m: int, algo: str,
+def encode_hash_program(k: int, m: int, algo: str | None,
                         key: bytes = MAGIC_KEY) -> Program:
     return _encode_hash_jit(k, m, algo, key)
 
 
-def encode_and_hash(x, k: int, m: int, algo: str = "highwayhash256S",
+def encode_and_hash(x, k: int, m: int,
+                    algo: str | None = "highwayhash256S",
                     key: bytes = MAGIC_KEY,
                     device: int | None = None):
     """((B, K, S) data) -> ((B, M, S) parity, (K+M, B, 32) digests).
@@ -270,6 +281,7 @@ def encode_and_hash(x, k: int, m: int, algo: str = "highwayhash256S",
     The PUT hot path: parity AND per-shard-block bitrot digests in one
     device dispatch; framing on the host is then pure byte interleaving.
     Digest layout is shard-major to match frame_shards_batch's
-    (n_shards, n_blocks) order.  `device` places the dispatch on that
-    coalescer lane's device (None = default device)."""
+    (n_shards, n_blocks) order.  `algo` None (an algorithm the host
+    hashes): the parity alone, digests None.  `device` places the
+    dispatch on that coalescer lane's device (None = default device)."""
     return _encode_hash_jit(k, m, algo, key)(_placed(x, device), device)

@@ -114,25 +114,22 @@ _CODECS: dict[tuple, object] = {}
 _CODEC_MU = threading.Lock()
 
 
-def _owner_codec(tag: str, k: int, m: int):
-    key = (tag, k, m)
+def _owner_codec(k: int, m: int):
+    """The host codec a worker's "nat" encode runs on at the owner."""
+    key = (k, m)
     with _CODEC_MU:
         c = _CODECS.get(key)
         if c is not None:
             return c
-    if tag == "dev":
+    from native import rs_comparator
+    from native._build import BuildError
+    try:
+        rs_comparator.load()
+        from .erasure_native import ReedSolomonNative
+        c = ReedSolomonNative(k, m)
+    except BuildError:  # no toolchain: portable codec
         from .erasure import ReedSolomonTPU
         c = ReedSolomonTPU(k, m)
-    else:
-        from native import rs_comparator
-        from native._build import BuildError
-        try:
-            rs_comparator.load()
-            from .erasure_native import ReedSolomonNative
-            c = ReedSolomonNative(k, m)
-        except BuildError:  # no toolchain: portable codec
-            from .erasure import ReedSolomonTPU
-            c = ReedSolomonTPU(k, m)
     with _CODEC_MU:
         _CODECS.setdefault(key, c)
         return _CODECS[key]
@@ -175,16 +172,18 @@ def kernel_from_key(key: tuple, device: int | None = None):
         from ..engine.erasure_set import BATCH_BLOCKS
     if kind == "enc":
         # The tag is the backend the submitting worker would have used.
+        # "dev": the digest-free device program of a host-hashed algo.
         _, tag, k, m, algo, _shard = key
         return coalesce.make_encode_kernel(
-            int(k), int(m), str(algo), BATCH_BLOCKS, device,
-            None if tag == "fd" else _owner_codec(str(tag), int(k), int(m)),
-            on_device=tag != "nat")
+            int(k), int(m), str(algo) if tag == "fd" else None,
+            BATCH_BLOCKS, device,
+            _owner_codec(int(k), int(m)) if tag == "nat" else None)
     if kind == "vt":
+        # algo None: the digest-free decode of a host-hashed algorithm.
         _, k, m, sources, targets, algo, _shard = key
         return coalesce.make_verify_kernel(
-            int(k), int(m), tuple(sources), tuple(targets), str(algo),
-            BATCH_BLOCKS, device)
+            int(k), int(m), tuple(sources), tuple(targets),
+            None if algo is None else str(algo), BATCH_BLOCKS, device)
     raise KeyError(f"no remote kernel for key kind {kind!r}")
 
 

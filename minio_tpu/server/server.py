@@ -185,8 +185,11 @@ class S3Server:
         self.draining = False
         # Boot asked for the lanes' shape ladder and it is still being
         # built: requests are served (at the next larger step), the
-        # readiness probe waits (see build_ladders).
+        # readiness probe waits (see build_ladders); `_asking`: boot is
+        # still deciding what to ask for, so an idle build thread says
+        # nothing yet.
         self.warming = False
+        self._asking = False
         self._inflight = 0
         self._drain_cv = threading.Condition()
         # What the stall watcher reads (see `_watch_stalls`): when each
@@ -869,13 +872,21 @@ class S3Server:
         `hold_ready` (boot) keeps /minio/health/ready at 503 until what
         was asked for is built, so whoever waits for readiness before
         sending load meets no compile and no oversized step; a request
-        that comes earlier is served all the same."""
+        that comes earlier is served all the same.  Deciding what to
+        ask for can take seconds (a host-hashed algorithm loads, on a
+        first boot builds, its native kernel to learn that its digests
+        stay on the host): readiness waits for that too."""
         self.warming = self.warming or hold_ready
-        cfg = self.handlers.config_sys
-        for parity in {None} | {
-                cfg.parity_for_class(sc) for sc in ("standard", "rrs")
-                if cfg.is_set("storage_class", sc)}:
-            self.pools.build_ladders(parity)
+        self._asking = self._asking or hold_ready
+        try:
+            cfg = self.handlers.config_sys
+            for parity in {None} | {
+                    cfg.parity_for_class(sc) for sc in ("standard", "rrs")
+                    if cfg.is_set("storage_class", sc)}:
+                self.pools.build_ladders(parity)
+        finally:
+            if hold_ready:
+                self._asking = False
 
     def shutdown(self) -> None:
         # The scanner's lifecycle belongs to the process (__main__) —
@@ -2443,7 +2454,7 @@ class S3Server:
             # ready = object layer bound (cluster boot done), the boot
             # ladder built, AND not draining — load balancers stop
             # routing here first.
-            if self.warming:
+            if self.warming and not self._asking:
                 from ..ops import coalesce
                 self.warming = not coalesce.ladder_idle()
             if self.draining or self.warming:

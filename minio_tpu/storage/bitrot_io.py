@@ -156,11 +156,18 @@ def digest_size(algo: str = DEFAULT_ALGO) -> int:
 def _hash_batch(blocks: np.ndarray,
                 algo: str = DEFAULT_ALGO) -> np.ndarray:
     """(n, L) uint8 -> (n, digest_size) digests for the given algorithm."""
+    with ospan.span("host.hash_batch"):
+        return hash_rows(blocks, algo)
+
+
+def hash_rows(blocks: np.ndarray, algo: str = DEFAULT_ALGO) -> np.ndarray:
+    """`_hash_batch` under no span of its own: for a caller whose span
+    is the hash (engine/shardmath.py: `engine.hash`)."""
     try:
-        with ospan.span("host.hash_batch"):
-            return ALGORITHMS[algo][1](blocks)
+        fn = ALGORITHMS[algo][1]
     except KeyError:
         raise ErrFileCorrupt(f"unknown bitrot algorithm {algo!r}") from None
+    return fn(blocks)
 
 
 def whole_file_digest(data: bytes, algo: str = DEFAULT_ALGO) -> bytes:

@@ -15,6 +15,7 @@ import hashlib
 import os
 import platform
 import subprocess
+import threading
 
 BUILD_DIR = os.path.join(os.path.dirname(os.path.abspath(__file__)), "build")
 
@@ -57,8 +58,10 @@ def build(name: str, src: str, deps: tuple[str, ...] = (),
         return so
     os.makedirs(BUILD_DIR, exist_ok=True)
     # Private temp + os.replace: a concurrent booter never CDLLs a
-    # half-written .so.
-    tmp = f"{so}.{os.getpid()}.tmp"
+    # half-written .so.  Private to the thread too: the first requests
+    # of a fresh checkout load a kernel from several threads at once,
+    # and two that shared a temp moved or unlinked each other's.
+    tmp = f"{so}.{os.getpid()}.{threading.get_ident()}.tmp"
     try:
         subprocess.run(["g++", *flags, "-o", tmp, src],
                        check=True, capture_output=True, text=True)

@@ -34,6 +34,13 @@ from minio_tpu.parallel.sharded import ShardedCodec  # noqa: E402
 HBM_BYTES = 16 * 10**9          # one v5e chip
 
 
+def _forget_programs() -> None:
+    for cached in (fused._encode_hash_jit, fused._verify_transform_jit,
+                   fused._hash_rows_jit, fused._hash_rows2d_jit):
+        cached.cache_clear()
+    jax.clear_caches()
+
+
 @pytest.fixture(scope="module")
 def topo():
     from jax.experimental import topologies
@@ -49,15 +56,15 @@ def topo():
     jax.config.update("jax_enable_compilation_cache", False)
     compilation_cache.reset_cache()
     saved = devices._VISIBLE
+    # A program an earlier test of this process traced on the CPU
+    # backend took its CPU branch: trace them anew.
+    _forget_programs()
     devices.adopt("tpu", t.devices[0].device_kind, len(t.devices))
     yield t
     devices._VISIBLE = saved
     # The jitted programs traced their TPU branch: a later test in this
     # process must not be handed them.
-    for cached in (fused._encode_hash_jit, fused._verify_transform_jit,
-                   fused._hash_rows_jit, fused._hash_rows2d_jit):
-        cached.cache_clear()
-    jax.clear_caches()
+    _forget_programs()
     jax.config.update("jax_enable_compilation_cache", cache_was)
     compilation_cache.reset_cache()
 
@@ -152,6 +159,30 @@ def test_ladder_steps_of_the_decode_program(one_chip, k, m, s, blocks):
     _check(dec, kernels=1)
     digests, rows = dec.out_info
     assert digests.shape == (blocks, k, 32)
+    assert [r.shape for r in rows] == [(blocks, s)] * m
+
+
+# A host-hashed algorithm (MinIO's default, highwayhash256S) rides the
+# lanes on the digest-free programs: the parity, and the one decode
+# program a geometry with no digest half, at the ladder's steps.
+@pytest.mark.parametrize("k,m,s,blocks", [
+    (8, 4, 131072, 32), (8, 4, 131072, 4), (6, 6, 174763, 16)])
+def test_digest_free_programs_of_a_host_hashed_algorithm(one_chip, k, m, s,
+                                                        blocks):
+    x = jax.ShapeDtypeStruct((blocks, k, s), jnp.uint8, sharding=one_chip)
+    enc = fused.encode_hash_program(k, m, None)
+    assert enc.name == f"encode_k{k}m{m}"
+    compiled = enc.jit.lower(x).compile()
+    _check(compiled, kernels=1)
+    assert compiled.out_info[0].shape == (blocks, m, s)
+    assert compiled.out_info[1] is None
+    dec = fused.verify_transform_program(k, m, (), (0,), None)
+    assert dec.name == f"transform_k{k}m{m}"
+    compiled = dec.jit.lower(x, jax.ShapeDtypeStruct(
+        (8 * m, 8 * k), jnp.bfloat16, sharding=one_chip)).compile()
+    _check(compiled, kernels=1)
+    digests, rows = compiled.out_info
+    assert digests is None
     assert [r.shape for r in rows] == [(blocks, s)] * m
 
 

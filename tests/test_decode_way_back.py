@@ -185,8 +185,9 @@ def test_the_scrape_carries_the_three_families(fresh_lanes):
 def plane(request, monkeypatch, fresh_lanes):
     """`lane`: the set's coalesced device dispatch (the device codec on
     the CPU backend); `direct`: the same program with MTPU_COALESCE=0;
-    `host_hashed`: an algorithm hashed on the host, the rows rebuilt by
-    the backend picker."""
+    `host_hashed`: an algorithm hashed on the host by the calling
+    thread, the rows rebuilt on the lane by the digest-free decode
+    program."""
     monkeypatch.setattr(shardmath, "platform", lambda: (True, False))
     monkeypatch.delenv("MTPU_MESH", raising=False)
     monkeypatch.setenv("MTPU_DEVICES", "1")

@@ -622,6 +622,39 @@ class TestBootLadder:
         finally:
             srv._httpd.server_close()
 
+    def test_readiness_waits_while_boot_decides_what_to_ask_for(
+            self, tmp_path, monkeypatch):
+        """Boot's asks can take seconds before the first reaches the
+        ladder's build thread (a host-hashed algorithm loads its native
+        kernel to learn that its digests stay on the host): an idle
+        build thread in that time is no ready ladder."""
+        from minio_tpu.engine.pools import ServerPools
+        from minio_tpu.server.server import S3Server
+        from minio_tpu.server.sigv4 import Credentials
+        srv = S3Server(ServerPools([make_ring(tmp_path, nsets=1)]),
+                       Credentials("minioadmin", "minioadmin"), port=0)
+        idle = {"v": True}
+        monkeypatch.setattr(coalesce, "ladder_idle", lambda: idle["v"])
+        seen = []
+
+        def ready():
+            return srv._dispatch_internal(None, "/minio/health/ready",
+                                          {}).status
+
+        def asking(parity=None, pools=None):
+            seen.append(ready())        # nothing queued yet: still 503
+            idle["v"] = False           # the ask reaches the thread
+
+        monkeypatch.setattr(srv.pools, "build_ladders", asking)
+        try:
+            srv.build_ladders(hold_ready=True)
+            assert seen == [503]
+            assert ready() == 503
+            idle["v"] = True
+            assert ready() == 200
+        finally:
+            srv._httpd.server_close()
+
     def test_a_pool_worker_builds_nothing(self, tmp_path, monkeypatch):
         """A worker has adopted the owner's platform and holds no
         device: asking for a ladder there (an admin `config set` lands

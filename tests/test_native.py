@@ -74,6 +74,38 @@ class TestBuildRule:
         edited = self._src(tmp_path, self.SRC.replace("42", "43"))
         assert _build.build("answer", edited) not in (here, there)
 
+    def test_threads_of_one_process_build_at_once(self, tmp_path,
+                                                  monkeypatch):
+        """The first requests of a fresh checkout ask for a kernel from
+        several threads at once: each gets the library, none a
+        BuildError (which would send its algorithm to a portable path
+        for the life of the process)."""
+        import ctypes
+        import threading
+        from native import _build
+        monkeypatch.setattr(_build, "BUILD_DIR", str(tmp_path / "build"))
+        src = self._src(tmp_path)
+        start = threading.Barrier(8)
+        got, errs = [], []
+
+        def one():
+            start.wait()
+            try:
+                got.append(_build.build("answer", src))
+            except _build.BuildError as e:
+                errs.append(e)
+
+        threads = [threading.Thread(target=one) for _ in range(8)]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(120)
+        assert not any(t.is_alive() for t in threads)
+        assert errs == [] and len(set(got)) == 1 and len(got) == 8
+        assert ctypes.CDLL(got[0]).answer() == 42
+        assert not any(f.endswith(".tmp")
+                       for f in __import__("os").listdir(tmp_path / "build"))
+
     def test_a_refused_source_is_a_build_error(self, tmp_path, monkeypatch):
         from native import _build
         monkeypatch.setattr(_build, "BUILD_DIR", str(tmp_path / "build"))

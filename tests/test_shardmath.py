@@ -126,14 +126,17 @@ def backends(monkeypatch):
         fused, "verify_and_transform",
         note("lane", (np.zeros((1, K, 32), np.uint8),
                       (np.zeros((1, S), np.uint8),))))
-    monkeypatch.setattr(bitrot_io, "_hash_batch",
-                        note("host_hash", np.zeros((K, 32), np.uint8)))
+    for name in ("_hash_batch", "hash_rows"):
+        monkeypatch.setattr(bitrot_io, name,
+                            note("host_hash", np.zeros((K, 32), np.uint8)))
     return called
 
 
 # platform, chips, sets, algo, native HighwayHash, MTPU_COALESCE, MTPU_MESH
 #   -> encode: (plane, where the digests are computed, the coalescer key's
-#      head or "direct"), verify_transform: its key's head or what it calls
+#      head or "direct"), verify_transform: its key's head or what it
+#      calls; ("vt", "host_hash"): the digest-free rebuild rides the lane
+#      while the calling thread hashes
 CHOICES = [
     (HOST, 1, 1, "mxh256", True, True, "",
      ("host_fused", "kernel", ("pf",)), ["host_hash", "native"]),
@@ -164,20 +167,17 @@ CHOICES = [
     (TPU, 4, 4, "mxh256", True, True, "1",
      ("mesh", "framing", "direct"), ["host_hash", "mesh"]),
     (TPU, 4, 4, "highwayhash256S", True, True, "",   # host kernel wins
-     ("device_codec", "framing", ("enc", "dev")),
-     ["host_hash", "device_codec"]),
+     ("device_codec", "framing", ("enc", "dev")), ("vt", "host_hash")),
     (TPU, 4, 4, "highwayhash256S", False, True, "",  # none: the device's
      ("lane", "device", ("enc", "fd")), ("vt",)),
     (TPU, 1, 1, "sha256", True, True, "",
-     ("device_codec", "framing", ("enc", "dev")),
-     ["host_hash", "device_codec"]),
+     ("device_codec", "framing", ("enc", "dev")), ("vt", "host_hash")),
     (TPU, 1, 1, "sha256", True, False, "",
      ("device_codec", "framing", "direct"), ["host_hash", "device_codec"]),
     (WORKER, 4, 1, "mxh256", True, True, "",    # holds no chip: no mesh
      ("lane", "device", ("enc", "fd")), ("vt",)),
     (WORKER, 4, 4, "highwayhash256S", True, True, "",
-     ("device_codec", "framing", ("enc", "dev")),
-     ["host_hash", "device_codec"]),
+     ("device_codec", "framing", ("enc", "dev")), ("vt", "host_hash")),
     (WORKER, 1, 1, "mxh256", True, False, "",
      ("lane", "device", "direct"), ["lane"]),
 ]
@@ -226,18 +226,21 @@ def test_the_seams_choice(host, backends, platform, chips, sets, algo,
 
     # verify + transform of a degraded read / a heal batch
     del backends[:], co.seen[:]
-    co.make_handle = lambda: Handle((np.zeros((1, K, 32), np.uint8),
-                                     (np.zeros((1, S), np.uint8),)))
+    host_hashed = vt == ("vt", "host_hash")
+    co.make_handle = lambda: Handle((
+        None if host_hashed else np.zeros((1, K, 32), np.uint8),
+        (np.zeros((1, S), np.uint8),)))
     x = np.zeros((1, K, S), np.uint8)
     digests, rebuilt = sm.verify_transform(x, K, M, (1, 2), (0,), algo)
     assert digests.shape == (1, K, 32)
     assert [r.shape for r in rebuilt] == [(1, S)]
-    if vt == ("vt",):
+    if vt[0] == "vt":
         (sub,) = co.seen
-        assert sub["key"] == ("vt", K, M, (1, 2), (0,), algo, S)
+        assert sub["key"] == ("vt", K, M, (1, 2), (0,),
+                              None if host_hashed else algo, S)
         assert (sub["device"], sub["weight"], sub["fn"].device) == \
             (lane, 1, lane)
-        assert backends == []
+        assert backends == list(vt[1:])
     else:
         assert co.seen == [] and backends == vt
 
@@ -302,12 +305,16 @@ def test_a_host_digest_stays_on_its_thread_however_busy_the_lane(
         host, backends, monkeypatch, state):
     """Where a digest is computed is a function of platform, algorithm
     and MTPU_COALESCE: on a lane in any state a host-hashed digest is
-    submitted nowhere, and an on-chip mxh256 digest still rides."""
+    submitted nowhere (on a chip only its digest-free rebuild is), and
+    an on-chip mxh256 digest still rides."""
     co = coalesce.DispatchCoalescer()
     seen = []
 
     def submit(key, payload, fn, weight=None, device=0):
         seen.append(key)
+        if key[0] == "vt":
+            return Handle((None, (np.zeros((payload.shape[0], S),
+                                           np.uint8),)))
         return Handle(np.zeros((payload.shape[0], 32), np.uint8))
 
     monkeypatch.setattr(co, "submit", submit)
@@ -332,7 +339,8 @@ def test_a_host_digest_stays_on_its_thread_however_busy_the_lane(
             got, rebuilt = sm.verify_transform(
                 np.zeros((1, K, S), np.uint8), K, M, (1, 2), (0,), algo)
             assert got.shape == (1, K, 32) and len(rebuilt) == 1
-            assert seen == [], (platform, algo)
+            assert seen == ([("vt", K, M, (1, 2), (0,), None, S)]
+                            if platform == TPU else []), (platform, algo)
     finally:
         co.close()
 
