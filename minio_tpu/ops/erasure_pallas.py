@@ -35,13 +35,20 @@ DEFAULT_TILE_S = 8192
 FORCE_INTERPRET = False
 
 
-def _choose_tile_s(s: int) -> int:
-    """Largest multiple-of-128 tile <= DEFAULT_TILE_S that divides s
-    (s is a positive multiple of 128, so 128 always does)."""
-    for t in range(min(DEFAULT_TILE_S, s), 0, -128):
-        if s % t == 0:
-            return t
-    raise ValueError(f"shard size {s} is not a positive multiple of 128")
+def tile_plan(s: int) -> tuple[int, int]:
+    """(tile, steps) along a shard axis of s > 0 bytes.
+
+    A shard of DEFAULT_TILE_S or fewer bytes is one full-width block.  A
+    longer one runs in DEFAULT_TILE_S tiles, cdiv(s, tile) of them: where
+    the tile divides s (EC:8+4, 2+2 at 1 MiB) they cover it exactly; where
+    it does not (S = ceil(1 MiB / K) for K = 3, 5, 6, 7, 12) the last tile
+    is ragged.  Its out-of-range input columns hold whatever the block
+    buffer held, and the matmul is bytewise along S, so they only feed
+    output columns that lie past s, which the kernel's write-back masks.
+    """
+    if s <= DEFAULT_TILE_S:
+        return s, 1
+    return DEFAULT_TILE_S, pl.cdiv(s, DEFAULT_TILE_S)
 
 
 def _unpack_mm_pack(x, mat_ref, rows: int):
@@ -72,13 +79,14 @@ def _kernel_salted(salt_ref, mat_ref, x_ref, out_ref, *, rows: int):
 
 
 @functools.partial(jax.jit,
-                   static_argnames=("rows", "tile_s", "interpret"))
+                   static_argnames=("rows", "interpret"))
 def _pallas_gf_matmul(mat: jax.Array, x: jax.Array, rows: int,
-                      tile_s: int, interpret: bool = False,
+                      interpret: bool = False,
                       salt: jax.Array | None = None) -> jax.Array:
     b, c, s = x.shape
+    tile_s, steps = tile_plan(s)
     common = dict(
-        grid=(b, s // tile_s),
+        grid=(b, steps),
         out_specs=pl.BlockSpec((1, rows, tile_s), lambda i, j: (i, 0, j),
                                memory_space=pltpu.VMEM),
         out_shape=jax.ShapeDtypeStruct((b, rows, s), jnp.uint8),
@@ -107,12 +115,12 @@ def gf_matmul_blocks(mat_bits: jax.Array | np.ndarray, x: jax.Array,
     """Fused-kernel GF(2^8) batched matmul; drop-in for the XLA path.
 
     mat_bits: (8R, 8C) plane-major bit matrix; x: (B, C, S) uint8 shards.
-    On a TPU every geometry runs the kernel: a shard size that is not a
-    lane multiple (K = 3, 5, 6, 7, 12 over a 1 MiB block) is zero-padded
-    up to the next multiple of 128 and the output sliced back — GF
-    matmul is bytewise along S, so the pad's output is simply dropped.
-    Off-TPU the portable XLA path (ops/erasure_jax.py) serves, unless a
-    test sets FORCE_INTERPRET to run the kernel in the interpreter.
+    On a TPU every geometry runs the kernel on x as it is, at
+    `tile_plan(S)`: a shard size that no large tile divides (K = 3, 5, 6,
+    7, 12 over a 1 MiB block) is neither padded nor sliced, its last
+    tile ragged.  Off-TPU the portable XLA path (ops/erasure_jax.py)
+    serves, unless a test sets FORCE_INTERPRET to run the kernel in the
+    interpreter.
 
     salt: optional (1,) int32 — xors every input byte inside the kernel
     (benchmark protocol; production passes None and pays nothing).
@@ -128,9 +136,5 @@ def gf_matmul_blocks(mat_bits: jax.Array | np.ndarray, x: jax.Array,
             if salt is not None:
                 x = x ^ salt[0].astype(jnp.uint8)
             return erasure_jax._gf_matmul_blocks(mat, x, rows)
-        pad = -s % 128
-        if pad:
-            x = jnp.pad(x, ((0, 0), (0, 0), (0, pad)))
-        out = _pallas_gf_matmul(mat, x, rows, _choose_tile_s(s + pad),
-                                interpret=not on_tpu, salt=salt)
-        return out[:, :, :s] if pad else out
+        return _pallas_gf_matmul(mat, x, rows, interpret=not on_tpu,
+                                 salt=salt)

@@ -15,6 +15,7 @@ path: they run only where no native HighwayHash builds).
 """
 
 import os
+import re
 
 import numpy as np
 import pytest
@@ -88,9 +89,16 @@ def _check(compiled, kernels: int) -> str:
     return text
 
 
-# (K, S, rows): 8+4 and 2+2 tile as they are; K=6 (the server's default on
-# 12 drives) and K=12 (16-drive EC:4) have S = ceil(1 MiB / K), no multiple
-# of 128, and reach the kernel padded.
+def _kernel_outputs(text: str) -> list[str]:
+    """The result shape of each tpu_custom_call in a compiled program."""
+    return re.findall(r"= (u8\[[\d,]+\])\{[^}]*\} custom-call\(.*"
+                      r'custom_call_target="tpu_custom_call"', text)
+
+
+# (K, S, rows): 8+4 and 2+2 run whole 8 KiB lane tiles; K=6 (the server's
+# default on 12 drives) and K=12 (16-drive EC:4) have S = ceil(1 MiB / K),
+# which no such tile divides: the kernel takes S as it is (its last tile
+# ragged) and writes (B, rows, S), with no pad before it or slice after.
 @pytest.mark.parametrize("k,s,rows", [
     (8, 131072, 4), (2, 524288, 2), (6, 174763, 6), (12, 87382, 4)])
 def test_gf_matmul_runs_the_kernel_for_every_geometry(one_chip, k, s, rows):
@@ -101,7 +109,8 @@ def test_gf_matmul_runs_the_kernel_for_every_geometry(one_chip, k, s, rows):
     compiled = jax.jit(
         lambda mat, x: erasure_pallas.gf_matmul_blocks(mat, x, rows)
     ).lower(mat, x).compile()
-    _check(compiled, kernels=1)
+    text = _check(compiled, kernels=1)
+    assert _kernel_outputs(text) == [f"u8[{BATCH_BLOCKS},{rows},{s}]"]
     assert compiled.out_info.shape == (BATCH_BLOCKS, rows, s)
 
 
@@ -145,18 +154,19 @@ def test_ladder_steps_of_encode_and_get_digest(one_chip, k, m, s, blocks):
 
 
 # The one decode program a geometry (PR 35): its matrix an operand, a
-# rebuilt row an output of its own.  EC:6+6 reaches the kernel padded
-# (174,763 -> 174,848) and is sliced back before the rows are split.
+# rebuilt row an output of its own.  EC:6+6 reaches the kernel at
+# S = 174,763 as it is, 64 blocks the batch the degraded GETs run.
 @pytest.mark.parametrize("k,m,s,blocks", [
-    (6, 6, 174763, 16), (6, 6, 174763, 1), (8, 4, 131072, 16),
-    (2, 2, 524288, 32)])
+    (6, 6, 174763, 16), (6, 6, 174763, 1), (6, 6, 174763, 64),
+    (8, 4, 131072, 16), (2, 2, 524288, 32)])
 def test_ladder_steps_of_the_decode_program(one_chip, k, m, s, blocks):
     prog = fused.verify_transform_program(k, m, (), (0,), "mxh256")
     dec = prog.jit.lower(
         jax.ShapeDtypeStruct((blocks, k, s), jnp.uint8, sharding=one_chip),
         jax.ShapeDtypeStruct((8 * m, 8 * k), jnp.bfloat16,
                              sharding=one_chip)).compile()
-    _check(dec, kernels=1)
+    text = _check(dec, kernels=1)
+    assert _kernel_outputs(text) == [f"u8[{blocks},{m},{s}]"]
     digests, rows = dec.out_info
     assert digests.shape == (blocks, k, 32)
     assert [r.shape for r in rows] == [(blocks, s)] * m

@@ -4,10 +4,12 @@ Runs on the 8-device virtual CPU mesh configured in conftest.py; the same
 code paths execute on real TPU (bench.py / __graft_entry__.py).
 """
 
+import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from minio_tpu.ops import erasure_pallas
+from minio_tpu.ops import erasure_pallas, fused
 from minio_tpu.ops.erasure_cpu import ReedSolomonCPU
 from minio_tpu.ops.erasure_jax import ReedSolomonTPU
 
@@ -77,11 +79,15 @@ def test_pallas_interpret_matches_oracle():
         assert np.array_equal(parity[b], want), f"block {b}"
 
 
-@pytest.mark.parametrize("k,m,s", [(6, 6, 174763), (12, 4, 87382)])
+# S = ceil(1 MiB / K) for every K whose shard size no 8 KiB lane tile
+# divides: 6 (the server's default on 12 drives), 12 (16-drive EC:4),
+# 3 (6 drives), 5 (9 drives), 7 (11 drives).
+@pytest.mark.parametrize("k,m,s", [
+    (6, 6, 174763), (12, 4, 87382), (3, 3, 349526), (5, 4, 209716),
+    (7, 4, 149797)])
 def test_pallas_interpret_pads_awkward_shard_sizes(k, m, s):
-    # S = ceil(1 MiB / K) for K = 6 (the server's default on 12 drives)
-    # and K = 12 (16-drive EC:4) is no multiple of 128: the kernel runs
-    # on S padded up to one and the pad's output is sliced off.
+    # The kernel runs on S as it is, its last lane tile ragged: the
+    # columns past S it computes are never written.
     blocks = _random_blocks(2, k, s, seed=7)
     cpu = ReedSolomonCPU(k, m)
     erasure_pallas.FORCE_INTERPRET = True
@@ -94,6 +100,83 @@ def test_pallas_interpret_pads_awkward_shard_sizes(k, m, s):
     for b in range(2):
         want = np.stack(cpu.encode(list(blocks[b]))[k:])
         assert np.array_equal(parity[b], want), f"block {b}"
+
+
+def test_pallas_interpret_rebuilds_six_rows_at_ec6p6():
+    # The degraded GET's rebuild at EC:6+6 (T = 6: three data rows and
+    # three parity rows lost), through the digest-free decode program.
+    k, m, s = 6, 6, 174763
+    blocks = _random_blocks(2, k, s, seed=17)
+    cpu = ReedSolomonCPU(k, m)
+    full = np.stack([np.stack(cpu.encode(list(b))) for b in blocks])
+    sources, targets = (3, 4, 5, 9, 10, 11), (0, 1, 2, 6, 7, 8)
+    want = [cpu.reconstruct([None if i in targets else full[b, i]
+                             for i in range(k + m)]) for b in range(2)]
+    erasure_pallas.FORCE_INTERPRET = True
+    fused._verify_transform_jit.cache_clear()
+    try:
+        digests, rows = fused.verify_and_transform(
+            full[:, list(sources)], k, m, sources, targets, algo=None)
+        rows = [np.asarray(r) for r in rows]
+    finally:
+        erasure_pallas.FORCE_INTERPRET = False
+        fused._verify_transform_jit.cache_clear()
+    assert digests is None and len(rows) == len(targets)
+    for j, t in enumerate(targets):
+        assert np.array_equal(rows[j], np.stack([w[t] for w in want])), t
+
+
+@pytest.mark.parametrize("s", [131072, 524288])
+def test_tile_plan_covers_a_tileable_shard_exactly(s):
+    # EC:8+4 and 2+2 at 1 MiB: today's tile, no ragged tail.
+    assert erasure_pallas.tile_plan(s) == (8192, s // 8192)
+
+
+@pytest.mark.parametrize("k", range(2, 17))
+def test_tile_plan_is_large_at_every_k(k):
+    s = -(-(1 << 20) // k)
+    tile, steps = erasure_pallas.tile_plan(s)
+    assert tile >= 4096 and tile % 128 == 0
+    assert steps == -(-s // tile) <= -(-s // 4096)
+
+
+def _pallas_grids(jaxpr) -> tuple[list[str], list[tuple]]:
+    """Every primitive of a jaxpr, nested ones too, and the grid and
+    shard-input block of each pallas_call in it."""
+    prims, grids = [], []
+    for eqn in jaxpr.eqns:
+        prims.append(eqn.primitive.name)
+        if eqn.primitive.name == "pallas_call":
+            gm = eqn.params["grid_mapping"]
+            block = gm.block_mappings[1].block_shape
+            grids.append((tuple(gm.grid), tuple(
+                getattr(d, "block_size", d) for d in block)))
+            continue
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            p, g = _pallas_grids(sub)
+            prims += p
+            grids += g
+    return prims, grids
+
+
+@pytest.mark.parametrize("k,s,grid", [
+    (8, 131072, (4, 16)), (2, 524288, (4, 64)), (6, 174763, (4, 22)),
+    (12, 87382, (4, 11))])
+def test_kernel_takes_the_shard_as_it_is(k, s, grid):
+    # No pad before the kernel and no slice after it, at any K; the
+    # 8+4 and 2+2 grids are those the kernel has always run.
+    erasure_pallas.FORCE_INTERPRET = True
+    try:
+        jaxpr = jax.make_jaxpr(
+            lambda mat, x: erasure_pallas.gf_matmul_blocks(mat, x, 4))(
+                jax.ShapeDtypeStruct((32, 8 * k), jnp.bfloat16),
+                jax.ShapeDtypeStruct((4, k, s), jnp.uint8))
+    finally:
+        erasure_pallas.FORCE_INTERPRET = False
+    prims, grids = _pallas_grids(jaxpr.jaxpr)
+    assert grids == [(grid, (1, k, 8192))]
+    assert not {"pad", "slice", "dynamic_slice"} & set(prims), prims
+    assert [v.aval.shape for v in jaxpr.jaxpr.outvars] == [(4, 4, s)]
 
 
 def test_xla_path_serves_any_shard_size_off_tpu():
